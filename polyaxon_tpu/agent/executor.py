@@ -96,18 +96,6 @@ class LocalExecutor:
         self.store = plane.store
         self.in_process = in_process
         self._gangs: dict[str, _Gang] = {}
-        # Persistent-compile-cache opt-in (POLYAXON_TPU_COMPILE_CACHE=1
-        # without an explicit dir): resolve to ONE shared dir under the
-        # agent's artifacts root, so every gang this agent launches —
-        # in-process threads and subprocesses alike (both read the env)
-        # — shares warm XLA executables and a preemption-requeued run
-        # skips recompilation.
-        from polyaxon_tpu.runtime import compile_cache
-
-        if (os.environ.get(compile_cache.ENV_CACHE, "").strip() == "1"
-                and not os.environ.get(compile_cache.ENV_CACHE_DIR)):
-            os.environ[compile_cache.ENV_CACHE_DIR] = os.path.join(
-                plane.artifacts_root, compile_cache.SHARED_CACHE_DIRNAME)
 
     # ------------------------------------------------------------------ init
     def _run_init_phases(self, plan: V1LaunchPlan) -> None:
@@ -508,6 +496,7 @@ class LocalExecutor:
             tracking.log_outputs(
                 steps=result.steps, throughput=result.throughput,
                 wall_time=result.wall_time, param_count=result.param_count,
+                **result.program_outputs(),
                 # Same resume-audit field as the subprocess entrypoint
                 # (runtime/launch.py): None means cold start.
                 restored_from_step=result.restored_from_step,

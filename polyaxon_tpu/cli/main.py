@@ -54,9 +54,6 @@ def _echo_run(record, verbose: bool = False) -> None:
 @click.group()
 def cli():
     """polyaxon_tpu: TPU-native ML orchestration."""
-    from polyaxon_tpu.utils import apply_jax_platforms_override
-
-    apply_jax_platforms_override()
 
 
 # ------------------------------------------------------------------- config

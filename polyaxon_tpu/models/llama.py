@@ -310,7 +310,7 @@ def _pipelined_layers(cfg: LlamaConfig, body, layer_params, x: jax.Array) -> jax
     microbatch rebuilds them locally instead of threading them through
     the ppermute chain.
     """
-    from polyaxon_tpu.ops.ring import ambient_mesh
+    from polyaxon_tpu.parallel.compat import ambient_mesh
     from polyaxon_tpu.parallel.pipeline import pipeline_forward, stack_stages
 
     mesh = ambient_mesh()
@@ -753,19 +753,49 @@ def chunk_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
 
 # ------------------------------------------------- paged KV decode surface
 # vLLM-style paged attention, TPU-first: the KV cache is a shared pool
-# of fixed-size pages ([L, P, page, KV, Hd]) addressed through per-row
+# of fixed-size pages ([L, P, KV, page, Hd]) addressed through per-row
 # block tables, so serving memory scales with tokens actually held, not
 # slots x max_len reservations (the allocator lives in serving/paged.py;
 # the reference orchestrator has no serving path at all — net-new
 # surface, SURVEY.md §2). Page 0 is scratch: idle rows and unallocated
 # coordinates write there, and masks keep it unread.
+#
+# The layout is kv-head-major within a page because the decode kernel
+# (ops/paged_attention.py) DMAs one (page, Hd) tile per kv head, and a
+# Mosaic block's two trailing dims must be whole. It is known to the
+# four functions below and to the kernel, and to nothing else.
+
+def paged_pool_shape(cfg, n_pages: int, page_size: int) -> tuple:
+    return (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+
+
+def paged_page_size(cache: dict) -> int:
+    return cache["k"].shape[-2]
+
+
+def paged_gather(pages: jax.Array, page_ids: jax.Array) -> jax.Array:
+    """The pages ``page_ids`` ([..., n], already clamped to real ids) of
+    a pool ``[..., P, KV, page, Hd]`` as token-major KV
+    ``[..., n·page, KV, Hd]``."""
+    got = jnp.take(pages, page_ids, axis=-4)  # [..., n, KV, page, Hd]
+    got = jnp.swapaxes(got, -3, -2)
+    return got.reshape(*got.shape[:-4], -1, *got.shape[-2:])
+
+
+def paged_scatter(pool: jax.Array, kv: jax.Array, page_idx: jax.Array,
+                  off: jax.Array) -> jax.Array:
+    """Write token-major ``kv`` [L, T, KV, Hd] into the whole pool
+    [L, P, KV, page, Hd] at (page_idx[t], off[t]). (Two index arrays
+    split by a slice put the indexed dim first, hence the moveaxis.)"""
+    return pool.at[:, page_idx, :, off].set(jnp.moveaxis(kv, 1, 0))
+
 
 def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
     if cfg.sliding_window is not None:
         raise ValueError(
             "paged KV does not support sliding_window yet — the ring "
             "buffer already bounds that cache; use kv='dense'")
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = paged_pool_shape(cfg, n_pages, page_size)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -784,7 +814,6 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
     B = x.shape[0]
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     n_rep = H // KV
-    page = k_pages.shape[2]
 
     h = _norm(cfg, x, layer["attn_norm"])
     q = (h @ _w(layer["wq"], dt)).reshape(B, 1, H, Hd)
@@ -793,8 +822,9 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
     scaling = getattr(cfg, "rope_scaling", None)
     q = _rope(q, positions, cfg.rope_theta, scaling)
     k = _rope(k, positions, cfg.rope_theta, scaling)
-    k_pages = k_pages.at[write_page, write_off].set(k[:, 0])
-    v_pages = v_pages.at[write_page, write_off].set(v[:, 0])
+    # One layer's pool is [P, KV, page, Hd]; row b writes [KV, Hd].
+    k_pages = k_pages.at[write_page, :, write_off].set(k[:, 0])
+    v_pages = v_pages.at[write_page, :, write_off].set(v[:, 0])
 
     impl = getattr(cfg, "paged_attention_impl", "gather")
     if impl == "auto":
@@ -813,10 +843,8 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
             pos_vec).astype(dt)[:, None]
     else:
         gathered = jnp.maximum(tables, 0)  # [B, maxp] — scratch for holes
-        keys = k_pages[gathered].reshape(B, -1, KV, Hd)  # [B, maxp*page, .]
-        vals = v_pages[gathered].reshape(B, -1, KV, Hd)
-        keys = repeat_kv(keys, n_rep)
-        vals = repeat_kv(vals, n_rep)
+        keys = repeat_kv(paged_gather(k_pages, gathered), n_rep)
+        vals = repeat_kv(paged_gather(v_pages, gathered), n_rep)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, keys).astype(jnp.float32)
         logits = logits * (Hd ** -0.5)
         logits = jnp.where(valid, logits, -1e30)
@@ -849,7 +877,7 @@ def paged_coords(pos: jax.Array, tables: jax.Array, page: int):
 def decode_step_paged(
     cfg: LlamaConfig,
     params: dict,
-    cache: dict,  # {"k"/"v": [L, P, page, KV, Hd]}
+    cache: dict,  # {"k"/"v": [L, P, KV, page, Hd]}
     tokens: jax.Array,  # [B] int32
     pos: jax.Array,  # [B] int32 per-row position being written (-1 idle)
     tables: jax.Array,  # [B, maxp] int32 page ids (-1 = unallocated)
@@ -858,7 +886,7 @@ def decode_step_paged(
     with pages covering 0..p matches the dense ragged step at p exactly
     (parity-tested)."""
     dt = cfg.dtype
-    page = cache["k"].shape[2]
+    page = paged_page_size(cache)
     positions, write_page, write_off, valid = paged_coords(pos, tables, page)
     x = _embed(cfg, params, tokens, dt)[:, None, :]
 
@@ -896,8 +924,8 @@ def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
     pidx = jnp.maximum(page_ids[t // page_size], 0)
     off = t % page_size
     return {
-        "k": cache["k"].at[:, pidx, off].set(k_all),
-        "v": cache["v"].at[:, pidx, off].set(v_all),
+        "k": paged_scatter(cache["k"], k_all, pidx, off),
+        "v": paged_scatter(cache["v"], v_all, pidx, off),
     }
 
 
@@ -1000,8 +1028,8 @@ def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
         pidx = jnp.where(idx < real_len, pidx, 0)
     off = t % page_size
     return {
-        "k": cache["k"].at[:, pidx, off].set(k_suf),
-        "v": cache["v"].at[:, pidx, off].set(v_suf),
+        "k": paged_scatter(cache["k"], k_suf, pidx, off),
+        "v": paged_scatter(cache["v"], v_suf, pidx, off),
     }
 
 

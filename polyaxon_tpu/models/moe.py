@@ -60,7 +60,6 @@ from polyaxon_tpu.models.common import (
 from polyaxon_tpu.models.common import _embed_rows, _w, lm_logits
 from polyaxon_tpu.models.llama import _rope
 from polyaxon_tpu.ops.attention import dot_product_attention
-from polyaxon_tpu.parallel import compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,7 +310,8 @@ def _moe_ragged(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down):
     mesh axes left to GSPMD) when called under plain jit with an
     ambient mesh, and degrade to the single-shard ragged math (still
     einsum-free) when no ep axis exists."""
-    from polyaxon_tpu.ops.ring import _axis_bound, ambient_mesh
+    from polyaxon_tpu.ops.ring import _axis_bound
+    from polyaxon_tpu.parallel.compat import ambient_mesh
 
     B, S, D = x.shape
     tokens = x.reshape(B * S, D)
@@ -319,7 +319,7 @@ def _moe_ragged(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down):
     if _axis_bound("ep"):
         out, aux = _moe_ragged_sharded(
             cfg, tokens, router_w, w_gate, w_up, w_down,
-            ep=compat.axis_size("ep"), axis_name="ep")
+            ep=jax.lax.axis_size("ep"), axis_name="ep")
         return out.reshape(B, S, D), aux
 
     mesh = ambient_mesh()
@@ -331,7 +331,7 @@ def _moe_ragged(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down):
             ep=1, axis_name=None)
         return out.reshape(B, S, D), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(_moe_ragged_sharded, cfg, ep=ep, axis_name="ep"),
         mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec("ep", None),
@@ -672,7 +672,7 @@ def decode_chunk(
 def decode_step_paged(
     cfg: MoEConfig,
     params: dict,
-    cache: dict,  # {"k"/"v": [L, P, page, KV, Hd]}
+    cache: dict,  # {"k"/"v": [L, P, KV, page, Hd]}
     tokens: jax.Array,  # [B] int32
     pos: jax.Array,  # [B] int32 per-row position (-1 = idle)
     tables: jax.Array,  # [B, maxp] int32 page ids (-1 = unallocated)
@@ -680,11 +680,12 @@ def decode_step_paged(
     """Paged-pool ragged decode (llama's block-table semantics, the
     expert FFN in the MLP slot) — parity with ``decode_step_ragged``
     for rows whose pages cover 0..p."""
-    from polyaxon_tpu.models.llama import paged_attn_step, paged_coords
+    from polyaxon_tpu.models.llama import (paged_attn_step, paged_coords,
+                                           paged_page_size)
 
     _check_decodable(cfg)
     dt = cfg.dtype
-    page = cache["k"].shape[2]
+    page = paged_page_size(cache)
     positions, write_page, write_off, valid = paged_coords(pos, tables, page)
     x = _embed_rows(params["embed"], tokens, dt)[:, None, :]
 
@@ -709,7 +710,9 @@ def decode_step_paged(
 
 def paged_init_cache(cfg: MoEConfig, n_pages: int, page_size: int) -> dict:
     """Paged pool (MoE configs carry no sliding window)."""
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    from polyaxon_tpu.models.llama import paged_pool_shape
+
+    shape = paged_pool_shape(cfg, n_pages, page_size)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -762,6 +765,7 @@ from polyaxon_tpu.models.llama import (  # noqa: E402  (re-exported hooks)
     cb_admission,
     cb_validate,
     insert_cache_row,
+    paged_gather,
     paged_insert_prefill,
     paged_insert_suffix,
 )

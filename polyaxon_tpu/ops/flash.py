@@ -19,9 +19,15 @@ The backward pass under ``jax.custom_vjp`` has two implementations:
   the forward. Both recompute P from the saved logsumexp residual,
   keep every matmul on the MXU in f32 accumulation, and skip causal /
   out-of-window blocks with ``pl.when``.
-- **Chunked XLA** (CPU test mesh, non-tiling shapes, and the parity
+- **Chunked XLA** (the CPU test mesh's interpret mode, and the parity
   reference): recomputes attention probabilities one K/V block at a
   time from the same residual, so it also never materializes S×S.
+
+Interpret mode exists for the CPU test mesh only: ``resolve_interpret``
+refuses it on any other backend, so a chip run cannot land on an
+interpreted kernel. Shapes that do not tile give way to the einsum
+reference with a warning per shape (``implementation_for`` is the one
+decision, so callers can ask which implementation a shape gets).
 
 The reference delegates attention entirely to user frameworks
 (SURVEY.md §2b: no model math in-repo); this kernel is owned surface.
@@ -32,25 +38,39 @@ from __future__ import annotations
 import functools
 import json
 import os
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from polyaxon_tpu.parallel import compat
-from jax.experimental import pallas as pl
-
-try:  # pltpu only imports cleanly where libtpu/mosaic is present
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 NEG_INF = -1e30
 LANES = 128  # TPU lane width: scratch vectors are kept lane-broadcast
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+class KernelFallbackWarning(UserWarning):
+    """A Pallas kernel gave way to its reference implementation."""
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Whether a Pallas TPU kernel runs interpreted. Interpret mode is
+    the CPU backend's only way to run these kernels (the test mesh);
+    anywhere else it is refused, so no chip path can end up on an
+    interpreted kernel. ``interpret=False`` under the CPU backend is
+    the AOT case: compiling for a described TPU topology."""
+    on_cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return on_cpu
+    if interpret and not on_cpu:
+        raise ValueError(
+            "Pallas interpret mode is for the CPU backend only; the "
+            f"default backend here is `{jax.default_backend()}`")
+    return bool(interpret)
 
 
 def pick_block(seq: int, preferred: int) -> int:
@@ -92,11 +112,10 @@ FLASH_TILES_PATH = os.path.join(
 
 @functools.lru_cache(maxsize=1)
 def _committed_tile_picks() -> dict:
-    try:
-        with open(FLASH_TILES_PATH) as fh:
-            table = json.load(fh)
-    except (OSError, ValueError):  # uncommitted/corrupt: heuristic only
-        return {}
+    # Committed data: a missing or corrupt table is a broken checkout
+    # and raises, rather than quietly tuning from the heuristic alone.
+    with open(FLASH_TILES_PATH) as fh:
+        table = json.load(fh)
     return {k: v for k, v in table.items() if not k.startswith("_")}
 
 
@@ -169,16 +188,24 @@ def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
         if window:
             mask &= rows - cols < window
     if qseg_ref is not None:
-        seg = qseg_ref[0][:, None] == kseg_ref[0][None, :]
+        seg = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
         mask = seg if mask is None else mask & seg
     return mask
+
+
+def _segment_rows(segments: jax.Array) -> jax.Array:
+    """[B, S] ids as [B, 1, S], so a (1, 1, block) tile's two trailing
+    dims are whole: the Mosaic lowering refuses a (1, block) tile of a
+    [B, S] array for any B > 1 (its second-to-last dim is neither the
+    array's nor a multiple of 8)."""
+    return segments.astype(jnp.int32)[:, None, :]
 
 
 def _fwd_kernel(
     q_ref,  # [1, 1, block_q, D]
     k_ref,  # [1, 1, block_k, D]
     v_ref,  # [1, 1, block_k, D]
-    *rest,  # [qseg [1,block_q], kseg [1,block_k] when use_segments,]
+    *rest,  # [qseg [1,1,block_q], kseg [1,1,block_k] when use_segments,]
             # o [1,1,block_q,D], lse [1,1,block_q,1],
             # acc/m/l VMEM scratch
     causal: bool,
@@ -266,9 +293,8 @@ def _flash_fwd_pallas(
         block_k=block_k, window=window, use_segments=use_segments,
     )
     compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = compat.tpu_compiler_params(
-            pltpu,
+    if not interpret:
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         )
     scratch = [
@@ -290,8 +316,8 @@ def _flash_fwd_pallas(
                 lambda b_, h_, qi, ki, n_rep=n_rep: (b_, h_ // n_rep, ki, 0),
             ),
         ] + ([
-            pl.BlockSpec((1, block_q), lambda b_, h_, qi, ki: (b_, qi)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, qi, ki: (b_, ki)),
+            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, ki: (b_, 0, qi)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, qi, ki: (b_, 0, ki)),
         ] if use_segments else []),
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
@@ -304,7 +330,8 @@ def _flash_fwd_pallas(
         scratch_shapes=scratch,
         compiler_params=compiler_params,
         interpret=interpret,
-    )(q, k, v, *([segments.astype(jnp.int32)] * 2 if use_segments else []))
+        name="flash_fwd",
+    )(q, k, v, *([_segment_rows(segments)] * 2 if use_segments else []))
     return o, lse[..., 0]
 
 
@@ -422,7 +449,7 @@ def _bwd_dkdv_kernel(
     delta_ref,  # [1, 1, block_q, 1]
     lse_ref,    # [1, 1, block_q, 1]
     dlse_ref,   # [1, 1, block_q, 1]  cotangent of the lse output
-    *rest,      # [qseg [1,block_q], kseg [1,block_k] when use_segments,]
+    *rest,      # [qseg [1,1,block_q], kseg [1,1,block_k] when use_segments,]
                 # dk [1,1,block_k,D], dv [1,1,block_k,D], scratch x2
     causal: bool,
     scale: float,
@@ -565,17 +592,17 @@ def _flash_bwd_pallas(
     lse4 = lse[..., None]  # [B,H,Sq,1]
     dlse4 = dlse.astype(jnp.float32)[..., None]  # [B,H,Sq,1]
     use_segments = segments is not None
-    seg_args = ([segments.astype(jnp.int32)] * 2) if use_segments else []
+    seg_args = ([_segment_rows(segments)] * 2) if use_segments else []
 
     n_q, n_k = sq // block_q, sk // block_k
     common = dict(causal=causal, scale=scale, block_q=block_q,
                   block_k=block_k, window=window, use_segments=use_segments)
 
     def cparams(n_parallel: int, n_arbitrary: int):
-        if pltpu is None or interpret:
+        if interpret:
             return None
-        return compat.tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel",) * n_parallel
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * n_parallel
             + ("arbitrary",) * n_arbitrary)
 
     # dk/dv: grid (b, kv, k_block, group_rep, q_block); the two inner
@@ -597,8 +624,10 @@ def _flash_bwd_pallas(
             pl.BlockSpec((1, 1, block_q, 1), qmap),
             pl.BlockSpec((1, 1, block_q, 1), qmap),
         ] + ([
-            pl.BlockSpec((1, block_q), lambda b_, kvh, ki, r, qi: (b_, qi)),
-            pl.BlockSpec((1, block_k), lambda b_, kvh, ki, r, qi: (b_, ki)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b_, kvh, ki, r, qi: (b_, 0, qi)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda b_, kvh, ki, r, qi: (b_, 0, ki)),
         ] if use_segments else []),
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
@@ -616,6 +645,7 @@ def _flash_bwd_pallas(
         ],
         compiler_params=cparams(3, 2),
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, do, delta, lse4, dlse4, *seg_args)
 
     # dq: gridded like the forward, accumulating over k blocks.
@@ -638,8 +668,8 @@ def _flash_bwd_pallas(
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ] + ([
-            pl.BlockSpec((1, block_q), lambda b_, h_, qi, ki: (b_, qi)),
-            pl.BlockSpec((1, block_k), lambda b_, h_, qi, ki: (b_, ki)),
+            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, ki: (b_, 0, qi)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, qi, ki: (b_, 0, ki)),
         ] if use_segments else []),
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
@@ -649,6 +679,7 @@ def _flash_bwd_pallas(
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=cparams(3, 1),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, delta, lse4, dlse4, *seg_args)[0]
     return dq, dk, dv
 
@@ -689,6 +720,18 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, window,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def implementation_for(seq_q: int, seq_k: int, head_dim: int,
+                       block_q: int = 512, block_k: int = 512) -> str:
+    """Which implementation ``flash_attention`` runs for a shape:
+    ``"pallas"`` when the sequence tiles into >=128 blocks and head_dim
+    is lane-compatible, else ``"einsum"`` (the reference)."""
+    bq = _pick_block(seq_q, block_q)
+    bk = _pick_block(seq_k, block_k)
+    if bq < 128 or bk < 128 or (head_dim % 128 and head_dim != 64):
+        return "einsum"
+    return "pallas"
+
+
 def flash_attention(
     q: jax.Array,  # [B, Sq, H, D]
     k: jax.Array,  # [B, Sk, KV, D]
@@ -712,9 +755,10 @@ def flash_attention(
     ``segment_ids``: packed sequences — attention is additionally
     restricted to equal segment ids (requires Sq == Sk).
 
-    Falls back to the einsum reference (``ops.attention.xla_attention``)
-    when shapes don't tile (seq not divisible into >=128 blocks, or
-    head_dim not lane-aligned) — callers never need to special-case.
+    Gives way to the einsum reference (``ops.attention.xla_attention``),
+    with a warning per shape, when shapes don't tile (seq not divisible
+    into >=128 blocks, or head_dim not lane-aligned) — see
+    ``implementation_for``.
     """
     return flash_attention_with_lse(
         q, k, v, causal=causal, softmax_scale=softmax_scale,
@@ -740,7 +784,7 @@ def flash_attention_with_lse(
     ``[B, H, Sq]`` (f32) — the residual ring attention needs to merge
     per-block partial attentions exactly. Differentiable in both
     outputs (the lse cotangent flows through the bwd kernels). Same
-    fallback rule: non-tiling shapes use the einsum reference, which
+    give-way rule: non-tiling shapes use the einsum reference, which
     also returns lse."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -753,13 +797,12 @@ def flash_attention_with_lse(
         raise ValueError(
             f"segment_ids requires Sq == Sk, got {sq} vs {sk}")
     if bwd_impl not in (None, "pallas", "xla"):
-        # Validate before the shape-based fallback so a typo can't ride
+        # Validate before the shape-based give-way so a typo can't ride
         # silently on non-tiling shapes.
         raise ValueError(f"unknown bwd_impl `{bwd_impl}`")
     if block_q == "auto" or block_k == "auto":
-        # Trace-time auto-pick keyed on (seq, head_dim, VMEM budget) —
-        # sweepable against the fixed default (VERDICT r4 item 3). On a
-        # real TPU backend the committed per-chip pick table is
+        # Trace-time auto-pick keyed on (seq, head_dim, VMEM budget).
+        # On a TPU backend the committed per-chip pick table is
         # consulted first (compile-validated tiles beat the estimate).
         kind = (jax.devices()[0].device_kind
                 if jax.default_backend() == "tpu" else None)
@@ -768,25 +811,43 @@ def flash_attention_with_lse(
         block_k = abk if block_k == "auto" else block_k
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
-    if pltpu is None or bq < 128 or bk < 128 or (d % 128 and d != 64):
+    if implementation_for(sq, sk, d, block_q, block_k) == "einsum":
         from polyaxon_tpu.ops.attention import xla_attention_with_lse
 
+        # One warning per shape (the default warnings filter dedups on
+        # the message): on a chip this is the difference between the
+        # kernel and an O(S²) score tensor.
+        warnings.warn(
+            f"flash_attention: shape Sq={sq} Sk={sk} head_dim={d} does "
+            f"not tile (blocks {bq}x{bk}, need >=128 and head_dim 64 or "
+            "a multiple of 128) — running the einsum reference instead "
+            "of the Pallas kernel", KernelFallbackWarning, stacklevel=3)
         return xla_attention_with_lse(
             q, k, v, causal=causal, softmax_scale=softmax_scale,
             window=window, segment_ids=segment_ids)
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     if bwd_impl is None:
-        # Pallas bwd on real TPU; the chunked-XLA bwd is faster than an
-        # interpreted Pallas kernel on the CPU test mesh.
+        # Pallas bwd wherever the kernel compiles; the chunked-XLA bwd
+        # is faster than an interpreted Pallas kernel on the CPU mesh.
         bwd_impl = "xla" if interpret else "pallas"
     scale = softmax_scale if softmax_scale is not None else d**-0.5
 
+    def kernel(qT, kT, vT, *segments):
+        return _flash(qT, kT, vT, segments[0] if segments else None,
+                      causal, scale, bq, bk, interpret, window or 0,
+                      bwd_impl)
+
     # Kernel layout: heads-major [B, H, S, D] so (seq, head_dim) is the
-    # trailing (sublane, lane) tile.
-    qT = q.transpose(0, 2, 1, 3)
-    kT = k.transpose(0, 2, 1, 3)
-    vT = v.transpose(0, 2, 1, 3)
-    o, lse = _flash(qT, kT, vT, segment_ids, causal, scale, bq, bk,
-                    interpret, window or 0, bwd_impl)
+    # trailing (sublane, lane) tile. Under a multi-device mesh the call
+    # runs per shard of (batch, kv heads): each kv head's whole GQA
+    # group shards with it, so the kernel's h // n_rep map stays local.
+    batch_axes, head_axis = compat.kernel_axes(b, kv)
+    bhsd = P(batch_axes, head_axis, None, None)
+    seg_args = () if segment_ids is None else (segment_ids,)
+    o, lse = compat.shard_kernel(
+        kernel,
+        in_specs=(bhsd,) * 3 + (P(batch_axes, None),) * len(seg_args),
+        out_specs=(bhsd, P(batch_axes, head_axis, None)),
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3), *seg_args)
     return o.transpose(0, 2, 1, 3), lse

@@ -23,14 +23,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from polyaxon_tpu.parallel import compat
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # pltpu only imports cleanly where libtpu/mosaic is present
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from polyaxon_tpu.ops.flash import resolve_interpret
+from polyaxon_tpu.parallel import compat
 
 NEG_INF = -1e30
 LANES = 128
@@ -40,8 +38,8 @@ def _decode_kernel(
     tables_ref,  # scalar prefetch: [B, maxp] int32 page ids (-1 = hole)
     pos_ref,  # scalar prefetch: [B] int32 row positions (-1 = idle)
     q_ref,  # [1, 1, rep, Hd]
-    k_ref,  # [1, page, 1, Hd] — page selected by the index map
-    v_ref,  # [1, page, 1, Hd]
+    k_ref,  # [1, 1, page, Hd] — page selected by the index map
+    v_ref,  # [1, 1, page, Hd]
     o_ref,  # [1, 1, rep, Hd]
     acc_ref,  # VMEM [rep, Hd] f32
     m_ref,  # VMEM [rep, LANES] f32
@@ -65,7 +63,7 @@ def _decode_kernel(
     @pl.when((pos >= 0) & (tables_ref[b, j] >= 0) & (j * page <= pos))
     def _compute():
         q = q_ref[0, 0]  # [rep, Hd]
-        k = k_ref[0, :, 0]  # [page, Hd]
+        k = k_ref[0, 0]  # [page, Hd]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -82,7 +80,7 @@ def _decode_kernel(
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
 
-        v = v_ref[0, :, 0]  # [page, Hd]
+        v = v_ref[0, 0]  # [page, Hd]
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -99,60 +97,64 @@ def _decode_kernel(
 
 def paged_decode_attention(
     q: jax.Array,  # [B, H, Hd] — the single decode position per row
-    k_pages: jax.Array,  # [P, page, KV, Hd]
+    k_pages: jax.Array,  # [P, KV, page, Hd]
     v_pages: jax.Array,
     tables: jax.Array,  # [B, maxp] int32 (-1 = unallocated)
     pos: jax.Array,  # [B] int32 (-1 = idle row → zeros out)
     *,
-    interpret: bool | None = None,  # None = interpret off-TPU
+    interpret: bool | None = None,  # None = interpret on the CPU backend
 ) -> jax.Array:
     """Attention of each row's query against its pages (positions
     0..pos inclusive — the current step's K/V must already be written
-    to the pool). Returns [B, H, Hd]."""
-    if pltpu is None:
-        raise ImportError(
-            "paged_decode_attention needs jax.experimental.pallas.tpu "
-            "(unavailable in this jax install) — use "
-            "paged_attention_impl='gather'")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    to the pool). Returns [B, H, Hd].
+
+    The pool is laid out ``[P, KV, page, Hd]`` so a page block is
+    ``(1, 1, page, Hd)``: its two trailing dims are the array's own,
+    which is what the Mosaic lowering demands of a block that is not a
+    multiple of the (8, 128) tile. Under a multi-device mesh the call
+    runs per ``tp`` shard of the kv heads (``compat.shard_kernel``)."""
+    interpret = resolve_interpret(interpret)
     B, H, Hd = q.shape
-    P, page, KV, _ = k_pages.shape
+    KV = k_pages.shape[1]
+    _, head_axis = compat.kernel_axes(B, KV)
+    heads = P(None, head_axis, None)
+    pool = P(None, head_axis, None, None)
+    return compat.shard_kernel(
+        functools.partial(_paged_decode, interpret=interpret),
+        in_specs=(heads, pool, pool, P(), P()),
+        out_specs=heads,
+    )(q, k_pages, v_pages, tables.astype(jnp.int32), pos.astype(jnp.int32))
+
+
+def _paged_decode(q, k_pages, v_pages, tables, pos, *, interpret: bool):
+    B, H, Hd = q.shape
+    _, KV, page, _ = k_pages.shape
     maxp = tables.shape[1]
     rep = H // KV
-    scale = Hd ** -0.5
 
-    q4 = q.reshape(B, KV, rep, Hd)
-    grid = (B, KV, maxp)
-
-    kernel = functools.partial(_decode_kernel, scale=scale, page=page)
     compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = compat.tpu_compiler_params(
-            pltpu,
+    if not interpret:
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def page_map(b, h, j, tables_ref, pos_ref):
+        # The page DMA: block index along the pool axis comes from the
+        # row's block table (clamped — holes are skipped by the kernel
+        # predicate, the clamp only keeps the index legal).
+        return (jnp.maximum(tables_ref[b, j], 0), h, 0, 0)
+
+    def row_map(b, h, j, tables_ref, pos_ref):
+        return (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, KV, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, Hd),
-                         lambda b, h, j, tables_ref, pos_ref: (b, h, 0, 0)),
-            # The page DMA: block index along the pool axis comes from
-            # the row's block table (clamped — holes are skipped by the
-            # kernel predicate, the clamp only keeps the index legal).
-            pl.BlockSpec(
-                (1, page, 1, Hd),
-                lambda b, h, j, tables_ref, pos_ref: (
-                    jnp.maximum(tables_ref[b, j], 0), 0, h, 0)),
-            pl.BlockSpec(
-                (1, page, 1, Hd),
-                lambda b, h, j, tables_ref, pos_ref: (
-                    jnp.maximum(tables_ref[b, j], 0), 0, h, 0)),
+            pl.BlockSpec((1, 1, rep, Hd), row_map),
+            pl.BlockSpec((1, 1, page, Hd), page_map),
+            pl.BlockSpec((1, 1, page, Hd), page_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rep, Hd),
-            lambda b, h, j, tables_ref, pos_ref: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rep, Hd), row_map),
         scratch_shapes=[
             pltpu.VMEM((rep, Hd), jnp.float32),
             pltpu.VMEM((rep, LANES), jnp.float32),
@@ -160,10 +162,11 @@ def paged_decode_attention(
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, scale=Hd ** -0.5, page=page),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, Hd), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), q4, k_pages, v_pages)
+        name="paged_decode",
+    )(tables, pos, q.reshape(B, KV, rep, Hd), k_pages, v_pages)
     return out.reshape(B, H, Hd)

@@ -38,7 +38,6 @@ all other axes left to GSPMD (partial-manual sharding).
 from __future__ import annotations
 
 import functools
-import logging
 import warnings
 from typing import Optional
 
@@ -47,6 +46,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from polyaxon_tpu.parallel import compat
+from polyaxon_tpu.parallel.compat import ambient_mesh
 
 NEG_INF = -1e30
 
@@ -83,34 +83,6 @@ def _axis_bound(axis_name: str) -> bool:
         return False
 
 
-def ambient_mesh():
-    """The mesh entered via ``with mesh:`` (as the runtime loop does).
-
-    Reads the resource env through ``jax._src.mesh`` directly: the
-    public re-export (``jax.interpreters.pxla.thread_resources``) is
-    deprecated since 0.8.2, and ``get_abstract_mesh()`` is only
-    populated by ``jax.sharding.use_mesh``, not by ``with mesh:``.
-    """
-    try:
-        from jax._src import mesh as mesh_lib
-
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception as exc:
-        logging.getLogger(__name__).debug(
-            "thread_resources mesh probe failed (jax internals moved?): %s",
-            exc)
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception as exc:
-        logging.getLogger(__name__).debug(
-            "get_abstract_mesh probe failed: %s", exc)
-    return None
-
-
 def _merge(o_a, lse_a, o_b, lse_b):
     """Exact online-softmax combination of two partial attentions.
     o: [B, S, H, D] f32; lse: [B, H, S] f32."""
@@ -132,7 +104,7 @@ def _block_attn(q, k, v, *, causal, scale):
 
 def _ring_causal_zigzag(q, k, v, *, scale, axis_name):
     """Causal ring attention with zigzag placement (module docstring)."""
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_loc = q.shape[1]
     half = s_loc // 2
@@ -235,7 +207,7 @@ def _ring_dense(q, k, v, *, scale, axis_name):
     The permute issued by the final iteration is unused (~1/cp extra
     bandwidth, itself hidden under that step's compute).
     """
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     rotate = [(i, (i + 1) % cp) for i in range(cp)]
     attn = functools.partial(_block_attn, scale=scale, causal=False)
 
@@ -262,7 +234,7 @@ def _ring_einsum_causal(q, k, v, *, scale, axis_name):
     diagonal are masked, not skipped."""
     from polyaxon_tpu.ops.attention import repeat_kv
 
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     n_rep = h // k.shape[2]
@@ -368,7 +340,7 @@ def ring_attention(
     # measured that spelling at 3.2x the step time on dp2xcp4; see
     # docs/performance.md "Communication audit").
     spec = P(compat.batch_axes_in(mesh), axis_name, None, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_sharded, causal=causal, scale=scale, axis_name=axis_name
         ),

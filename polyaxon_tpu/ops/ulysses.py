@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from polyaxon_tpu.ops.ring import _axis_bound, ambient_mesh
+from polyaxon_tpu.ops.ring import _axis_bound
 from polyaxon_tpu.parallel import compat
+from polyaxon_tpu.parallel.compat import ambient_mesh
 
 
 def _ulysses_sharded(
@@ -41,7 +42,7 @@ def _ulysses_sharded(
 ) -> jax.Array:
     from polyaxon_tpu.ops.attention import repeat_kv, xla_attention
 
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n:
         raise ValueError(f"Ulysses needs heads ({h}) % axis size ({n}) == 0")
@@ -106,7 +107,7 @@ def ulysses_attention(
     # all-to-all passes (4 extra all-gathers/step on dp2xcp4; see
     # docs/performance.md "Communication audit").
     spec = P(compat.batch_axes_in(mesh), axis_name, None, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_sharded, causal=causal, scale=softmax_scale,
             axis_name=axis_name, attn_impl=attn_impl,

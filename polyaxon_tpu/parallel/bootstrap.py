@@ -50,7 +50,7 @@ def read_env_contract(env: Optional[dict[str, str]] = None) -> ProcessGroup:
 def initialize(group: Optional[ProcessGroup] = None) -> ProcessGroup:
     """Idempotently bootstrap the JAX process group from the env contract.
 
-    Single-process (the common local/emulator case) is a no-op; multi-
+    Single-process (the common local one-host case) is a no-op; multi-
     process calls ``jax.distributed.initialize`` against the coordinator
     over DCN.
     """

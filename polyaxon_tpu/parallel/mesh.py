@@ -129,7 +129,7 @@ def build_mesh(
             logger.info("hybrid mesh: dcn_axes=%s over %d hardware slices",
                         sorted(dcn_axes), slices)
         except ValueError:
-            # Devices without slice_index (CPU mesh, emulator): emulate the
+            # Devices without slice_index (the CPU mesh): imitate the
             # slice granularity by putting DCN axes slowest-varying so each
             # contiguous device block is one "slice".
             perm = sorted(range(len(names)), key=lambda i: names[i] not in dcn_axes)
@@ -149,7 +149,7 @@ def build_mesh(
                 allow_split_physical_axes=bool(mesh_spec and mesh_spec.allow_split_physical_axes),
             )
         except Exception:
-            # CPU meshes / odd emulated topologies: fall back to a plain
+            # CPU meshes / odd virtual topologies: fall back to a plain
             # row-major reshape (no ICI assignment to optimize anyway).
             device_array = np.asarray(devices).reshape(sizes)
     return Mesh(device_array, names)

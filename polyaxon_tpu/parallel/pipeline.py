@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from polyaxon_tpu.parallel import compat
 
 
 def spmd_pipeline(
@@ -50,7 +49,7 @@ def spmd_pipeline(
 ) -> jax.Array:
     """Run the pipeline INSIDE shard_map; returns [n_micro, mb, ...]
     stage outputs, valid on the LAST stage (callers psum-select)."""
-    n_stages = compat.axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -142,7 +141,7 @@ def pipeline_forward(
             axis_name=axis_name, double_buffer=double_buffer)
         return outs[None]  # [1(stage), n_micro, mb, ...]
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         sharded,
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -169,3 +169,18 @@ def batch_spec(mesh: Mesh, rules: Rules, ndim: int = 2) -> P:
 
 def param_bytes(params: Any) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+
+
+def bytes_per_device(tree: Any) -> dict[int, int]:
+    """Bytes of ``tree`` resident on each addressable device, by device
+    id. Says at a glance whether a tree is sharded (each device holds a
+    fraction), replicated (each holds all of it), or sitting whole on
+    the first device — which code that has only seen one chip can do
+    without anyone noticing."""
+    held: dict[int, int] = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] = (held.get(shard.device.id, 0)
+                                     + shard.data.size * leaf.dtype.itemsize)
+    return dict(sorted(held.items()))
+

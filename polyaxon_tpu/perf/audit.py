@@ -98,6 +98,7 @@ def audit_point(
         raise ValueError(
             f"point {point.name!r} needs {n_needed} devices, have "
             f"{len(devices)} (CI runs on the 8-device virtual CPU mesh)")
+    hlo_lib.require_modelled_device(devices[0])
     mesh = build_mesh(axes=dict(point.axes), devices=devices[:n_needed])
     rules = rules_for_mesh(mesh)
 
@@ -190,6 +191,7 @@ def audit_point_aot(point: AuditPoint, topology_name: str = "v5e:2x4",
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=topology_name)
     devices = list(topo.devices)
+    hlo_lib.require_modelled_device(devices[0])
     mesh = build_mesh(axes=dict(point.axes), devices=devices)
     rules = rules_for_mesh(mesh)
     overrides: dict[str, Any] = {"max_seq_len": point.seq_len}

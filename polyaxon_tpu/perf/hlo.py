@@ -99,6 +99,43 @@ COLLECTIVE_KINDS = (
 PEAK_FLOPS_PER_S = 1.97e14   # bf16 peak per chip
 ICI_BYTES_PER_S = 4.5e10     # per-chip interconnect bandwidth
 
+
+def require_modelled_device(device) -> None:
+    """The overlap model's constants are one chip's. HLO compiled for
+    the CPU mesh is ranked with them by design (the budget floors were
+    taken the same way), but a TPU of another kind is refused rather
+    than modelled with a v5e's peak."""
+    from polyaxon_tpu.runtime.flops import peak_flops
+
+    if device.platform == "tpu" and peak_flops(device) != PEAK_FLOPS_PER_S:
+        raise ValueError(
+            f"perf/hlo.py models overlap with v5e constants; HLO for "
+            f"`{device.device_kind}` needs its own PEAK_FLOPS_PER_S / "
+            "ICI_BYTES_PER_S")
+
+
+# The named scope a pallas_call's `name=` opens sits right before
+# `/pallas_call` in the custom call's op_name, wrapped by whatever
+# transform traced it: `.../flash_fwd/pallas_call`,
+# `.../jvp(flash_fwd)/pallas_call`,
+# `.../transpose(jvp(flash_bwd_dq))/pallas_call`.
+_PALLAS_CALL_RE = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*?/(?:\w+\()*([\w\-]+)\)*/pallas_call"')
+
+
+def pallas_kernels(hlo_text: str) -> dict[str, int]:
+    """Mosaic kernels in a compiled module, by ``pallas_call`` name →
+    number of call sites. This is what tells a program that runs the
+    kernel from one that took a reference path: a kernel that gave way
+    (or ran interpreted) leaves no ``tpu_custom_call``."""
+    found: dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        match = _PALLAS_CALL_RE.search(line)
+        if match:
+            found[match.group(1)] = found.get(match.group(1), 0) + 1
+    return found
+
 # f8 variants first so "f8e4m3fn" doesn't half-match "f8".
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,

@@ -1,86 +1,84 @@
-"""Persistent XLA compilation cache wiring.
+"""Where the persistent XLA compilation cache lives.
 
-Every preemption-requeue (restartPolicy/backoff machinery) restarts the
-gang process and repays full XLA compilation before the first step can
-dispatch — minutes of device idle that the checkpoint-resume machinery
-already made otherwise cheap. JAX ships a persistent compilation cache
-(``jax_compilation_cache_dir``) keyed on the compiled computation's
-fingerprint; pointing it at a directory that survives restarts makes
-the second attempt's compile a disk load.
+Every restart of a run or a server repays full XLA compilation before
+its first step; JAX's persistent cache turns the second compile into a
+disk load, provided the directory is the same both times: a cache
+that moves is never found again. One rule, for training, serving and
+every bench script alike:
 
-Resolution order (first hit wins):
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of it is all
+   there is. Nothing here touches the config, whatever else is set, so
+   a cache placed from outside (a CI volume, the operator's shell) is
+   the one in use.
+2. Unset, and the backend is a TPU: one fixed directory inside the
+   checkout, ``<repo>/.jax-compile-cache`` — never a temp name, pid or
+   timestamp, so the next process finds it.
+3. Unset, any other backend: off. XLA:CPU's AOT reload of sharded
+   executables is unreliable on oversubscribed hosts (tests/conftest.py
+   documents cache hits hanging at collective rendezvous).
 
-1. ``runtime.compile_cache_dir`` in the run spec;
-2. ``POLYAXON_TPU_COMPILE_CACHE_DIR`` — explicit directory;
-3. ``POLYAXON_TPU_COMPILE_CACHE=1`` — opt-in switch; the agent's
-   executor resolves it to a shared ``.jax-compile-cache`` under its
-   artifacts root so all runs of one agent share warm entries.
-
-``POLYAXON_TPU_COMPILE_CACHE=0`` force-disables regardless of the
-above. The cache is OPT-IN (off when nothing is set): XLA:CPU's AOT
-reload is unreliable on oversubscribed hosts (tests/conftest.py
-documents sharded cache-hit executables hanging at collective
-rendezvous), so only runs that ask for it pay that risk.
+``enable()`` is called by each entry point that compiles
+(``run_jaxjob``, ``ServingServer``); it initializes the backend, so it
+belongs to the process that owns the device.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
-from typing import Iterator, Optional
+from typing import Optional
 
 logger = logging.getLogger(__name__)
 
-ENV_CACHE_DIR = "POLYAXON_TPU_COMPILE_CACHE_DIR"
-ENV_CACHE = "POLYAXON_TPU_COMPILE_CACHE"
-# The executor's shared default, relative to the agent's artifacts root.
-SHARED_CACHE_DIRNAME = ".jax-compile-cache"
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax-compile-cache")
 
 
-def resolve_cache_dir(config_dir: Optional[str] = None) -> Optional[str]:
-    """The cache directory this process should use, or None (disabled)."""
-    if os.environ.get(ENV_CACHE, "").strip() == "0":
-        return None
-    return config_dir or os.environ.get(ENV_CACHE_DIR) or None
+# Requests this process made of the persistent cache, as jax's own
+# monitoring events count them ("compile_requests_use_cache" = asked,
+# "cache_hits" = answered from disk).
+_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "requests",
+           "/jax/compilation_cache/cache_hits": "hits"}
+_counts = {"requests": 0, "hits": 0}
+_listening = False
 
 
-@contextlib.contextmanager
-def compilation_cache(cache_dir: Optional[str]) -> Iterator[Optional[str]]:
-    """Scope the persistent compilation cache to one run.
+def _count(event: str, **_) -> None:
+    if event in _EVENTS:
+        _counts[_EVENTS[event]] += 1
 
-    The knobs are process-global jax config; save/restore keeps one
-    run's opt-in from silently flipping every later run in the same
-    process (the in-process executor runs many)."""
-    if not cache_dir:
-        yield None
-        return
+
+def stats() -> dict:
+    """The cache directory in effect (None = off) and what this process
+    has asked of it so far: a run can then say whether a short compile
+    was a disk load."""
     import jax
-    from jax.experimental.compilation_cache import (
-        compilation_cache as jax_cc,
-    )
 
-    os.makedirs(cache_dir, exist_ok=True)
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min_time = jax.config.jax_persistent_cache_min_compile_time_secs
-    prev_min_size = jax.config.jax_persistent_cache_min_entry_size_bytes
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache every executable: the default 1s floor would skip exactly
-    # the small-model compiles the tests and smoke tiers exercise.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax initializes its file cache AT MOST ONCE per process, and any
-    # compile that ran before the dir was configured latches it to
-    # "disabled"; reset so this run's config is actually read (and
-    # again on exit so later runs don't keep writing into ours).
-    jax_cc.reset_cache()
-    logger.info("persistent compilation cache at %s", cache_dir)
-    try:
-        yield cache_dir
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prev_min_time)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          prev_min_size)
-        jax_cc.reset_cache()
+    return {"dir": jax.config.jax_compilation_cache_dir, **_counts}
+
+
+def enable() -> Optional[str]:
+    """Apply the rule above; returns the directory in use, or None."""
+    global _listening
+    import jax
+
+    if not _listening:
+        jax.monitoring.register_event_listener(_count)
+        _listening = True
+    placed = os.environ.get(ENV_JAX_CACHE_DIR)
+    if placed:
+        return placed
+    if jax.default_backend() != "tpu":
+        return None
+    if jax.config.jax_compilation_cache_dir != REPO_CACHE_DIR:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+        # jax initializes its file cache at most once per process, and
+        # a compile that ran before the directory was set latches it to
+        # "disabled"; reset so the setting is read.
+        compilation_cache.reset_cache()
+        logger.info("persistent compilation cache at %s", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR

@@ -39,10 +39,6 @@ class RuntimeConfig(BaseModel):
     # to `prefetch` ready batches queued. 0 = synchronous (the host
     # pays generation + transfer inside every step).
     prefetch: int = Field(default=2, ge=0)
-    # Persistent XLA compilation cache (runtime/compile_cache.py):
-    # a directory here (or via POLYAXON_TPU_COMPILE_CACHE_DIR) lets
-    # requeued/preempted runs skip recompilation. None = env-driven.
-    compile_cache_dir: Optional[str] = None
     # Attention/remat knobs forwarded to the model config when supported.
     remat: Optional[str] = None
     attention_impl: Optional[str] = None

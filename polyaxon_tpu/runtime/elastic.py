@@ -251,22 +251,35 @@ def prewarm(job, target_devices: int, axes: dict[str, int], *,
 
     Modes (``POLYAXON_TPU_ELASTIC_PREWARM``):
 
-    - ``subprocess`` (default): a contained AOT child actually compiles
-      and runs one step of the job on the target mesh — a hung or
-      crashed compile cannot take the agent down with it;
-    - ``inline``: in-process structural validation (mesh build, sharding
-      rules, batch divisibility) without paying a compile — the cheap
-      mode the CI drill uses;
+    - ``subprocess`` (default off-TPU): a contained child actually
+      compiles and runs one step of the job on the target mesh — a hung
+      or crashed compile cannot take the agent down with it;
+    - ``inline`` (default on a TPU): in-process structural validation
+      (mesh build, sharding rules, batch divisibility) without paying a
+      compile — the cheap mode the CI drill uses;
     - ``skip``: trust the topology (operators who have pre-baked the
       compile cache).
+
+    A chip belongs to one process, and this one holds it: a child that
+    asks for the devices fails or hangs until the timeout. So on a TPU
+    backend the default is ``inline`` (the next segment then compiles
+    in this process, as every segment does), and asking for
+    ``subprocess`` there is refused at once instead of after a hang.
     """
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
     mode = (mode or os.environ.get(ENV_ELASTIC_PREWARM, "")
-            or "subprocess").strip().lower()
+            or ("inline" if on_tpu else "subprocess")).strip().lower()
     if mode == "skip":
         return {"ok": True, "mode": "skip", "devices": int(target_devices)}
     if mode == "inline":
         return _prewarm_inline(job, target_devices, axes, devices=devices)
     if mode == "subprocess":
+        if on_tpu:
+            raise PrewarmError(
+                "prewarm mode `subprocess` cannot work on a TPU backend: "
+                "this process holds the chips the child would need")
         return _prewarm_subprocess(
             job, target_devices, axes,
             timeout=DEFAULT_PREWARM_TIMEOUT if timeout is None else timeout)

@@ -15,8 +15,7 @@ import logging
 from typing import Optional
 
 # bf16 peak matmul throughput per chip, for MFU. Keyed by substring of
-# jax's device_kind; unknown kinds (e.g. the CPU test mesh) report
-# mfu=null rather than a fabricated number.
+# jax's device_kind (published per-chip figures, Google Cloud TPU docs).
 PEAK_FLOPS = {
     "v5 lite": 197e12,  # v5e ("TPU v5 lite")
     "v5e": 197e12,
@@ -26,12 +25,21 @@ PEAK_FLOPS = {
 }
 
 
-def peak_flops(device_kind: str) -> Optional[float]:
-    kind = (device_kind or "").lower()
+def peak_flops(device) -> Optional[float]:
+    """bf16 peak FLOP/s of a ``jax.Device``. A TPU whose kind is not in
+    the table is an error, not a default: an MFU against a guessed peak
+    is a wrong number. Other platforms (the CPU test mesh) have no
+    peak, and callers report ``mfu`` as null there."""
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
     for key, peak in PEAK_FLOPS.items():
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no bf16 peak recorded for TPU device_kind `{device.device_kind}`"
+        " — add it to runtime/flops.py PEAK_FLOPS (with its source) "
+        "before reporting MFU on this chip")
 
 
 def train_flops_per_token(model: str, seq: int,

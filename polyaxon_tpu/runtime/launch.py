@@ -36,9 +36,7 @@ def main() -> int:
         force=True,
     )
     from polyaxon_tpu.parallel import overlap
-    from polyaxon_tpu.utils import apply_jax_platforms_override
 
-    apply_jax_platforms_override()
     # Pin the latency-hiding scheduler before the backend initializes
     # (bootstrap.initialize below) so collective overlap — and with it
     # the budgeted overlap_ratio floors — cannot silently regress with
@@ -77,6 +75,7 @@ def main() -> int:
                 throughput_unit=f"{result.unit}/sec",
                 wall_time=result.wall_time,
                 param_count=result.param_count,
+                **result.program_outputs(),
                 # Preemption-requeue proof: a requeued attempt reports
                 # where its checkpoint restore landed (None → cold
                 # start), so the plane can audit that resume actually

@@ -1,6 +1,6 @@
 """The JAXJob training loop: mesh → data → compiled step → metrics/
-checkpoints. Single code path from the 1-chip emulator to multi-host
-slices (only the mesh and the env contract change — SURVEY.md §7 step 2).
+checkpoints. Single code path from one chip to multi-host slices (only
+the mesh and the env contract change — SURVEY.md §7 step 2).
 """
 
 from __future__ import annotations
@@ -20,8 +20,9 @@ from polyaxon_tpu.obs import flight as obs_flight
 from polyaxon_tpu.obs import metrics as obs_metrics
 from polyaxon_tpu.obs import trace as obs_trace
 from polyaxon_tpu.parallel import build_mesh, rules_for_mesh
-from polyaxon_tpu.parallel.sharding import param_bytes
+from polyaxon_tpu.parallel.sharding import bytes_per_device, param_bytes
 from polyaxon_tpu.polyflow.runs import V1JAXJob, V1JaxCheckpointing
+from polyaxon_tpu.runtime import compile_cache
 from polyaxon_tpu.runtime import data as data_lib
 from polyaxon_tpu.runtime.checkpoint import (CheckpointManager,
                                              TieredCheckpointManager)
@@ -55,10 +56,36 @@ class TrainResult:
     # ~0 when the prefetcher keeps up, ≈ generation+transfer time when
     # the input pipeline is the bottleneck.
     input_wait_ms: float = 0.0
-    # Wall time of the warm-up train_step dispatch+completion (XLA
-    # compile dominates); drops to executable-load time on a
+    # Wall time of lowering + compiling the train step (the one
+    # compilation of the run); drops to executable-load time on a
     # persistent-compile-cache hit.
     compile_time_s: float = 0.0
+    # The persistent compile cache as this run used it
+    # (runtime.compile_cache.stats): directory, requests, hits.
+    compile_cache: dict = dataclasses.field(default_factory=dict)
+    # Mosaic kernels in the compiled step, by pallas_call name → call
+    # sites (perf.hlo.pallas_kernels). Empty means every attention ran
+    # a reference path: on the CPU mesh (interpret mode), or on a chip
+    # because a shape gave way.
+    step_kernels: dict[str, int] = dataclasses.field(default_factory=dict)
+    # Parameter bytes resident on each addressable device, by device id
+    # (parallel.sharding.bytes_per_device): sharded, replicated, or all
+    # on the first.
+    param_bytes_per_device: dict[int, int] = dataclasses.field(
+        default_factory=dict)
+    # Peak device memory of this process's first device, where the
+    # backend reports it (None on the CPU mesh).
+    peak_hbm_bytes: Optional[int] = None
+
+    def program_outputs(self) -> dict:
+        """What the run says about its compiled program and where it
+        lived — the part of a run's outputs both entry points (the
+        in-process executor and runtime/launch.py) log alike."""
+        return {"compile_time_s": self.compile_time_s,
+                "compile_cache": self.compile_cache,
+                "step_kernels": self.step_kernels,
+                "param_bytes_per_device": self.param_bytes_per_device,
+                "peak_hbm_bytes": self.peak_hbm_bytes}
 
 
 def _model_config_cls(model_name: str):
@@ -128,15 +155,12 @@ def run_jaxjob(
                                               component="runtime")
         close_tracer = True
 
-    from polyaxon_tpu.runtime import compile_cache
-
-    with compile_cache.compilation_cache(
-            compile_cache.resolve_cache_dir(cfg.compile_cache_dir)):
-        return _run_jaxjob(job, cfg, artifacts_dir=artifacts_dir,
-                           on_metrics=on_metrics, devices=devices,
-                           mesh_axes=mesh_axes,
-                           should_stop=should_stop, tracer=tracer,
-                           close_tracer=close_tracer)
+    compile_cache.enable()
+    return _run_jaxjob(job, cfg, artifacts_dir=artifacts_dir,
+                       on_metrics=on_metrics, devices=devices,
+                       mesh_axes=mesh_axes,
+                       should_stop=should_stop, tracer=tracer,
+                       close_tracer=close_tracer)
 
 
 def _run_jaxjob(
@@ -234,6 +258,7 @@ def _run_jaxjob(
         n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
         logger.info("model=%s params=%.2fM bytes=%.1fMB", cfg.model, n_params / 1e6,
                     param_bytes(state["params"]) / 1e6)
+        params_per_device = bytes_per_device(state["params"])
 
         ckpt: Optional[CheckpointManager] = None
         restored_from = None
@@ -325,43 +350,130 @@ def _run_jaxjob(
         last_eval: dict[str, float] = {}
         evaled_at = -1  # state["step"] value the last eval scored
         step_rng = jax.random.key(cfg.seed + 17)
-        # Warm up compile outside the timed window; the dispatch-to-
-        # ready wall of this first step IS the compile cost (execution
-        # of one step rides along, noise next to XLA), emitted as
-        # compile_time_s so cache-hit restarts are attributable.
+        # Compile the step ONCE, ahead of time, outside the timed
+        # window: the loop then holds the executable it runs, so it can
+        # say which kernels are in it, a later shape or layout drift
+        # raises instead of recompiling behind the run's back, and
+        # compile_time_s is the compile alone, so cache-hit restarts
+        # are attributable.
+        from polyaxon_tpu.perf.hlo import pallas_kernels
+
         first_batch = next(batches)
         with _span(tracer, "jit_compile") as sp:
             t_compile = time.perf_counter()
-            state, metrics = train_step(state, first_batch, step_rng)
-            # polycheck: ignore[hotpath-host-sync] -- deliberate: the dispatch-to-ready wall of this first step IS the measured compile cost
-            jax.block_until_ready(metrics["loss"])
+            train_step = train_step.lower(
+                state, first_batch, step_rng).compile()
             compile_time_s = time.perf_counter() - t_compile
+            step_kernels = pallas_kernels(train_step.as_text())
             if sp is not None:
-                sp.set(compile_time_s=round(compile_time_s, 3))
-
+                sp.set(compile_time_s=round(compile_time_s, 3),
+                       step_kernels=step_kernels)
+        logger.info("train step compiled in %.1fs, kernels=%s",
+                    compile_time_s, step_kernels or "none")
         # Per-step MFU self-reporting (SURVEY §5.1): every emission
         # carries tokens/sec + achieved TFLOPs/chip, and MFU when both
         # the analytic FLOPs/token and the chip's peak are known
-        # (CPU mesh → flops known, peak unknown → mfu omitted).
+        # (CPU mesh → flops known, no peak → mfu omitted; a TPU kind
+        # missing from the peaks table raises).
         from polyaxon_tpu.runtime.flops import peak_flops, train_flops_per_token
 
         n_chips = int(mesh.devices.size)
         # polycheck: ignore[hotpath-host-sync] -- n_params is a host-side sum of static leaf sizes; one-shot setup before the loop
         flops_unit = (train_flops_per_token(cfg.model, seq, int(n_params))
                       if model_def.unit == "tokens" else None)
-        peak = peak_flops(getattr(jax.devices()[0], "device_kind", ""))
+        peak = peak_flops(mesh.devices.flat[0])
         t_emit = time.perf_counter()
         # polycheck: ignore[hotpath-wallclock] -- observability timestamp: span wall-clock twin of t_emit; never feeds training state or replay
         t_emit_wall = time.time()  # wall twin of t_emit for step spans
-        # The warm-up step above consumed batch `start_step` and
-        # advanced the state — it is a REAL training step, so the first
-        # emission window starts at 1, making step windows contiguous
-        # from `start_step` across restore/resize segment boundaries
-        # (the oracle's loss_continuity invariant reads these windows).
-        steps_since_emit = 1
+        steps_since_emit = 0
         emitted_compile = False
         wait_window = 0.0  # host seconds blocked on data, per emission
         wait_total = 0.0   # ... over all timed steps
+
+        def emit(step: int, metrics: dict) -> None:
+            """One emission window: the steps since the last emission,
+            as metrics (on_metrics), one `step` span and one histogram
+            sample."""
+            nonlocal t_emit, t_emit_wall, steps_since_emit, wait_window
+            nonlocal emitted_compile
+            # polycheck: ignore[hotpath-host-sync] -- deliberate emission-window materialization at log_every cadence, off the per-step path
+            vals = {k: float(v) for k, v in metrics.items()}
+            # Rolling window since the last emission; block so the
+            # window covers completed device work, not dispatch.
+            # polycheck: ignore[hotpath-host-sync] -- deliberate emission-window sync (see comment above): throughput must cover completed device work
+            jax.block_until_ready(metrics["loss"])
+            window = time.perf_counter() - t_emit
+            if window > 0 and steps_since_emit:
+                ups = units_per_step * steps_since_emit / window
+                vals[f"{model_def.unit}_per_sec"] = ups
+                vals["step_time_ms"] = 1e3 * window / steps_since_emit
+                # Host time blocked on next(batches), per step:
+                # ~0 when prefetch keeps up; ≈ generation+transfer
+                # when the input pipeline is the bottleneck.
+                vals["input_wait_ms"] = (1e3 * wait_window
+                                         / steps_since_emit)
+                if flops_unit:
+                    achieved = ups * flops_unit / n_chips
+                    vals["tflops_per_sec_per_chip"] = achieved / 1e12
+                    if peak:
+                        vals["mfu"] = achieved / peak
+            if not emitted_compile:
+                # One-shot: the compile wall, so a metric stream can
+                # attribute a cheap restart to the persistent compile
+                # cache.
+                vals["compile_time_s"] = compile_time_s
+                emitted_compile = True
+            # The emission window is one `step` span on the
+            # timeline (reusing the already-derived step_time_ms /
+            # input_wait_ms) and one histogram sample — per-window,
+            # not per-step, so tracing cost stays off the hot path.
+            if steps_since_emit and window > 0:
+                obs_metrics.training_step_hist().observe(
+                    window / steps_since_emit)
+            if tracer is not None and steps_since_emit:
+                tracer.record_completed(
+                    # polycheck: ignore[hotpath-wallclock] -- observability timestamp: span end on the wall-clock timeline, per-window not per-step
+                    "step", start=t_emit_wall, end=time.time(),
+                    parent_id=(run_span.span_id if run_span is not None
+                               else None),
+                    attributes={
+                        "from_step": step - steps_since_emit + 1,
+                        "to_step": step,
+                        "steps": steps_since_emit,
+                        **{k: round(vals[k], 3) for k in
+                           ("step_time_ms", "input_wait_ms", "loss")
+                           if k in vals},
+                    })
+            steps_since_emit = 0
+            wait_window = 0.0
+            if tracer is not None:
+                # The flight ring keeps the last emissions a dying
+                # run saw — the postmortem's "final instruments".
+                obs_flight.RECORDER.note(
+                    tracer.trace_id, "metrics", step=step,
+                    # polycheck: ignore[hotpath-host-sync] -- vals already holds host floats (materialized at the emission sync above); no new device sync
+                    **{k: round(float(v), 5) for k, v in vals.items()})
+            on_metrics(step, vals)
+            # Stamp AFTER the callback: tracking I/O must not
+            # deflate the next window's reported throughput.
+            t_emit = time.perf_counter()
+            # polycheck: ignore[hotpath-wallclock] -- observability timestamp: re-stamp the span wall twin after tracking I/O
+            t_emit_wall = time.time()
+
+        # The first execution runs outside the run-level timed window
+        # (it pays one-time program load), but it consumed batch
+        # `start_step` and advanced the state — a REAL training step, so
+        # it opens the first emission window: step windows stay
+        # contiguous from `start_step` across restore/resize segment
+        # boundaries (the oracle's loss_continuity invariant reads
+        # them), and a fresh run reports its first loss, which should
+        # sit at ln(vocab).
+        state, metrics = train_step(state, first_batch, step_rng)
+        steps_since_emit = 1
+        # polycheck: ignore[hotpath-host-sync] -- deliberate: the first execution must finish before the timed window opens
+        jax.block_until_ready(metrics["loss"])
+        if on_metrics and start_step % cfg.log_every == 0:
+            emit(start_step, metrics)
 
         t0 = time.perf_counter()
         timed_steps = 0
@@ -386,69 +498,7 @@ def _run_jaxjob(
                 jax.block_until_ready(metrics["loss"])
                 jax.profiler.stop_trace()
             if on_metrics and (step % cfg.log_every == 0 or step == cfg.steps - 1):
-                # polycheck: ignore[hotpath-host-sync] -- deliberate emission-window materialization at log_every cadence, off the per-step path
-                vals = {k: float(v) for k, v in metrics.items()}
-                # Rolling window since the last emission; block so the
-                # window covers completed device work, not dispatch.
-                # polycheck: ignore[hotpath-host-sync] -- deliberate emission-window sync (see comment above): throughput must cover completed device work
-                jax.block_until_ready(metrics["loss"])
-                window = time.perf_counter() - t_emit
-                if window > 0 and steps_since_emit:
-                    ups = units_per_step * steps_since_emit / window
-                    vals[f"{model_def.unit}_per_sec"] = ups
-                    vals["step_time_ms"] = 1e3 * window / steps_since_emit
-                    # Host time blocked on next(batches), per step:
-                    # ~0 when prefetch keeps up; ≈ generation+transfer
-                    # when the input pipeline is the bottleneck.
-                    vals["input_wait_ms"] = (1e3 * wait_window
-                                             / steps_since_emit)
-                    if flops_unit:
-                        achieved = ups * flops_unit / n_chips
-                        vals["tflops_per_sec_per_chip"] = achieved / 1e12
-                        if peak:
-                            vals["mfu"] = achieved / peak
-                if not emitted_compile:
-                    # One-shot: the warm-up compile wall, so a metric
-                    # stream can attribute a cheap restart to the
-                    # persistent compile cache.
-                    vals["compile_time_s"] = compile_time_s
-                    emitted_compile = True
-                # The emission window is one `step` span on the
-                # timeline (reusing the already-derived step_time_ms /
-                # input_wait_ms) and one histogram sample — per-window,
-                # not per-step, so tracing cost stays off the hot path.
-                if steps_since_emit and window > 0:
-                    obs_metrics.training_step_hist().observe(
-                        window / steps_since_emit)
-                if tracer is not None and steps_since_emit:
-                    tracer.record_completed(
-                        # polycheck: ignore[hotpath-wallclock] -- observability timestamp: span end on the wall-clock timeline, per-window not per-step
-                        "step", start=t_emit_wall, end=time.time(),
-                        parent_id=(run_span.span_id if run_span is not None
-                                   else None),
-                        attributes={
-                            "from_step": step - steps_since_emit + 1,
-                            "to_step": step,
-                            "steps": steps_since_emit,
-                            **{k: round(vals[k], 3) for k in
-                               ("step_time_ms", "input_wait_ms", "loss")
-                               if k in vals},
-                        })
-                steps_since_emit = 0
-                wait_window = 0.0
-                if tracer is not None:
-                    # The flight ring keeps the last emissions a dying
-                    # run saw — the postmortem's "final instruments".
-                    obs_flight.RECORDER.note(
-                        tracer.trace_id, "metrics", step=step,
-                        # polycheck: ignore[hotpath-host-sync] -- vals already holds host floats (materialized at the emission sync above); no new device sync
-                        **{k: round(float(v), 5) for k, v in vals.items()})
-                on_metrics(step, vals)
-                # Stamp AFTER the callback: tracking I/O must not
-                # deflate the next window's reported throughput.
-                t_emit = time.perf_counter()
-                # polycheck: ignore[hotpath-wallclock] -- observability timestamp: re-stamp the span wall twin after tracking I/O
-                t_emit_wall = time.time()
+                emit(step, metrics)
             if eval_step is not None and step % cfg.eval_every == 0:
                 # Drain queued train dispatches BEFORE stamping the
                 # exclusion window, or their device time would be
@@ -497,6 +547,8 @@ def _run_jaxjob(
                     on_metrics(max(int(state["step"]) - 1, 0), last_eval)
             final_metrics.update(last_eval)
         final_step = int(state["step"])
+        peak_hbm = (mesh.local_devices[0].memory_stats() or {}).get(
+            "peak_bytes_in_use")
 
         # Flush the partial un-emitted window (an early stop — resize,
         # preemption, stop request — lands between emissions): without
@@ -547,6 +599,10 @@ def _run_jaxjob(
         restore_tier=restore_tier,
         input_wait_ms=1e3 * wait_total / timed_steps if timed_steps else 0.0,
         compile_time_s=compile_time_s,
+        compile_cache=compile_cache.stats(),
+        step_kernels=step_kernels,
+        param_bytes_per_device=params_per_device,
+        peak_hbm_bytes=peak_hbm,
     )
 
 

@@ -3,11 +3,14 @@ SURVEY.md §3 boundary summary: everything else orchestrates around the
 compiled step function).
 
 Placement strategy: params/state get explicit NamedShardings from the
-model's logical axes + the mesh's rule table; optimizer state inherits
-them through XLA sharding propagation (mu/nu are ``zeros_like(params)``
-inside the jitted init, so propagation is exact); gradients are reduced
-by the compiler-inserted psums over dp/fsdp. ``donate`` on the state
-keeps HBM flat across steps.
+model's logical axes + the mesh's rule table, and every optimizer-state
+leaf shaped like its parameter is pinned to that parameter's sharding
+(``_place_opt_state``) in the init AND in the step. Propagation alone
+does not do it: a ``zeros_like`` in the jitted init comes out
+replicated, which costs a full unsharded mu/nu per device at start-up
+and makes step 2's arguments differ from step 1's. Gradients are
+reduced by the compiler-inserted psums over dp/fsdp. ``donate`` on the
+state keeps HBM flat across steps.
 """
 
 from __future__ import annotations
@@ -34,6 +37,23 @@ def state_shardings(model_def: ModelDef, mesh: Mesh, rules: Rules) -> dict:
     }
 
 
+def _place_opt_state(optimizer, opt_state, params, param_shardings):
+    """Pin each params-shaped optimizer leaf (adam mu/nu, momentum) to
+    its parameter's sharding; leaves of another shape (adafactor's
+    factored rows/columns, counters) are left to the compiler."""
+
+    def masked(leaf):  # optax.masked's placeholder for frozen params
+        return isinstance(leaf, optax.MaskedNode)
+
+    def place(leaf, param, sharding):
+        if masked(leaf) or leaf.shape != param.shape:
+            return leaf
+        return jax.lax.with_sharding_constraint(leaf, sharding)
+
+    return optax.tree_map_params(optimizer, place, opt_state, params,
+                                 param_shardings, is_leaf=masked)
+
+
 def build_init(
     model_def: ModelDef,
     optimizer: optax.GradientTransformation,
@@ -48,7 +68,8 @@ def build_init(
         mutable = variables.get("state", {})
         if mutable:
             mutable = jax.lax.with_sharding_constraint(mutable, shardings["state"])
-        opt_state = optimizer.init(params)
+        opt_state = _place_opt_state(optimizer, optimizer.init(params),
+                                     params, shardings["params"])
         return {
             "params": params,
             "state": mutable,
@@ -193,6 +214,8 @@ def build_train_step(
         )
         new_params = optax.apply_updates(state["params"], updates)
         new_params = jax.lax.with_sharding_constraint(new_params, shardings["params"])
+        new_opt_state = _place_opt_state(optimizer, new_opt_state, new_params,
+                                         shardings["params"])
         metrics = dict(metrics)
         metrics["grad_norm"] = optax.global_norm(grads)
         new_state = {

@@ -46,6 +46,48 @@ from polyaxon_tpu.serving.speculative import LaneView, SpeculationPolicy
 logger = logging.getLogger(__name__)
 
 
+class _FixedShapeProgram:
+    """A jitted function whose argument shapes never change (the decode
+    step: slots and the block-table width are fixed), compiled ahead of
+    time on its first call. The owner keeps the executable, so it can
+    say which Mosaic kernels are in it, and a drifting shape raises
+    instead of compiling a second program."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._compiled = None
+        self.kernels: dict[str, int] = {}
+
+    def __call__(self, *args):
+        if self._compiled is None:
+            from polyaxon_tpu.perf.hlo import pallas_kernels
+
+            self._compiled = self._jitted.lower(*args).compile()
+            self.kernels = pallas_kernels(self._compiled.as_text())
+        return self._compiled(*args)
+
+
+def _device_stats(params, cache) -> dict:
+    """The devices the params live on, as jax reports them, what each
+    holds of the params and the KV cache, and the first one's peak
+    memory where the backend tracks it."""
+    from polyaxon_tpu.parallel.sharding import bytes_per_device, param_bytes
+
+    devices = sorted(jax.tree.leaves(params)[0].devices(),
+                     key=lambda d: d.id)
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+        "param_bytes": param_bytes(params),
+        "param_bytes_per_device": bytes_per_device(params),
+        "kv_bytes": param_bytes(cache),
+        "kv_bytes_per_device": bytes_per_device(cache),
+        "peak_hbm_bytes": (devices[0].memory_stats() or {}).get(
+            "peak_bytes_in_use"),
+    }
+
+
 class QueueFull(RuntimeError):
     """The continuous engine's pending queue is at its cap: the caller
     should shed load (HTTP 503 + Retry-After) instead of queueing
@@ -201,7 +243,7 @@ class ContinuousBatchingEngine:
                  request_tracing: bool = True,
                  trace_capacity: int = reqtrace.DEFAULT_RING_CAPACITY,
                  trace_dump_path: Optional[str] = None,
-                 registry=None):
+                 registry=None, mesh=None):
         from polyaxon_tpu.serving.server import _family
 
         family = _family(model)
@@ -322,6 +364,11 @@ class ContinuousBatchingEngine:
         self.slots = slots
         self.max_len = max_len or cfg.max_seq_len
         self._family_mod = family
+        # The mesh the params are sharded over (None = one device). The
+        # KV cache shards its kv heads over `tp` with them, and the
+        # loop thread enters the mesh so the kernels' shard_map wrappers
+        # (parallel/compat.py) find it while tracing.
+        self._mesh = mesh
         # Fleet-scoped telemetry (ISSUE 20): `registry` may be a
         # `REGISTRY.scoped(component=...)` view — every series this
         # engine records then carries the replica's identity, and its
@@ -358,10 +405,7 @@ class ContinuousBatchingEngine:
                 self._pool = PagePool(n_rows, self.max_len, page_size,
                                       kv_pages + 1,
                                       prefix_cache=prefix_cache)
-            self._cache = family.paged_init_cache(
-                cfg, self._pool.n_pages, page_size)
-        else:
-            self._cache = family.cb_init_cache(cfg, slots, self.max_len)
+        self._cache = self._new_cache()
         self.draft = draft
         self._spec_rounds = 0
         self._spec_tokens = 0
@@ -525,10 +569,11 @@ class ContinuousBatchingEngine:
         # Two executables; the loop picks per iteration by whether any
         # live row actually uses top-p/top-k (same idea as the static
         # engine's `filtered` compile key).
-        self._step_plain = jax.jit(functools.partial(step, filtered=False),
-                                   donate_argnums=(1,))
-        self._step_filtered = jax.jit(
-            functools.partial(step, filtered=True), donate_argnums=(1,))
+        self._step_plain = _FixedShapeProgram(jax.jit(
+            functools.partial(step, filtered=False), donate_argnums=(1,)))
+        self._step_filtered = _FixedShapeProgram(jax.jit(
+            functools.partial(step, filtered=True), donate_argnums=(1,)))
+        self._decode_programs = (self._step_plain, self._step_filtered)
 
         # One lru-bounded executable per prompt length for BOTH kv
         # modes; paged folds the page scatter into the same program
@@ -586,12 +631,8 @@ class ContinuousBatchingEngine:
                 def compiled_suffix_prefill(slen: int, n_pref: int):
                     def run(params, suffix, cache, page_ids, m, real_len):
                         pref = jnp.maximum(page_ids[:n_pref], 0)
-                        kp = cache["k"][:, pref]
-                        kp = kp.reshape(kp.shape[0], n_pref * ps,
-                                        *kp.shape[3:])
-                        vp = cache["v"][:, pref]
-                        vp = vp.reshape(vp.shape[0], n_pref * ps,
-                                        *vp.shape[3:])
+                        kp = family.paged_gather(cache["k"], pref)
+                        vp = family.paged_gather(cache["v"], pref)
                         k_suf, v_suf = family.paged_prefill_suffix_kv(
                             cfg, params, suffix, kp, vp, m)
                         # Padded tail positions (>= real_len) carry
@@ -1558,6 +1599,8 @@ class ContinuousBatchingEngine:
 
     def stats(self) -> dict:
         """Live engine counters + occupancy gauges for /v1/stats."""
+        from polyaxon_tpu.runtime import compile_cache
+
         return {
             "engine": "continuous",
             "slots": self.slots,
@@ -1590,6 +1633,14 @@ class ContinuousBatchingEngine:
             "traced_requests": len(self._ring),
             "stopped": self._stopped,
             "kv": self.kv,
+            # Mosaic kernels in the compiled decode step (empty until
+            # the first step compiles, and wherever attention runs a
+            # reference path: the CPU mesh, or the gather formulation).
+            "decode_kernels": {
+                name: count for program in self._decode_programs
+                for name, count in program.kernels.items()},
+            "device": _device_stats(self.params, self._cache),
+            "compile_cache": compile_cache.stats(),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
                 "spec_rounds": self._spec_rounds,
@@ -1727,6 +1778,40 @@ class ContinuousBatchingEngine:
                 self._go_live(b, req, pos0, tok0)
         return True
 
+    def _new_cache(self) -> dict:
+        """A zeroed KV cache (the paged pool, or the dense slot cache).
+        Under a mesh its kv-head dim shards over `tp` by the kernels'
+        own divisibility rule, and is born sharded — never whole on the
+        first device."""
+        family, cfg = self._family_mod, self.cfg
+        if self._pool is not None:
+            def build():
+                return family.paged_init_cache(
+                    cfg, self._pool.n_pages, self._pool.page_size)
+        else:
+            def build():
+                return family.cb_init_cache(cfg, self.slots, self.max_len)
+        if self._mesh is None:
+            return build()
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from polyaxon_tpu.parallel import compat
+
+        with self._mesh:
+            _, head_axis = compat.kernel_axes(1, cfg.n_kv_heads)
+        # kv heads are dim 2 of the paged pool [L, P, KV, page, Hd] and
+        # dim 3 of the dense cache [L, B, C, KV, Hd].
+        kv_dim = 2 if self._pool is not None else 3
+
+        def spec(aval):
+            dims = [None] * aval.ndim
+            dims[kv_dim] = head_axis
+            return NamedSharding(self._mesh, PartitionSpec(*dims))
+
+        shapes = jax.eval_shape(build)
+
+        return jax.jit(build, out_shardings=jax.tree.map(spec, shapes))()
+
     def _handle_step_failure(self, exc: Exception, what: str) -> bool:
         """Shared device-failure recovery for the plain step AND the
         speculative round: fail every live request with the error,
@@ -1754,15 +1839,11 @@ class ContinuousBatchingEngine:
         # The old cache was donated to the failed program — its buffer
         # is gone (or poisoned). Rebuild. (Every live row was retired
         # above, so a paged pool is fully free.)
+        self._cache = self._new_cache()
         if self._pool is not None:
-            self._cache = self._family_mod.paged_init_cache(
-                self.cfg, self._pool.n_pages, self._pool.page_size)
             # The rebuilt cache is zeros: resident prefix pages no
             # longer hold the content their keys promise.
             self._pool.invalidate_prefix_cache()
-        else:
-            self._cache = self._family_mod.cb_init_cache(
-                self.cfg, self.slots, self.max_len)
         if self.draft is not None:
             self._draft_cache = self._draft_family.cb_init_cache(
                 self._draft_cfg, self.slots, self.max_len)
@@ -1995,6 +2076,12 @@ class ContinuousBatchingEngine:
             self._publish_queue_depth()
 
     def _loop(self) -> None:
+        if self._mesh is None:
+            return self._run_loop()
+        with self._mesh:
+            return self._run_loop()
+
+    def _run_loop(self) -> None:
         while True:
             with self._cv:
                 while (not self._stopped and not self._queue_depth()
@@ -2134,12 +2221,12 @@ class ContinuousBatchingEngine:
             filtered = any(
                 r is not None and (r.top_p < 1.0 or r.top_k > 0)
                 for r in self._slot_req)
-            step_fn = (self._step_filtered if filtered
-                       else self._step_plain)
             # Decode sees ONLY the decode-pool rows: lane rows sit
             # past self.slots and belong to staged prefills.
             tables = (jnp.asarray(self._pool.tables[:self.slots])
                       if self._pool is not None else None)
+            step_fn = (self._step_filtered if filtered
+                       else self._step_plain)
             nxt, self._cache = step_fn(
                 self.params, self._cache,
                 jnp.asarray(self._cur), jnp.asarray(self._pos),

@@ -95,6 +95,12 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
     jitted with sharded out_shardings, and checkpoint tensors move
     host → their own device shards directly.
     """
+    from polyaxon_tpu.runtime import compile_cache
+
+    # Every serving entry loads params before it compiles anything (the
+    # server, the fleet's replica factory, the serving bench scripts),
+    # so this is where they all get the one compile-cache rule.
+    compile_cache.enable()
     family = _family(model)
     cfg = family.CONFIGS[model]
 
@@ -151,12 +157,12 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
                     lambda ref, x: jnp.asarray(x, ref.dtype),
                     template, loaded)
             logger.info("restored %s step=%s", checkpoint, step)
-    elif shardings is not None:
+    else:
+        # One program, sharded or not: op-by-op init compiles a program
+        # per tensor shape, which is minutes of start-up at real widths.
         init_fn = jax.jit(lambda key: family.init(cfg, key)["params"],
                           out_shardings=shardings)
         params = init_fn(jax.random.key(seed))
-    else:
-        params = family.init(cfg, jax.random.key(seed))["params"]
 
     if mesh is not None:
         logger.info("sharded %s over mesh %s", model,
@@ -837,7 +843,7 @@ class ServingServer:
                 class_max_pending=class_max_pending,
                 preemption=preemption,
                 request_tracing=request_tracing,
-                trace_dump_path=trace_dump_path)
+                trace_dump_path=trace_dump_path, mesh=self.mesh)
         elif batching == "static":
             if prefill_chunk is not None:
                 raise ValueError(

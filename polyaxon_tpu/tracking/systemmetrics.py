@@ -43,7 +43,11 @@ def host_metrics() -> dict[str, float]:
 
 def tpu_metrics() -> dict[str, float]:
     """Best-effort per-device metrics from the PJRT client; keys are
-    ``tpu<i>_*``. Empty off-TPU or when the plugin exposes no stats."""
+    ``tpu<i>_*``. Empty off-TPU or when the client exposes no stats.
+    Initializes the backend, so it is sampled only in the process that
+    runs the job (``runtime/launch.py``'s lead, or user code holding
+    the tracking ``Run``) — never in the agent, which must stay off
+    the chip its gangs need."""
     out: dict[str, float] = {}
     try:
         import jax
@@ -142,8 +146,12 @@ class SystemMetricsMonitor:
     def sample(self) -> dict[str, float]:
         metrics = host_metrics()
         if self.include_tpu:
-            metrics.update(tpu_metrics())
-            metrics.update(libtpu_metrics())
+            device = tpu_metrics()
+            metrics.update(device)
+            if device:
+                # Only with a TPU attached to THIS process: loading
+                # libtpu anywhere else contends for its one-process lock.
+                metrics.update(libtpu_metrics())
         return metrics
 
     def _loop(self) -> None:

@@ -1,6 +1,3 @@
-from polyaxon_tpu.utils.env import (
-    apply_jax_platforms_override,
-    cpu_mesh_xla_flags,
-)
+from polyaxon_tpu.utils.env import cpu_mesh_xla_flags
 
-__all__ = ["apply_jax_platforms_override", "cpu_mesh_xla_flags"]
+__all__ = ["cpu_mesh_xla_flags"]

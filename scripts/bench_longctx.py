@@ -181,9 +181,6 @@ def main() -> int:
         return 0 if agree else 1
 
     # Chip mode: flash at 8k then 16k; the O(S) claim is the ratio.
-    from polyaxon_tpu.utils import apply_jax_platforms_override
-
-    apply_jax_platforms_override()
     model = args.model or "llama_200m"
     points = []
     for seq in (8192, 16384):

@@ -27,10 +27,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from polyaxon_tpu.utils import apply_jax_platforms_override  # noqa: E402
-
-apply_jax_platforms_override()
-
 
 def main() -> int:
     parser = argparse.ArgumentParser()

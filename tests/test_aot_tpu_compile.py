@@ -1,0 +1,224 @@
+"""The chip's compiler, asked about the hot-path kernels before any chip is.
+
+Interpret mode on the CPU mesh runs a Pallas kernel as plain jax.numpy,
+so it cannot see what the TPU lowering refuses: a block whose trailing
+dims are not whole tiles, more VMEM than a kernel may use, a Mosaic call
+GSPMD is asked to partition. libtpu compiles for a *described* topology
+with no chip attached (on-chip-measurement guide, section 2.3), so each
+case here is a real Mosaic compile at a published model's widths, a
+second or two each.
+
+libtpu admits one loading process at a time (a second one aborts on its
+lock file), so every case compiles in ONE child process, started once
+per test run behind a file lock — xdist workers share its JSON report —
+and each case asserts its own entry. The module skips where the topology
+cannot be described.
+"""
+
+from __future__ import annotations
+
+import fcntl
+import json
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+TOPOLOGY = "v5e:2x2"
+FLASH_KERNELS = {"flash_fwd": 1, "flash_bwd_dkdv": 1, "flash_bwd_dq": 1}
+PAGED_KERNELS = {"paged_decode": 1}
+
+# name -> (kind, shape/mesh arguments, kernels the compiled module holds).
+# Widths are the zoo's published configs (models/llama.py CONFIGS):
+# llama3_1b H32/KV8/D64, llama3_8b and mistral_7b H32/KV8/D128 (mistral
+# adds the 4096 window), gemma_2b H8/KV1/D256; serving pages hold 16.
+CASES = {
+    "flash-llama3_1b-s2048": (
+        "flash", dict(h=32, kv=8, d=64, s=2048), FLASH_KERNELS),
+    "flash-llama3_1b-s8192-window-segments": (
+        "flash", dict(h=32, kv=8, d=64, s=8192, window=4096, segments=True),
+        FLASH_KERNELS),
+    "flash-llama3_1b-committed-1024-tiles": (
+        "flash", dict(h=32, kv=8, d=64, s=2048, block="committed"),
+        FLASH_KERNELS),
+    "flash-llama3_8b-d128-segments": (
+        "flash", dict(h=32, kv=8, d=128, s=2048, segments=True),
+        FLASH_KERNELS),
+    "flash-mistral_7b-d128-window": (
+        "flash", dict(h=32, kv=8, d=128, s=8192, window=4096), FLASH_KERNELS),
+    "flash-gemma_2b-d256-mqa": (
+        "flash", dict(h=8, kv=1, d=256, s=2048), FLASH_KERNELS),
+    "flash-in-fsdp4-mesh": (
+        "flash", dict(h=32, kv=8, d=64, s=2048, b=8, mesh={"fsdp": 4}),
+        FLASH_KERNELS),
+    "flash-in-dp2-tp2-mesh": (
+        "flash", dict(h=32, kv=8, d=64, s=2048, b=8,
+                      mesh={"dp": 2, "tp": 2}), FLASH_KERNELS),
+    "paged-llama3_1b-kv8-d64": (
+        "paged", dict(h=32, kv=8, d=64), PAGED_KERNELS),
+    "paged-llama3_8b-mistral_7b-kv8-d128": (
+        "paged", dict(h=32, kv=8, d=128), PAGED_KERNELS),
+    "paged-gemma_2b-kv1-d256": (
+        "paged", dict(h=8, kv=1, d=256), PAGED_KERNELS),
+    "paged-in-tp4-mesh": (
+        "paged", dict(h=32, kv=8, d=64, mesh={"tp": 4}), PAGED_KERNELS),
+}
+
+
+# ------------------------------------------------------------------ child
+def _described_mesh(topo, axes):
+    from polyaxon_tpu.parallel import build_mesh
+
+    n = 1
+    for size in (axes or {}).values():
+        n *= size
+    return build_mesh(axes=dict(axes or {"dp": 1}),
+                      devices=list(topo.devices)[:n])
+
+
+def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
+                   block=None, mesh=None):
+    """Forward AND backward (the custom-vjp Pallas kernels) of one flash
+    call, under `mesh` when given, batch and heads sharded as the train
+    step shards them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from polyaxon_tpu.ops import flash
+
+    mesh = _described_mesh(topo, mesh)
+    batch = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
+
+    def aval(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    bshd = P(batch or None, None, heads, None)
+    args = [aval((b, s, h, d), jnp.bfloat16, bshd),
+            aval((b, s, kv, d), jnp.bfloat16, bshd),
+            aval((b, s, kv, d), jnp.bfloat16, bshd)]
+    if segments:
+        args.append(aval((b, s), jnp.int32, P(batch or None, None)))
+    tiles = {}
+    if block == "committed":
+        pick = flash._committed_tile_picks()[topo.devices[0].device_kind]
+        tiles = dict(block_q=pick["block_q"], block_k=pick["block_k"])
+
+    def loss(q, k, v, *seg):
+        out = flash.flash_attention(
+            q, k, v, causal=True, window=window, interpret=False,
+            segment_ids=seg[0] if seg else None, **tiles)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with mesh:
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+            *args).compile()
+
+
+def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
+                   mesh=None):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from polyaxon_tpu.ops.paged_attention import paged_decode_attention
+
+    mesh = _described_mesh(topo, mesh)
+    heads = "tp" if mesh.shape.get("tp", 1) > 1 else None
+
+    def aval(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    maxp = max_len // page
+    pool = aval((slots * maxp + 1, kv, page, d), jnp.bfloat16,
+                P(None, heads, None, None))
+    with mesh:
+        return jax.jit(
+            lambda *a: paged_decode_attention(*a, interpret=False)).lower(
+                aval((slots, h, d), jnp.bfloat16, P(None, heads, None)),
+                pool, pool, aval((slots, maxp), jnp.int32),
+                aval((slots,), jnp.int32)).compile()
+
+
+def _child_main() -> int:
+    """Compile every case against the described topology; one JSON
+    object on stdout. Never raises: a refusal is that case's entry."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # Without it libtpu spends minutes asking a GCP metadata server
+    # that is not there (perf/aot.py has the story).
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import jax
+    from jax.experimental import topologies
+
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip; keep it off.
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name=TOPOLOGY)
+    except Exception as exc:  # noqa: BLE001 — the module then skips
+        print(json.dumps({"skip": f"{type(exc).__name__}: {exc}"[:300]}))
+        return 0
+    from polyaxon_tpu.perf.hlo import pallas_kernels
+
+    report = {}
+    for name, (kind, kwargs, _) in CASES.items():
+        compile_case = _compile_flash if kind == "flash" else _compile_paged
+        t0 = time.time()
+        try:
+            compiled = compile_case(topo, **kwargs)
+            report[name] = {"ok": True,
+                            "kernels": pallas_kernels(compiled.as_text())}
+        except Exception as exc:  # noqa: BLE001 — the refusal IS the result
+            report[name] = {"ok": False,
+                            "error": f"{type(exc).__name__}: {exc}"[:600]}
+        report[name]["seconds"] = round(time.time() - t0, 2)
+    print(json.dumps({"device_kind": topo.devices[0].device_kind,
+                      "cases": report}))
+    return 0
+
+
+# ------------------------------------------------------------------ tests
+@pytest.fixture(scope="module")
+def aot_report(tmp_path_factory):
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent  # the directory every worker shares
+    cached = base / "aot_tpu_compile.json"
+    with open(base / "aot_tpu_compile.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when the file closes
+        if not cached.exists():
+            proc = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "--child"],
+                capture_output=True, text=True, timeout=600,
+                env={**os.environ, "JAX_PLATFORMS": "cpu"})
+            lines = [ln for ln in proc.stdout.splitlines()
+                     if ln.startswith("{")]
+            assert lines, (f"AOT child rc={proc.returncode} left no report: "
+                           f"{proc.stderr[-2000:]}")
+            cached.write_text(lines[-1])
+        report = json.loads(cached.read_text())
+    if "skip" in report:
+        pytest.skip(f"cannot describe a {TOPOLOGY} topology here: "
+                    f"{report['skip']}")
+    return report
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_compiles_for_described_tpu(aot_report, name):
+    entry = aot_report["cases"][name]
+    assert entry["ok"], f"the TPU compiler refused {name}: {entry['error']}"
+    # The kernel itself, not a reference path that happens to compile.
+    assert entry["kernels"] == CASES[name][2], entry
+
+
+if __name__ == "__main__":
+    if "--child" in sys.argv:
+        sys.exit(_child_main())

@@ -291,6 +291,26 @@ class TestPrewarm:
         monkeypatch.setenv(elastic.ENV_ELASTIC_PREWARM, "skip")
         assert elastic.prewarm(make_job(), 4, {"dp": 4})["mode"] == "skip"
 
+    def test_process_holding_a_tpu_never_spawns_a_compile_child(
+            self, monkeypatch):
+        """A chip belongs to one process and the run holds it: a prewarm
+        child asking for the devices would fail or hang to the timeout.
+        On a TPU backend the default is the in-process validation, and
+        an explicit `subprocess` is refused at once."""
+        import jax
+
+        monkeypatch.delenv(elastic.ENV_ELASTIC_PREWARM, raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        spawned = []
+        monkeypatch.setattr(elastic, "_prewarm_subprocess",
+                            lambda *a, **kw: spawned.append(a))
+        out = elastic.prewarm(make_job(), 4, {"dp": 4},
+                              devices=jax.devices()[:4])
+        assert out["mode"] == "inline"
+        with pytest.raises(elastic.PrewarmError, match="holds the chips"):
+            elastic.prewarm(make_job(), 4, {"dp": 4}, mode="subprocess")
+        assert not spawned
+
     def test_inline_validates_survivor_mesh(self):
         out = elastic.prewarm(make_job(), 4, {"dp": 4}, mode="inline")
         assert out["ok"] and out["mode"] == "inline"

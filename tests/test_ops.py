@@ -2,6 +2,8 @@
 einsum reference. Runs on the 8-device virtual CPU mesh (conftest), the
 same way the driver's dryrun validates sharding."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +51,90 @@ class TestFlash:
         gr = jax.grad(loss(lambda *a: xla_attention(*a)), argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+    @pytest.mark.parametrize("axes", [
+        {"fsdp": 4}, {"dp": 2, "tp": 2}, {"dp": 2, "fsdp": 2, "tp": 2}])
+    def test_partitioned_under_mesh_matches_reference(self, cpu_devices,
+                                                      axes):
+        """Under a multi-device ambient mesh the kernel runs per shard
+        of (batch, kv heads) inside shard_map (a Mosaic call cannot be
+        partitioned by GSPMD — tests/test_aot_tpu_compile.py holds the
+        compiler to that). Values and gradients must not notice, with
+        packed segments riding the batch sharding."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        n = int(np.prod(list(axes.values())))
+        mesh = Mesh(np.array(cpu_devices[:n]).reshape(tuple(axes.values())),
+                    tuple(axes))
+        q, k, v = _qkv(b=4, h=4, kv=2)
+        seg = jnp.repeat(jnp.arange(4, dtype=jnp.int32), 64)[None].repeat(
+            4, axis=0)
+        batch = tuple(a for a in ("dp", "fsdp") if a in axes)
+        place = lambda x: jax.device_put(x, NamedSharding(  # noqa: E731
+            mesh, P(batch, *([None] * (x.ndim - 1)))))
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v, segment_ids=seg) ** 2)
+
+        want, gw = jax.value_and_grad(loss(xla_attention), (0, 1, 2))(q, k, v)
+        with mesh:
+            got, gg = jax.jit(jax.value_and_grad(
+                loss(functools.partial(flash_attention, block_q=128,
+                                       block_k=128, bwd_impl="pallas")),
+                (0, 1, 2)))(place(q), place(k), place(v))
+        np.testing.assert_allclose(got, want, rtol=2e-5)
+        for a, b in zip(gg, gw):
+            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+    def test_partitioned_inside_an_enclosing_manual_region(self,
+                                                           cpu_devices):
+        """Called from a body that already bound some axes (the pipeline
+        binds `pp`), the kernel's shard_map nests over the rest: it must
+        take the context's mesh (a nested shard_map refuses the concrete
+        one) and bind only the unbound axes."""
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        mesh = Mesh(np.array(cpu_devices[:4]).reshape(2, 2), ("pp", "fsdp"))
+        q, k, v = _qkv(b=4, h=4, kv=2)
+        want = xla_attention(q, k, v)
+
+        def stage(q, k, v):  # pp is manual here, fsdp still automatic
+            assert jax.sharding.get_abstract_mesh().manual_axes == ("pp",)
+            return flash_attention(q, k, v, block_q=128, block_k=128)
+
+        with mesh:
+            got = jax.jit(jax.shard_map(
+                stage, mesh=mesh, in_specs=P(), out_specs=P(),
+                axis_names={"pp"}, check_vma=False))(q, k, v)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    def test_interpret_mode_is_refused_off_cpu(self, monkeypatch):
+        """No chip path may land on an interpreted kernel: interpret
+        mode resolves from the CPU backend only and an explicit request
+        anywhere else raises."""
+        from polyaxon_tpu.ops import flash
+
+        assert flash.resolve_interpret(None) is True  # the CPU test mesh
+        assert flash.resolve_interpret(False) is False  # AOT for a topology
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert flash.resolve_interpret(None) is False
+        with pytest.raises(ValueError, match="CPU backend only"):
+            flash.resolve_interpret(True)
+
+    def test_give_way_is_loud_and_named(self):
+        """A shape that does not tile runs the einsum reference — with
+        a warning naming the shape, and `implementation_for` telling
+        callers the same decision in advance."""
+        from polyaxon_tpu.ops import flash
+
+        assert flash.implementation_for(2048, 2048, 64) == "pallas"
+        assert flash.implementation_for(96, 96, 64) == "einsum"
+        assert flash.implementation_for(256, 256, 80) == "einsum"
+        q, k, v = _qkv(s=96)
+        with pytest.warns(flash.KernelFallbackWarning, match="Sq=96"):
+            out = flash_attention(q, k, v)
+        np.testing.assert_allclose(out, xla_attention(q, k, v),
+                                   atol=2e-5, rtol=2e-5)
 
     def test_sliding_window_matches_band_mask(self):
         """xla window path equals an explicit band-mask softmax, and the
@@ -376,9 +462,7 @@ class TestRing:
         ref = xla_attention(q, k, v, causal=True)
         ring._warned_einsum_fallback = False
         spec = P(None, "cp", None, None)
-        from polyaxon_tpu.parallel import compat
-
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             functools.partial(ring._ring_attention_sharded, causal=True,
                               scale=q.shape[-1] ** -0.5, axis_name="cp"),
             mesh=cp_mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -406,10 +490,8 @@ class TestRing:
         q, k, v = _qkv(b=1, s=4096, h=4, kv=2)
         spec = jax.sharding.PartitionSpec(None, "cp", None, None)
 
-        from polyaxon_tpu.parallel import compat
-
         def build(fn):
-            f = compat.shard_map(
+            f = jax.shard_map(
                 functools.partial(fn, scale=64 ** -0.5, axis_name="cp"),
                 mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
                 check_vma=False)

@@ -236,6 +236,44 @@ class TestMoEPaged:
 
 
 class TestPagedKernel:
+    def test_pallas_engine_under_tp_mesh(self):
+        """`plx serve --mesh tp=N` with the kernel: the pool is born
+        sharded over kv heads (every device holds a slice — nothing
+        whole on the first), the kernel runs per tp shard inside
+        shard_map, and tokens match the one-device gather engine."""
+        from polyaxon_tpu.parallel import build_mesh
+        from polyaxon_tpu.serving.server import load_params
+
+        mesh = build_mesh(axes={"tp": 2}, devices=jax.devices()[:2])
+        cfg, params = load_params("llama_tiny", seed=0, mesh=mesh)
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32,
+                                  paged_attention_impl="pallas")
+        prompts = [[5, 6, 7, 1, 2, 3, 4, 9, 8, 2, 11], [3, 1, 4, 1, 5]]
+        sharded = ContinuousBatchingEngine(
+            "llama_tiny", cfg, params, slots=2, max_len=32, kv="paged",
+            page_size=4, mesh=mesh)
+        try:
+            got = sharded.generate(prompts, max_new_tokens=5, timeout=300)
+            shards = sharded._cache["k"].addressable_shards
+            stats = sharded.stats()
+        finally:
+            sharded.stop()
+        assert {s.device.id for s in shards} == {0, 1}
+        assert all(s.data.shape[2] == cfg.n_kv_heads // 2 for s in shards)
+        assert stats["device"]["count"] == 2
+
+        cfg1, params1 = load_params("llama_tiny", seed=0)
+        cfg1 = dataclasses.replace(cfg1, dtype=jnp.float32,
+                                   paged_attention_impl="gather")
+        single = ContinuousBatchingEngine(
+            "llama_tiny", cfg1, params1, slots=2, max_len=32, kv="paged",
+            page_size=4)
+        try:
+            want = single.generate(prompts, max_new_tokens=5, timeout=300)
+        finally:
+            single.stop()
+        assert got == want
+
     def test_kernel_matches_gather_reference(self):
         """The Pallas paged-decode kernel (interpret mode on CPU) must
         match the XLA gather+masked-softmax formulation on live rows —
@@ -248,6 +286,8 @@ class TestPagedKernel:
         B, H, KV, Hd, page, P, maxp = 3, 4, 2, 16, 4, 9, 4
         ks = jax.random.split(key, 4)
         q = jax.random.normal(ks[0], (B, H, Hd), jnp.float32)
+        # Token-major pages for the reference; the kernel takes the
+        # pool's kv-head-major layout [P, KV, page, Hd].
         k_pages = jax.random.normal(ks[1], (P, page, KV, Hd), jnp.float32)
         v_pages = jax.random.normal(ks[2], (P, page, KV, Hd), jnp.float32)
         tables = jnp.asarray([[5, 2, -1, -1],
@@ -255,7 +295,8 @@ class TestPagedKernel:
                               [-1, -1, -1, -1]], jnp.int32)
         pos = jnp.asarray([6, 2, -1], jnp.int32)
 
-        got = paged_decode_attention(q, k_pages, v_pages, tables, pos,
+        got = paged_decode_attention(q, k_pages.swapaxes(1, 2),
+                                     v_pages.swapaxes(1, 2), tables, pos,
                                      interpret=True)
 
         # Gather reference (the models/llama.py formulation).

@@ -32,6 +32,48 @@ from polyaxon_tpu.perf.hlo import (
 
 
 class TestHloParse:
+    def test_pallas_kernels_named_through_every_transform(self):
+        """`pallas_kernels` is what tells a program that runs a Mosaic
+        kernel from one that took a reference path: it reads the
+        pallas_call's `name=` out of the custom call's op_name whatever
+        transform wrapped it, and ignores custom calls that are not
+        Mosaic's."""
+        from polyaxon_tpu.perf.hlo import pallas_kernels
+
+        hlo = """
+  %flash_fwd.1 = (bf16[2,32,2048,64]{3,2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_call", backend_config={"x":{}}, metadata={op_name="jit(step)/shard_map/flash_fwd/pallas_call" stack_frame_id=2}
+  %jvp.2 = (bf16[2,32,2048,64]{3,2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(flash_fwd)/pallas_call" stack_frame_id=3}
+  %t.3 = bf16[2,8,2048,64]{3,2,1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(flash_bwd_dkdv))/pallas_call"}
+  %p.4 = bf16[4,32,64]{2,1,0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/while/body/paged_decode/pallas_call"}
+  %s.5 = f32[8]{0} custom-call(%z), custom_call_target="Sharding", metadata={op_name="jit(step)/not_a_kernel/pallas_call"}
+  %d.6 = f32[8,8]{1,0} dot(%x, %y), metadata={op_name="jit(step)/dot_general"}
+"""
+        assert pallas_kernels(hlo) == {
+            "flash_fwd": 2, "flash_bwd_dkdv": 1, "paged_decode": 1}
+        assert pallas_kernels("") == {}
+
+    def test_time_model_refuses_a_tpu_it_does_not_describe(self):
+        """The overlap model's constants are v5e's: CPU-mesh HLO is
+        ranked with them by design, another TPU kind is an error, and a
+        TPU kind nobody recorded a peak for is an error everywhere MFU
+        is computed — never a default."""
+        import types
+
+        from polyaxon_tpu.perf.hlo import require_modelled_device
+        from polyaxon_tpu.runtime.flops import peak_flops
+
+        def device(platform, kind):
+            return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+        assert peak_flops(device("cpu", "cpu")) is None
+        assert peak_flops(device("tpu", "TPU v5 lite")) == 197e12
+        with pytest.raises(ValueError, match="no bf16 peak recorded"):
+            peak_flops(device("tpu", "TPU v9 hypothetical"))
+        require_modelled_device(device("cpu", "cpu"))
+        require_modelled_device(device("tpu", "TPU v5 lite"))
+        with pytest.raises(ValueError, match="v5e constants"):
+            require_modelled_device(device("tpu", "TPU v4"))
+
     def test_counts_shapes_and_groups(self):
         hlo = """
   %all-reduce.1 = f32[256,64]{1,0} all-reduce(f32[256,64]{1,0} %add.5), channel_id=1, replica_groups={{0,1,2,3},{4,5,6,7}}, to_apply=%sum

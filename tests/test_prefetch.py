@@ -213,36 +213,58 @@ class TestLoopPrefetch:
 
 
 class TestCompileCacheResolution:
-    """Dir resolution is pure env/config logic — no jax involved."""
+    """The one rule of runtime/compile_cache.py: a cache placed through
+    ``JAX_COMPILATION_CACHE_DIR`` is JAX's alone, a TPU without it gets
+    the fixed directory in the checkout, anything else gets none."""
 
-    def test_precedence_and_kill_switch(self, monkeypatch):
+    @pytest.mark.parametrize("placed,backend,want_dir,want_update", [
+        # Placed from outside: returned as-is, config never touched —
+        # whatever the backend and whatever the repo's old variables say.
+        ("/outside/cache", "tpu", "/outside/cache", False),
+        ("/outside/cache", "cpu", "/outside/cache", False),
+        # Not placed, TPU: the fixed path under the checkout.
+        (None, "tpu", "REPO", True),
+        # Not placed, CPU test mesh: off (tests/conftest.py says why).
+        (None, "cpu", None, False),
+    ])
+    def test_rule(self, monkeypatch, placed, backend, want_dir,
+                  want_update):
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
         from polyaxon_tpu.runtime import compile_cache as cc
 
-        monkeypatch.delenv(cc.ENV_CACHE, raising=False)
-        monkeypatch.delenv(cc.ENV_CACHE_DIR, raising=False)
-        assert cc.resolve_cache_dir(None) is None  # opt-in: off by default
-        assert cc.resolve_cache_dir("/cfg") == "/cfg"
-        monkeypatch.setenv(cc.ENV_CACHE_DIR, "/envdir")
-        assert cc.resolve_cache_dir(None) == "/envdir"
-        assert cc.resolve_cache_dir("/cfg") == "/cfg"  # config wins
-        monkeypatch.setenv(cc.ENV_CACHE, "0")  # force-disable beats all
-        assert cc.resolve_cache_dir("/cfg") is None
+        updates = []
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(jax.config, "update",
+                            lambda key, value: updates.append((key, value)))
+        monkeypatch.setattr(compilation_cache, "reset_cache", lambda: None)
+        # Retired knobs must not steer anything.
+        monkeypatch.setenv("POLYAXON_TPU_COMPILE_CACHE_DIR", "/retired")
+        monkeypatch.setenv("POLYAXON_TPU_COMPILE_CACHE", "1")
+        if placed:
+            monkeypatch.setenv(cc.ENV_JAX_CACHE_DIR, placed)
+        else:
+            monkeypatch.delenv(cc.ENV_JAX_CACHE_DIR, raising=False)
 
-    def test_executor_resolves_shared_default(self, tmp_path, monkeypatch):
-        """POLYAXON_TPU_COMPILE_CACHE=1 without an explicit dir: the
-        executor points every gang (env-inherited) at ONE cache under
-        the agent's artifacts root, so a preemption-requeued run finds
-        the first attempt's executables."""
-        from polyaxon_tpu.agent.executor import LocalExecutor
-        from polyaxon_tpu.controlplane import ControlPlane
+        got = cc.enable()
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fixed = os.path.join(repo, ".jax-compile-cache")
+        assert got == (fixed if want_dir == "REPO" else want_dir)
+        assert updates == ([("jax_compilation_cache_dir", fixed)]
+                           if want_update else [])
+
+    def test_no_entry_point_places_the_cache_itself(self):
+        """The executor used to derive a directory under the agent's
+        artifacts root and the run spec carried its own; both are gone,
+        so a spec key or an agent cannot move the cache."""
         from polyaxon_tpu.runtime import compile_cache as cc
+        from polyaxon_tpu.runtime.config import RuntimeConfig
 
-        monkeypatch.setenv(cc.ENV_CACHE, "1")
-        monkeypatch.delenv(cc.ENV_CACHE_DIR, raising=False)
-        plane = ControlPlane(str(tmp_path / "home"))
-        LocalExecutor(plane)
-        assert os.environ[cc.ENV_CACHE_DIR] == os.path.join(
-            plane.artifacts_root, cc.SHARED_CACHE_DIRNAME)
+        assert "compile_cache_dir" not in RuntimeConfig.model_fields
+        assert not hasattr(cc, "resolve_cache_dir")
+        assert os.path.basename(cc.REPO_CACHE_DIR) == ".jax-compile-cache"
 
 
 @pytest.mark.perf
@@ -274,28 +296,38 @@ class TestOverlapPerf:
         assert best_ratio >= 0.9, best_ratio
         assert not _prefetch_threads()
 
-    def test_compile_cache_reuse_across_runs(self, cpu_devices, tmp_path):
-        """Two identical run_jaxjob invocations against one persistent
-        compile cache: the second's warm-up (compile_time_s) is a disk
-        load, not an XLA compile. Single-device mesh on purpose — this
-        host's XLA:CPU AOT reload of SHARDED executables is the known
-        hazard tests/conftest.py documents."""
-        import jax
+    def test_compile_cache_reuse_across_runs(self, tmp_path):
+        """Two identical launches against one cache placed through
+        ``JAX_COMPILATION_CACHE_DIR``: the second's compile_time_s is a
+        disk load, not an XLA compile. The variable is read when jax is
+        imported, so each launch is its own process; single-device mesh
+        on purpose — this host's XLA:CPU AOT reload of SHARDED
+        executables is the known hazard tests/conftest.py documents."""
+        import json
+        import subprocess
+        import sys
+
+        from polyaxon_tpu.compiler.compile import ENV_JAXJOB_SPEC
+        from polyaxon_tpu.tracking.run import ENV_ARTIFACTS_PATH
 
         cache = str(tmp_path / "xla-cache")
+        spec = _job(steps=2, mesh={"dp": 1}, log_every=1).to_dict()
 
         def run(tag):
-            return run_jaxjob(
-                _job(steps=2, mesh={"dp": 1}, log_every=1,
-                     compile_cache_dir=cache),
-                artifacts_dir=str(tmp_path / tag),
-                devices=jax.devices()[:1])
+            out = str(tmp_path / tag)
+            env = {**os.environ, "JAX_PLATFORMS": "cpu",
+                   "XLA_FLAGS": "",  # one CPU device, not conftest's 8
+                   "JAX_COMPILATION_CACHE_DIR": cache,
+                   "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+                   ENV_JAXJOB_SPEC: json.dumps(spec),
+                   ENV_ARTIFACTS_PATH: out}
+            subprocess.run(
+                [sys.executable, "-m", "polyaxon_tpu.runtime.launch"],
+                env=env, check=True, timeout=600, capture_output=True)
+            with open(os.path.join(out, "outputs.json")) as fh:
+                return json.load(fh)["compile_time_s"]
 
         cold = run("cold")
-        import os
         assert os.listdir(cache), "cache dir is empty after a cold run"
         warm = run("warm")
-        assert warm.compile_time_s < cold.compile_time_s, (
-            cold.compile_time_s, warm.compile_time_s)
-        # Scoped config: the run restored the global jax setting.
-        assert jax.config.jax_compilation_cache_dir is None
+        assert warm < cold, (cold, warm)

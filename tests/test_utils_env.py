@@ -21,26 +21,15 @@ class TestCpuMeshXlaFlags:
     def test_defaults(self):
         flags = self._flags()
         assert "--xla_force_host_platform_device_count=8" in flags
-        # The watchdog flag is version-gated: XLA CHECK-aborts the whole
-        # process on any UNKNOWN flag in XLA_FLAGS, so on a jaxlib that
-        # predates it (< 0.5) appending it would turn every jax test
-        # into a fatal abort. Present iff this jaxlib parses it.
-        import jaxlib
+        # The installed jaxlib parses the watchdog flag (XLA CHECK-aborts
+        # on an UNKNOWN flag, which is why older images gated it).
+        assert ("--xla_cpu_collective_call_terminate_timeout_seconds=600"
+                in flags)
 
-        expect = tuple(int(p)
-                       for p in jaxlib.__version__.split(".")[:2]) >= (0, 5)
-        present = ("--xla_cpu_collective_call_terminate_timeout_seconds=600"
-                   in flags)
-        assert present == expect
-
-    def test_watchdog_gate_matches_probe(self):
-        from polyaxon_tpu.utils.env import _jaxlib_knows_collective_watchdog
-
+    def test_watchdog_timeout_param(self):
         flags = self._flags(watchdog_timeout_s=123)
-        present = any(
-            f.startswith("--xla_cpu_collective_call_terminate_timeout")
-            for f in flags)
-        assert present == _jaxlib_knows_collective_watchdog()
+        assert ("--xla_cpu_collective_call_terminate_timeout_seconds=123"
+                in flags)
 
     def test_device_count_param(self):
         assert "--xla_force_host_platform_device_count=4" in self._flags(
