@@ -245,10 +245,12 @@ class TimelineRing:
 TRACE_DUMP_FILE = "request-timelines.json"
 
 
-def dump_ring(ring: TimelineRing, path: str) -> str:
+def dump_ring(ring: TimelineRing, path: str, **beside: Any) -> str:
     """Persist a ring dump atomically (tmp + replace, the postmortem
     idiom). A directory path gets :data:`TRACE_DUMP_FILE` appended.
-    Raises on I/O failure — the caller owns fail-open policy."""
+    `beside`: further top-level keys of the file (the engine's
+    `slow_ticks`). Raises on I/O failure — the caller owns fail-open
+    policy."""
     import json
 
     if os.path.isdir(path) or path.endswith(os.sep):
@@ -258,7 +260,7 @@ def dump_ring(ring: TimelineRing, path: str) -> str:
         os.makedirs(parent, exist_ok=True)
     tmp = path + ".tmp"
     with open(tmp, "w") as fh:
-        json.dump(ring.to_dump(), fh, indent=2, default=str)
+        json.dump({**ring.to_dump(), **beside}, fh, indent=2, default=str)
     os.replace(tmp, path)
     return path
 

@@ -27,11 +27,12 @@ ragged decoder step).
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
+import statistics
 import threading
 import time
 from dataclasses import dataclass, field
-import functools
 from functools import lru_cache
 from typing import Optional
 
@@ -67,15 +68,93 @@ class _FixedShapeProgram:
         return self._compiled(*args)
 
 
-def _device_stats(params, cache) -> dict:
-    """The devices the params live on, as jax reports them, what each
-    holds of the params and the KV cache, and the first one's peak
-    memory where the backend tracks it."""
+class _PhaseClock:
+    """Where an engine tick's host time goes. ``phase(name)`` is entered
+    from the engine thread only: it opens a
+    ``jax.profiler.TraceAnnotation("engine:<name>")``, which puts the
+    span on the host plane of any running profile on the device
+    operations' own clock (a flag test when none runs), and books the
+    span's ``perf_counter_ns`` time, less what its children took, to a
+    cumulative and a per-tick vector. Leaf phases never overlap, and
+    what a tick spends under none of them (the lines between phases,
+    this clock's own cost) is `tick.other`, so the vector sums to the
+    tick. A tick longer than ``max(SLOW_FLOOR_S, SLOW_FACTOR x the
+    median of the last 64)`` leaves a record in ``slow`` with the
+    engine's state from ``snapshot()``: the black box of a stall that
+    nobody was tracing. (An engine's first tick has no median to be
+    held against, and compiles: it is never recorded.)"""
+
+    LEAVES = ("sweep", "admit.pick", "admit.match", "admit.prefill",
+              "admit.other", "prefill_chunk", "step.keys", "step.upload",
+              "step.dispatch", "step.readback", "step.emit", "spec",
+              "observe", "tick.other")
+    SLOW_FLOOR_S = 1.0
+    SLOW_FACTOR = 8.0
+    SLOW_KEPT = 16
+
+    def __init__(self, snapshot):
+        self._snapshot = snapshot
+        # Every key is there from the start: readers on other threads
+        # copy these dicts while the engine thread adds to their values.
+        self.total_ns = dict.fromkeys(self.LEAVES, 0)
+        self._tick_ns = dict.fromkeys(self.LEAVES, 0)
+        self.ticks = 0
+        self._open: list[list] = []  # per open span: [start, children's ns]
+        self._recent: collections.deque = collections.deque(maxlen=64)
+        self.slow: tuple = ()  # replaced whole, so a reader never races
+
+    @contextlib.contextmanager
+    def phase(self, name: str, book: Optional[str] = None):
+        """A span `engine:<name>`; its time outside its children is
+        booked under `book` (default: its name)."""
+        frame = [time.perf_counter_ns(), 0]
+        self._open.append(frame)
+        try:
+            with jax.profiler.TraceAnnotation("engine:" + name):
+                yield
+        finally:
+            self._open.pop()
+            took = time.perf_counter_ns() - frame[0]
+            if self._open:
+                self._open[-1][1] += took
+            key = book or name
+            self.total_ns[key] += took - frame[1]
+            self._tick_ns[key] += took - frame[1]
+
+    @contextlib.contextmanager
+    def tick(self):
+        """One loop iteration: the parent span of every phase."""
+        for key in self.LEAVES:
+            self._tick_ns[key] = 0
+        try:
+            with self.phase("tick", book="tick.other"):
+                yield
+        finally:
+            took = sum(self._tick_ns.values())
+            self.ticks += 1
+            if (took > self.SLOW_FLOOR_S * 1e9 and self._recent
+                    and took > self.SLOW_FACTOR
+                    * statistics.median(self._recent)):
+                record = {
+                    "t_wall": time.time(), "duration_ms": took / 1e6,
+                    "phases_ms": {k: v / 1e6
+                                  for k, v in self._tick_ns.items() if v},
+                    **self._snapshot()}
+                self.slow = (self.slow + (record,))[-self.SLOW_KEPT:]
+            self._recent.append(took)
+
+
+def _device_stats(params, cache) -> tuple:
+    """(first device, what `/v1/stats` says of the devices): those the
+    params live on, as jax reports them, and what each holds of the
+    params and the KV cache. Computed once, where the cache is built:
+    the counts never change, and the engine thread donates the cache's
+    buffers to every step, so a later reader must not touch them."""
     from polyaxon_tpu.parallel.sharding import bytes_per_device, param_bytes
 
     devices = sorted(jax.tree.leaves(params)[0].devices(),
                      key=lambda d: d.id)
-    return {
+    return devices[0], {
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
         "count": len(devices),
@@ -83,8 +162,6 @@ def _device_stats(params, cache) -> dict:
         "param_bytes_per_device": bytes_per_device(params),
         "kv_bytes": param_bytes(cache),
         "kv_bytes_per_device": bytes_per_device(cache),
-        "peak_hbm_bytes": (devices[0].memory_stats() or {}).get(
-            "peak_bytes_in_use"),
     }
 
 
@@ -406,6 +483,8 @@ class ContinuousBatchingEngine:
                                       kv_pages + 1,
                                       prefix_cache=prefix_cache)
         self._cache = self._new_cache()
+        self._device0, self._device_stats = _device_stats(
+            self.params, self._cache)
         self.draft = draft
         self._spec_rounds = 0
         self._spec_tokens = 0
@@ -531,6 +610,11 @@ class ContinuousBatchingEngine:
         self._steps_total = 0
         self._live_slot_steps = 0
         self._queue_depth_peak = 0
+        # Host time of the loop by phase (always on): `/v1/stats`
+        # `tick_phase_ns`, the `engine:` spans of a profile, `slow_ticks`.
+        self._clock = _PhaseClock(self._tick_snapshot)
+        self._phase = self._clock.phase
+        self._admissions = 0
         # A device that throws persistently (e.g. OOM) would otherwise
         # burn one rebuilt-cache step per queued request; after this
         # many consecutive failures the engine fails fast instead.
@@ -569,10 +653,18 @@ class ContinuousBatchingEngine:
         # Two executables; the loop picks per iteration by whether any
         # live row actually uses top-p/top-k (same idea as the static
         # engine's `filtered` compile key).
+        # Named functions, not partials: a trace then shows the program
+        # as `jit_decode_step`, not `jit__unknown`.
+        def decode_step(*args):
+            return step(*args, filtered=False)
+
+        def decode_step_filtered(*args):
+            return step(*args, filtered=True)
+
         self._step_plain = _FixedShapeProgram(jax.jit(
-            functools.partial(step, filtered=False), donate_argnums=(1,)))
+            decode_step, donate_argnums=(1,)))
         self._step_filtered = _FixedShapeProgram(jax.jit(
-            functools.partial(step, filtered=True), donate_argnums=(1,)))
+            decode_step_filtered, donate_argnums=(1,)))
         self._decode_programs = (self._step_plain, self._step_filtered)
 
         # One lru-bounded executable per prompt length for BOTH kv
@@ -981,8 +1073,11 @@ class ContinuousBatchingEngine:
         # postmortem tooling builds one around a recovered ring), so the
         # scoped view is optional here.
         obs = getattr(self, "_obs", None) or obs_metrics.REGISTRY
+        clock = getattr(self, "_clock", None)
         try:
-            path = reqtrace.dump_ring(self._ring, self.trace_dump_path)
+            path = reqtrace.dump_ring(
+                self._ring, self.trace_dump_path,
+                slow_ticks=list(clock.slow) if clock else [])
             obs_metrics.serving_trace_dumps_total(obs).inc(outcome="ok")
             logger.info("request-timeline ring dumped to %s", path)
         except Exception:
@@ -1195,7 +1290,7 @@ class ContinuousBatchingEngine:
                 continue
             # Pick under the lock: cancel() mutates the queue from HTTP
             # threads, and an unsynchronized pop can race it empty.
-            with self._cv:
+            with self._phase("admit.pick"), self._cv:
                 if not self._queue_depth():
                     break
                 req = self._pick_next_locked()
@@ -1214,8 +1309,9 @@ class ContinuousBatchingEngine:
                 self._publish_queue_depth()
             admit_res = None
             if self._pool is not None:
-                admit_res = self._pool.admit(b, len(req.tokens),
-                                             req.tokens)
+                with self._phase("admit.match"):
+                    admit_res = self._pool.admit(b, len(req.tokens),
+                                                 req.tokens)
                 if not admit_res:
                     # can_admit raced/drifted: put the request back at
                     # the head (FIFO preserved) and wait for
@@ -1230,6 +1326,7 @@ class ContinuousBatchingEngine:
                     break
             # Dequeued for real: close the queue_wait phase and feed
             # the SLO histogram (submit → admission dequeue).
+            self._admissions += 1
             obs_metrics.serving_queue_wait_hist(self._obs).observe(
                 time.time() - req.submitted_at, **{"class": req.klass})
             if req.trace is not None:
@@ -1241,96 +1338,9 @@ class ContinuousBatchingEngine:
                 if admit_res is not None:
                     skip = self._note_prefix_outcome(
                         req, admit_res, len(prefill_tokens or ()))
-                    if admit_res.cow is not None:
-                        # Fork the partially-shared page ONCE on
-                        # device; the suffix prefill then writes only
-                        # the divergent tokens into the private copy.
-                        src, dst = admit_res.cow
-                        self._cache = self._copy_page(
-                            self._cache, jnp.int32(src), jnp.int32(dst))
-                if (prefill_tokens and self.prefill_chunk is not None
-                        and len(prefill_tokens) > self.prefill_chunk):
-                    # Long prompt: reserve the slot and stream the
-                    # prompt in chunks across loop iterations instead
-                    # of blocking the pool on one monolithic prefill.
-                    if req.trace is not None:
-                        req.trace.start_phase(
-                            "prefill", mode="chunked",
-                            prompt_tokens=len(prefill_tokens),
-                            chunk=self.prefill_chunk)
-                    row_t = self._family_mod.cb_init_cache(
-                        self.cfg, 1, self.max_len)
-                    row_d = (self._draft_family.cb_init_cache(
-                        self._draft_cfg, 1, self.max_len)
-                        if self.draft is not None else None)
-                    self._prefilling[b] = [
-                        req, np.asarray(prefill_tokens, np.int32), 0,
-                        row_t, row_d, pos0, tok0]
-                    continue
-                if prefill_tokens:
-                    if skip >= len(prefill_tokens):
-                        # Whole prefill served from the radix cache:
-                        # every page is already written — no program
-                        # runs at all, decode starts immediately.
-                        if req.trace is not None:
-                            req.trace.start_phase(
-                                "prefill", mode="cached",
-                                prompt_tokens=len(prefill_tokens),
-                                cached_tokens=skip)
-                    elif skip > 0 and self._suffix_prefill is not None:
-                        # Partial hit: compute KV only for the novel
-                        # suffix, attending the matched prefix pages
-                        # gathered from the pool — O(S·P) instead of
-                        # the full O(P²) recompute.
-                        if req.trace is not None:
-                            req.trace.start_phase(
-                                "prefill", mode="suffix",
-                                prompt_tokens=len(prefill_tokens),
-                                cached_tokens=skip)
-                        suffix = prefill_tokens[skip:]
-                        n_pref = -(-skip // self._pool.page_size)
-                        bucket = bucket_suffix_len(len(suffix))
-                        padded = np.zeros(bucket, np.int32)
-                        padded[:len(suffix)] = suffix
-                        fn = self._suffix_prefill(bucket, n_pref)
-                        self._cache = fn(
-                            self.params,
-                            jnp.asarray([padded], jnp.int32),
-                            self._cache,
-                            jnp.asarray(self._pool.padded_row(b)),
-                            jnp.int32(skip),
-                            jnp.int32(len(suffix)))
-                    else:
-                        if req.trace is not None:
-                            req.trace.start_phase(
-                                "prefill", mode="monolithic",
-                                prompt_tokens=len(prefill_tokens))
-                        row = jnp.asarray([prefill_tokens], jnp.int32)
-                        fn = self._compiled_prefill(len(prefill_tokens))
-                        if self._pool is not None:
-                            self._cache = fn(
-                                self.params, row, self._cache,
-                                jnp.asarray(self._pool.padded_row(b)))
-                        else:
-                            row_cache = fn(self.params, row)
-                            self._cache = self._insert(
-                                self._cache, row_cache, jnp.int32(b))
-                if prefill_tokens and self.draft is not None:
-                    # The draft's cache row prefills the same prompt
-                    # prefix; its first query (cur at pos) writes
-                    # position pos inside the round. (Drafts require
-                    # kv='dense', so the radix skip never applies —
-                    # `row` was built by the monolithic branch.)
-                    draft_row = self._compiled_draft_prefill(
-                        len(prefill_tokens))(self._draft_params, row)
-                    self._draft_cache = self._draft_insert(
-                        self._draft_cache, draft_row, jnp.int32(b))
-                if self._pool is not None:
-                    # The prefill (or full cache hit) really wrote the
-                    # pages this admission registered: the fresh radix
-                    # leaf survives the slot from here on.
-                    self._pool.commit_prefix(b)
-                self._go_live(b, req, pos0, tok0)
+                with self._phase("admit.prefill"):
+                    self._admit_prefill(b, req, admit_res, skip, pos0,
+                                        tok0, prefill_tokens)
             except Exception as exc:  # noqa: BLE001 — request-scoped
                 if self._pool is not None:
                     # Failed admission frees pages AND forgets any
@@ -1350,6 +1360,103 @@ class ContinuousBatchingEngine:
                 if not self._count_request_failure(exc):
                     return
 
+    def _admit_prefill(self, b: int, req: _Request, admit_res, skip: int,
+                       pos0: int, tok0: int, prefill_tokens) -> None:
+        """What an admission runs on the device: the CoW fork, then the
+        prefill program for what the radix cache did not serve, and the
+        slot goes live (or, for a long prompt under `prefill_chunk`,
+        is reserved for `_advance_prefill`)."""
+        if admit_res is not None and admit_res.cow is not None:
+            # Fork the partially-shared page ONCE on device; the suffix
+            # prefill then writes only the divergent tokens into the
+            # private copy.
+            src, dst = admit_res.cow
+            self._cache = self._copy_page(
+                self._cache, jnp.int32(src), jnp.int32(dst))
+        if (prefill_tokens and self.prefill_chunk is not None
+                and len(prefill_tokens) > self.prefill_chunk):
+            # Long prompt: reserve the slot and stream the
+            # prompt in chunks across loop iterations instead
+            # of blocking the pool on one monolithic prefill.
+            if req.trace is not None:
+                req.trace.start_phase(
+                    "prefill", mode="chunked",
+                    prompt_tokens=len(prefill_tokens),
+                    chunk=self.prefill_chunk)
+            row_t = self._family_mod.cb_init_cache(
+                self.cfg, 1, self.max_len)
+            row_d = (self._draft_family.cb_init_cache(
+                self._draft_cfg, 1, self.max_len)
+                if self.draft is not None else None)
+            self._prefilling[b] = [
+                req, np.asarray(prefill_tokens, np.int32), 0,
+                row_t, row_d, pos0, tok0]
+            return
+        if prefill_tokens:
+            if skip >= len(prefill_tokens):
+                # Whole prefill served from the radix cache:
+                # every page is already written — no program
+                # runs at all, decode starts immediately.
+                if req.trace is not None:
+                    req.trace.start_phase(
+                        "prefill", mode="cached",
+                        prompt_tokens=len(prefill_tokens),
+                        cached_tokens=skip)
+            elif skip > 0 and self._suffix_prefill is not None:
+                # Partial hit: compute KV only for the novel
+                # suffix, attending the matched prefix pages
+                # gathered from the pool — O(S·P) instead of
+                # the full O(P²) recompute.
+                if req.trace is not None:
+                    req.trace.start_phase(
+                        "prefill", mode="suffix",
+                        prompt_tokens=len(prefill_tokens),
+                        cached_tokens=skip)
+                suffix = prefill_tokens[skip:]
+                n_pref = -(-skip // self._pool.page_size)
+                bucket = bucket_suffix_len(len(suffix))
+                padded = np.zeros(bucket, np.int32)
+                padded[:len(suffix)] = suffix
+                fn = self._suffix_prefill(bucket, n_pref)
+                self._cache = fn(
+                    self.params,
+                    jnp.asarray([padded], jnp.int32),
+                    self._cache,
+                    jnp.asarray(self._pool.padded_row(b)),
+                    jnp.int32(skip),
+                    jnp.int32(len(suffix)))
+            else:
+                if req.trace is not None:
+                    req.trace.start_phase(
+                        "prefill", mode="monolithic",
+                        prompt_tokens=len(prefill_tokens))
+                row = jnp.asarray([prefill_tokens], jnp.int32)
+                fn = self._compiled_prefill(len(prefill_tokens))
+                if self._pool is not None:
+                    self._cache = fn(
+                        self.params, row, self._cache,
+                        jnp.asarray(self._pool.padded_row(b)))
+                else:
+                    row_cache = fn(self.params, row)
+                    self._cache = self._insert(
+                        self._cache, row_cache, jnp.int32(b))
+        if prefill_tokens and self.draft is not None:
+            # The draft's cache row prefills the same prompt
+            # prefix; its first query (cur at pos) writes
+            # position pos inside the round. (Drafts require
+            # kv='dense', so the radix skip never applies —
+            # `row` was built by the monolithic branch.)
+            draft_row = self._compiled_draft_prefill(
+                len(prefill_tokens))(self._draft_params, row)
+            self._draft_cache = self._draft_insert(
+                self._draft_cache, draft_row, jnp.int32(b))
+        if self._pool is not None:
+            # The prefill (or full cache hit) really wrote the
+            # pages this admission registered: the fresh radix
+            # leaf survives the slot from here on.
+            self._pool.commit_prefix(b)
+        self._go_live(b, req, pos0, tok0)
+
     # ------------------------------------------------------ prefill lane
     def _admit_lane(self) -> None:
         """Disaggregated admission: queued requests land on free
@@ -1361,7 +1468,7 @@ class ContinuousBatchingEngine:
         for p in range(self.slots, self.slots + self.prefill_slots):
             if p in self._lane:
                 continue
-            with self._cv:
+            with self._phase("admit.pick"), self._cv:
                 if not self._queue_depth():
                     break
                 req = self._pick_next_locked()
@@ -1373,7 +1480,8 @@ class ContinuousBatchingEngine:
                             pages_free=self._pool.free_pages)
                     break
                 self._publish_queue_depth()
-            admit_res = self._pool.admit(p, len(req.tokens), req.tokens)
+            with self._phase("admit.match"):
+                admit_res = self._pool.admit(p, len(req.tokens), req.tokens)
             if not admit_res:
                 obs_metrics.serving_admissions_total(self._obs).inc(
                     outcome="deferred")
@@ -1382,6 +1490,7 @@ class ContinuousBatchingEngine:
                 with self._cv:
                     self._queue_for(req).appendleft(req)
                 break
+            self._admissions += 1
             obs_metrics.serving_queue_wait_hist(self._obs).observe(
                 time.time() - req.submitted_at, **{"class": req.klass})
             if req.trace is not None:
@@ -1392,9 +1501,12 @@ class ContinuousBatchingEngine:
                 skip = self._note_prefix_outcome(
                     req, admit_res, len(prefill_tokens or ()))
                 if admit_res.cow is not None:
-                    src, dst = admit_res.cow
-                    self._cache = self._copy_page(
-                        self._cache, jnp.int32(src), jnp.int32(dst))
+                    # The lane's only device work at admission; the
+                    # chunks run under `prefill_chunk`.
+                    with self._phase("admit.prefill"):
+                        src, dst = admit_res.cow
+                        self._cache = self._copy_page(
+                            self._cache, jnp.int32(src), jnp.int32(dst))
                 toks = np.asarray(prefill_tokens or [], np.int32)
                 skip = min(skip, len(toks))
                 if req.trace is not None:
@@ -1616,6 +1728,15 @@ class ContinuousBatchingEngine:
             "preemptions": dict(self._preemptions),
             "readmit_suffix_tokens": self._readmit_suffix_tokens,
             "decode_steps": self._steps_total,
+            # Host time of the loop by phase (the `engine:` spans of a
+            # profile, docs/observability.md): cumulative ns per leaf
+            # phase, which sum to the ticks' own time; the ticks longer
+            # than max(1 s, 8 x the running median), each with its
+            # phase split.
+            "ticks_total": self._clock.ticks,
+            "tick_phase_ns": dict(self._clock.total_ns),
+            "slow_ticks": list(self._clock.slow),
+            "admissions_total": self._admissions,
             # Mean fraction of slots live per decode step: ~1.0 means
             # continuous batching is actually winning; low values with
             # a deep queue mean admission (prefill) is the bottleneck.
@@ -1639,7 +1760,9 @@ class ContinuousBatchingEngine:
             "decode_kernels": {
                 name: count for program in self._decode_programs
                 for name, count in program.kernels.items()},
-            "device": _device_stats(self.params, self._cache),
+            "device": {**self._device_stats, "peak_hbm_bytes": (
+                self._device0.memory_stats() or {}).get(
+                    "peak_bytes_in_use")},
             "compile_cache": compile_cache.stats(),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
@@ -2093,10 +2216,24 @@ class ContinuousBatchingEngine:
             # Idle waiting above is excluded from the tick duration:
             # the histogram measures work per iteration (admission +
             # prefill chunk + decode step), not queue quiet time.
-            t0 = time.time()
-            if not self._tick():
+            with self._clock.tick():
+                t0 = time.time()
+                alive = self._tick()
+                if alive:
+                    with self._phase("observe"):
+                        self._observe_tick(time.time() - t0)
+            if not alive:
                 return
-            self._observe_tick(time.time() - t0)
+
+    def _tick_snapshot(self) -> dict:
+        """The engine's state beside a slow tick's phase split."""
+        return {
+            "live": sum(1 for r in self._slot_req if r is not None),
+            "prefilling": len(self._prefilling) + len(self._lane),
+            "queued": self._queue_depth(),
+            "decode_steps": self._steps_total,
+            "kv_pages_free": (self._pool.free_pages
+                              if self._pool is not None else None)}
 
     def _observe_tick(self, dt: float) -> None:
         """Engine-tick telemetry: iteration duration plus the batch
@@ -2133,30 +2270,37 @@ class ContinuousBatchingEngine:
         goes live this tick), then give the decode lane its budgeted
         steps. Returns False when fail-fast stopped the engine (the
         loop exits); True otherwise — including idle iterations."""
-        for b in range(self.slots):  # drop cancelled live requests
-            req = self._slot_req[b]
-            if req is not None and req.cancelled:
-                self._retire(b)
-        self._maybe_preempt()
-        if self.prefill_slots:
-            self._lane_handoff()  # free lane rows before admission
-            self._admit_lane()
-        else:
-            self._admit()
+        with self._phase("sweep"):
+            for b in range(self.slots):  # drop cancelled live requests
+                req = self._slot_req[b]
+                if req is not None and req.cancelled:
+                    self._retire(b)
+            self._maybe_preempt()
+        with self._phase("admit", book="admit.other"):
+            if self.prefill_slots:
+                self._lane_handoff()  # free lane rows before admission
+                self._admit_lane()
+            else:
+                self._admit()
         if self._stopped:  # admission may fail-fast mid-pass
             return False
         self._queue_depth_peak = max(self._queue_depth_peak,
                                      self._queue_depth())
         live = sum(1 for r in self._slot_req if r is not None)
         if self._lane:
-            if not self._lane_tick(live):
+            with self._phase("prefill_chunk"):
+                alive = self._lane_tick(live)
+            if not alive:
                 return False  # fail-fast stopped the engine
-            self._lane_handoff()
+            with self._phase("admit", book="admit.other"):
+                self._lane_handoff()
             live = sum(1 for r in self._slot_req if r is not None)
         elif self._prefilling:
             # Idle pool → advance every reservation (a cold-start
             # burst must not serialize one slot at a time).
-            if not self._advance_prefill(all_slots=(live == 0)):
+            with self._phase("prefill_chunk"):
+                alive = self._advance_prefill(all_slots=(live == 0))
+            if not alive:
                 return False  # fail-fast stopped the engine
             live = sum(1 for r in self._slot_req if r is not None)
         if live == 0:
@@ -2184,7 +2328,9 @@ class ContinuousBatchingEngine:
                     self.spec_k))
                 obs_metrics.serving_spec_draft_len(self._obs).set(k)
                 if k > 0:
-                    if not self._spec_iteration(k):
+                    with self._phase("spec"):
+                        alive = self._spec_iteration(k)
+                    if not alive:
                         return False
                     self._note_decode_step()
                     continue
@@ -2213,30 +2359,42 @@ class ContinuousBatchingEngine:
         """One ragged decode step for the decode pool. Returns False
         when fail-fast stopped the engine."""
         try:
-            keys = jnp.stack([
-                jax.random.fold_in(self._keys[b],
-                                   len(r.out) if (r := self._slot_req[b])
-                                   else 0)
-                for b in range(self.slots)])
+            with self._phase("step.keys"):
+                keys = jnp.stack([
+                    jax.random.fold_in(
+                        self._keys[b],
+                        len(r.out) if (r := self._slot_req[b]) else 0)
+                    for b in range(self.slots)])
             filtered = any(
                 r is not None and (r.top_p < 1.0 or r.top_k > 0)
                 for r in self._slot_req)
-            # Decode sees ONLY the decode-pool rows: lane rows sit
-            # past self.slots and belong to staged prefills.
-            tables = (jnp.asarray(self._pool.tables[:self.slots])
-                      if self._pool is not None else None)
             step_fn = (self._step_filtered if filtered
                        else self._step_plain)
-            nxt, self._cache = step_fn(
-                self.params, self._cache,
-                jnp.asarray(self._cur), jnp.asarray(self._pos),
-                keys, jnp.asarray(self._temps),
-                jnp.asarray(self._top_ps), jnp.asarray(self._top_ks),
-                tables)
-            nxt = np.asarray(nxt)
+            with self._phase("step.upload"):
+                # Decode sees ONLY the decode-pool rows: lane rows sit
+                # past self.slots and belong to staged prefills.
+                tables = (jnp.asarray(self._pool.tables[:self.slots])
+                          if self._pool is not None else None)
+                cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
+                temps = jnp.asarray(self._temps)
+                top_ps = jnp.asarray(self._top_ps)
+                top_ks = jnp.asarray(self._top_ks)
+            with self._phase("step.dispatch"):
+                nxt, self._cache = step_fn(
+                    self.params, self._cache, cur, pos, keys, temps,
+                    top_ps, top_ks, tables)
+            with self._phase("step.readback"):
+                nxt = np.asarray(nxt)
         except Exception as exc:  # noqa: BLE001 — fail live requests
             return self._handle_step_failure(exc, "decode step")
         self._consec_step_failures = 0
+        with self._phase("step.emit"):
+            self._emit_step(nxt)
+        return True
+
+    def _emit_step(self, nxt: np.ndarray) -> None:
+        """Hand each live slot its token of the step just read back:
+        append, retire at budget or eos, or take the next page."""
         for b in range(self.slots):
             req = self._slot_req[b]
             if req is None:
@@ -2264,4 +2422,3 @@ class ContinuousBatchingEngine:
                     f"(pos {int(self._pos[b])}); raise --kv-pages "
                     "or lower concurrency")
                 self._retire(b)
-        return True

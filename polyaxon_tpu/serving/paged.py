@@ -610,5 +610,8 @@ class PagePool:
         return out
 
     def padded_row(self, slot: int) -> np.ndarray:
-        """The slot's block-table row (fixed [max_pages_per_row])."""
-        return self.tables[slot]
+        """The slot's block-table row (fixed [max_pages_per_row]), as a
+        copy: the caller hands it to a program that may run after the
+        row has moved on (a handoff, the next page), and `jnp.asarray`
+        of a view can alias the table itself on the CPU backend."""
+        return self.tables[slot].copy()
