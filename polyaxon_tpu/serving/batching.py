@@ -144,6 +144,17 @@ class _PhaseClock:
             self._recent.append(took)
 
 
+def step_keys(seeds, counts):
+    """The decode step's sampling keys, derived inside the compiled
+    program: row ``b``'s is ``fold_in(key(seeds[b]), counts[b])``, bit
+    for bit the key the engine used to build on the host for every
+    token. `seeds` is what ``jnp.asarray`` makes of the requests' int64
+    seeds, `counts` the tokens each request has so far."""
+    return jax.vmap(
+        lambda seed, count: jax.random.fold_in(jax.random.key(seed), count)
+    )(seeds, counts)
+
+
 def _device_stats(params, cache) -> tuple:
     """(first device, what `/v1/stats` says of the devices): those the
     params live on, as jax reports them, and what each holds of the
@@ -544,10 +555,16 @@ class ContinuousBatchingEngine:
         self._prefilling: dict[int, list] = {}
         self._pos = np.full(slots, -1, np.int32)  # -1 = free slot
         self._cur = np.zeros(slots, np.int32)
+        # Per-slot sampling state: written at admission and at retire
+        # (`_set_sampling`), never per token. The decode step reads the
+        # device copy, uploaded again only after a write. Seeds stay
+        # int64 on the host: `jnp.asarray` narrows them exactly as
+        # `jax.random.key(int)` narrows a Python int.
+        self._seeds = np.zeros(slots, np.int64)
         self._temps = np.zeros(slots, np.float32)
         self._top_ps = np.ones(slots, np.float32)
         self._top_ks = np.zeros(slots, np.int32)
-        self._keys = [jax.random.key(0)] * slots
+        self._sampling_dev: Optional[tuple] = None
         self._slot_req: list[Optional[_Request]] = [None] * slots
 
         # Graceful degradation: a bounded pending queue. None =
@@ -620,10 +637,11 @@ class ContinuousBatchingEngine:
         # many consecutive failures the engine fails fast instead.
         self.max_step_failures = 3
 
-        def step(params, cache, tokens, pos, keys, temps, top_ps, top_ks,
-                 tables, *, filtered: bool):
+        def step(params, cache, tokens, pos, seeds, counts, temps, top_ps,
+                 top_ks, tables, *, filtered: bool):
             from polyaxon_tpu.models.common import sample_row
 
+            keys = step_keys(seeds, counts)
             # Quantized trees pass through whole — weights unwrap at
             # consumption inside the model (models/llama.py _w), so
             # int8 stays the HBM format in the per-step program.
@@ -1820,10 +1838,19 @@ class ContinuousBatchingEngine:
         self._slot_req[b] = req
         self._pos[b] = pos0
         self._cur[b] = tok0
-        self._temps[b] = req.temperature
-        self._top_ps[b] = req.top_p
-        self._top_ks[b] = req.top_k
-        self._keys[b] = jax.random.key(req.seed)
+        self._set_sampling(b, req.temperature, req.top_p, req.top_k,
+                           req.seed)
+
+    def _set_sampling(self, b: int, temperature: float = 0.0,
+                      top_p: float = 1.0, top_k: int = 0,
+                      seed: int = 0) -> None:
+        """Slot ``b``'s sampling state (the defaults: a free slot's).
+        The next decode step uploads the four vectors again."""
+        self._seeds[b] = seed
+        self._temps[b] = temperature
+        self._top_ps[b] = top_p
+        self._top_ks[b] = top_k
+        self._sampling_dev = None
 
     def _count_request_failure(self, exc: Exception) -> bool:
         """Request-scoped device-failure accounting, shared by the
@@ -2065,9 +2092,7 @@ class ContinuousBatchingEngine:
         self._pos[b] = -1
         if self._pool is not None:
             self._pool.release(b)
-        self._temps[b] = 0.0
-        self._top_ps[b] = 1.0
-        self._top_ks[b] = 0
+        self._set_sampling(b)
         if req is not None:
             if req.cancelled and not req.error:
                 req.error = "cancelled"
@@ -2180,9 +2205,7 @@ class ContinuousBatchingEngine:
         discarded = len(req.out)
         self._slot_req[b] = None
         self._pos[b] = -1
-        self._temps[b] = 0.0
-        self._top_ps[b] = 1.0
-        self._top_ks[b] = 0
+        self._set_sampling(b)
         self._pool.release(b)
         req.preemptions += 1
         req.out.clear()
@@ -2360,11 +2383,11 @@ class ContinuousBatchingEngine:
         when fail-fast stopped the engine."""
         try:
             with self._phase("step.keys"):
-                keys = jnp.stack([
-                    jax.random.fold_in(
-                        self._keys[b],
-                        len(r.out) if (r := self._slot_req[b]) else 0)
-                    for b in range(self.slots)])
+                # What the program folds into each slot's base key: the
+                # tokens its request has so far (0 for a free slot).
+                counts = np.fromiter(
+                    (len(r.out) if r is not None else 0
+                     for r in self._slot_req), np.int32, self.slots)
             filtered = any(
                 r is not None and (r.top_p < 1.0 or r.top_k > 0)
                 for r in self._slot_req)
@@ -2376,13 +2399,21 @@ class ContinuousBatchingEngine:
                 tables = (jnp.asarray(self._pool.tables[:self.slots])
                           if self._pool is not None else None)
                 cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
-                temps = jnp.asarray(self._temps)
-                top_ps = jnp.asarray(self._top_ps)
-                top_ks = jnp.asarray(self._top_ks)
+                counts = jnp.asarray(counts)
+                if self._sampling_dev is None:
+                    # Copies, made on the host (`jnp.array` would run a
+                    # device program for each): the CPU backend aliases
+                    # what it is handed, and the next admission writes
+                    # these.
+                    self._sampling_dev = tuple(
+                        jnp.asarray(a.copy())
+                        for a in (self._seeds, self._temps, self._top_ps,
+                                  self._top_ks))
+                seeds, temps, top_ps, top_ks = self._sampling_dev
             with self._phase("step.dispatch"):
                 nxt, self._cache = step_fn(
-                    self.params, self._cache, cur, pos, keys, temps,
-                    top_ps, top_ks, tables)
+                    self.params, self._cache, cur, pos, seeds, counts,
+                    temps, top_ps, top_ks, tables)
             with self._phase("step.readback"):
                 nxt = np.asarray(nxt)
         except Exception as exc:  # noqa: BLE001 — fail live requests

@@ -59,10 +59,12 @@ def run(model):
         outs = engine.generate(PROMPTS, max_new_tokens=60, timeout=300)
         stop.set()
         poller.join(timeout=30)
-        after = engine.stats()
     finally:
         stop.set()
         engine.stop()
+    # Read with the engine's thread ended: a request is done before the
+    # tick that finished it is counted.
+    after = engine.stats()
     hist = registry.snapshot()[
         "polyaxon_serving_engine_tick_seconds"]["series"][""]
     return {"before": before, "after": after, "samples": samples,
