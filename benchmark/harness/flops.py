@@ -7,12 +7,22 @@ The embedding is a row lookup and counts nothing. Training needs three
 times the forward pass (one forward, two matmuls a projection in the
 backward); recomputation under remat is the program's choice and does
 not count.
+
+A family whose layers are not all alike brings its own count, as
+``forward_flops_per_token(config, layers, seq_len)`` in its file under
+``families/``; where a family gives none, every layer is counted alike
+from the keys of Mistral's and Mixtral's published files.
 """
 
 from __future__ import annotations
 
+from harness import spec
+
 
 def forward_flops_per_token(config: dict, layers: int, seq_len: int) -> float:
+    own = getattr(spec.load_family(config), "forward_flops_per_token", None)
+    if own is not None:
+        return float(own(config, layers, seq_len))
     d = config["hidden_size"]
     q = config["num_attention_heads"] * config["head_dim"]
     kv = config["num_key_value_heads"] * config["head_dim"]

@@ -4,60 +4,88 @@ The zoo's entries depart from the published files (``mistral_7b`` has a
 4,096 window and a 32,000 vocabulary, ``mixtral_8x7b`` a 5e5
 ``rope_theta`` and 8k positions), and neither `plx serve` nor the job
 spec can override every field. So the configuration's file is turned
-into the family's config dataclass and registered in its ``CONFIGS``
-(and the zoo's factory table) under the configuration's name; from
-there on the program's normal path runs: ``ServingServer`` for serving,
-``run_jaxjob`` for training.
+into its family's config dataclass and registered in the family's
+``CONFIGS`` (and the zoo's factory table) under the configuration's
+name; from there on the program's normal path runs: ``ServingServer``
+for serving, ``run_jaxjob`` for training.
+
+This file names no family. A configuration's ``family`` key names a
+file, ``families/<family>.py`` (``spec.load_family``: beside the
+configuration's own tree first, then under the benchmark's), and a PR
+that brings a model brings that file with its configuration and its
+reference, and edits nothing here.
+
+**What the harness asks**, of the three things such a PR brings.
+
+*The family file* gives ``build(config, role) -> (module, cfg)``: the
+program's family module and an instance of its config dataclass for
+`role` (``serve`` or ``train``), read from the configuration file's own
+keys, whatever the published file calls them, at the depth and context
+limit of the configuration's ``serve`` / ``train`` section. Every check
+that belongs to the family (what its program cannot express) is made
+there. It may give ``forward_flops_per_token(config, layers, seq_len)``,
+the benchmark's own count where the layers are not all alike
+(``harness/flops.py``). It imports the program inside ``build`` only:
+the parent process loads it and never imports jax.
+
+*Of the pair, and of the program behind it*, the harness uses:
+
+- ``module.CONFIGS``, a dict: `register` writes ``cfg`` into it under
+  the configuration's name, and ``models._FACTORIES[name]`` to a call
+  of ``module.model_def(name, **overrides)``. The program finds a name
+  by walking fixed lists of family modules (``serving/server.py
+  _family``, ``runtime/loop.py _model_config_cls`` and ``_get_cfg``), so
+  a new ``models/<family>.py`` has to be in those lists;
+- ``cfg.vocab_size`` (the load generator draws token ids under it) and
+  ``cfg.n_layers`` (reported; the reference is given it as its depth);
+- serving: ``ServingServer(name, seed=, batching="continuous",
+  kv="paged", slots=, page_size=, kv_pages=, prefix_cache=True)`` from
+  the configuration's ``serve`` section, its ``/v1/generate``,
+  ``/requests/<id>/timeline`` and ``engine.stats()``
+  (``kv_invariant_violations``, ``step_failures``, ``rejected``,
+  ``decode_steps`` decide `correct` or feed readers). The engine asks
+  the module for ``decode_step_ragged``, ``cb_init_cache``,
+  ``cb_prefill``, ``cb_admission``, ``cb_validate``,
+  ``insert_cache_row``, ``decode_step_paged``, ``paged_init_cache``,
+  ``paged_prefill_kv``, ``paged_insert_prefill`` and, for the radix
+  cache, ``paged_gather``, ``paged_prefill_suffix_kv``,
+  ``paged_insert_suffix``; weights come from
+  ``module.init(cfg, jax.random.key(seed))["params"]``;
+- training: ``run_jaxjob`` of a ``jaxjob`` whose ``runtime:`` section is
+  `runtime_section` (plus ``capacity_factor`` where the ``train``
+  section has one) on the ``train`` section's ``mesh``, with
+  ``on_metrics`` after every step (``loss``, ``grad_norm``),
+  ``should_stop``, and a state of ``params`` and an ``opt_state`` whose
+  adam part has ``mu`` (``train_phase.StateWatch`` reads both).
+
+*The reference module* (the configuration's ``reference`` key, a file
+under ``reference/`` that imports nothing of the program and makes the
+weights again from the seed, bit for bit the program's):
+
+- ``init_weights(config, layers, seed)``;
+- ``logits(config, weights, tokens, precision)``, tokens ``[1, n]`` at
+  one padded length, ``precision`` ``"highest"`` or, for the control,
+  ``"int8"``;
+- for a training cell ``train_steps(config, layers, seed, *, steps,
+  batch, seq_len, lr, wd, clip, precision, capacity_factor,
+  shardings)`` returning ``steps`` (a ``loss`` and a ``grad_norm``
+  each), ``grad0_leaf`` and ``update_leaf``: norms by the leaf, named
+  as the program's ``params`` tree names its leaves (``/``-joined keys).
+
+``tools/aot_memory.py`` reaches further into the two families that are
+here; it is a sizing tool, not on a run's path.
 """
 
 from __future__ import annotations
 
-
-def _depth(config: dict, role: str) -> int:
-    return int(config.get(role, {}).get("num_hidden_layers",
-                                        config["num_hidden_layers"]))
+from harness import spec
 
 
 def build_model_config(config: dict, role: str):
     """(family module, its config dataclass instance) for `role`
-    (``serve`` or ``train``), straight from the published keys."""
-    import jax.numpy as jnp
-
-    if config["hidden_act"] != "silu" or config["tie_word_embeddings"]:
-        raise ValueError("the families here are SwiGLU with an untied head")
-    if config["hidden_size"] != (config["head_dim"]
-                                 * config["num_attention_heads"]):
-        raise ValueError("the program derives head_dim from hidden_size")
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        config["torch_dtype"]]
-    shared = dict(
-        vocab_size=config["vocab_size"], dim=config["hidden_size"],
-        n_layers=_depth(config, role), n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        ffn_dim=config["intermediate_size"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]), dtype=dtype,
-        max_seq_len=int(config.get(role, {}).get(
-            "max_len", config["max_position_embeddings"])))
-    if config["family"] == "llama":
-        from polyaxon_tpu.models import llama
-
-        return llama, llama.LlamaConfig(
-            sliding_window=config["sliding_window"], rope_scaling=None,
-            **shared)
-    if config["family"] == "moe":
-        from polyaxon_tpu.models import moe
-
-        if config["sliding_window"] is not None:
-            raise ValueError("the moe family has no sliding window")
-        section = config.get(role, {})
-        return moe, moe.MoEConfig(
-            n_experts=config["num_local_experts"],
-            experts_per_token=config["num_experts_per_tok"],
-            router_aux_coef=float(config["router_aux_loss_coef"]),
-            capacity_factor=float(section.get("capacity_factor", 1.25)),
-            **shared)
-    raise ValueError(f"unknown family `{config['family']}`")
+    (``serve`` or ``train``), as the configuration's family file reads
+    it from the configuration's own keys."""
+    return spec.load_family(config).build(config, role)
 
 
 def register(config: dict, role: str):

@@ -1,9 +1,10 @@
 """Finding a cell's files by the names BENCHMARK.json gives.
 
-A cell names a configuration and a traffic mix; a metric names its
-reader. Each lives in a file of its own under the benchmark's
-directory, so a later PR adds a cell, a configuration, a mix or a metric
-by adding files and entries, and edits none that is there.
+A cell names a configuration and a traffic mix; a configuration names
+its family and its reference; a metric names its reader. Each lives in a
+file of its own under the benchmark's directory, so a later PR adds a
+cell, a configuration, a family, a mix or a metric by adding files and
+entries, and edits none that is there.
 """
 
 from __future__ import annotations
@@ -56,6 +57,13 @@ def load_traffic(name: str, traffic_dir: str | None = None) -> dict:
         return json.load(fh)
 
 
+def _tree(config: dict) -> str:
+    """The directory whose ``configs/`` holds the configuration's file:
+    the benchmark's own, or a fixture tree's."""
+    path = config.get("_path")
+    return os.path.dirname(os.path.dirname(path)) if path else BENCH_DIR
+
+
 class Cell:
     """One entry of ``workloads`` with everything its names lead to."""
 
@@ -66,10 +74,10 @@ class Cell:
         self.name = workload
         self.chips = int(self.entry["chips"])
         self.config = load_config(self.entry["config"], self.bench, root)
+        load_family(self.config)        # a family with no file fails here
         self.traffic = load_traffic(
             self.entry["traffic"],
-            os.path.join(os.path.dirname(os.path.dirname(
-                self.config["_path"])), "traffic"))
+            os.path.join(_tree(self.config), "traffic"))
         self.kind = {"closed": "serve", "open": "serve",
                      "train": "train"}[self.traffic["kind"]]
 
@@ -96,6 +104,14 @@ class Cell:
         return out
 
 
+def _module(path: str, name: str):
+    modspec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    module = importlib.util.module_from_spec(modspec)
+    modspec.loader.exec_module(module)
+    return module
+
+
 def load_reader(metric_name: str):
     """``layer_metrics/<name>.py``: a module with ``read(ctx)`` that
     returns the value, or None where it finds nothing to read."""
@@ -103,23 +119,34 @@ def load_reader(metric_name: str):
     if not os.path.exists(path):
         raise SpecError(f"per-layer metric `{metric_name}` has no reader "
                         f"at {path}")
-    modspec = importlib.util.spec_from_file_location(
-        "layer_metric_" + metric_name.replace(".", "_").replace("-", "_"),
-        path)
-    module = importlib.util.module_from_spec(modspec)
-    modspec.loader.exec_module(module)
-    return module
+    return _module(path, "layer_metric_" + metric_name)
 
 
 def load_kernel(kernel_name: str):
     """``kernels/<kernel>.py``: the operations and bytes the algorithm
     needs for one call, from its shapes."""
-    path = os.path.join(BENCH_DIR, "kernels", f"{kernel_name}.py")
-    modspec = importlib.util.spec_from_file_location(
-        "kernel_" + kernel_name, path)
-    module = importlib.util.module_from_spec(modspec)
-    modspec.loader.exec_module(module)
-    return module
+    return _module(os.path.join(BENCH_DIR, "kernels", f"{kernel_name}.py"),
+                   "kernel_" + kernel_name)
+
+
+def load_family(config: dict):
+    """``families/<family>.py``, by the configuration's ``family`` key:
+    a module with ``build(config, role)`` and, where its layers are not
+    all alike, ``forward_flops_per_token`` (the contract is in
+    ``harness/program.py``). Looked for beside the configuration's own
+    tree first, as its traffic is, then under the benchmark's."""
+    family = config.get("family")
+    if not family:
+        raise SpecError(f"configuration `{config.get('name')}` names no "
+                        "family")
+    paths = [os.path.join(tree, "families", f"{family}.py")
+             for tree in dict.fromkeys([_tree(config), BENCH_DIR])]
+    for path in paths:
+        if os.path.exists(path):
+            return _module(path, "family_" + family)
+    raise SpecError(f"configuration `{config.get('name')}` is of family "
+                    f"`{family}`, which has no file at "
+                    + " or ".join(paths))
 
 
 def load_peaks(device_kind: str) -> dict:

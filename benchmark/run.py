@@ -97,7 +97,8 @@ def make_plan(cell, *, seed: int, seconds: float, trace: bool,
               keep_trace: bool = False, break_path: str | None = None,
               rate: float | None = None, root: str = ROOT) -> dict:
     """What a phase is handed: the cell's files, the run's arguments and
-    an empty directory for what it writes."""
+    an empty directory for what it writes. The configuration keeps its
+    ``_path``: its family is looked for beside its own tree first."""
     out_dir = os.path.join(root, ".benchmark_out",
                            f"{cell.name}-{seed}-{int(trace)}")
     shutil.rmtree(out_dir, ignore_errors=True)
@@ -106,7 +107,7 @@ def make_plan(cell, *, seed: int, seconds: float, trace: bool,
         "workload": cell.name, "chips": cell.chips, "seed": seed,
         "seconds": seconds, "trace": bool(trace),
         "trace_seconds": TRACE_SECONDS, "require_chip": require_chip,
-        "config": {k: v for k, v in cell.config.items() if k != "_path"},
+        "config": cell.config,
         "traffic": ({**cell.traffic, "rate_per_s": rate} if rate
                     else cell.traffic),
         "out_dir": out_dir, "t_start": T_START,

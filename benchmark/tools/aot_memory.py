@@ -17,6 +17,12 @@ The program asks ``jax.default_backend()`` to choose between a Pallas
 kernel and its reference path; under the CPU backend it would compile
 the reference. The script answers "tpu" for it while it lowers (steering
 done here, not through an option of the program).
+
+A sizing tool, not on a run's path: where a run goes through the
+program's server, this calls the family module's ``init`` and paged
+functions (``paged_init_cache``, ``decode_step_paged``, the prefill and
+suffix pairs) itself, with the arguments the two families here take. A
+family with other state to size brings a tool of its own.
 """
 
 from __future__ import annotations
