@@ -9,7 +9,8 @@ from __future__ import annotations
 
 from typing import Callable
 
-from polyaxon_tpu.models import bert, llama, mnist, moe, resnet, t5, vit
+from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet, t5,
+                                 vit)
 from polyaxon_tpu.models.common import ModelDef
 
 _FACTORIES: dict[str, Callable[..., ModelDef]] = {}
@@ -18,6 +19,8 @@ for _name in llama.CONFIGS:
     _FACTORIES[_name] = (lambda n: lambda **kw: llama.model_def(n, **kw))(_name)
 for _name in moe.CONFIGS:
     _FACTORIES[_name] = (lambda n: lambda **kw: moe.model_def(n, **kw))(_name)
+for _name in lfm2.CONFIGS:
+    _FACTORIES[_name] = (lambda n: lambda **kw: lfm2.model_def(n, **kw))(_name)
 for _name in vit.CONFIGS:
     _FACTORIES[_name] = (lambda n: lambda **kw: vit.model_def(n, **kw))(_name)
 for _name in bert.CONFIGS:

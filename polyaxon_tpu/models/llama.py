@@ -233,6 +233,15 @@ def _norm(cfg, x: jax.Array, weight: jax.Array) -> jax.Array:
                     offset=getattr(cfg, "norm_offset", 0.0))
 
 
+def _qk_norm(cfg, layer: dict, q: jax.Array, k: jax.Array):
+    """RMSNorm over each head's own dims on q and on k, before RoPE, for
+    a layer that carries ``q_norm``/``k_norm`` gains ([Hd]); a layer
+    without them (every llama and moe preset) gets q and k back."""
+    if "q_norm" not in layer:
+        return q, k
+    return _norm(cfg, q, layer["q_norm"]), _norm(cfg, k, layer["k_norm"])
+
+
 def _act(cfg):
     """MLP gate activation: SwiGLU (silu) or Gemma's tanh-approx GeGLU."""
     kind = getattr(cfg, "mlp_activation", "silu")
@@ -507,6 +516,7 @@ def cached_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     q = (h @ _w(layer["wq"], dt)).reshape(B, 1, H, Hd)
     k = (h @ _w(layer["wk"], dt)).reshape(B, 1, KV, Hd)
     v = (h @ _w(layer["wv"], dt)).reshape(B, 1, KV, Hd)
+    q, k = _qk_norm(cfg, layer, q, k)
     scaling = getattr(cfg, "rope_scaling", None)
     q = _rope(q, positions, cfg.rope_theta, scaling)
     k = _rope(k, positions, cfg.rope_theta, scaling)
@@ -782,6 +792,15 @@ def paged_gather(pages: jax.Array, page_ids: jax.Array) -> jax.Array:
     return got.reshape(*got.shape[:-4], -1, *got.shape[-2:])
 
 
+def paged_gather_prefix(cache: dict, page_ids: jax.Array) -> tuple:
+    """What a suffix prefill reads of the matched pages ``page_ids``
+    ([n], clamped to real ids, in chain order): their K and V,
+    token-major [L, n·page, KV, Hd] each, in the order
+    ``paged_prefill_suffix_kv`` takes them."""
+    return (paged_gather(cache["k"], page_ids),
+            paged_gather(cache["v"], page_ids))
+
+
 def paged_scatter(pool: jax.Array, kv: jax.Array, page_idx: jax.Array,
                   off: jax.Array) -> jax.Array:
     """Write token-major ``kv`` [L, T, KV, Hd] into the whole pool
@@ -819,6 +838,7 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
     q = (h @ _w(layer["wq"], dt)).reshape(B, 1, H, Hd)
     k = (h @ _w(layer["wk"], dt)).reshape(B, 1, KV, Hd)
     v = (h @ _w(layer["wv"], dt)).reshape(B, 1, KV, Hd)
+    q, k = _qk_norm(cfg, layer, q, k)
     scaling = getattr(cfg, "rope_scaling", None)
     q = _rope(q, positions, cfg.rope_theta, scaling)
     k = _rope(k, positions, cfg.rope_theta, scaling)
@@ -951,6 +971,7 @@ def suffix_attn_step(cfg, layer: dict, x: jax.Array, k_prefix: jax.Array,
     q = (h @ _w(layer["wq"], dt)).reshape(B, S, H, Hd)
     k = (h @ _w(layer["wk"], dt)).reshape(B, S, KV, Hd)
     v = (h @ _w(layer["wv"], dt)).reshape(B, S, KV, Hd)
+    q, k = _qk_norm(cfg, layer, q, k)
     q = _rope(q, positions, cfg.rope_theta, scaling)
     k = _rope(k, positions, cfg.rope_theta, scaling)
     keys = repeat_kv(jnp.concatenate([k_prefix, k], axis=1), n_rep)
@@ -1033,8 +1054,10 @@ def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
     }
 
 
-def generate(
-    cfg: LlamaConfig,
+def generate_loop(
+    prefill_fn,  # (cfg, params, prompt, max_len) -> (logits [B, V], cache)
+    decode_step_fn,  # (cfg, params, cache, tokens [B], pos) -> (logits, cache)
+    cfg,
     params: dict,
     prompt: jax.Array,  # [B, P] int32
     *,
@@ -1044,7 +1067,9 @@ def generate(
     top_k: int = 0,
     rng: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Greedy (temperature 0) or sampled continuation: [B, max_new].
+    """Greedy (temperature 0) or sampled continuation: [B, max_new] —
+    one family's prefill, then its scalar-position decode step in a
+    scan; every decoder family's ``generate`` is this loop.
 
     ``temperature``/``top_p``/``top_k`` may be traced scalars (the
     serving path passes them as jitted arguments so sweeping knobs
@@ -1059,7 +1084,7 @@ def generate(
         raise ValueError("sampling (temperature > 0) needs an rng key")
     rng = rng if rng is not None else jax.random.key(0)
 
-    logits, cache = prefill(cfg, params, prompt, P + max_new_tokens)
+    logits, cache = prefill_fn(cfg, params, prompt, P + max_new_tokens)
 
     def sample(logits, key):
         if sampling:
@@ -1070,12 +1095,18 @@ def generate(
         cache, logits, key = carry
         key, sub = jax.random.split(key)
         token = sample(logits, sub).astype(jnp.int32)
-        logits, cache = decode_step(cfg, params, cache, token, P + t)
+        logits, cache = decode_step_fn(cfg, params, cache, token, P + t)
         return (cache, logits, key), token
 
     (_, logits, _), tokens = jax.lax.scan(
         decode_loop, (cache, logits, rng), jnp.arange(max_new_tokens))
     return tokens.T  # [B, max_new]
+
+
+def generate(cfg: LlamaConfig, params: dict, prompt: jax.Array, **sampling):
+    """``generate_loop`` over this family's prefill and decode step."""
+    return generate_loop(prefill, decode_step, cfg, params, prompt,
+                         **sampling)
 
 
 def apply(

@@ -52,7 +52,6 @@ from polyaxon_tpu.models.common import (
     Variables,
     chunked_lm_loss,
     rms_norm,
-    sample_logits,
     scaled_init,
     shift_right,
     truncated_normal_init,
@@ -195,6 +194,14 @@ def _router_aux_loss(cfg: MoEConfig, frac_tokens: jax.Array,
     return cfg.n_experts * jnp.sum(frac_tokens * frac_probs)
 
 
+def _expert_ffn(expert_in: jax.Array, w_gate, w_up, w_down, dt) -> jax.Array:
+    """Every expert's SwiGLU over its own buffer: [E, C, D] → [E, C, D],
+    weights read at the point of use (``_w``)."""
+    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
+    up = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
+    return jnp.einsum("ecf,efd->ecd", gate * up, _w(w_down, dt))
+
+
 def _moe_ragged_sharded(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down,
                         *, ep: int, axis_name: Optional[str]):
     """Ragged expert dispatch for one ep shard (or the whole problem
@@ -231,9 +238,7 @@ def _moe_ragged_sharded(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down,
     s_cap = min(s_cap, T_loc * K)
 
     logits = (x @ _w(router_w, dt)).astype(jnp.float32)  # [T_loc, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_probs, top_idx = jax.lax.top_k(probs, K)  # [T_loc, K]
-    top_probs = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+    top_idx, top_probs, probs = route(cfg, logits)  # [T_loc, K]
 
     # ---- flatten (token, choice) pairs, token-major -----------------
     P_ = T_loc * K
@@ -273,10 +278,7 @@ def _moe_ragged_sharded(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down,
     expert_in = jnp.zeros((E_loc, capacity, D), dt).at[
         jnp.where(keep_e, eid, E_loc), slot_e].set(rx, mode="drop")
 
-    gate = jax.nn.silu(
-        jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
-    up = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up, _w(w_down, dt))
+    expert_out = _expert_ffn(expert_in, w_gate, w_up, w_down, dt)
 
     out_rows = jnp.where(
         keep_e[:, None],
@@ -348,6 +350,64 @@ def _moe_ragged(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down):
     return out.reshape(B, S, D), aux
 
 
+def route(cfg, logits: jax.Array, expert_bias: Optional[jax.Array] = None):
+    """Router logits [T, E] fp32 → (chosen experts [T, K] int32, their
+    combine weights [T, K] fp32, every expert's score [T, E]).
+
+    Two scorings share every dispatch below. Softmax (Mixtral's, the
+    default): the K largest probabilities, renormalised to sum to one.
+    Sigmoid (``cfg.router_score == "sigmoid"``): each expert scored on
+    its own; the K experts are chosen by ``score + expert_bias`` (a
+    load-balancing buffer that steers selection only) but weighted by
+    the score alone, over ``sum + 1e-6`` when ``cfg.norm_topk_prob``,
+    times ``cfg.routed_scaling_factor``."""
+    K = cfg.experts_per_token
+    if getattr(cfg, "router_score", "softmax") == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_idx = jax.lax.top_k(probs, K)  # [T, K]
+        return top_idx, top_w / jnp.sum(top_w, axis=-1, keepdims=True), probs
+    scores = jax.nn.sigmoid(logits)
+    chosen_by = scores if expert_bias is None else scores + expert_bias
+    _, top_idx = jax.lax.top_k(chosen_by, K)
+    top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+    if cfg.norm_topk_prob:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6)
+    return top_idx, top_w * cfg.routed_scaling_factor, scores
+
+
+def dense_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
+                   w_gate, w_up, w_down, capacity: int, dt):
+    """The GShard one-hot dispatch, the batched SwiGLU experts and the
+    weighted combine, for any routing `route` gives: ``tokens`` [T, D],
+    ``top_idx``/``top_w`` [T, K] → (out [T, D], the choices' one-hot
+    [T, K, E]). An expert holds ``capacity`` tokens, filled
+    choice-major in token order; a pair beyond that is dropped."""
+    T, K = top_idx.shape
+    E = w_gate.shape[0]
+    # Per k-choice: position of each token inside its expert's buffer =
+    # how many earlier (token, choice) pairs picked that expert.
+    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [T, K, E]
+    oh_km = onehot.transpose(1, 0, 2)  # choice-major [K, T, E]
+    flat = oh_km.reshape(K * T, E)
+    positions = (jnp.cumsum(flat, axis=0) - flat)  # [K*T, E] slots used before
+    pos_in_expert = jnp.sum(positions * flat, axis=-1).reshape(K, T)  # [K, T]
+    keep = pos_in_expert < capacity
+
+    # dispatch[t, e, c] = 1 where token t sits in slot c of expert e.
+    slot_onehot = jax.nn.one_hot(
+        pos_in_expert.astype(jnp.int32), capacity, dtype=jnp.float32)
+    dispatch = jnp.einsum(
+        "kte,ktc->tec", oh_km,
+        slot_onehot * keep[..., None].astype(jnp.float32))
+    combine = jnp.einsum(
+        "kte,ktc,kt->tec", oh_km, slot_onehot,
+        top_w.T * keep.astype(jnp.float32))
+
+    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dt), tokens)  # [E,C,D]
+    expert_out = _expert_ffn(expert_in, w_gate, w_up, w_down, dt)
+    return jnp.einsum("tec,ecd->td", combine.astype(dt), expert_out), onehot
+
+
 def moe_block(
     cfg: MoEConfig,
     x: jax.Array,  # [B, S, D]
@@ -382,53 +442,24 @@ def moe_block(
 
     tokens = x.reshape(T, D)
     logits = (tokens @ _w(router_w, dt)).astype(jnp.float32)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
 
     if cfg.router == "expert_choice":
         # Experts pick their top-`capacity` tokens: balanced by
         # construction, so no aux loss. Tokens outside every expert's
         # choice pass through the residual unchanged.
+        probs = jax.nn.softmax(logits, axis=-1)
         g, idx = jax.lax.top_k(probs.T, min(capacity, T))  # [E, C]
         expert_in = tokens[idx]  # [E, C, D]
-        gate = jax.nn.silu(
-            jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
-        up = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
-        expert_out = jnp.einsum("ecf,efd->ecd", gate * up, _w(w_down, dt))
+        expert_out = _expert_ffn(expert_in, w_gate, w_up, w_down, dt)
         weighted = (g[..., None].astype(dt) * expert_out).reshape(-1, D)
         out = jnp.zeros((T, D), dt).at[idx.reshape(-1)].add(weighted)
         return out.reshape(B, S, D), jnp.zeros((), jnp.float32)
     if cfg.router != "top_k":
         raise ValueError(f"unknown MoE router `{cfg.router}`")
 
-    top_probs, top_idx = jax.lax.top_k(probs, K)  # [T, K]
-    top_probs = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
-
-    # Dense one-hot dispatch with capacity accounting. Per k-choice:
-    # position of each token inside its expert's buffer = how many
-    # earlier (token, choice) pairs picked that expert.
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [T, K, E]
-    oh_km = onehot.transpose(1, 0, 2)  # choice-major [K, T, E]
-    flat = oh_km.reshape(K * T, E)
-    positions = (jnp.cumsum(flat, axis=0) - flat)  # [K*T, E] slots used before
-    pos_in_expert = jnp.sum(positions * flat, axis=-1).reshape(K, T)  # [K, T]
-    keep = pos_in_expert < capacity
-
-    # dispatch[t, e, c] = 1 where token t sits in slot c of expert e.
-    slot_onehot = jax.nn.one_hot(
-        pos_in_expert.astype(jnp.int32), capacity, dtype=jnp.float32)
-    dispatch = jnp.einsum(
-        "kte,ktc->tec", oh_km,
-        slot_onehot * keep[..., None].astype(jnp.float32))
-    combine = jnp.einsum(
-        "kte,ktc,kt->tec", oh_km, slot_onehot,
-        top_probs.T * keep.astype(jnp.float32))
-
-    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dt), tokens)  # [E,C,D]
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
-    up = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up, _w(w_down, dt))
-    out = jnp.einsum("tec,ecd->td", combine.astype(dt), expert_out)
-
+    top_idx, top_probs, probs = route(cfg, logits)
+    out, onehot = dense_dispatch(tokens, top_idx, top_probs, w_gate, w_up,
+                                 w_down, capacity, dt)
     aux = _router_aux_loss(cfg, jnp.mean(onehot[:, 0, :], axis=0),
                            jnp.mean(probs, axis=0))
     return out.reshape(B, S, D), aux
@@ -508,7 +539,7 @@ def _prompt_pass(cfg: MoEConfig, params: dict, prompt: jax.Array):
     """Shared causal prompt sweep (one body for both prefill flavours,
     same contract as llama's): (final hidden x [B, P, D], k_all, v_all
     [L, B, P, KV, Hd]). The MoE FFN replaces the dense MLP; routing
-    runs over the B·P prompt tokens exactly as in training."""
+    runs over the B·P prompt tokens as one group that drops nothing."""
     dt = cfg.dtype
     B, P = prompt.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -526,8 +557,14 @@ def _prompt_pass(cfg: MoEConfig, params: dict, prompt: jax.Array):
                                      impl=cfg.attention_impl)
         x = x + attn.reshape(B, P, H * Hd) @ _w(layer["wo"], dt)
         h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
+        # No-drop, as every other serving path (decode, the suffix
+        # prefill): a prompt served whole and the same prompt served
+        # from cached pages plus a suffix must hold the same KV, and a
+        # pair dropped at the training capacity here is not dropped
+        # there.
         moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"])
+                               layer["w_up"], layer["w_down"],
+                               min_capacity=B * P)
         return x + moe_out, (k, v)
 
     x, (k_all, v_all) = jax.lax.scan(layer_step, x, params["layers"])
@@ -766,6 +803,7 @@ from polyaxon_tpu.models.llama import (  # noqa: E402  (re-exported hooks)
     cb_validate,
     insert_cache_row,
     paged_gather,
+    paged_gather_prefix,
     paged_insert_prefill,
     paged_insert_suffix,
 )
@@ -781,44 +819,14 @@ def cb_prefill(cfg: MoEConfig, params: dict, prompt: jax.Array,
     return cache
 
 
-def generate(
-    cfg: MoEConfig,
-    params: dict,
-    prompt: jax.Array,  # [B, P] int32
-    *,
-    max_new_tokens: int,
-    temperature: float = 0.0,
-    top_p: float = 1.0,
-    top_k: int = 0,
-    rng: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Greedy (temperature 0) or sampled continuation: [B, max_new] —
-    the same serving contract as llama.generate (all sampling knobs
-    may be traced scalars; top_p/top_k filter in-program via
-    models/common.py sample_logits)."""
-    B, P = prompt.shape
-    sampling = isinstance(temperature, jax.Array) or temperature > 0
-    if sampling and rng is None:
-        raise ValueError("sampling (temperature > 0) needs an rng key")
-    rng = rng if rng is not None else jax.random.key(0)
+def generate(cfg: MoEConfig, params: dict, prompt: jax.Array, **sampling):
+    """Greedy or sampled continuation [B, max_new]: llama's
+    ``generate_loop`` over this family's prefill and decode step (the
+    same serving contract)."""
+    from polyaxon_tpu.models.llama import generate_loop
 
-    logits, cache = prefill(cfg, params, prompt, P + max_new_tokens)
-
-    def sample(logits, key):
-        if sampling:
-            return sample_logits(logits, key, temperature, top_p, top_k)
-        return jnp.argmax(logits, axis=-1)
-
-    def decode_loop(carry, t):
-        cache, logits, key = carry
-        key, sub = jax.random.split(key)
-        token = sample(logits, sub).astype(jnp.int32)
-        logits, cache = decode_step(cfg, params, cache, token, P + t)
-        return (cache, logits, key), token
-
-    (_, logits, _), tokens = jax.lax.scan(
-        decode_loop, (cache, logits, rng), jnp.arange(max_new_tokens))
-    return tokens.T  # [B, max_new]
+    return generate_loop(prefill, decode_step, cfg, params, prompt,
+                         **sampling)
 
 
 def apply(

@@ -89,9 +89,10 @@ class TrainResult:
 
 
 def _model_config_cls(model_name: str):
-    from polyaxon_tpu.models import bert, llama, mnist, moe, resnet, t5, vit
+    from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet,
+                                     t5, vit)
 
-    for mod in (llama, moe, vit, bert, resnet, mnist, t5):
+    for mod in (llama, moe, lfm2, vit, bert, resnet, mnist, t5):
         if model_name in mod.CONFIGS:
             return type(mod.CONFIGS[model_name])
     raise ValueError(f"Unknown model `{model_name}`")
@@ -607,9 +608,10 @@ def _run_jaxjob(
 
 
 def _get_cfg(model_name: str):
-    from polyaxon_tpu.models import bert, llama, mnist, moe, resnet, t5, vit
+    from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet,
+                                     t5, vit)
 
-    for mod in (llama, moe, vit, bert, resnet, mnist, t5):
+    for mod in (llama, moe, lfm2, vit, bert, resnet, mnist, t5):
         if model_name in mod.CONFIGS:
             return mod.CONFIGS[model_name]
     raise ValueError(f"Unknown model `{model_name}`")

@@ -496,6 +496,26 @@ class ContinuousBatchingEngine:
         self._cache = self._new_cache()
         self._device0, self._device_stats = _device_stats(
             self.params, self._cache)
+        # What a page holds: page_size tokens of K and V and, for a
+        # family whose cache has such a leaf, one fixed-size state. A
+        # radix match that ends inside a page has no true state, so the
+        # pool then matches whole pages only (decided here, from the
+        # cache's structure; there is no option).
+        self._page_bytes = (0, 0)
+        if self._pool is not None:
+            from polyaxon_tpu.serving.paged import page_bytes
+
+            self._page_bytes = page_bytes(
+                self._cache, self._pool.n_pages, self._pool.page_size)
+            self._pool.whole_page_matches = self._page_bytes[1] > 0
+        # A family may keep, with its cache, the (row, choice) pairs its
+        # decode steps routed to each expert. The engine thread reads it
+        # between ticks, when `stats()` has asked (`_serve_expert_tokens`).
+        self._counts_experts = "moe_expert_tokens" in self._cache
+        self._expert_tokens: Optional[np.ndarray] = None
+        self._expert_tokens_asking = threading.Lock()  # one asker at a time
+        self._expert_tokens_wanted = threading.Event()
+        self._expert_tokens_ready = threading.Event()
         self.draft = draft
         self._spec_rounds = 0
         self._spec_tokens = 0
@@ -695,10 +715,12 @@ class ContinuousBatchingEngine:
                 ps = page_size
 
                 def run(params, prompt, cache, page_ids):
-                    k_all, v_all = family.paged_prefill_kv(
-                        cfg, params, prompt)
+                    # What the family's prefill returns (K and V; a
+                    # hybrid family's state with them) goes to its
+                    # insert as it is.
                     return family.paged_insert_prefill(
-                        cache, k_all, v_all, page_ids, ps)
+                        cache, *family.paged_prefill_kv(
+                            cfg, params, prompt), page_ids, ps)
 
                 return jax.jit(run, donate_argnums=(2,))
 
@@ -731,7 +753,8 @@ class ContinuousBatchingEngine:
                         for name, arr in cache.items()}
 
             self._copy_page = jax.jit(copy_page, donate_argnums=(0,))
-            if hasattr(family, "paged_prefill_suffix_kv"):
+            if (hasattr(family, "paged_prefill_suffix_kv")
+                    and hasattr(family, "paged_gather_prefix")):
                 ps = page_size
 
                 # 32, not 16: the prefill LANE reuses this cache with
@@ -741,10 +764,9 @@ class ContinuousBatchingEngine:
                 def compiled_suffix_prefill(slen: int, n_pref: int):
                     def run(params, suffix, cache, page_ids, m, real_len):
                         pref = jnp.maximum(page_ids[:n_pref], 0)
-                        kp = family.paged_gather(cache["k"], pref)
-                        vp = family.paged_gather(cache["v"], pref)
-                        k_suf, v_suf = family.paged_prefill_suffix_kv(
-                            cfg, params, suffix, kp, vp, m)
+                        novel = family.paged_prefill_suffix_kv(
+                            cfg, params, suffix,
+                            *family.paged_gather_prefix(cache, pref), m)
                         # Padded tail positions (>= real_len) carry
                         # garbage KV; the insert routes them to the
                         # scratch page. Real positions are unaffected:
@@ -752,8 +774,7 @@ class ContinuousBatchingEngine:
                         # real queries (padding sits after every real
                         # position), so no extra attention mask.
                         return family.paged_insert_suffix(
-                            cache, k_suf, v_suf, page_ids, m, ps,
-                            real_len)
+                            cache, *novel, page_ids, m, ps, real_len)
 
                     return jax.jit(run, donate_argnums=(2,))
 
@@ -1429,7 +1450,9 @@ class ContinuousBatchingEngine:
                     req.trace.start_phase(
                         "prefill", mode="suffix",
                         prompt_tokens=len(prefill_tokens),
-                        cached_tokens=skip)
+                        cached_tokens=skip,
+                        state_pages_written=self._state_pages(
+                            skip, len(prefill_tokens)))
                 suffix = prefill_tokens[skip:]
                 n_pref = -(-skip // self._pool.page_size)
                 bucket = bucket_suffix_len(len(suffix))
@@ -1447,7 +1470,9 @@ class ContinuousBatchingEngine:
                 if req.trace is not None:
                     req.trace.start_phase(
                         "prefill", mode="monolithic",
-                        prompt_tokens=len(prefill_tokens))
+                        prompt_tokens=len(prefill_tokens),
+                        state_pages_written=self._state_pages(
+                            0, len(prefill_tokens)))
                 row = jnp.asarray([prefill_tokens], jnp.int32)
                 fn = self._compiled_prefill(len(prefill_tokens))
                 if self._pool is not None:
@@ -1474,6 +1499,14 @@ class ContinuousBatchingEngine:
             # leaf survives the slot from here on.
             self._pool.commit_prefix(b)
         self._go_live(b, req, pos0, tok0)
+
+    def _state_pages(self, start: int, stop: int) -> int:
+        """Pages a prefill of positions start..stop-1 leaves a per-page
+        state in (0 for a cache of K and V alone)."""
+        if not self._page_bytes[1] or stop <= start:
+            return 0
+        ps = self._pool.page_size
+        return (stop - 1) // ps - start // ps + 1
 
     # ------------------------------------------------------ prefill lane
     def _admit_lane(self) -> None:
@@ -1782,6 +1815,11 @@ class ContinuousBatchingEngine:
                 self._device0.memory_stats() or {}).get(
                     "peak_bytes_in_use")},
             "compile_cache": compile_cache.stats(),
+            # [expert layer][expert]: (row, choice) pairs of live rows
+            # the decode steps routed there (families with routed
+            # experts that count them; absent otherwise).
+            **({"moe_expert_tokens": self._read_expert_tokens()}
+               if self._counts_experts else {}),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
                 "spec_rounds": self._spec_rounds,
@@ -1806,6 +1844,10 @@ class ContinuousBatchingEngine:
             **({"kv_pages_total": self._pool.n_pages - 1,
                 "kv_pages_free": self._pool.free_pages,
                 "kv_page_size": self._pool.page_size,
+                # Bytes one page holds over all layers (K, V and any
+                # per-page state), and the state's part of it.
+                "kv_page_bytes": sum(self._page_bytes),
+                "kv_state_bytes_per_page": self._page_bytes[1],
                 "kv_prefix_hits": self._pool.prefix_hits,
                 "kv_prefix_misses": self._pool.prefix_misses,
                 # Radix prefix-reuse dividend: prefill tokens the
@@ -1955,7 +1997,8 @@ class ContinuousBatchingEngine:
 
         def spec(aval):
             dims = [None] * aval.ndim
-            dims[kv_dim] = head_axis
+            if aval.ndim == 5:  # K and V; any other leaf is replicated
+                dims[kv_dim] = head_axis
             return NamedSharding(self._mesh, PartitionSpec(*dims))
 
         shapes = jax.eval_shape(build)
@@ -2232,10 +2275,12 @@ class ContinuousBatchingEngine:
             with self._cv:
                 while (not self._stopped and not self._queue_depth()
                        and not self._prefilling and not self._lane
-                       and all(r is None for r in self._slot_req)):
+                       and all(r is None for r in self._slot_req)
+                       and not self._expert_tokens_wanted.is_set()):
                     self._cv.wait()
                 if self._stopped:
                     return
+            self._serve_expert_tokens()
             # Idle waiting above is excluded from the tick duration:
             # the histogram measures work per iteration (admission +
             # prefill chunk + decode step), not queue quiet time.
@@ -2247,6 +2292,27 @@ class ContinuousBatchingEngine:
                         self._observe_tick(time.time() - t0)
             if not alive:
                 return
+
+    def _serve_expert_tokens(self) -> None:
+        """Between ticks, on the engine thread (the only one that may
+        touch the cache: every step donates it): copy the family's
+        routed-pairs counter to the host when `stats()` has asked."""
+        if self._expert_tokens_wanted.is_set():
+            self._expert_tokens_wanted.clear()
+            self._expert_tokens = np.asarray(self._cache["moe_expert_tokens"])
+            self._expert_tokens_ready.set()
+
+    def _read_expert_tokens(self) -> Optional[list]:
+        """The counter as of the next tick boundary (the last copy where
+        the engine thread does not answer: stopped, or mid-compile)."""
+        with self._expert_tokens_asking:
+            self._expert_tokens_ready.clear()
+            self._expert_tokens_wanted.set()
+            with self._cv:
+                self._cv.notify_all()
+            self._expert_tokens_ready.wait(timeout=2.0)
+            got = self._expert_tokens
+        return None if got is None else got.tolist()
 
     def _tick_snapshot(self) -> dict:
         """The engine's state beside a slow tick's phase split."""

@@ -255,6 +255,24 @@ class RadixPrefixIndex:
         return visit(self.root)[0]
 
 
+def page_bytes(cache: dict, n_pages: int, page_size: int) -> tuple:
+    """(bytes a page holds of per-token leaves, of per-page state
+    leaves), over all layers, read off the device cache's structure: a
+    paged leaf has the pages on axis 1; ``[L, P, KV, page_size, Hd]``
+    holds tokens (K, V), any other shape one fixed-size state a page.
+    A leaf without a page axis (a counter) is no page's content."""
+    tokens = state = 0
+    for leaf in cache.values():
+        if leaf.ndim < 3 or leaf.shape[1] != n_pages:
+            continue
+        per_page = leaf.size * leaf.dtype.itemsize // n_pages
+        if leaf.ndim == 5 and leaf.shape[3] == page_size:
+            tokens += per_page
+        else:
+            state += per_page
+    return tokens, state
+
+
 class PagePool:
     def __init__(self, slots: int, max_len: int, page_size: int,
                  n_pages: int, prefix_cache: bool = True):
@@ -269,6 +287,12 @@ class PagePool:
         self._free = list(range(n_pages - 1, 0, -1))
         self.tables = np.full((slots, self.max_pages_per_row), -1, np.int32)
         self.prefix_cache = prefix_cache
+        # Set by the engine for a cache whose pages also hold a
+        # fixed-size state (`page_bytes`): the state of a page is the
+        # one after its last written position, so a match that ends
+        # inside a page has none. Matches are then rounded down to
+        # whole pages and no copy-on-write fork is planned.
+        self.whole_page_matches = False
         self._ref = np.zeros(n_pages, np.int32)
         self._index = RadixPrefixIndex(page_size) if prefix_cache else None
         # The ONE leaf each slot's admission added to the tree — the
@@ -345,7 +369,8 @@ class PagePool:
         at length-1 needs a private page regardless."""
         if self._index is None or tokens is None:
             return [], None
-        return self._index.match(tokens, length - 1, touch=touch)
+        pages, cow = self._index.match(tokens, length - 1, touch=touch)
+        return pages, None if self.whole_page_matches else cow
 
     def _plan_locked(self, length: int, tokens) -> int:
         """Allocatable units this admission consumes: adopted pages

@@ -69,16 +69,17 @@ def _bucket(n: int, lo: int = 16) -> int:
 
 def _family(model: str):
     """Model family module with CONFIGS/init/generate and a SEQ2SEQ
-    flag (llama-style decoders, Mixtral-style MoE decoders, and
-    t5-style encoder-decoders)."""
-    from polyaxon_tpu.models import llama, moe, t5
+    flag (llama-style decoders, Mixtral-style MoE decoders, lfm2-style
+    hybrid decoders: short convolutions beside attention, sigmoid-routed
+    experts; and t5-style encoder-decoders)."""
+    from polyaxon_tpu.models import lfm2, llama, moe, t5
 
-    for mod in (llama, moe, t5):
+    for mod in (llama, moe, lfm2, t5):
         if model in mod.CONFIGS:
             return mod
     raise ValueError(
         f"model `{model}` is not servable; decoders: "
-        f"{sorted(llama.CONFIGS) + sorted(moe.CONFIGS)}, "
+        f"{sorted(llama.CONFIGS) + sorted(moe.CONFIGS) + sorted(lfm2.CONFIGS)}, "
         f"seq2seq: {sorted(t5.CONFIGS)}")
 
 
