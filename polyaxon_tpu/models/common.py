@@ -137,6 +137,23 @@ def _w(w, dt):
     return w.astype(dt)
 
 
+def served_params(params, dt, read_at_float32):
+    """The params tree as a server holds it: every leaf in the compute
+    dtype ``dt`` that all of its consumption sites cast it to (``_w``,
+    ``lm_logits``, ``_embed_rows``), so that the cast runs once at load
+    and is a no-op inside every decode and prefill program. Leaves
+    named in ``read_at_float32`` (the family's ``READ_AT_FLOAT32`` table
+    beside its ``logical_axes``: norm gains, whatever else a body reads
+    with ``.astype(float32)``) stay as they are; rounding those would
+    change the result. Training keeps float32 masters and never comes
+    here."""
+    def cast(path, leaf):
+        name = str(getattr(path[-1], "key", path[-1]))
+        return leaf if name in read_at_float32 else leaf.astype(dt)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 def _lm_chunk_len(V: int, chunk: int):
     """Largest power-of-two chunk <= min(chunk, V // 2), or None when V
     is too small to split (callers fall back to the one-dot path)."""

@@ -255,6 +255,17 @@ def logical_axes(cfg: Lfm2Config) -> Variables:
     }
 
 
+# Leaves read at float32: every norm gain, the convolution's taps
+# (``conv_op``), and the router with its bias (``expert_ffn``: the
+# scores decide a top-k, so its matmul is float32 at full precision,
+# unlike ``models/moe.py``'s, which reads its router through ``_w``).
+# The rest are read at ``cfg.dtype`` and a server holds them so
+# (``common.served_params``).
+READ_AT_FLOAT32 = frozenset(
+    {"attn_norm", "q_norm", "k_norm", "conv_norm", "mlp_norm", "moe_norm",
+     "final_norm", "w_conv", "router", "expert_bias"})
+
+
 def _at(stack: dict, i: int) -> dict:
     """Layer `i` of one kind's stacked parameters."""
     return {name: leaf[i] for name, leaf in stack.items()}

@@ -222,6 +222,16 @@ def logical_axes(cfg: LlamaConfig) -> Variables:
     return {"params": params, "state": {}}
 
 
+# Leaves the bodies read at float32 (``rms_norm`` on every gain); all
+# others are read through ``_w`` / ``lm_head(...).astype(dt)`` /
+# ``_embed_rows`` at ``cfg.dtype``, which is what a server holds them
+# in (``common.served_params``, applied by ``serving/server.py
+# load_params``). ``q_norm``/``k_norm``: lfm2's attention layers, which
+# run these bodies.
+READ_AT_FLOAT32 = frozenset(
+    {"attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm"})
+
+
 _rope = rope  # shared impl (models.common.rope)
 
 

@@ -183,6 +183,12 @@ def logical_axes(cfg: MoEConfig) -> Variables:
     }
 
 
+# Leaves read at float32 (the norm gains); the rest, the router
+# included (``_w(router_w, dt)``), are read at ``cfg.dtype`` and a
+# server holds them so (``common.served_params``).
+READ_AT_FLOAT32 = frozenset({"attn_norm", "moe_norm", "final_norm"})
+
+
 def _router_aux_loss(cfg: MoEConfig, frac_tokens: jax.Array,
                      frac_probs: jax.Array) -> jax.Array:
     """Load-balancing aux loss (Switch eq. 4) from the two GLOBAL mean

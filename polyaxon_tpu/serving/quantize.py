@@ -149,3 +149,17 @@ def tree_bytes(params: Any) -> int:
         leaf.nbytes for leaf in jax.tree.leaves(
             params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
         if hasattr(leaf, "nbytes"))
+
+
+def weight_bytes(params: Any) -> dict[str, int]:
+    """``tree_bytes`` split by the dtype the bytes are held in (an int8
+    leaf counts its ``q`` under int8 and its scales under float32):
+    `/v1/stats` ``weight_bytes``. A server holds its matmul weights in
+    the compute dtype (``server.py load_params``), so float32 here is
+    the norm gains and what else a family reads at float32."""
+    held: dict[str, int] = {}
+    for leaf in jax.tree.leaves(params):
+        if hasattr(leaf, "nbytes"):
+            name = str(leaf.dtype)
+            held[name] = held.get(name, 0) + int(leaf.nbytes)
+    return dict(sorted(held.items()))

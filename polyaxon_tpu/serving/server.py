@@ -54,8 +54,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from polyaxon_tpu.models.common import served_params
 from polyaxon_tpu.serving.batching import QueueFull, validate_sampling
-from polyaxon_tpu.serving.quantize import quantize_tree, tree_bytes
+from polyaxon_tpu.serving.quantize import (quantize_tree, tree_bytes,
+                                           weight_bytes)
 
 logger = logging.getLogger(__name__)
 
@@ -84,9 +86,20 @@ def _family(model: str):
 
 
 def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
-                mesh=None, lora_alpha: float = 16.0):
+                mesh=None, lora_alpha: float = 16.0,
+                quantize: Optional[str] = None):
     """Model params: latest step of an Orbax checkpoint dir (a saved
     JAXJob train state or a bare params tree), else random init.
+
+    A server holds its weights in the precision it computes in: every
+    leaf that its family reads at ``cfg.dtype`` is cast to it here,
+    once (``models/common.py served_params``; the family's
+    ``READ_AT_FLOAT32`` names what stays float32), so no decode or
+    prefill program repeats the cast. The values are drawn or restored
+    in float32 first and then rounded: the very values the programs'
+    own casts gave. A family that states nothing keeps float32 (t5).
+    ``quantize`` ("int8"): the float32 tree goes to ``quantize_tree``
+    instead and nothing is cast, which is the tree it always gave.
 
     ``mesh``: shard the weights over it using the model's logical axes
     and the mesh's rule table (the same tables training uses) — serving
@@ -113,10 +126,18 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
         shardings = tree_shardings(
             family.logical_axes(cfg)["params"], mesh, rules_for_mesh(mesh))
 
+    read_at_float32 = getattr(family, "READ_AT_FLOAT32", None)
+
+    def init_params(key):
+        params = family.init(cfg, key)["params"]
+        if quantize or read_at_float32 is None:
+            return params
+        return served_params(params, cfg.dtype, read_at_float32)
+
     # Shape/dtype template: no memory, used for structure validation
-    # and dtype casts either way.
-    template = jax.eval_shape(
-        lambda key: family.init(cfg, key)["params"], jax.random.key(0))
+    # and dtype casts either way (the served dtypes: a restored leaf is
+    # rounded on the host, after any LoRA merge in float32).
+    template = jax.eval_shape(init_params, jax.random.key(0))
 
     if checkpoint:
         import orbax.checkpoint as ocp
@@ -161,9 +182,17 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
     else:
         # One program, sharded or not: op-by-op init compiles a program
         # per tensor shape, which is minutes of start-up at real widths.
-        init_fn = jax.jit(lambda key: family.init(cfg, key)["params"],
-                          out_shardings=shardings)
+        # The cast is part of it, so the float32 tree never sits whole
+        # in device memory beside its copy.
+        init_fn = jax.jit(init_params, out_shardings=shardings)
         params = init_fn(jax.random.key(seed))
+
+    if quantize:
+        full = tree_bytes(params)
+        params = quantize_tree(params, mode=quantize)
+        logger.info("quantized %s weights %s: %.1f MiB -> %.1f MiB",
+                    model, quantize, full / 2**20,
+                    tree_bytes(params) / 2**20)
 
     if mesh is not None:
         logger.info("sharded %s over mesh %s", model,
@@ -187,6 +216,7 @@ class _Engine:
         self.cfg = cfg
         self.params = params
         self.draft = draft
+        self._weight_bytes = weight_bytes(params)
         self._served = 0
         self._tokens_out = 0
         self._lock = threading.Lock()  # one TPU program at a time
@@ -360,6 +390,7 @@ class _Engine:
             "engine": "static",
             "requests_served": self._served,
             "tokens_generated": self._tokens_out,
+            "weight_bytes": dict(self._weight_bytes),
         }
 
 
@@ -797,13 +828,8 @@ class ServingServer:
             self.mesh = build_mesh(V1MeshSpec(axes=mesh_axes),
                                    devices=devices)
         cfg, params = load_params(model, checkpoint, seed=seed,
-                                  mesh=self.mesh, lora_alpha=lora_alpha)
-        if quantize:
-            full = tree_bytes(params)
-            params = quantize_tree(params, mode=quantize)
-            logger.info("quantized %s weights %s: %.1f MiB -> %.1f MiB",
-                        model, quantize, full / 2**20,
-                        tree_bytes(params) / 2**20)
+                                  mesh=self.mesh, lora_alpha=lora_alpha,
+                                  quantize=quantize)
         draft = None
         if draft_model is not None:
             if spec_k < 1:
@@ -822,9 +848,8 @@ class ServingServer:
             # unsharded real-size draft sits whole on device 0 (OOM
             # risk) or gets replicated by GSPMD on every call.
             draft_cfg, draft_params = load_params(
-                draft_model, draft_checkpoint, seed=seed, mesh=self.mesh)
-            if quantize:
-                draft_params = quantize_tree(draft_params, mode=quantize)
+                draft_model, draft_checkpoint, seed=seed, mesh=self.mesh,
+                quantize=quantize)
             draft = (draft_model, draft_cfg, draft_params, spec_k)
             logger.info("speculative decoding: draft=%s k=%d",
                         draft_model, spec_k)

@@ -33,7 +33,7 @@ def measure(model: str, quantize: bool, slots: int, steps: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from polyaxon_tpu.serving.quantize import quantize_tree, tree_bytes
+    from polyaxon_tpu.serving.quantize import tree_bytes
     from polyaxon_tpu.serving.server import _family, load_params
 
     family = _family(model)
@@ -47,7 +47,9 @@ def measure(model: str, quantize: bool, slots: int, steps: int,
         cfg = dataclasses.replace(cfg, lm_logits_chunk=lm_chunk)
     full_bytes = tree_bytes(params)
     if quantize:
-        params = quantize_tree(params)
+        # From float32, as the server does (load_params quantizes the
+        # tree before any cast to the compute dtype).
+        _, params = load_params(model, seed=seed, quantize="int8")
     max_len = min(cfg.max_seq_len, prompt_len + steps + 8)
 
     # The continuous engine's exact step program, driven synchronously:
