@@ -1,36 +1,32 @@
 """Built-in model zoo: every BASELINE config's model family, JAX-native.
 
-Registry maps runtime spec names → ``ModelDef`` factories. Factories
-accept config overrides (e.g. ``seq_len``/``remat``) from the JAXJob
-runtime section.
+A family is one module of this package with a ``CONFIGS`` dict (name →
+its config dataclass) and ``model_def(name, **overrides)``. ``FAMILIES``
+below is the one list of them: the factory table, the server's and the
+engine's lookup (``family_of``) and the train loop's (``config_of``) all
+read it, so a new family is its file and its name in that tuple.
+Factories accept config overrides (e.g. ``seq_len``/``remat``) from the
+JAXJob runtime section.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet, t5,
                                  vit)
 from polyaxon_tpu.models.common import ModelDef
 
+# Decoders first: `serving/server.py` lists the servable names in this
+# order.
+FAMILIES = (llama, moe, lfm2, t5, vit, bert, resnet, mnist)
+
 _FACTORIES: dict[str, Callable[..., ModelDef]] = {}
 
-for _name in llama.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: llama.model_def(n, **kw))(_name)
-for _name in moe.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: moe.model_def(n, **kw))(_name)
-for _name in lfm2.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: lfm2.model_def(n, **kw))(_name)
-for _name in vit.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: vit.model_def(n, **kw))(_name)
-for _name in bert.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: bert.model_def(n, **kw))(_name)
-for _name in resnet.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: resnet.model_def(n, **kw))(_name)
-for _name in mnist.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: mnist.model_def(n, **kw))(_name)
-for _name in t5.CONFIGS:
-    _FACTORIES[_name] = (lambda n: lambda **kw: t5.model_def(n, **kw))(_name)
+for _mod in FAMILIES:
+    for _name in _mod.CONFIGS:
+        _FACTORIES[_name] = functools.partial(_mod.model_def, _name)
 
 
 def get_model(name: str, **overrides) -> ModelDef:
@@ -41,3 +37,19 @@ def get_model(name: str, **overrides) -> ModelDef:
 
 def available_models() -> list[str]:
     return sorted(_FACTORIES)
+
+
+def family_of(name: str):
+    """The family module whose ``CONFIGS`` holds ``name`` now. It is
+    read on every call: a configuration may be written into a family's
+    ``CONFIGS`` (and ``_FACTORIES``) after import, which is how the
+    benchmark registers a published model's file under its own name."""
+    for mod in FAMILIES:
+        if name in mod.CONFIGS:
+            return mod
+    raise ValueError(f"Unknown model `{name}`. Available: {sorted(_FACTORIES)}")
+
+
+def config_of(name: str):
+    """``name``'s config dataclass instance, as its family holds it now."""
+    return family_of(name).CONFIGS[name]

@@ -174,6 +174,14 @@ CONFIGS: dict[str, LlamaConfig] = {
 }
 
 
+def train_flops_per_token(cfg: LlamaConfig, seq: int,
+                          param_count: int) -> int:
+    """6N for the matmul params (fwd 2N + bwd 4N) plus the causal-
+    attention score/value matmuls (6 * n_layers * seq * d_model fwd+bwd
+    after halving for causality). Read by ``runtime/flops.py``."""
+    return 6 * param_count + 6 * cfg.n_layers * seq * cfg.dim
+
+
 def init(cfg: LlamaConfig, rng: jax.Array) -> Variables:
     keys = jax.random.split(rng, 10)
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
@@ -272,11 +280,26 @@ def _embed(cfg, params: dict, tokens: jax.Array, dt) -> jax.Array:
     return x
 
 
+def _window(cfg) -> Optional[int]:
+    """The sliding window, None for full causal attention and for a
+    family whose config carries no such field (moe)."""
+    return getattr(cfg, "sliding_window", None)
+
+
+def _head(cfg, params: dict) -> tuple:
+    """(the lm-head table as stored, whether it is ``embed`` and so
+    [V, D]); a config without ``tie_embeddings`` (moe) is untied."""
+    tied = getattr(cfg, "tie_embeddings", False)
+    return params["embed"] if tied else params["lm_head"], tied
+
+
 def _mlp(cfg, x: jax.Array, layer: dict) -> jax.Array:
     """The gated-MLP residual block (norm → act(gate)·up → down),
     shared by the training layer and every decode flavour so the
-    convention can never desync between them (this block was
-    previously copy-pasted five times)."""
+    convention can never desync between them. It is the default of the
+    serving bodies' ``ffn`` argument, ``(cfg, x [B, T, D], layer) -> x``:
+    the one seam between the decoder families. ``models/moe.py`` passes
+    its expert block and serves through the same bodies."""
     dt = cfg.dtype
     h = _norm(cfg, x, layer["mlp_norm"])
     gate = _act(cfg)(h @ _w(layer["w_gate"], dt))
@@ -418,21 +441,21 @@ def lm_head(cfg: LlamaConfig, params: dict) -> jax.Array:
     a quantized table dequantized here is loop-invariant, so XLA
     hoists the full-precision [D, V] table onto the loop carry
     (ADVICE r4 #1; see common.lm_logits)."""
-    w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+    w, tied = _head(cfg, params)
     if hasattr(w, "dequantize"):
         # Unwrap at consumption (same contract as _w): callers sit
         # inside jit, so the convert+scale fuses into the logits
         # matmul's operand read and int8 stays the HBM format.
         w = w.dequantize()
-    return w.T if cfg.tie_embeddings else w
+    return w.T if tied else w
 
 
 def decode_logits(cfg: LlamaConfig, params: dict, x: jax.Array) -> jax.Array:
     """Hidden states [..., D] → fp32 logits [..., V], safe inside
     decode loops (common.lm_logits keeps a quantized head int8 on the
     loop carry via chunked consumption)."""
-    w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    return lm_logits(x, w, cfg.dtype, transpose=cfg.tie_embeddings,
+    w, tied = _head(cfg, params)
+    return lm_logits(x, w, cfg.dtype, transpose=tied,
                      chunk=cfg.lm_logits_chunk)
 
 
@@ -453,9 +476,8 @@ def cache_len(cfg: LlamaConfig, max_len: int) -> int:
     """KV-cache length: with a sliding window the cache is a ring buffer
     of `sliding_window` slots (bounded memory for long generations);
     otherwise the full sequence length."""
-    if cfg.sliding_window is not None:
-        return min(max_len, cfg.sliding_window)
-    return max_len
+    window = _window(cfg)
+    return max_len if window is None else min(max_len, window)
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
@@ -550,6 +572,7 @@ def decode_step_ragged(
     cache: dict,
     tokens: jax.Array,  # [B] int32 current-position token ids
     pos: jax.Array,  # [B] int32 per-row position being written (-1 = idle)
+    ffn=_mlp,
 ) -> tuple[jax.Array, dict]:
     """One autoregressive step with PER-ROW positions — the kernel under
     continuous batching (serving/batching.py), where each cache slot
@@ -568,7 +591,7 @@ def decode_step_ragged(
         layer, k_cache, v_cache = inputs  # caches [B, C, KV, Hd]
         x, k_cache, v_cache = cached_attn_step(
             cfg, layer, x, k_cache, v_cache, positions, slot, valid)
-        x = _mlp(cfg, x, layer)
+        x = ffn(cfg, x, layer)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -578,7 +601,8 @@ def decode_step_ragged(
     return logits, {"k": new_k, "v": new_v}
 
 
-def _prompt_pass(cfg: LlamaConfig, params: dict, prompt: jax.Array):
+def _prompt_pass(cfg: LlamaConfig, params: dict, prompt: jax.Array,
+                 ffn=_mlp):
     """The shared causal prompt sweep: one batched pass over [B, P]
     token ids → (final hidden x [B, P, D], k_all, v_all [L, B, P, KV,
     Hd]). Both prefill flavours (ring-buffer assembly below, raw-KV
@@ -589,22 +613,23 @@ def _prompt_pass(cfg: LlamaConfig, params: dict, prompt: jax.Array):
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None], (B, P))
     x = _embed(cfg, params, prompt, dt)
+    scaling = getattr(cfg, "rope_scaling", None)
 
     def layer_step(x, layer):
         h = _norm(cfg, x, layer["attn_norm"])
         q = (h @ _w(layer["wq"], dt)).reshape(B, P, H, Hd)
         k = (h @ _w(layer["wk"], dt)).reshape(B, P, KV, Hd)
         v = (h @ _w(layer["wv"], dt)).reshape(B, P, KV, Hd)
-        q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        attn = dot_product_attention(q, k, v, causal=True,
-                                     impl=cfg.attention_impl,
-                                     window=cfg.sliding_window,
-                                     block_q=cfg.flash_block_q,
-                                     block_k=cfg.flash_block_k,
-                                     bwd_impl=cfg.flash_bwd_impl)
+        q = _rope(q, positions, cfg.rope_theta, scaling)
+        k = _rope(k, positions, cfg.rope_theta, scaling)
+        attn = dot_product_attention(
+            q, k, v, causal=True, impl=cfg.attention_impl,
+            window=_window(cfg),
+            block_q=getattr(cfg, "flash_block_q", None),
+            block_k=getattr(cfg, "flash_block_k", None),
+            bwd_impl=getattr(cfg, "flash_bwd_impl", None))
         x = x + attn.reshape(B, P, H * Hd) @ _w(layer["wo"], dt)
-        x = _mlp(cfg, x, layer)
+        x = ffn(cfg, x, layer)
         return x, (k, v)
 
     x, (k_all, v_all) = jax.lax.scan(layer_step, x, params["layers"])
@@ -616,6 +641,7 @@ def prefill(
     params: dict,
     prompt: jax.Array,  # [B, P] int32
     max_len: int,
+    ffn=_mlp,
 ) -> tuple[jax.Array, dict]:
     """One batched causal pass over the prompt, filling the KV cache:
     returns (last-position logits [B, V] fp32, cache). O(1) layer sweeps
@@ -623,20 +649,21 @@ def prefill(
     dt = cfg.dtype
     B, P = prompt.shape
     Hd = cfg.head_dim
-    x, k_all, v_all = _prompt_pass(cfg, params, prompt)
+    window = _window(cfg)
+    x, k_all, v_all = _prompt_pass(cfg, params, prompt, ffn)
     # Ring-buffer cache assembly: position p lands in slot p % C. With a
     # full-length cache that is the identity; with a sliding-window ring
     # only the last C prompt positions are kept (older ones can never be
     # attended again).
     C = cache_len(cfg, max_len)
-    if cfg.sliding_window is None and P > max_len:
+    if window is None and P > max_len:
         raise ValueError(
             f"prompt length {P} exceeds cache length {max_len} "
             "(full attention cannot drop prompt positions)")
-    if cfg.sliding_window is not None and C < min(P, cfg.sliding_window):
+    if window is not None and C < min(P, window):
         raise ValueError(
             f"cache length {C} (max_len {max_len}) cannot hold the last "
-            f"min(P={P}, window={cfg.sliding_window}) prompt positions "
+            f"min(P={P}, window={window}) prompt positions "
             "that remain attendable — raise max_len")
     keep = min(P, C)
     if P <= C:
@@ -700,6 +727,7 @@ def decode_chunk(
     cache: dict,  # full-length cache: slot == position (C == max_len)
     tokens: jax.Array,  # [B, c] int32 — c tokens per row
     pos0: jax.Array,  # [B] int32 — position of tokens[:, 0] per row
+    ffn=_mlp,
 ) -> tuple[jax.Array, dict]:
     """Cached forward over a SHORT chunk of c tokens per row (the
     speculative-decoding verify step): writes their KV at positions
@@ -708,7 +736,7 @@ def decode_chunk(
     no ring wrap, no sliding window), which is what makes acceptance
     rollback-free: stale entries beyond the accepted prefix sit at
     positions the next chunk rewrites before anything attends them."""
-    if cfg.sliding_window is not None:
+    if _window(cfg) is not None:
         raise ValueError("speculative decode_chunk requires a full-length "
                          "cache (no sliding_window)")
     dt = cfg.dtype
@@ -726,7 +754,7 @@ def decode_chunk(
         layer, k_cache, v_cache = inputs  # caches [B, C, KV, Hd]
         x, k_cache, v_cache = chunk_attn_step(
             cfg, layer, x, k_cache, v_cache, positions, valid)
-        x = _mlp(cfg, x, layer)
+        x = ffn(cfg, x, layer)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -820,7 +848,7 @@ def paged_scatter(pool: jax.Array, kv: jax.Array, page_idx: jax.Array,
 
 
 def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
-    if cfg.sliding_window is not None:
+    if _window(cfg) is not None:
         raise ValueError(
             "paged KV does not support sliding_window yet — the ring "
             "buffer already bounds that cache; use kv='dense'")
@@ -911,6 +939,7 @@ def decode_step_paged(
     tokens: jax.Array,  # [B] int32
     pos: jax.Array,  # [B] int32 per-row position being written (-1 idle)
     tables: jax.Array,  # [B, maxp] int32 page ids (-1 = unallocated)
+    ffn=_mlp,
 ) -> tuple[jax.Array, dict]:
     """`decode_step_ragged` over the paged pool: a row at position p
     with pages covering 0..p matches the dense ragged step at p exactly
@@ -925,7 +954,7 @@ def decode_step_paged(
         x, k_pages, v_pages = paged_attn_step(
             cfg, layer, x, k_pages, v_pages, positions,
             write_page, write_off, tables, valid)
-        x = _mlp(cfg, x, layer)
+        x = ffn(cfg, x, layer)
         return x, (k_pages, v_pages)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -935,12 +964,13 @@ def decode_step_paged(
     return logits, {"k": new_k, "v": new_v}
 
 
-def paged_prefill_kv(cfg: LlamaConfig, params: dict, prompt: jax.Array):
+def paged_prefill_kv(cfg: LlamaConfig, params: dict, prompt: jax.Array,
+                     ffn=_mlp):
     """Prompt pass returning raw per-position KV (no ring assembly):
     (k_all, v_all) [L, P, KV, Hd] for a single row [1, P] — the paged
     insert scatters these into the row's pages. Same ``_prompt_pass``
     body as ``prefill``, so the engines cannot diverge."""
-    _, k_all, v_all = _prompt_pass(cfg, params, prompt)
+    _, k_all, v_all = _prompt_pass(cfg, params, prompt, ffn)
     return k_all[:, 0], v_all[:, 0]  # [L, P, KV, Hd]
 
 
@@ -1006,7 +1036,7 @@ def _suffix_mask(S: int, m_pad: int, m: jax.Array) -> jax.Array:
 
 def paged_prefill_suffix_kv(cfg: LlamaConfig, params: dict,
                             suffix: jax.Array, k_prefix: jax.Array,
-                            v_prefix: jax.Array, m: jax.Array):
+                            v_prefix: jax.Array, m: jax.Array, ffn=_mlp):
     """Prefill only the NOVEL tail of a prompt whose first ``m`` tokens
     hit the radix prefix cache: ``suffix`` [1, S] holds the token ids at
     absolute positions m..m+S-1, ``k_prefix``/``v_prefix`` [L, Mpad, KV,
@@ -1026,7 +1056,7 @@ def paged_prefill_suffix_kv(cfg: LlamaConfig, params: dict,
         layer, kp, vp = inputs
         x, k, v = suffix_attn_step(
             cfg, layer, x, kp[None], vp[None], positions, valid)
-        x = _mlp(cfg, x, layer)
+        x = ffn(cfg, x, layer)
         return x, (k, v)
 
     _, (k_all, v_all) = jax.lax.scan(

@@ -56,7 +56,8 @@ from polyaxon_tpu.models.common import (
     shift_right,
     truncated_normal_init,
 )
-from polyaxon_tpu.models.common import _embed_rows, _w, lm_logits
+from polyaxon_tpu.models import llama
+from polyaxon_tpu.models.common import _embed_rows, _w
 from polyaxon_tpu.models.llama import _rope
 from polyaxon_tpu.ops.attention import dot_product_attention
 
@@ -133,6 +134,17 @@ CONFIGS: dict[str, MoEConfig] = {
         ffn_dim=128, n_experts=4, max_seq_len=128, rope_theta=10_000.0,
     ),
 }
+
+
+def train_flops_per_token(cfg: MoEConfig, seq: int, param_count: int) -> int:
+    """llama's count over the *active* params: only K of E experts run
+    per token, so N is the dense params plus K/E of the expert-FFN
+    params — counting all experts would overstate tflops/MFU by
+    roughly E/K on the FFN share. Read by ``runtime/flops.py``."""
+    expert_params = cfg.n_layers * cfg.n_experts * 3 * cfg.dim * cfg.ffn_dim
+    active = (param_count - expert_params
+              + expert_params * cfg.experts_per_token // cfg.n_experts)
+    return 6 * active + 6 * cfg.n_layers * seq * cfg.dim
 
 
 def init(cfg: MoEConfig, rng: jax.Array) -> Variables:
@@ -533,72 +545,23 @@ def forward(
 
 
 # ---------------------------------------------------------------- decode
-def init_cache(cfg: MoEConfig, batch: int, max_len: int) -> dict:
-    """KV cache [L, B, C, KV, Hd] per tensor, compute dtype — the same
-    layout as the llama cache (full-length: MoE configs carry no
-    sliding window)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _prompt_pass(cfg: MoEConfig, params: dict, prompt: jax.Array):
-    """Shared causal prompt sweep (one body for both prefill flavours,
-    same contract as llama's): (final hidden x [B, P, D], k_all, v_all
-    [L, B, P, KV, Hd]). The MoE FFN replaces the dense MLP; routing
-    runs over the B·P prompt tokens as one group that drops nothing."""
-    dt = cfg.dtype
-    B, P = prompt.shape
-    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None], (B, P))
-    x = _embed_rows(params["embed"], prompt, dt)
-
-    def layer_step(x, layer):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = (h @ _w(layer["wq"], dt)).reshape(B, P, H, Hd)
-        k = (h @ _w(layer["wk"], dt)).reshape(B, P, KV, Hd)
-        v = (h @ _w(layer["wv"], dt)).reshape(B, P, KV, Hd)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        attn = dot_product_attention(q, k, v, causal=True,
-                                     impl=cfg.attention_impl)
-        x = x + attn.reshape(B, P, H * Hd) @ _w(layer["wo"], dt)
-        h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-        # No-drop, as every other serving path (decode, the suffix
-        # prefill): a prompt served whole and the same prompt served
-        # from cached pages plus a suffix must hold the same KV, and a
-        # pair dropped at the training capacity here is not dropped
-        # there.
-        moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"],
-                               min_capacity=B * P)
-        return x + moe_out, (k, v)
-
-    x, (k_all, v_all) = jax.lax.scan(layer_step, x, params["layers"])
-    return x, k_all, v_all
-
-
-def prefill(
-    cfg: MoEConfig,
-    params: dict,
-    prompt: jax.Array,  # [B, P] int32
-    max_len: int,
-) -> tuple[jax.Array, dict]:
-    """One batched causal pass over the prompt, filling the KV cache:
-    (last-position logits [B, V] fp32, cache)."""
-    _check_decodable(cfg)
-    dt = cfg.dtype
-    B = prompt.shape[0]
-    x, k_all, v_all = _prompt_pass(cfg, params, prompt)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, -1] @ _w(params["lm_head"], dt)).astype(jnp.float32)
-    cache = init_cache(cfg, B, max_len)
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], k_all, (0, 0, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], v_all, (0, 0, 0, 0, 0)),
-    }
-    return logits, cache
+# Serving runs llama's bodies (models/llama.py) with the expert block in
+# the FFN slot: the attention steps, the cache layouts, the paged
+# coordinates and inserts, the lm head and the admission hooks are that
+# family's, and what is not wrapped below is re-exported as it is (MoE
+# configs carry no sliding window: every cache is full-length).
+from polyaxon_tpu.models.llama import (  # noqa: E402,F401  (re-exported hooks)
+    cb_admission,
+    cb_init_cache,
+    cb_validate,
+    init_cache,
+    insert_cache_row,
+    paged_gather,
+    paged_gather_prefix,
+    paged_init_cache,
+    paged_insert_prefill,
+    paged_insert_suffix,
+)
 
 
 def _check_decodable(cfg: MoEConfig) -> None:
@@ -613,210 +576,86 @@ def _check_decodable(cfg: MoEConfig) -> None:
             "groups cannot reproduce")
 
 
-def decode_step_ragged(
-    cfg: MoEConfig,
-    params: dict,
-    cache: dict,
-    tokens: jax.Array,  # [B] int32
-    pos: jax.Array,  # [B] int32 per-row position (-1 = idle)
-) -> tuple[jax.Array, dict]:
-    """One autoregressive step with PER-ROW positions (continuous
-    batching). Built on the same ``cached_attn_step`` kernel as the
-    llama family — the families differ only in the FFN sublayer. The
-    router sees the B current tokens as its dispatch group: top-k
-    selection is per-token, so decode routing matches training routing
-    for the same hidden state. Capacity is floored at the group size
-    (``min_capacity=B`` below) so decode NEVER drops: at B live slots
-    the factor-derived capacity would be 1-2 and any routing skew
-    would silently diverge served outputs from training."""
-    from polyaxon_tpu.models.llama import cached_attn_step, ragged_cache_coords
+def _expert_block(cfg: MoEConfig, x: jax.Array, layer: dict) -> jax.Array:
+    """The expert FFN residual block of every serving path: what
+    llama's bodies call where that family calls its ``_mlp``. The
+    router sees the B·T tokens it is given (a step's live slots, a
+    verify chunk, a prompt, a prefill suffix) as one dispatch group,
+    and capacity is floored at the group's size, so serving NEVER
+    drops: top-k selection is per token and matches training routing
+    for the same hidden state, where the factor-derived capacity (1-2
+    slots for a handful of rows) would silently diverge on any skew.
+    The same rule on every path is what lets a prompt served whole and
+    the same prompt served from cached pages plus a suffix hold the
+    same KV."""
+    B, T, _ = x.shape
+    h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
+    moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
+                           layer["w_up"], layer["w_down"],
+                           min_capacity=B * T)
+    return x + moe_out
 
+
+def prefill(cfg: MoEConfig, params: dict, prompt: jax.Array, max_len: int):
+    """One batched causal pass over the prompt [B, P], filling the KV
+    cache: (last-position logits [B, V] fp32, cache)."""
     _check_decodable(cfg)
-    dt = cfg.dtype
-    C = cache["k"].shape[2]
-    positions, slot, valid = ragged_cache_coords(pos, C)
-    x = _embed_rows(params["embed"], tokens, dt)[:, None, :]  # [B, 1, D]
-
-    def layer_step(x, inputs):
-        layer, k_cache, v_cache = inputs  # caches [B, C, KV, Hd]
-        x, k_cache, v_cache = cached_attn_step(
-            cfg, layer, x, k_cache, v_cache, positions, slot, valid)
-        h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-        moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"],
-                               min_capacity=h.shape[0])
-        return x + moe_out, (k_cache, v_cache)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(x[:, 0], params["lm_head"], dt,
-                       chunk=cfg.lm_logits_chunk)
-    return logits, {"k": new_k, "v": new_v}
+    return llama.prefill(cfg, params, prompt, max_len, ffn=_expert_block)
 
 
-def decode_step(
-    cfg: MoEConfig,
-    params: dict,
-    cache: dict,
-    tokens: jax.Array,  # [B] int32
-    pos: jax.Array,  # scalar int32 position being written
-) -> tuple[jax.Array, dict]:
+def decode_step_ragged(cfg: MoEConfig, params: dict, cache: dict,
+                       tokens: jax.Array, pos: jax.Array):
+    """One autoregressive step with PER-ROW positions ([B], -1 = idle):
+    continuous batching's kernel."""
+    _check_decodable(cfg)
+    return llama.decode_step_ragged(cfg, params, cache, tokens, pos,
+                                    ffn=_expert_block)
+
+
+def decode_step(cfg: MoEConfig, params: dict, cache: dict,
+                tokens: jax.Array, pos: jax.Array):
     """Scalar-position decode: the all-rows-in-lockstep special case of
-    ``decode_step_ragged`` (one body, same ring-cache semantics as
-    llama)."""
+    ``decode_step_ragged``."""
     B = tokens.shape[0]
     return decode_step_ragged(
         cfg, params, cache, tokens,
         jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)))
 
 
-def decode_chunk(
-    cfg: MoEConfig,
-    params: dict,
-    cache: dict,  # full-length cache: slot == position
-    tokens: jax.Array,  # [B, c] int32
-    pos0: jax.Array,  # [B] int32
-) -> tuple[jax.Array, dict]:
-    """Speculative-verify chunk for MoE targets: llama's
-    ``chunk_attn_step`` with the expert FFN in the MLP slot. Routing
-    sees the B·c chunk tokens as its dispatch group with no-drop
-    capacity (same rule as ``decode_step_ragged``). MoE configs carry
-    no sliding window, so the slot==position invariant holds."""
-    from polyaxon_tpu.models.llama import chunk_attn_step
-
+def decode_chunk(cfg: MoEConfig, params: dict, cache: dict,
+                 tokens: jax.Array, pos0: jax.Array):
+    """Speculative-verify chunk [B, c] from per-row positions ``pos0``
+    over a full-length cache (slot == position)."""
     _check_decodable(cfg)
-    dt = cfg.dtype
-    B, c = tokens.shape
-    C = cache["k"].shape[2]
-    positions = pos0[:, None] + jnp.arange(c)[None, :]
-    x = _embed_rows(params["embed"], tokens, dt)
-    cols = jnp.arange(C)[None, None, :]
-    valid = (cols <= positions[:, :, None])[:, None]  # [B, 1, c, C]
-
-    def layer_step(x, inputs):
-        layer, k_cache, v_cache = inputs
-        x, k_cache, v_cache = chunk_attn_step(
-            cfg, layer, x, k_cache, v_cache, positions, valid)
-        h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-        moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"],
-                               min_capacity=B * c)
-        return x + moe_out, (k_cache, v_cache)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(x, params["lm_head"], dt,
-                       chunk=cfg.lm_logits_chunk)
-    return logits, {"k": new_k, "v": new_v}
+    return llama.decode_chunk(cfg, params, cache, tokens, pos0,
+                              ffn=_expert_block)
 
 
-def decode_step_paged(
-    cfg: MoEConfig,
-    params: dict,
-    cache: dict,  # {"k"/"v": [L, P, KV, page, Hd]}
-    tokens: jax.Array,  # [B] int32
-    pos: jax.Array,  # [B] int32 per-row position (-1 = idle)
-    tables: jax.Array,  # [B, maxp] int32 page ids (-1 = unallocated)
-) -> tuple[jax.Array, dict]:
-    """Paged-pool ragged decode (llama's block-table semantics, the
-    expert FFN in the MLP slot) — parity with ``decode_step_ragged``
-    for rows whose pages cover 0..p."""
-    from polyaxon_tpu.models.llama import (paged_attn_step, paged_coords,
-                                           paged_page_size)
-
+def decode_step_paged(cfg: MoEConfig, params: dict, cache: dict,
+                      tokens: jax.Array, pos: jax.Array, tables: jax.Array):
+    """``decode_step_ragged`` over the paged pool: parity for rows whose
+    pages cover 0..p."""
     _check_decodable(cfg)
-    dt = cfg.dtype
-    page = paged_page_size(cache)
-    positions, write_page, write_off, valid = paged_coords(pos, tables, page)
-    x = _embed_rows(params["embed"], tokens, dt)[:, None, :]
-
-    def layer_step(x, inputs):
-        layer, k_pages, v_pages = inputs
-        x, k_pages, v_pages = paged_attn_step(
-            cfg, layer, x, k_pages, v_pages, positions,
-            write_page, write_off, tables, valid)
-        h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-        moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"],
-                               min_capacity=h.shape[0])
-        return x + moe_out, (k_pages, v_pages)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(x[:, 0], params["lm_head"], dt,
-                       chunk=cfg.lm_logits_chunk)
-    return logits, {"k": new_k, "v": new_v}
-
-
-def paged_init_cache(cfg: MoEConfig, n_pages: int, page_size: int) -> dict:
-    """Paged pool (MoE configs carry no sliding window)."""
-    from polyaxon_tpu.models.llama import paged_pool_shape
-
-    shape = paged_pool_shape(cfg, n_pages, page_size)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    return llama.decode_step_paged(cfg, params, cache, tokens, pos, tables,
+                                   ffn=_expert_block)
 
 
 def paged_prefill_kv(cfg: MoEConfig, params: dict, prompt: jax.Array):
     """Raw per-position KV for the paged insert ([L, P, KV, Hd], single
-    row) — same ``_prompt_pass`` body as ``prefill``."""
+    row): the prompt pass ``prefill`` runs."""
     _check_decodable(cfg)
-    _, k_all, v_all = _prompt_pass(cfg, params, prompt)
-    return k_all[:, 0], v_all[:, 0]
+    return llama.paged_prefill_kv(cfg, params, prompt, ffn=_expert_block)
 
 
 def paged_prefill_suffix_kv(cfg: MoEConfig, params: dict,
                             suffix: jax.Array, k_prefix: jax.Array,
                             v_prefix: jax.Array, m: jax.Array):
-    """Suffix-only prefill after a radix prefix-cache hit (llama's
-    ``suffix_attn_step`` with the expert FFN in the MLP slot): computes
-    KV only for the S novel tokens at absolute positions m..m+S-1,
-    attending the matched prefix pages. Routing sees the suffix tokens
-    as its dispatch group with no-drop capacity."""
-    from polyaxon_tpu.models.llama import _suffix_mask, suffix_attn_step
-
+    """Suffix-only prefill after a radix prefix-cache hit: KV for the S
+    novel tokens at absolute positions m..m+S-1, attending the matched
+    prefix pages."""
     _check_decodable(cfg)
-    dt = cfg.dtype
-    B, S = suffix.shape
-    m_pad = k_prefix.shape[1]
-    positions = jnp.broadcast_to(
-        m + jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    valid = _suffix_mask(S, m_pad, m)
-    x = _embed_rows(params["embed"], suffix, dt)
-
-    def layer_step(x, inputs):
-        layer, kp, vp = inputs
-        x, k, v = suffix_attn_step(
-            cfg, layer, x, kp[None], vp[None], positions, valid)
-        h = rms_norm(x, layer["moe_norm"], cfg.norm_eps)
-        moe_out, _ = moe_block(cfg, h, layer["router"], layer["w_gate"],
-                               layer["w_up"], layer["w_down"],
-                               min_capacity=B * S)
-        return x + moe_out, (k, v)
-
-    _, (k_all, v_all) = jax.lax.scan(
-        layer_step, x, (params["layers"], k_prefix, v_prefix))
-    return k_all[:, 0], v_all[:, 0]
-
-
-# Continuous-batching hooks: admission/validation semantics are the
-# llama decoder-only ones; cache init/prefill are moe's own; the paged
-# inserts are pure indexing shared verbatim.
-from polyaxon_tpu.models.llama import (  # noqa: E402  (re-exported hooks)
-    cb_admission,
-    cb_validate,
-    insert_cache_row,
-    paged_gather,
-    paged_gather_prefix,
-    paged_insert_prefill,
-    paged_insert_suffix,
-)
-
-
-def cb_init_cache(cfg: MoEConfig, slots: int, max_len: int) -> dict:
-    return init_cache(cfg, slots, max_len)
+    return llama.paged_prefill_suffix_kv(cfg, params, suffix, k_prefix,
+                                         v_prefix, m, ffn=_expert_block)
 
 
 def cb_prefill(cfg: MoEConfig, params: dict, prompt: jax.Array,
@@ -829,10 +668,8 @@ def generate(cfg: MoEConfig, params: dict, prompt: jax.Array, **sampling):
     """Greedy or sampled continuation [B, max_new]: llama's
     ``generate_loop`` over this family's prefill and decode step (the
     same serving contract)."""
-    from polyaxon_tpu.models.llama import generate_loop
-
-    return generate_loop(prefill, decode_step, cfg, params, prompt,
-                         **sampling)
+    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
+                               **sampling)
 
 
 def apply(

@@ -44,30 +44,18 @@ def peak_flops(device) -> Optional[float]:
 
 def train_flops_per_token(model: str, seq: int,
                           param_count: int) -> Optional[int]:
-    """Training FLOPs per token: 6N for the *active* matmul params
-    (fwd 2N + bwd 4N) plus the causal-attention score/value matmuls
-    (6 * n_layers * seq * d_model fwd+bwd after halving for causality).
-
-    For MoE models only K of E experts run per token, so N is the
-    dense params plus K/E of the expert-FFN params — counting all
-    experts would overstate tflops/MFU by roughly E/K on the FFN
-    share. Families without a derivation (vit/bert/resnet/...) return
-    None.
+    """Training FLOPs per token of ``model``, by its family's own
+    ``train_flops_per_token(cfg, seq, param_count)`` (beside its
+    ``CONFIGS``: llama's and moe's). A family without a derivation
+    (lfm2, vit/bert/resnet/...) and an unknown name give None.
     """
     try:
-        from polyaxon_tpu.models import llama, moe
+        from polyaxon_tpu.models import family_of
 
-        cfg = llama.CONFIGS.get(model)
-        if cfg is not None:
-            return 6 * param_count + 6 * cfg.n_layers * seq * cfg.dim
-        mcfg = moe.CONFIGS.get(model)
-        if mcfg is not None:
-            expert_params = (mcfg.n_layers * mcfg.n_experts
-                             * 3 * mcfg.dim * mcfg.ffn_dim)
-            active = (param_count - expert_params
-                      + expert_params * mcfg.experts_per_token
-                      // mcfg.n_experts)
-            return 6 * active + 6 * mcfg.n_layers * seq * mcfg.dim
+        family = family_of(model)
+        derive = getattr(family, "train_flops_per_token", None)
+        if derive is not None:
+            return derive(family.CONFIGS[model], seq, param_count)
     except Exception as exc:
         logging.getLogger(__name__).debug(
             "flops derivation failed for %r: %s", model, exc)

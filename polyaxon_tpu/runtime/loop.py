@@ -15,7 +15,7 @@ import contextlib
 import jax
 import numpy as np
 
-from polyaxon_tpu.models import get_model
+from polyaxon_tpu.models import config_of, get_model
 from polyaxon_tpu.obs import flight as obs_flight
 from polyaxon_tpu.obs import metrics as obs_metrics
 from polyaxon_tpu.obs import trace as obs_trace
@@ -86,16 +86,6 @@ class TrainResult:
                 "step_kernels": self.step_kernels,
                 "param_bytes_per_device": self.param_bytes_per_device,
                 "peak_hbm_bytes": self.peak_hbm_bytes}
-
-
-def _model_config_cls(model_name: str):
-    from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet,
-                                     t5, vit)
-
-    for mod in (llama, moe, lfm2, vit, bert, resnet, mnist, t5):
-        if model_name in mod.CONFIGS:
-            return type(mod.CONFIGS[model_name])
-    raise ValueError(f"Unknown model `{model_name}`")
 
 
 def _dataset_kwargs(cfg: RuntimeConfig, model_cfg, per_host_batch: int) -> dict:
@@ -185,10 +175,10 @@ def _run_jaxjob(
     logger.info("mesh axes=%s devices=%d", dict(zip(mesh.axis_names, mesh.devices.shape)),
                 mesh.devices.size)
 
-    config_cls = _model_config_cls(cfg.model)
-    overrides = cfg.model_overrides(config_cls)
+    base_cfg = config_of(cfg.model)
+    overrides = cfg.model_overrides(type(base_cfg))
     model_def = get_model(cfg.model, **overrides)
-    model_cfg = dataclasses.replace(_get_cfg(cfg.model), **overrides)
+    model_cfg = dataclasses.replace(base_cfg, **overrides)
 
     n_devices = mesh.devices.size
     if cfg.global_batch_size:
@@ -605,13 +595,3 @@ def _run_jaxjob(
         param_bytes_per_device=params_per_device,
         peak_hbm_bytes=peak_hbm,
     )
-
-
-def _get_cfg(model_name: str):
-    from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, resnet,
-                                     t5, vit)
-
-    for mod in (llama, moe, lfm2, vit, bert, resnet, mnist, t5):
-        if model_name in mod.CONFIGS:
-            return mod.CONFIGS[model_name]
-    raise ValueError(f"Unknown model `{model_name}`")

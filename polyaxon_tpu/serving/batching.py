@@ -333,9 +333,9 @@ class ContinuousBatchingEngine:
                  trace_capacity: int = reqtrace.DEFAULT_RING_CAPACITY,
                  trace_dump_path: Optional[str] = None,
                  registry=None, mesh=None):
-        from polyaxon_tpu.serving.server import _family
+        from polyaxon_tpu.models import family_of
 
-        family = _family(model)
+        family = family_of(model)
         # Disaggregated prefill/decode (ISSUE 18): `prefill_slots`
         # extra block-table rows form a prefill LANE — admissions land
         # there, stream their novel suffix in chunks via the radix
@@ -529,7 +529,7 @@ class ContinuousBatchingEngine:
                     "needs slot == position too)")
             if spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-            self._draft_family = _family(draft_model)
+            self._draft_family = family_of(draft_model)
             if getattr(self._draft_family, "SEQ2SEQ", False):
                 raise ValueError(
                     f"draft `{draft_model}` is seq2seq — a drafting "

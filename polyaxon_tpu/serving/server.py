@@ -70,19 +70,29 @@ def _bucket(n: int, lo: int = 16) -> int:
 
 
 def _family(model: str):
-    """Model family module with CONFIGS/init/generate and a SEQ2SEQ
-    flag (llama-style decoders, Mixtral-style MoE decoders, lfm2-style
-    hybrid decoders: short convolutions beside attention, sigmoid-routed
-    experts; and t5-style encoder-decoders)."""
-    from polyaxon_tpu.models import lfm2, llama, moe, t5
+    """``models.family_of`` held to what can be served: a family module
+    with ``generate`` beside CONFIGS/init, and a SEQ2SEQ flag (llama-style
+    decoders, Mixtral-style MoE decoders, lfm2-style hybrid decoders:
+    short convolutions beside attention, sigmoid-routed experts; and
+    t5-style encoder-decoders)."""
+    from polyaxon_tpu import models
 
-    for mod in (llama, moe, lfm2, t5):
-        if model in mod.CONFIGS:
-            return mod
+    try:
+        family = models.family_of(model)
+    except ValueError:
+        family = None
+    if hasattr(family, "generate"):
+        return family
+
+    def servable(seq2seq: bool) -> list:
+        return [name for mod in models.FAMILIES
+                if hasattr(mod, "generate")
+                and getattr(mod, "SEQ2SEQ", False) == seq2seq
+                for name in sorted(mod.CONFIGS)]
+
     raise ValueError(
-        f"model `{model}` is not servable; decoders: "
-        f"{sorted(llama.CONFIGS) + sorted(moe.CONFIGS) + sorted(lfm2.CONFIGS)}, "
-        f"seq2seq: {sorted(t5.CONFIGS)}")
+        f"model `{model}` is not servable; decoders: {servable(False)}, "
+        f"seq2seq: {servable(True)}")
 
 
 def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,

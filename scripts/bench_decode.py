@@ -33,10 +33,11 @@ def measure(model: str, quantize: bool, slots: int, steps: int,
     import jax.numpy as jnp
     import numpy as np
 
+    from polyaxon_tpu.models import family_of
     from polyaxon_tpu.serving.quantize import tree_bytes
-    from polyaxon_tpu.serving.server import _family, load_params
+    from polyaxon_tpu.serving.server import load_params
 
-    family = _family(model)
+    family = family_of(model)
     cfg, params = load_params(model, seed=seed)
     if lm_chunk is not None:
         # Sweepable lever: the quantized decode-logits vocab chunk

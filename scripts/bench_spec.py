@@ -45,7 +45,8 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from polyaxon_tpu.serving.server import _family, load_params
+    from polyaxon_tpu.models import family_of
+    from polyaxon_tpu.serving.server import load_params
     from polyaxon_tpu.serving.speculative import generate_speculative
 
     cfg, params = load_params(args.model, seed=0)
@@ -55,7 +56,7 @@ def main() -> int:
               f"{cfg.vocab_size}: a mismatched draft proposes garbage — "
               "pick a same-vocab pair", file=sys.stderr)
         return 2
-    family, draft_family = _family(args.model), _family(args.draft)
+    family, draft_family = family_of(args.model), family_of(args.draft)
     prompt = jax.random.randint(jax.random.key(1), (1, args.prompt_len),
                                 0, min(cfg.vocab_size,
                                        draft_cfg.vocab_size), jnp.int32)

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyaxon_tpu.models import available_models, bert, get_model, llama, mnist, resnet, vit
+from polyaxon_tpu import models
+from polyaxon_tpu.models import (available_models, bert, get_model, lfm2,
+                                 llama, mnist, moe, resnet, vit)
 from polyaxon_tpu.parallel import build_mesh, rules_for_mesh, tree_shardings
 from polyaxon_tpu.polyflow import V1MeshSpec
 
@@ -405,3 +407,80 @@ class TestRegistry:
                 lambda p, a: None, v, axes,
                 is_leaf=lambda x: isinstance(x, tuple) and not isinstance(x, dict),
             )
+
+    def test_every_registered_name_has_its_family(self):
+        """The factory table and the families' own tables are one set:
+        what `get_model` builds, `family_of` and `config_of` find."""
+        for name in available_models():
+            family = models.family_of(name)
+            assert family in models.FAMILIES
+            assert models.config_of(name) is family.CONFIGS[name]
+        with pytest.raises(ValueError, match="Unknown model `nope`"):
+            models.family_of("nope")
+
+    @pytest.mark.parametrize("family,tiny", [
+        (llama, "llama_tiny"), (moe, "moe_tiny"), (lfm2, "lfm2_tiny")],
+        ids=["llama", "moe", "lfm2"])
+    def test_a_configuration_written_in_after_import_is_found(
+            self, monkeypatch, family, tiny):
+        """What `benchmark/harness/program.py register` does: a config
+        dataclass written into the family's CONFIGS and the factory
+        table at run time is found by every lookup of the program (the
+        registry, the server's loader, the train loop)."""
+        import dataclasses
+
+        from polyaxon_tpu.polyflow import V1JAXJob
+        from polyaxon_tpu.runtime import run_jaxjob
+        from polyaxon_tpu.runtime.flops import train_flops_per_token
+        from polyaxon_tpu.serving.server import load_params
+
+        name = f"written_in_{tiny}"
+        cfg = dataclasses.replace(family.CONFIGS[tiny], max_seq_len=64)
+        monkeypatch.setitem(family.CONFIGS, name, cfg)
+        monkeypatch.setitem(
+            models._FACTORIES, name,
+            lambda **overrides: family.model_def(name, **overrides))
+
+        assert models.family_of(name) is family
+        assert models.config_of(name) is cfg
+        served_cfg, params = load_params(name, seed=0)
+        assert served_cfg is cfg
+        assert params["embed"].shape == (cfg.vocab_size, cfg.dim)
+        assert (train_flops_per_token(name, 32, 1000)
+                == train_flops_per_token(tiny, 32, 1000))
+        result = run_jaxjob(V1JAXJob.from_dict({
+            "kind": "jaxjob", "mesh": {"axes": {"dp": 2}},
+            "runtime": {"model": name, "dataset": "lm_synthetic",
+                        "steps": 1, "batch_size": 2, "seq_len": 32,
+                        "log_every": 100}}), devices=jax.devices()[:2])
+        assert result.steps == 1
+        assert np.isfinite(result.final_metrics["loss"])
+
+    @pytest.mark.parametrize("name,want", [
+        ("llama_tiny", 6 * 1000 + 6 * 2 * 32 * 64),
+        # 2 layers x 4 experts x 3 x 64 x 128 expert params, 2 of 4 active.
+        ("moe_tiny", 6 * (200_000 - 196_608 // 2) + 6 * 2 * 32 * 64),
+        ("lfm2_tiny", None), ("vit_tiny", None), ("nope", None)])
+    def test_train_flops_are_the_familys_own_count(self, name, want):
+        from polyaxon_tpu.runtime.flops import train_flops_per_token
+
+        param_count = 200_000 if name == "moe_tiny" else 1000
+        assert train_flops_per_token(name, 32, param_count) == want
+
+    def test_the_engine_does_not_import_the_http_front(self):
+        """`serving/batching.py` (the engine) sits under
+        `serving/server.py` (the front that builds it): a family is
+        looked up in `models`, and nothing is imported upwards."""
+        import ast
+        import inspect
+
+        from polyaxon_tpu.serving import batching
+
+        imported = set()
+        for node in ast.walk(ast.parse(inspect.getsource(batching))):
+            if isinstance(node, ast.ImportFrom):
+                imported.add(node.module)
+                imported.update(f"{node.module}.{a.name}" for a in node.names)
+            elif isinstance(node, ast.Import):
+                imported.update(a.name for a in node.names)
+        assert "polyaxon_tpu.serving.server" not in imported
