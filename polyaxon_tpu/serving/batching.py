@@ -648,6 +648,11 @@ class ContinuousBatchingEngine:
         # slots stay busy — avg_occupancy is THE number that says so.
         self._steps_total = 0
         self._live_slot_steps = 0
+        # What the paged decode kernel walks beside what it is handed:
+        # pages the live rows hold, and table entries, summed over the
+        # plain decode steps.
+        self._paged_pages_live = 0
+        self._paged_pages_table = 0
         self._queue_depth_peak = 0
         # Host time of the loop by phase (always on): `/v1/stats`
         # `tick_phase_ns`, the `engine:` spans of a profile, `slow_ticks`.
@@ -1849,6 +1854,10 @@ class ContinuousBatchingEngine:
             **({"kv_pages_total": self._pool.n_pages - 1,
                 "kv_pages_free": self._pool.free_pages,
                 "kv_page_size": self._pool.page_size,
+                # Their ratio is the part of the block tables the paged
+                # decode kernel has pages to fetch for.
+                "paged_pages_live": self._paged_pages_live,
+                "paged_pages_table": self._paged_pages_table,
                 # Bytes one page holds over all layers (K, V and any
                 # per-page state), and the state's part of it.
                 "kv_page_bytes": sum(self._page_bytes),
@@ -2470,6 +2479,11 @@ class ContinuousBatchingEngine:
                 tables = (jnp.asarray(self._pool.tables[:self.slots])
                           if self._pool is not None else None)
                 cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
+                if tables is not None:
+                    live = self._pos[self._pos >= 0]
+                    self._paged_pages_live += int(
+                        (live // self._pool.page_size + 1).sum())
+                    self._paged_pages_table += tables.size
                 counts = jnp.asarray(counts)
                 if self._sampling_dev is None:
                     # Copies, made on the host (`jnp.array` would run a

@@ -64,6 +64,15 @@ CASES = {
         "paged", dict(h=8, kv=1, d=256), PAGED_KERNELS),
     "paged-in-tp4-mesh": (
         "paged", dict(h=32, kv=8, d=64, mesh={"tp": 4}), PAGED_KERNELS),
+    "paged-mistral_7b-d128-in-tp4-mesh": (
+        "paged", dict(h=32, kv=8, d=128, mesh={"tp": 4}), PAGED_KERNELS),
+    # The engines the benchmark serves (benchmark/configs/*.json `serve`).
+    "paged-mistral7b-cells-16x4096-kv8-d128": (
+        "paged", dict(h=32, kv=8, d=128, slots=16, max_len=4096,
+                      n_pages=3073), PAGED_KERNELS),
+    "paged-lfm2-cell-32x2048-kv8-d64": (
+        "paged", dict(h=32, kv=8, d=64, slots=32, max_len=2048,
+                      n_pages=3073), PAGED_KERNELS),
 }
 
 
@@ -120,7 +129,7 @@ def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
 
 
 def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
-                   mesh=None):
+                   n_pages=None, mesh=None):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -135,7 +144,7 @@ def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
                                     sharding=NamedSharding(mesh, spec))
 
     maxp = max_len // page
-    pool = aval((slots * maxp + 1, kv, page, d), jnp.bfloat16,
+    pool = aval((n_pages or slots * maxp + 1, kv, page, d), jnp.bfloat16,
                 P(None, heads, None, None))
     with mesh:
         return jax.jit(

@@ -20,6 +20,84 @@ def _cfg():
                                dtype=jnp.float32)
 
 
+# Shapes for `test_kernel_matches_gather_reference`. `pos` is each
+# row's position (-1 = idle); a row holds the pages its position needs,
+# ids dealt out of order, but for `holes` (row, table entry) left at -1.
+# A head size that fills the lanes (128, 256) takes the kernel's
+# streamed form, 16 pages a turn; any other the pipelined form, 8 pages
+# a step (fewer for a narrower table). With pages of 4 that is 64 and
+# 32 tokens; with the serving page of 16, 256 and 128.
+_KERNEL_CASES = {
+    "ragged-hole-idle-gqa": dict(
+        kv=2, rep=2, hd=16, page=4, maxp=4, pos=[6, 2, -1],
+        tables=[[5, 2, -1, -1], [1, -1, -1, -1], [-1, -1, -1, -1]]),
+    "hd64-kv8-rep4-bf16": dict(
+        kv=8, rep=4, hd=64, page=16, maxp=16, dtype=jnp.bfloat16,
+        pos=[100, 255, 0, 143]),
+    "hd128-kv8-rep4-bf16": dict(
+        kv=8, rep=4, hd=128, page=16, maxp=32, dtype=jnp.bfloat16,
+        pos=[37, 300, -1, 511]),
+    "hd128-kv2-rep4": dict(
+        kv=2, rep=4, hd=128, page=4, maxp=16, pos=[33, 5, 62]),
+    "hd256-kv1-rep1": dict(
+        kv=1, rep=1, hd=256, page=4, maxp=8, pos=[9, 30]),
+    "hd256-kv1-rep8-bf16": dict(
+        kv=1, rep=8, hd=256, page=16, maxp=8, dtype=jnp.bfloat16,
+        pos=[77, 16]),
+    "hd64-kv2-rep1": dict(
+        kv=2, rep=1, hd=64, page=4, maxp=16, pos=[40, 3]),
+    "hd64-kv8-rep1": dict(
+        kv=8, rep=1, hd=64, page=4, maxp=8, pos=[21, 12, -1]),
+    "hd128-kv8-rep1": dict(
+        kv=8, rep=1, hd=128, page=4, maxp=8, pos=[21, 12, -1]),
+    # Neither the page nor the step a power of two: the index
+    # arithmetic divides where it otherwise shifts.
+    "hd64-page-of-3-table-of-6": dict(
+        kv=2, rep=2, hd=64, page=3, maxp=6, pos=[16, 7, -1, 2]),
+    "hd128-page-of-3-table-of-6": dict(
+        kv=2, rep=2, hd=128, page=3, maxp=6, pos=[16, 7, -1, 2]),
+}
+# What the walk over the live pages has to get right, in both forms.
+_KERNEL_WALKS = {
+    "length-not-a-multiple-of-a-step": dict(
+        maxp=48, pos=[44, 69, 134, 100]),
+    "length-ends-on-a-page-boundary": dict(
+        maxp=48, pos=[3, 31, 63, 127, 67]),
+    "row-fills-the-whole-table": dict(maxp=32, pos=[127, 127, 10]),
+    "single-token-rows": dict(maxp=32, pos=[0, 0, 17]),
+    "idle-rows-between-live-ones": dict(
+        maxp=32, pos=[-1, 37, -1, -1, 5, -1, 90]),
+    "all-rows-idle": dict(maxp=32, pos=[-1, -1]),
+    "hole-inside-a-live-range": dict(
+        maxp=32, pos=[50, 22, 125, 80],
+        holes=[(0, 3), (0, 9), (1, 0), (2, 31), (2, 16), (3, 17)]),
+    "table-width-not-divisible-by-a-step": dict(
+        maxp=20, pos=[47, 20, 79]),
+    "table-width-7-narrower-than-a-step": dict(maxp=7, pos=[27, 9, -1]),
+    "table-width-1": dict(maxp=1, pos=[3, 0]),
+    "32-rows": dict(
+        maxp=32, pos=[(7 * b) % 128 if b % 5 else -1 for b in range(32)]),
+}
+_KERNEL_CASES.update({
+    f"{form}-{walk}": dict(kv=2, rep=4, hd=hd, page=4, **spec)
+    for walk, spec in _KERNEL_WALKS.items()
+    for form, hd in (("pipelined-hd64", 64), ("streamed-hd128", 128))})
+
+
+def _kernel_tables(spec) -> jax.Array:
+    if "tables" in spec:
+        return jnp.asarray(spec["tables"], jnp.int32)
+    maxp, page = spec["maxp"], spec["page"]
+    tables = np.full((len(spec["pos"]), maxp), -1, np.int32)
+    ids = iter(np.random.default_rng(0).permutation(tables.size) + 1)
+    for b, pos in enumerate(spec["pos"]):
+        for p in range(pos // page + 1 if pos >= 0 else 0):
+            tables[b, p] = next(ids)
+    for b, p in spec.get("holes", ()):
+        tables[b, p] = -1
+    return jnp.asarray(tables)
+
+
 class TestPagedDecodeParity:
     def test_matches_dense_ragged_step_by_step(self):
         """A row whose pages cover 0..p must produce the dense ragged
@@ -133,6 +211,33 @@ class TestPagedEngine:
         assert got == want
         assert stats["kv"] == "paged"
         assert stats["kv_pages_free"] == stats["kv_pages_total"]  # all freed
+
+    def test_stats_count_live_pages_beside_table_entries(self):
+        """`paged_pages_live` adds, each decode step, the pages the live
+        rows hold (pos // page + 1); `paged_pages_table` the entries of
+        the block tables the kernel is handed (slots x max_len/page)."""
+        cfg = _cfg()
+        engine = ContinuousBatchingEngine(
+            "llama_tiny", cfg, self._params(cfg), slots=2, max_len=32,
+            kv="paged", page_size=4)
+        try:
+            before = engine.stats()
+            assert (before["paged_pages_live"],
+                    before["paged_pages_table"]) == (0, 0)
+            live = steps = 0
+            for prompt, new in (([3, 1, 4, 1, 5, 9], 7), ([2, 7], 3)):
+                engine.generate([prompt], max_new_tokens=new, timeout=300)
+                # One row alone: steps at positions n-1 .. n+new-2.
+                live += sum((len(prompt) - 1 + i) // 4 + 1
+                            for i in range(new))
+                steps += new
+                stats = engine.stats()
+                assert stats["decode_steps"] == steps
+                assert stats["paged_pages_live"] == live
+                assert stats["paged_pages_table"] == steps * 2 * (32 // 4)
+        finally:
+            engine.stop()
+        assert 0 < live / stats["paged_pages_table"] < 0.25
 
     def test_oversubscribed_pool_backpressure(self):
         """A pool HALF the dense reservation still serves all requests
@@ -274,38 +379,43 @@ class TestPagedKernel:
             single.stop()
         assert got == want
 
-    def test_kernel_matches_gather_reference(self):
+    @pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+    def test_kernel_matches_gather_reference(self, case):
         """The Pallas paged-decode kernel (interpret mode on CPU) must
         match the XLA gather+masked-softmax formulation on live rows —
-        ragged positions, holes in the tables, GQA — and zero idle
-        rows."""
+        ragged positions, holes in the tables, GQA, every head size and
+        table width the repo serves — and zero idle rows."""
         from polyaxon_tpu.ops.attention import repeat_kv
         from polyaxon_tpu.ops.paged_attention import paged_decode_attention
 
-        key = jax.random.key(0)
-        B, H, KV, Hd, page, P, maxp = 3, 4, 2, 16, 4, 9, 4
-        ks = jax.random.split(key, 4)
-        q = jax.random.normal(ks[0], (B, H, Hd), jnp.float32)
+        spec = dict(_KERNEL_CASES[case])
+        KV, rep, Hd, page = (spec[k] for k in ("kv", "rep", "hd", "page"))
+        dtype = spec.get("dtype", jnp.float32)
+        pos = jnp.asarray(spec["pos"], jnp.int32)
+        tables = _kernel_tables(spec)
+        B, maxp = tables.shape
+        H, P = KV * rep, int(tables.max()) + 2
+        ks = jax.random.split(jax.random.key(0), 3)
+        q = jax.random.normal(ks[0], (B, H, Hd), jnp.float32).astype(dtype)
         # Token-major pages for the reference; the kernel takes the
         # pool's kv-head-major layout [P, KV, page, Hd].
-        k_pages = jax.random.normal(ks[1], (P, page, KV, Hd), jnp.float32)
-        v_pages = jax.random.normal(ks[2], (P, page, KV, Hd), jnp.float32)
-        tables = jnp.asarray([[5, 2, -1, -1],
-                              [1, -1, -1, -1],
-                              [-1, -1, -1, -1]], jnp.int32)
-        pos = jnp.asarray([6, 2, -1], jnp.int32)
+        k_pages = jax.random.normal(
+            ks[1], (P, page, KV, Hd), jnp.float32).astype(dtype)
+        v_pages = jax.random.normal(
+            ks[2], (P, page, KV, Hd), jnp.float32).astype(dtype)
 
         got = paged_decode_attention(q, k_pages.swapaxes(1, 2),
                                      v_pages.swapaxes(1, 2), tables, pos,
                                      interpret=True)
+        assert got.shape == (B, H, Hd) and got.dtype == dtype
 
-        # Gather reference (the models/llama.py formulation).
+        # Gather reference (the models/llama.py formulation), float32.
         gathered = jnp.maximum(tables, 0)
-        keys_r = repeat_kv(k_pages[gathered].reshape(B, -1, KV, Hd),
-                           H // KV)
-        vals_r = repeat_kv(v_pages[gathered].reshape(B, -1, KV, Hd),
-                           H // KV)
-        logits = jnp.einsum("bhd,bkhd->bhk", q, keys_r) * Hd ** -0.5
+        keys_r, vals_r = (
+            repeat_kv(pages.astype(jnp.float32)[gathered].reshape(
+                B, -1, KV, Hd), rep) for pages in (k_pages, v_pages))
+        logits = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
+                            keys_r) * Hd ** -0.5
         j = jnp.arange(maxp * page)[None, :]
         allocated = jnp.repeat(tables >= 0, page, axis=1)
         valid = ((j <= jnp.maximum(pos, 0)[:, None]) & (pos[:, None] >= 0)
@@ -313,9 +423,12 @@ class TestPagedKernel:
         probs = jax.nn.softmax(jnp.where(valid, logits, -1e30), axis=-1)
         want = jnp.einsum("bhk,bkhd->bhd", probs, vals_r)
 
-        np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]),
-                                   atol=1e-5, rtol=1e-5)
-        assert (np.asarray(got[2]) == 0).all()  # idle row → zeros
+        live = np.asarray(pos) >= 0
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        got = np.asarray(got.astype(jnp.float32))
+        np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                                   atol=tol, rtol=tol)
+        assert (got[~live] == 0).all()  # idle rows → zeros
 
     def test_pallas_impl_matches_gather_in_step(self):
         """decode_step_paged with paged_attention_impl='pallas'
