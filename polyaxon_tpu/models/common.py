@@ -88,10 +88,14 @@ def rope_frequencies(d_half: int, theta: float,
     return (1.0 - smooth) * scaled + smooth * freqs
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float,
+def rope(x: jax.Array, positions: jax.Array, theta: Optional[float],
          scaling: Optional[dict] = None) -> jax.Array:
     """Rotary position embeddings on [B, S, H, D] with fp32 trig (shared
-    by the Llama decoder and the T5-style decoder self-attention)."""
+    by the Llama decoder and the T5-style decoder self-attention).
+    ``theta`` None is a model without rotary embedding (attention that
+    leaves position to other layers): ``x`` comes back as it is."""
+    if theta is None:
+        return x
     d_half = x.shape[-1] // 2
     freqs = rope_frequencies(d_half, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, d_half]

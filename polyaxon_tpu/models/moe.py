@@ -394,17 +394,26 @@ def route(cfg, logits: jax.Array, expert_bias: Optional[jax.Array] = None):
 
 
 def dense_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
-                   w_gate, w_up, w_down, capacity: int, dt):
-    """The GShard one-hot dispatch, the batched SwiGLU experts and the
+                   w_gate, w_up, w_down, capacity: int, dt, *, first: int = 0,
+                   experts=_expert_ffn):
+    """The GShard one-hot dispatch, the batched experts and the
     weighted combine, for any routing `route` gives: ``tokens`` [T, D],
     ``top_idx``/``top_w`` [T, K] → (out [T, D], the choices' one-hot
     [T, K, E]). An expert holds ``capacity`` tokens, filled
-    choice-major in token order; a pair beyond that is dropped."""
+    choice-major in token order; a pair beyond that is dropped.
+
+    The E experts held are ``first .. first+E−1`` of those the router
+    chose among (E from the stacked weights): a pair whose expert lies
+    elsewhere has an all-zero one-hot row and adds nothing. ``experts``
+    computes every held expert over its own buffer, ``(expert_in [E, C,
+    D], w_gate, w_up, w_down, dt) → [E, C, D]``: the SwiGLU of
+    `_expert_ffn`, or `relu2_expert_ffn`."""
     T, K = top_idx.shape
-    E = w_gate.shape[0]
+    E = w_down.shape[0]
     # Per k-choice: position of each token inside its expert's buffer =
     # how many earlier (token, choice) pairs picked that expert.
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [T, K, E]
+    onehot = jax.nn.one_hot(top_idx - first if first else top_idx, E,
+                            dtype=jnp.float32)  # [T, K, E]
     oh_km = onehot.transpose(1, 0, 2)  # choice-major [K, T, E]
     flat = oh_km.reshape(K * T, E)
     positions = (jnp.cumsum(flat, axis=0) - flat)  # [K*T, E] slots used before
@@ -422,8 +431,74 @@ def dense_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
         top_w.T * keep.astype(jnp.float32))
 
     expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dt), tokens)  # [E,C,D]
-    expert_out = _expert_ffn(expert_in, w_gate, w_up, w_down, dt)
+    expert_out = experts(expert_in, w_gate, w_up, w_down, dt)
     return jnp.einsum("tec,ecd->td", combine.astype(dt), expert_out), onehot
+
+
+def relu2_expert_ffn(expert_in: jax.Array, w_gate, w_up, w_down,
+                     dt) -> jax.Array:
+    """Every expert's ungated squared-ReLU MLP over its own buffer, [E,
+    C, D] → [E, C, D]: ``W_down(relu(W_up x)²)``. `dense_dispatch`'s
+    ``experts`` for a family without a gate (``w_gate`` is None)."""
+    del w_gate
+    hidden = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
+    return jnp.einsum("ecf,efd->ecd", jnp.square(jax.nn.relu(hidden)),
+                      _w(w_down, dt))
+
+
+_RAGGED_ROWS = 128
+
+
+def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
+                    w_up, w_down, first: int, dt,
+                    layer: Optional[int] = None):
+    """A dispatch that pays for the pairs it routes: the (token,
+    choice) pairs sorted by expert, two grouped matmuls over them
+    (``jax.lax.ragged_dot``: the chip's compiler makes a grouped-matmul
+    kernel of it that steps over the rows its groups cover), the
+    weighted sum back by token. ``tokens`` [T, D], ``top_idx``/``top_w``
+    [T, K] over every expert the router scores → out [T, D].
+
+    The E experts held are ``first .. first+E−1``, ungated squared-ReLU
+    MLPs ``w_up`` [E, D, F], ``w_down`` [E, F, D]. Pairs whose expert
+    lies elsewhere sort behind the last group, belong to none and add
+    nothing. No capacity: nothing is dropped, and nothing is computed
+    for a slot no pair fills (where `dense_dispatch` at the no-drop
+    capacity builds [T, E, T]).
+
+    With ``layer``, ``w_up``/``w_down`` are the layers' stacked leaves
+    [L, E, ...] and the kernel is handed them whole, as L·E groups of
+    which only layer ``layer``'s hold rows: it reads that layer's
+    experts where they lie. (Handed ``w_up[layer]``, the program first
+    copies the slice: 0.7 GB a matmul at 128 experts of 1,024 x 2,688.)"""
+    T, K = top_idx.shape
+    E = w_up.shape[-3]
+    local = (top_idx - first).reshape(T * K)
+    held = (local >= 0) & (local < E)
+    # The chip's compiler makes its grouped kernel only of a row count
+    # that is a multiple of 8 (at 22,506 rows it fell back to every
+    # group multiplying every row: 128 times the work, AOT for a
+    # described v5e): rows of padding, which belong to no group either.
+    pad = -(T * K) % _RAGGED_ROWS
+    group = jnp.pad(jnp.where(held, local, E), (0, pad),
+                    constant_values=E)               # E: held elsewhere
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), axis=0)
+    rows = tokens[jnp.minimum(order, T * K - 1) // K]  # by expert
+    if layer is not None:
+        L = w_up.shape[0]
+        sizes = jnp.zeros((L, E), jnp.int32).at[layer].set(sizes).reshape(-1)
+        w_up = w_up.reshape(L * E, *w_up.shape[2:])
+        w_down = w_down.reshape(L * E, *w_down.shape[2:])
+    hidden = jax.lax.ragged_dot(rows, _w(w_up, dt), sizes)
+    out = jax.lax.ragged_dot(jnp.square(jax.nn.relu(hidden)),
+                             _w(w_down, dt), sizes)
+    # Back in pair order; a row past the groups holds whatever the
+    # kernel left there, and is masked, not scaled.
+    back = out[jnp.argsort(order)[:T * K]].reshape(T, K, -1)
+    weight = jnp.where(held, top_w.reshape(T * K), 0.0).reshape(T, K, 1)
+    return jnp.sum(jnp.where(weight > 0, back.astype(jnp.float32) * weight,
+                             0.0), axis=1).astype(dt)
 
 
 def moe_block(

@@ -1,0 +1,703 @@
+"""Nemotron-H-style hybrid decoder: Mamba-2 layers beside grouped-query
+attention and latent expert layers, each layer one mixer alone.
+
+The published ``nemotron_h`` architecture (nvidia/NVIDIA-Nemotron-3-
+Super-120B-A12B-BF16 ``config.json``). With ``rms(x, g) = x /
+sqrt(mean(x²) + eps) · g``, layer ``l`` is ``x ← x + Mixer_l(rms(x,
+norm_l))``, the mixer named by character ``l`` of
+``hybrid_override_pattern``; after the last layer ``rms(x, norm_f)``
+and the untied head.
+
+- ``M``: the Mamba-2 mixer (``ops/mamba2.py``): what a sequence carries
+  between tokens is the state ``S`` [H, P, N] float32 and the last K−1
+  inputs of its convolution.
+- ``*``: attention, q/k/v/o without bias, causal GQA softmax, **no
+  rotary embedding** (``rope_theta`` None): llama's bodies.
+- ``E``: the latent expert layer. ``s = sigmoid(u · W_r)`` over every
+  routed expert in float32; chosen by ``top_k(s + bias)``, weighted by
+  ``s`` alone, normalised over the chosen, times
+  ``routed_scaling_factor`` (``models/moe.py route``); ``ℓ = u ·
+  W_down`` to the latent width; ``r = Σ_k w_k · W2_e(relu(W1_e ℓ)²)``;
+  ``out = r · W_up + Ws2(relu(Ws1 u)²)``, the shared expert on the full
+  width.
+
+**The chip's share of the experts.** ``held_experts = (first, count)``
+names the routed experts whose weights this chip holds (``w1``/``w2``
+are ``[L_moe, count, ...]``). The layer routes over all ``n_experts``
+with the published router; a (token, choice) pair whose expert lies
+elsewhere adds nothing here, and the partial sum goes up through
+``W_up`` and on to the next layer. No code stands in for the absent
+chips or their exchange. A decode step dispatches its rows through the
+one-hot buffers at the no-drop capacity (``moe.dense_dispatch``), a
+sequence through sorted pairs and grouped matmuls
+(``moe.sorted_dispatch``).
+
+**Layers of different kinds.** Parameters are stacked by kind
+(``attn``, ``ssm``, ``moe``) and a static plan (`layer_plan`) walks the
+pattern.
+
+**The cache.** ``k``/``v`` hold the attention layers' pages ``[L_attn,
+P, KV, page, Hd]``; the Mamba-2 layers' state is *per row*, not per
+page (a layer's state is 4 MB a sequence): ``rows`` holds ``ssm``
+``[L_ssm, rows, H, P, N]`` float32 and ``conv`` ``[L_ssm, rows, K−1,
+conv_dim]``, indexed by the engine's row. A decode step reads and
+writes each live row's state in place; a prefill writes its row's; a
+radix match has no state to resume from, so the pool matches nothing
+for such a cache (``serving/paged.py``). ``moe_expert_tokens``
+``[L_moe, count]`` counts the decode steps' (row, choice) pairs by held
+expert, ``moe_pairs_elsewhere`` ``[L_moe]`` those routed to experts
+this chip does not hold.
+
+One sequence pass (`_sequence_pass`: a suffix behind an optional
+prefix) serves ``forward``, the whole-prompt prefill and the suffix
+prefill; speculation and chunked dense prefill need ``decode_chunk``,
+which this family does not have (the state has no rollback), and the
+engine refuses them by that.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+from polyaxon_tpu.models import llama, moe
+from polyaxon_tpu.models.common import (
+    Batch,
+    ModelDef,
+    Variables,
+    _embed_rows,
+    _w,
+    chunked_lm_loss,
+    lm_logits,
+    rms_norm,
+    scaled_init,
+    shift_right,
+    truncated_normal_init,
+)
+# A prefilled row goes into its slot as the other hybrid family's does
+# (every leaf's axis 1 is the slot); decoder-only admission and the K/V
+# page gather are llama's as they are.
+from polyaxon_tpu.models.lfm2 import (  # noqa: F401  (re-exported hook)
+    _at,
+    insert_cache_row,
+)
+from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
+    cb_admission,
+    cb_validate,
+    paged_gather,
+)
+from polyaxon_tpu.ops import mamba2
+
+SEQ2SEQ = False
+
+PUBLISHED_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131_072
+    dim: int = 4096
+    # "M" Mamba-2 | "*" attention | "E" experts, per layer.
+    pattern: str = PUBLISHED_PATTERN
+    n_heads: int = 32
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    ssm_heads: int = 128
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    n_experts: int = 512  # what the router scores
+    experts_per_token: int = 22
+    moe_latent_dim: int = 1024
+    moe_ffn_dim: int = 2688  # per routed expert, on the latent width
+    shared_ffn_dim: int = 5376  # the shared expert, on the full width
+    # (first, count) of the routed experts held here; None: all.
+    held_experts: Optional[tuple] = None
+    # The router (models/moe.py `route`).
+    router_score: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 5.0
+    # The seeded draw of dt_bias (Mamba-2's: Δ log-uniform between).
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    rope_theta: Optional[float] = None  # no rotary embedding
+    max_seq_len: int = 262_144
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    paged_attention_impl: str = "auto"  # as LlamaConfig's
+    loss_chunk: int = 256
+    lm_logits_chunk: int = 4096
+
+    def __post_init__(self):
+        unknown = set(self.pattern) - set("M*E")
+        if unknown or not self.pattern:
+            raise ValueError(f"pattern `{self.pattern}` names layers other "
+                             "than M (Mamba-2), * (attention), E (experts)")
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError("ssm_heads is not a multiple of ssm_groups")
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(f"held_experts {self.held_experts} lie outside "
+                             f"the {self.n_experts} routed experts")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.pattern)
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the routed experts held here."""
+        return self.held_experts or (0, self.n_experts)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+
+CONFIGS: dict[str, NemotronHConfig] = {
+    "nemotron3_super_120b_a12b": NemotronHConfig(),
+    "nemotron_h_tiny": NemotronHConfig(
+        vocab_size=256, dim=64, pattern="M*EME", n_heads=4, n_kv_heads=2,
+        head_dim=16, ssm_heads=8, ssm_head_dim=8, ssm_state=16,
+        ssm_groups=2, chunk_size=8, n_experts=16, experts_per_token=4,
+        moe_latent_dim=32, moe_ffn_dim=48, shared_ffn_dim=96,
+        max_seq_len=128),
+}
+
+_KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
+
+
+@functools.lru_cache(maxsize=None)
+def _plan(pattern: str) -> tuple:
+    seen = {"ssm": 0, "attn": 0, "moe": 0}
+    out = []
+    for char in pattern:
+        kind = _KINDS[char]
+        out.append((kind, seen[kind]))
+        seen[kind] += 1
+    return tuple(out)
+
+
+def layer_plan(cfg: NemotronHConfig) -> tuple:
+    """Per layer, in published order: (kind, its index in that kind's
+    stack)."""
+    return _plan(cfg.pattern)
+
+
+def kind_counts(cfg: NemotronHConfig) -> dict:
+    return {kind: cfg.pattern.count(char) for char, kind in _KINDS.items()}
+
+
+def init(cfg: NemotronHConfig, rng: jax.Array) -> Variables:
+    """Seeded float32 weights, stacked by kind. Projections as the zoo
+    draws them (truncated normal, 1/sqrt(fan_in); the tables std 0.02).
+    What the published model learns as small vectors is drawn so that
+    each shows in the result: ``A_log = log(A)``, A uniform in [1, 16),
+    and ``dt_bias`` the inverse softplus of a Δ log-uniform in
+    [time_step_min, time_step_max) (both Mamba-2's own initialisation);
+    ``D`` around one and ``expert_bias`` around zero (std 0.02)."""
+    keys = jax.random.split(rng, 22)
+    n = kind_counts(cfg)
+    D, H, KV, Hd = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    La, Ls, Le = n["attn"], n["ssm"], n["moe"]
+    Hs, K = cfg.ssm_heads, cfg.conv_kernel
+    d_in, conv_dim = cfg.ssm_inner, cfg.conv_dim
+    E, held = cfg.n_experts, cfg.held[1]
+    Dl, F, Fs = cfg.moe_latent_dim, cfg.moe_ffn_dim, cfg.shared_ffn_dim
+    step = jnp.exp(
+        jax.random.uniform(keys[9], (Ls, Hs))
+        * (math.log(cfg.time_step_max) - math.log(cfg.time_step_min))
+        + math.log(cfg.time_step_min))
+    step = jnp.maximum(step, cfg.time_step_floor)
+    params = {
+        "embed": truncated_normal_init(keys[0], (cfg.vocab_size, D)),
+        "attn": {
+            "attn_norm": jnp.ones((La, D)),
+            "wq": scaled_init(keys[1], (La, D, H * Hd), fan_in=D),
+            "wk": scaled_init(keys[2], (La, D, KV * Hd), fan_in=D),
+            "wv": scaled_init(keys[3], (La, D, KV * Hd), fan_in=D),
+            "wo": scaled_init(keys[4], (La, H * Hd, D), fan_in=H * Hd),
+        },
+        "ssm": {
+            "ssm_norm": jnp.ones((Ls, D)),
+            "w_in": scaled_init(keys[5], (Ls, D, d_in + conv_dim + Hs),
+                                fan_in=D),
+            "conv_w": scaled_init(keys[6], (Ls, conv_dim, K), fan_in=K),
+            "conv_b": truncated_normal_init(keys[7], (Ls, conv_dim)),
+            "A_log": jnp.log(jax.random.uniform(
+                keys[8], (Ls, Hs), minval=1.0, maxval=16.0)),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "D": 1.0 + truncated_normal_init(keys[10], (Ls, Hs)),
+            "gate_norm": jnp.ones((Ls, d_in)),
+            "w_out": scaled_init(keys[11], (Ls, d_in, D), fan_in=d_in),
+        },
+        "moe": {
+            "moe_norm": jnp.ones((Le, D)),
+            "router": scaled_init(keys[12], (Le, D, E), fan_in=D),
+            "expert_bias": truncated_normal_init(keys[13], (Le, E)),
+            "w_latent_down": scaled_init(keys[14], (Le, D, Dl), fan_in=D),
+            "w_latent_up": scaled_init(keys[15], (Le, Dl, D), fan_in=Dl),
+            "w1": scaled_init(keys[16], (Le, held, Dl, F), fan_in=Dl),
+            "w2": scaled_init(keys[17], (Le, held, F, Dl), fan_in=F),
+            "ws1": scaled_init(keys[18], (Le, D, Fs), fan_in=D),
+            "ws2": scaled_init(keys[19], (Le, Fs, D), fan_in=Fs),
+        },
+        "final_norm": jnp.ones((D,)),
+        "lm_head": truncated_normal_init(keys[20], (D, cfg.vocab_size)),
+    }
+    return {"params": params, "state": {}}
+
+
+def logical_axes(cfg: NemotronHConfig) -> Variables:
+    del cfg
+    return {
+        "params": {
+            "embed": ("vocab", "embed"),
+            "attn": {
+                "attn_norm": ("layers", "embed"),
+                "wq": ("layers", "embed", "heads"),
+                "wk": ("layers", "embed", "kv_heads"),
+                "wv": ("layers", "embed", "kv_heads"),
+                "wo": ("layers", "heads", "embed"),
+            },
+            "ssm": {
+                "ssm_norm": ("layers", "embed"),
+                "w_in": ("layers", "embed", "mlp"),
+                "conv_w": ("layers", "mlp", None),
+                "conv_b": ("layers", "mlp"),
+                "A_log": ("layers", None),
+                "dt_bias": ("layers", None),
+                "D": ("layers", None),
+                "gate_norm": ("layers", "mlp"),
+                "w_out": ("layers", "mlp", "embed"),
+            },
+            "moe": {
+                "moe_norm": ("layers", "embed"),
+                "router": ("layers", "embed", None),
+                "expert_bias": ("layers", None),
+                "w_latent_down": ("layers", "embed", None),
+                "w_latent_up": ("layers", None, "embed"),
+                "w1": ("layers", "expert", None, "mlp"),
+                "w2": ("layers", "expert", "mlp", None),
+                "ws1": ("layers", "embed", "mlp"),
+                "ws2": ("layers", "mlp", "embed"),
+            },
+            "final_norm": ("embed",),
+            "lm_head": ("embed", "vocab"),
+        },
+        "state": {},
+    }
+
+
+# Leaves read at float32: every norm gain, the recurrence's own vectors
+# and the convolution's taps and bias (``ops/mamba2.py`` reads them with
+# ``.astype(float32)``: the taps are summed in float32), and the
+# router with its bias (the scores decide a top-k, so that matmul is
+# float32 at full precision, as lfm2's). The rest are read at
+# ``cfg.dtype`` and a server holds them so (``common.served_params``).
+READ_AT_FLOAT32 = frozenset(
+    {"attn_norm", "ssm_norm", "gate_norm", "moe_norm", "final_norm",
+     "A_log", "dt_bias", "D", "conv_w", "conv_b", "router", "expert_bias"})
+
+
+# ------------------------------------------------------------ the layers
+def ssm_layer(cfg: NemotronHConfig, layer: dict, x: jax.Array,
+              conv_tail: jax.Array, state: jax.Array, real_len=None):
+    """The Mamba-2 layer over ``x`` [B, S, D] behind what the sequence
+    carries (``ops/mamba2.py mixer``). Returns (x after the residual,
+    new convolution tail, new state)."""
+    u = rms_norm(x, layer["ssm_norm"], cfg.norm_eps)
+    out, tail, state = mamba2.mixer(cfg, layer, u, conv_tail, state,
+                                    real_len)
+    return x + out, tail, state
+
+
+def routed_experts(cfg: NemotronHConfig, stack: dict, i: int,
+                   tokens: jax.Array, sequence: bool):
+    """The held experts' part of the routed sum in expert layer ``i``
+    of ``stack`` (``params["moe"]``) for ``tokens`` [T, D] (already
+    normalised), back on the full width: (r · W_up [T, D], the held
+    choices' one-hot [T, K, count] or None for a sequence)."""
+    dt = cfg.dtype
+    # The scores decide a top-k, where a rounding flips an expert: the
+    # router's own matmul runs in float32 at full precision.
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     stack["router"][i].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_idx, top_w, _ = moe.route(cfg, logits, stack["expert_bias"][i])
+    latent = tokens @ _w(stack["w_latent_down"][i], dt)
+    first = cfg.held[0]
+    if sequence:
+        routed = moe.sorted_dispatch(latent, top_idx, top_w, stack["w1"],
+                                     stack["w2"], first, dt, layer=i)
+        onehot = None
+    else:
+        routed, onehot = moe.dense_dispatch(
+            latent, top_idx, top_w, None, stack["w1"][i], stack["w2"][i],
+            tokens.shape[0], dt, first=first,
+            experts=moe.relu2_expert_ffn)
+    return routed @ _w(stack["w_latent_up"][i], dt), onehot
+
+
+def shared_expert(cfg: NemotronHConfig, stack: dict, i: int,
+                  tokens: jax.Array) -> jax.Array:
+    dt = cfg.dtype
+    hidden = jnp.square(jax.nn.relu(tokens @ _w(stack["ws1"][i], dt)))
+    return hidden @ _w(stack["ws2"][i], dt)
+
+
+def expert_layer(cfg: NemotronHConfig, stack: dict, i: int, x: jax.Array):
+    """Expert layer ``i``'s residual over ``x`` [B, S, D], its B·S
+    tokens one dispatch group; nothing is dropped. A single position a
+    row (a decode step) goes through the one-hot buffers, a sequence
+    through sorted pairs. Returns (x after the residual, the held
+    choices' one-hot [B·S, K, count] or None)."""
+    B, S, D = x.shape
+    tokens = rms_norm(x, stack["moe_norm"][i], cfg.norm_eps).reshape(B * S, D)
+    routed, onehot = routed_experts(cfg, stack, i, tokens, sequence=S > 1)
+    out = routed + shared_expert(cfg, stack, i, tokens)
+    return x + out.reshape(B, S, D), onehot
+
+
+def _head(cfg: NemotronHConfig, params: dict, x: jax.Array) -> jax.Array:
+    """Final norm and the untied head: hidden [..., D] → fp32 logits."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return lm_logits(x, params["lm_head"], cfg.dtype,
+                     chunk=cfg.lm_logits_chunk)
+
+
+def init_rows(cfg: NemotronHConfig, rows: int) -> dict:
+    """What ``rows`` sequences carry through the Mamba-2 layers, zeroed:
+    the state, float32, and the convolution's last K−1 inputs."""
+    n = kind_counts(cfg)["ssm"]
+    return {"ssm": jnp.zeros((n, rows, cfg.ssm_heads, cfg.ssm_head_dim,
+                              cfg.ssm_state), jnp.float32),
+            "conv": jnp.zeros((n, rows, cfg.conv_kernel - 1, cfg.conv_dim),
+                              cfg.dtype)}
+
+
+def _sequence_pass(cfg: NemotronHConfig, params: dict, tokens: jax.Array,
+                   k_prefix: Optional[jax.Array] = None,
+                   v_prefix: Optional[jax.Array] = None,
+                   carried: Optional[dict] = None, m=0, real_len=None):
+    """One causal pass over ``tokens`` [B, S] at absolute positions
+    m..m+S−1, behind a prefix that already exists: its K/V
+    ``k_prefix``/``v_prefix`` [L_attn, B, Mpad, KV, Hd] (columns at or
+    past ``m`` masked) and what the Mamba-2 layers carry after position
+    m−1, ``carried`` (`init_rows`' two leaves for B rows). Without a
+    prefix (all None, m = 0) it is the whole-sequence forward.
+    Positions at or past ``real_len`` are padding (``mamba2.mixer``).
+    Returns (hidden before the final norm [B, S, D], k [L_attn, B, S,
+    KV, Hd], v, what the layers carry after the last real position)."""
+    dt = cfg.dtype
+    B, S = tokens.shape
+    if k_prefix is None:
+        shape = (kind_counts(cfg)["attn"], B, 0, cfg.n_kv_heads,
+                 cfg.head_dim)
+        k_prefix = v_prefix = jnp.zeros(shape, dt)
+    if carried is None:
+        carried = init_rows(cfg, B)
+    positions = jnp.broadcast_to(
+        m + jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    valid = llama._suffix_mask(S, k_prefix.shape[2], m)
+    x = _embed_rows(params["embed"], tokens, dt)
+    ks, vs, tails, states = [], [], [], []
+    for kind, i in layer_plan(cfg):
+        if kind == "attn":
+            x, k, v = llama.suffix_attn_step(
+                cfg, _at(params["attn"], i), x, k_prefix[i], v_prefix[i],
+                positions, valid)
+            ks.append(k)
+            vs.append(v)
+        elif kind == "ssm":
+            x, tail, state = ssm_layer(
+                cfg, _at(params["ssm"], i), x, carried["conv"][i],
+                carried["ssm"][i], real_len)
+            tails.append(tail)
+            states.append(state)
+        else:
+            x, _ = expert_layer(cfg, params["moe"], i, x)
+    return x, jnp.stack(ks), jnp.stack(vs), {
+        "ssm": jnp.stack(states), "conv": jnp.stack(tails)}
+
+
+def forward(cfg: NemotronHConfig, params: dict,
+            tokens: jax.Array) -> jax.Array:
+    """Token ids [B, S] → logits [B, S, vocab] fp32."""
+    x, _, _, _ = _sequence_pass(cfg, params, tokens)
+    return _head(cfg, params, x)
+
+
+# ------------------------------------------------------- dense slot cache
+def init_cache(cfg: NemotronHConfig, batch: int, max_len: int) -> dict:
+    """The slot cache: K/V [L_attn, B, C, KV, Hd] and what each slot
+    carries through the Mamba-2 layers (`init_rows`)."""
+    kv = (kind_counts(cfg)["attn"], batch, max_len, cfg.n_kv_heads,
+          cfg.head_dim)
+    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+            **init_rows(cfg, batch)}
+
+
+def prefill(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
+            max_len: int):
+    """One pass over the prompt [B, P]: (last-position logits [B, V]
+    fp32, the slot cache holding it)."""
+    P = prompt.shape[1]
+    if P > max_len:
+        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
+    x, k, v, carried = _sequence_pass(cfg, params, prompt)
+    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
+    cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad), **carried}
+    return _head(cfg, params, x[:, -1]), cache
+
+
+def _put(stack: jax.Array, new: jax.Array, i: int) -> jax.Array:
+    """``new`` [B, ...] over the first B rows of layer ``i`` of
+    ``stack`` [L, rows ≥ B, ...], as an update of that slice in place
+    (an ``.at[i, :B].set`` is a scatter, which the chip's compiler
+    turns into a pass over the whole stack: 1.25 GB a Mamba-2 layer a
+    step at 64 rows)."""
+    return jax.lax.dynamic_update_slice(
+        stack, new[None].astype(stack.dtype), (i,) + (0,) * new.ndim)
+
+
+def _decode_layers(cfg: NemotronHConfig, params: dict, x: jax.Array,
+                   pos: jax.Array, attend, ssm: jax.Array, conv: jax.Array,
+                   counters: Optional[dict] = None):
+    """One position a row through every layer. ``attend(i, layer, x)``
+    is the attention layer over the cache in use; ``ssm``/``conv`` are
+    the rows' carried leaves ([L_ssm, rows ≥ B, ...]; a row at position
+    0 starts from zeros, an idle row's is garbage the next admission's
+    prefill replaces), updated in place a layer at a time. Live rows'
+    routed pairs are added to ``counters`` where given."""
+    B = x.shape[0]
+    started = pos > 0
+    live = (pos >= 0).astype(jnp.int32)
+    for kind, i in layer_plan(cfg):
+        if kind == "attn":
+            x = attend(i, _at(params["attn"], i), x)
+        elif kind == "ssm":
+            state = jnp.where(started[:, None, None, None], ssm[i, :B], 0.0)
+            tail = jnp.where(started[:, None, None], conv[i, :B], 0)
+            x, tail, state = ssm_layer(cfg, _at(params["ssm"], i), x, tail,
+                                       state)
+            ssm, conv = _put(ssm, state, i), _put(conv, tail, i)
+        else:
+            x, onehot = expert_layer(cfg, params["moe"], i, x)
+            if counters is not None:
+                held = jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live)
+                counters = {
+                    "moe_expert_tokens":
+                        counters["moe_expert_tokens"].at[i].add(held),
+                    "moe_pairs_elsewhere":
+                        counters["moe_pairs_elsewhere"].at[i].add(
+                            cfg.experts_per_token * jnp.sum(live)
+                            - jnp.sum(held))}
+    return x, ssm, conv, counters
+
+
+def decode_step_ragged(cfg: NemotronHConfig, params: dict, cache: dict,
+                       tokens: jax.Array, pos: jax.Array):
+    """One step with per-row positions ([B], −1 = idle) over the slot
+    cache: llama's ``cached_attn_step`` in the attention layers, the
+    row's own carried state in the Mamba-2 layers."""
+    positions, slot, valid = llama.ragged_cache_coords(pos,
+                                                       cache["k"].shape[2])
+    kv = {"k": cache["k"], "v": cache["v"]}
+
+    def attend(i, layer, x):
+        x, k, v = llama.cached_attn_step(cfg, layer, x, kv["k"][i],
+                                         kv["v"][i], positions, slot, valid)
+        kv["k"], kv["v"] = _put(kv["k"], k, i), _put(kv["v"], v, i)
+        return x
+
+    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
+    x, ssm, conv, _ = _decode_layers(cfg, params, x, pos, attend,
+                                     cache["ssm"], cache["conv"])
+    return _head(cfg, params, x[:, 0]), {**kv, "ssm": ssm, "conv": conv}
+
+
+def decode_step(cfg: NemotronHConfig, params: dict, cache: dict,
+                tokens: jax.Array, pos: jax.Array):
+    """Scalar-position decode: every row at the same position."""
+    return decode_step_ragged(
+        cfg, params, cache, tokens,
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
+
+
+def generate(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
+             **sampling):
+    """Greedy or sampled continuation [B, max_new]: llama's
+    ``generate_loop`` over this family's prefill and decode step."""
+    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
+                               **sampling)
+
+
+def cb_init_cache(cfg: NemotronHConfig, slots: int, max_len: int) -> dict:
+    return init_cache(cfg, slots, max_len)
+
+
+def cb_prefill(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
+               max_len: int) -> dict:
+    return prefill(cfg, params, prompt, max_len)[1]
+
+
+# ------------------------------------------------------------ paged cache
+def paged_init_cache(cfg: NemotronHConfig, n_pages: int,
+                     page_size: int) -> dict:
+    """The paged part of the cache (module docstring): K/V pages of the
+    attention layers and the decode steps' routed pairs. The engine
+    adds `paged_init_rows` under ``rows``."""
+    n = kind_counts(cfg)
+    kv = (n["attn"], n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+            "moe_expert_tokens": jnp.zeros((n["moe"], cfg.held[1]),
+                                           jnp.int32),
+            "moe_pairs_elsewhere": jnp.zeros((n["moe"],), jnp.int32)}
+
+
+# What each of the engine's rows carries beside its pages: the engine
+# keeps it under ``cache["rows"]``, leaves ``[L, rows, ...]``.
+paged_init_rows = init_rows
+
+
+def decode_step_paged(cfg: NemotronHConfig, params: dict, cache: dict,
+                      tokens: jax.Array, pos: jax.Array,
+                      tables: jax.Array):
+    """`decode_step_ragged` over the paged pool: row b's K and V in its
+    pages, its Mamba-2 state in row b of ``cache["rows"]``, read and
+    written in place."""
+    page = cache["k"].shape[-2]
+    positions, write_page, write_off, valid = llama.paged_coords(
+        pos, tables, page)
+    kv = {"k": cache["k"], "v": cache["v"]}
+
+    def attend(i, layer, x):
+        x, k, v = llama.paged_attn_step(
+            cfg, layer, x, kv["k"][i], kv["v"][i], positions, write_page,
+            write_off, tables, valid)
+        kv["k"], kv["v"] = _put(kv["k"], k, i), _put(kv["v"], v, i)
+        return x
+
+    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
+    x, ssm, conv, counters = _decode_layers(
+        cfg, params, x, pos, attend, cache["rows"]["ssm"],
+        cache["rows"]["conv"],
+        counters={name: cache[name] for name in (
+            "moe_expert_tokens", "moe_pairs_elsewhere")})
+    return _head(cfg, params, x[:, 0]), {
+        **kv, **counters, "rows": {"ssm": ssm, "conv": conv}}
+
+
+def _row_of(rows: dict, row) -> dict:
+    """Row ``row`` (traced) of every per-row leaf, as a batch of one:
+    [L, 1, ...]."""
+    return {name: jax.lax.dynamic_slice_in_dim(leaf, row, 1, axis=1)
+            for name, leaf in rows.items()}
+
+
+def _set_row(rows: dict, carried: dict, row) -> dict:
+    return {name: jax.lax.dynamic_update_slice_in_dim(
+        leaf, carried[name].astype(leaf.dtype), row, axis=1)
+        for name, leaf in rows.items()}
+
+
+def paged_prefill_kv(cfg: NemotronHConfig, params: dict, prompt: jax.Array):
+    """The whole prompt [1, P] as a suffix behind nothing: (k, v
+    [L_attn, P, KV, Hd], what the row carries after it) for
+    `paged_insert_prefill`."""
+    _, k, v, carried = _sequence_pass(cfg, params, prompt)
+    return k[:, 0], v[:, 0], carried
+
+
+def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
+                         carried: dict, page_ids: jax.Array,
+                         page_size: int, row) -> dict:
+    """K and V into the row's pages as llama does, the carried leaves
+    into row ``row``."""
+    kv = llama.paged_insert_prefill(
+        {"k": cache["k"], "v": cache["v"]}, k_all, v_all, page_ids,
+        page_size)
+    return {**cache, **kv, "rows": _set_row(cache["rows"], carried, row)}
+
+
+def paged_gather_prefix(cache: dict, page_ids: jax.Array, row) -> tuple:
+    """What a suffix prefill reads of the row's earlier chunks: K and V
+    of the pages ``page_ids`` token-major [L_attn, n·page, KV, Hd], and
+    what row ``row`` carries (true where the prefix is this row's own
+    work, which is the prefill lane's case: a radix match has no state,
+    so for this cache the pool gives none)."""
+    return (paged_gather(cache["k"], page_ids),
+            paged_gather(cache["v"], page_ids),
+            _row_of(cache["rows"], row))
+
+
+def paged_prefill_suffix_kv(cfg: NemotronHConfig, params: dict,
+                            suffix: jax.Array, k_prefix: jax.Array,
+                            v_prefix: jax.Array, carried: dict, m,
+                            real_len):
+    """The tail ``suffix`` [1, S] (``real_len`` of it real, the rest
+    padding) of a prompt whose first ``m`` tokens exist
+    (`paged_gather_prefix`'s three): (k, v [L_attn, S, KV, Hd], what the
+    row carries after the last real position) for
+    `paged_insert_suffix`. At ``m`` = 0 the row starts from zeros,
+    whatever it held."""
+    carried = jax.tree.map(lambda leaf: jnp.where(m > 0, leaf, 0), carried)
+    _, k, v, carried = _sequence_pass(
+        cfg, params, suffix, k_prefix[:, None], v_prefix[:, None], carried,
+        m, real_len)
+    return k[:, 0], v[:, 0], carried
+
+
+def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
+                        carried: dict, page_ids: jax.Array, start,
+                        page_size: int, real_len, row) -> dict:
+    kv = llama.paged_insert_suffix(
+        {"k": cache["k"], "v": cache["v"]}, k_suf, v_suf, page_ids, start,
+        page_size, real_len)
+    return {**cache, **kv, "rows": _set_row(cache["rows"], carried, row)}
+
+
+# --------------------------------------------------------------- training
+def apply(cfg: NemotronHConfig, variables: Variables, batch: Batch,
+          train: bool = True, rng: Optional[jax.Array] = None):
+    """Next-token loss (chunked head). No auxiliary loss: the published
+    model balances its experts through the selection bias, which this
+    objective leaves alone."""
+    tokens = batch["tokens"]
+    if batch.get("segments") is not None:
+        raise ValueError("nemotron_h models do not support packed sequences "
+                         "(segments): the recurrent state would cross them")
+    params = variables["params"]
+    x, _, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    loss, acc = chunked_lm_loss(x, params["lm_head"].astype(cfg.dtype),
+                                tokens, batch.get("mask"),
+                                chunk=cfg.loss_chunk)
+    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
+
+
+def model_def(name: str, **overrides) -> ModelDef:
+    cfg = dataclasses.replace(CONFIGS[name], **overrides)
+    return ModelDef(
+        name=name,
+        init=functools.partial(init, cfg),
+        apply=functools.partial(apply, cfg),
+        logical_axes=functools.partial(logical_axes, cfg),
+        unit="tokens",
+    )

@@ -501,20 +501,27 @@ class ContinuousBatchingEngine:
         # What a page holds: page_size tokens of K and V and, for a
         # family whose cache has such a leaf, one fixed-size state. A
         # radix match that ends inside a page has no true state, so the
-        # pool then matches whole pages only (decided here, from the
-        # cache's structure; there is no option).
-        self._page_bytes = (0, 0)
+        # pool then matches whole pages only. What a row holds beside
+        # its pages (``cache["rows"]``: a recurrent state a sequence)
+        # no match can resume from, so the pool then matches nothing
+        # (both decided here, from the cache's structure; there is no
+        # option).
+        self._page_bytes = (0, 0, 0)
         if self._pool is not None:
             from polyaxon_tpu.serving.paged import page_bytes
 
             self._page_bytes = page_bytes(
                 self._cache, self._pool.n_pages, self._pool.page_size)
             self._pool.whole_page_matches = self._page_bytes[1] > 0
+            if self._page_bytes[2]:
+                self._pool.match_nothing()
         # A family may keep, with its cache, the (row, choice) pairs its
         # decode steps routed to each expert. The engine thread reads it
         # between ticks, when `stats()` has asked (`_serve_expert_tokens`).
-        self._counts_experts = "moe_expert_tokens" in self._cache
-        self._expert_tokens: Optional[np.ndarray] = None
+        self._expert_counters = tuple(
+            name for name in ("moe_expert_tokens", "moe_pairs_elsewhere")
+            if name in self._cache)
+        self._expert_tokens: Optional[dict] = None
         self._expert_tokens_asking = threading.Lock()  # one asker at a time
         self._expert_tokens_wanted = threading.Event()
         self._expert_tokens_ready = threading.Event()
@@ -721,13 +728,14 @@ class ContinuousBatchingEngine:
             if self.kv == "paged":
                 ps = page_size
 
-                def run(params, prompt, cache, page_ids):
+                def run(params, prompt, cache, page_ids, *row):
                     # What the family's prefill returns (K and V; a
                     # hybrid family's state with them) goes to its
-                    # insert as it is.
+                    # insert as it is; `row` (`_row_arg`) says where a
+                    # cache with per-row leaves keeps this one's.
                     return family.paged_insert_prefill(
                         cache, *family.paged_prefill_kv(
-                            cfg, params, prompt), page_ids, ps)
+                            cfg, params, prompt), page_ids, ps, *row)
 
                 return jax.jit(run, donate_argnums=(2,))
 
@@ -760,6 +768,17 @@ class ContinuousBatchingEngine:
                         for name, arr in cache.items()}
 
             self._copy_page = jax.jit(copy_page, donate_argnums=(0,))
+
+            def copy_row(cache, src, dst):
+                return {**cache, "rows": {
+                    name: jax.lax.dynamic_update_slice_in_dim(
+                        arr, jax.lax.dynamic_slice_in_dim(arr, src, 1, 1),
+                        dst, 1)
+                    for name, arr in cache["rows"].items()}}
+
+            # What a lane row carries follows its pages to the decode
+            # slot (`_lane_handoff`).
+            self._copy_row = jax.jit(copy_row, donate_argnums=(0,))
             if (hasattr(family, "paged_prefill_suffix_kv")
                     and hasattr(family, "paged_gather_prefix")):
                 ps = page_size
@@ -769,11 +788,16 @@ class ContinuousBatchingEngine:
                 # the classic suffix shapes.
                 @lru_cache(maxsize=32)
                 def compiled_suffix_prefill(slen: int, n_pref: int):
-                    def run(params, suffix, cache, page_ids, m, real_len):
+                    def run(params, suffix, cache, page_ids, m, real_len,
+                            *row):
                         pref = jnp.maximum(page_ids[:n_pref], 0)
+                        # A row's state is carried past the padding by
+                        # the pass itself, which is told where it ends.
+                        extent = (m, real_len) if row else (m,)
                         novel = family.paged_prefill_suffix_kv(
                             cfg, params, suffix,
-                            *family.paged_gather_prefix(cache, pref), m)
+                            *family.paged_gather_prefix(cache, pref, *row),
+                            *extent)
                         # Padded tail positions (>= real_len) carry
                         # garbage KV; the insert routes them to the
                         # scratch page. Real positions are unaffected:
@@ -781,7 +805,7 @@ class ContinuousBatchingEngine:
                         # real queries (padding sits after every real
                         # position), so no extra attention mask.
                         return family.paged_insert_suffix(
-                            cache, *novel, page_ids, m, ps, real_len)
+                            cache, *novel, page_ids, m, ps, real_len, *row)
 
                     return jax.jit(run, donate_argnums=(2,))
 
@@ -1472,7 +1496,7 @@ class ContinuousBatchingEngine:
                     self._cache,
                     jnp.asarray(self._pool.padded_row(b)),
                     jnp.int32(skip),
-                    jnp.int32(len(suffix)))
+                    jnp.int32(len(suffix)), *self._row_arg(b))
             else:
                 if req.trace is not None:
                     req.trace.start_phase(
@@ -1485,7 +1509,8 @@ class ContinuousBatchingEngine:
                 if self._pool is not None:
                     self._cache = fn(
                         self.params, row, self._cache,
-                        jnp.asarray(self._pool.padded_row(b)))
+                        jnp.asarray(self._pool.padded_row(b)),
+                        *self._row_arg(b))
                 else:
                     row_cache = fn(self.params, row)
                     self._cache = self._insert(
@@ -1506,6 +1531,11 @@ class ContinuousBatchingEngine:
             # leaf survives the slot from here on.
             self._pool.commit_prefix(b)
         self._go_live(b, req, pos0, tok0)
+
+    def _row_arg(self, row: int) -> tuple:
+        """What a prefill program is told beside the block table: the
+        row itself, for a cache with per-row leaves; nothing else."""
+        return (jnp.int32(row),) if self._page_bytes[2] else ()
 
     def _state_pages(self, start: int, stop: int) -> int:
         """Pages a prefill of positions start..stop-1 leaves a per-page
@@ -1628,7 +1658,8 @@ class ContinuousBatchingEngine:
                     self.params, jnp.asarray([padded], jnp.int32),
                     self._cache,
                     jnp.asarray(self._pool.padded_row(p)),
-                    jnp.int32(i), jnp.int32(len(chunk)))
+                    jnp.int32(i), jnp.int32(len(chunk)),
+                    *self._row_arg(p))
             except Exception as exc:  # noqa: BLE001 — request-scoped
                 self._drop_lane_reservation(
                     p, f"{type(exc).__name__}: {exc}")
@@ -1677,6 +1708,10 @@ class ContinuousBatchingEngine:
                 return  # decode pool full: staged rows wait in place
             self._pool.commit_prefix(p)
             moved = self._pool.handoff(p, b)
+            if self._page_bytes[2]:
+                with self._phase("admit.prefill"):
+                    self._cache = self._copy_row(
+                        self._cache, jnp.int32(p), jnp.int32(b))
             del self._lane[p]
             self._handoffs += 1
             self._handoff_pages += moved
@@ -1825,11 +1860,12 @@ class ContinuousBatchingEngine:
                 self._device0.memory_stats() or {}).get(
                     "peak_bytes_in_use")},
             "compile_cache": compile_cache.stats(),
-            # [expert layer][expert]: (row, choice) pairs of live rows
-            # the decode steps routed there (families with routed
-            # experts that count them; absent otherwise).
-            **({"moe_expert_tokens": self._read_expert_tokens()}
-               if self._counts_experts else {}),
+            # `moe_expert_tokens` [expert layer][held expert]: (row,
+            # choice) pairs of live rows the decode steps routed there;
+            # `moe_pairs_elsewhere` [expert layer]: those routed to
+            # experts another chip holds (families with routed experts
+            # that count them; absent otherwise).
+            **(self._read_expert_tokens() if self._expert_counters else {}),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
                 "spec_rounds": self._spec_rounds,
@@ -1860,8 +1896,11 @@ class ContinuousBatchingEngine:
                 "paged_pages_table": self._paged_pages_table,
                 # Bytes one page holds over all layers (K, V and any
                 # per-page state), and the state's part of it.
-                "kv_page_bytes": sum(self._page_bytes),
+                "kv_page_bytes": sum(self._page_bytes[:2]),
                 "kv_state_bytes_per_page": self._page_bytes[1],
+                # What a slot holds beside its pages, whatever its
+                # length: a family's per-row recurrent state.
+                "kv_state_bytes_per_slot": self._page_bytes[2],
                 "kv_prefix_hits": self._pool.prefix_hits,
                 "kv_prefix_misses": self._pool.prefix_misses,
                 # Radix prefix-reuse dividend: prefill tokens the
@@ -1991,9 +2030,16 @@ class ContinuousBatchingEngine:
         first device."""
         family, cfg = self._family_mod, self.cfg
         if self._pool is not None:
+            rows = self.slots + self.prefill_slots
+
             def build():
-                return family.paged_init_cache(
+                cache = family.paged_init_cache(
                     cfg, self._pool.n_pages, self._pool.page_size)
+                if hasattr(family, "paged_init_rows"):
+                    # What a sequence carries whatever its length, by
+                    # the engine's row (lane rows behind the slots).
+                    cache["rows"] = family.paged_init_rows(cfg, rows)
+                return cache
         else:
             def build():
                 return family.cb_init_cache(cfg, self.slots, self.max_len)
@@ -2009,15 +2055,17 @@ class ContinuousBatchingEngine:
         # dim 3 of the dense cache [L, B, C, KV, Hd].
         kv_dim = 2 if self._pool is not None else 3
 
-        def spec(aval):
+        def spec(path, aval):
             dims = [None] * aval.ndim
-            if aval.ndim == 5:  # K and V; any other leaf is replicated
+            # K and V; any other leaf (per-row ones too) is replicated.
+            if aval.ndim == 5 and path[0].key != "rows":
                 dims[kv_dim] = head_axis
             return NamedSharding(self._mesh, PartitionSpec(*dims))
 
         shapes = jax.eval_shape(build)
 
-        return jax.jit(build, out_shardings=jax.tree.map(spec, shapes))()
+        return jax.jit(build, out_shardings=jax.tree_util.tree_map_with_path(
+            spec, shapes))()
 
     def _handle_step_failure(self, exc: Exception, what: str) -> bool:
         """Shared device-failure recovery for the plain step AND the
@@ -2313,12 +2361,15 @@ class ContinuousBatchingEngine:
         routed-pairs counter to the host when `stats()` has asked."""
         if self._expert_tokens_wanted.is_set():
             self._expert_tokens_wanted.clear()
-            self._expert_tokens = np.asarray(self._cache["moe_expert_tokens"])
+            self._expert_tokens = {
+                name: np.asarray(self._cache[name]).tolist()
+                for name in self._expert_counters}
             self._expert_tokens_ready.set()
 
-    def _read_expert_tokens(self) -> Optional[list]:
-        """The counter as of the next tick boundary (the last copy where
-        the engine thread does not answer: stopped, or mid-compile)."""
+    def _read_expert_tokens(self) -> dict:
+        """The counters as of the next tick boundary (the last copy
+        where the engine thread does not answer: stopped, or
+        mid-compile; None before any)."""
         with self._expert_tokens_asking:
             self._expert_tokens_ready.clear()
             self._expert_tokens_wanted.set()
@@ -2326,7 +2377,7 @@ class ContinuousBatchingEngine:
                 self._cv.notify_all()
             self._expert_tokens_ready.wait(timeout=2.0)
             got = self._expert_tokens
-        return None if got is None else got.tolist()
+        return got or dict.fromkeys(self._expert_counters)
 
     def _tick_snapshot(self) -> dict:
         """The engine's state beside a slow tick's phase split."""

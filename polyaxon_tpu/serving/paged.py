@@ -257,20 +257,25 @@ class RadixPrefixIndex:
 
 def page_bytes(cache: dict, n_pages: int, page_size: int) -> tuple:
     """(bytes a page holds of per-token leaves, of per-page state
-    leaves), over all layers, read off the device cache's structure: a
-    paged leaf has the pages on axis 1; ``[L, P, KV, page_size, Hd]``
-    holds tokens (K, V), any other shape one fixed-size state a page.
-    A leaf without a page axis (a counter) is no page's content."""
+    leaves, bytes a row holds of per-row leaves), over all layers, read
+    off the device cache's structure. A paged leaf has the pages on
+    axis 1: ``[L, P, KV, page_size, Hd]`` holds tokens (K, V), any other
+    shape one fixed-size state a page. What a sequence carries whatever
+    its length (a recurrent state too large to keep a page) lies under
+    ``rows``, leaves ``[L, rows, ...]`` indexed by the engine's row. A
+    leaf without a page axis (a counter) is no page's content."""
     tokens = state = 0
-    for leaf in cache.values():
-        if leaf.ndim < 3 or leaf.shape[1] != n_pages:
+    for name, leaf in cache.items():
+        if name == "rows" or leaf.ndim < 3 or leaf.shape[1] != n_pages:
             continue
         per_page = leaf.size * leaf.dtype.itemsize // n_pages
         if leaf.ndim == 5 and leaf.shape[3] == page_size:
             tokens += per_page
         else:
             state += per_page
-    return tokens, state
+    row = sum(leaf.size * leaf.dtype.itemsize // leaf.shape[1]
+              for leaf in cache.get("rows", {}).values())
+    return tokens, state, row
 
 
 class PagePool:
@@ -309,6 +314,17 @@ class PagePool:
         self.cow_forks = 0          # mid-page divergences forked
         self.cached_tokens_total = 0  # prefill tokens served from cache
         self.prefix_evictions = 0   # resident pages reclaimed under pressure
+
+    def match_nothing(self) -> None:
+        """For a cache with per-row leaves (`page_bytes`): what a row
+        carries is the state after its own last position, and a matched
+        prefix has none to resume from, so no prompt matches and no
+        retired page is kept (called by the engine before any
+        admission, from the cache's structure; there is no option)."""
+        with self._lock:
+            assert not self._ref.any(), "pages admitted before match_nothing"
+            self.prefix_cache = False
+            self._index = None
 
     @classmethod
     def dense_equivalent(cls, slots: int, max_len: int, page_size: int,

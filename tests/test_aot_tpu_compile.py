@@ -20,6 +20,7 @@ from __future__ import annotations
 import fcntl
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -73,6 +74,15 @@ CASES = {
     "paged-lfm2-cell-32x2048-kv8-d64": (
         "paged", dict(h=32, kv=8, d=64, slots=32, max_len=2048,
                       n_pages=3073), PAGED_KERNELS),
+    # The Mamba-2 / attention / latent-expert family's two serving
+    # programs at a small size with the published head sizes (128; state
+    # 64 x 128): the decode step over pages and rows holds the paged
+    # kernel, the prefill the compiler's grouped-matmul kernel (two a
+    # held-expert layer), not every group over every row.
+    "nemotron_h-decode-step-pages-and-rows": (
+        "nemotron_h", dict(program="decode"), PAGED_KERNELS),
+    "nemotron_h-prefill-sorted-dispatch": (
+        "nemotron_h", dict(program="prefill"), {"ragged-dot": 2}),
 }
 
 
@@ -154,6 +164,64 @@ def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
                 aval((slots,), jnp.int32)).compile()
 
 
+def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
+                        n_pages=257, prompt=127):
+    """`decode_step_paged` or the whole-prompt prefill with its insert,
+    as the engine builds them (serving/batching.py), for a one-period
+    model of a quarter of its experts."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from polyaxon_tpu.models import nemotron_h as nh
+    from polyaxon_tpu.models.common import served_params
+
+    cfg = dataclasses.replace(
+        nh.CONFIGS["nemotron_h_tiny"], vocab_size=1024, dim=512,
+        pattern="*EM", n_heads=4, n_kv_heads=2, head_dim=128, ssm_heads=16,
+        ssm_head_dim=64, ssm_state=128, ssm_groups=8, chunk_size=128,
+        n_experts=32, held_experts=(8, 8), experts_per_token=6,
+        moe_latent_dim=256, moe_ffn_dim=384, shared_ffn_dim=512,
+        paged_attention_impl="pallas")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def avals(build):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            jax.eval_shape(build))
+
+    params = avals(lambda: served_params(
+        nh.init(cfg, jax.random.key(0))["params"], cfg.dtype,
+        nh.READ_AT_FLOAT32))
+    cache = avals(lambda: {**nh.paged_init_cache(cfg, n_pages, page),
+                           "rows": nh.paged_init_rows(cfg, slots)})
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    maxp = max_len // page
+    real_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"  # the kernel, not its CPU path
+    try:
+        if program == "decode":
+            def step(params, cache, tokens, pos, tables):
+                return nh.decode_step_paged(cfg, params, cache, tokens, pos,
+                                            tables)
+
+            return jax.jit(step, donate_argnums=(1,)).lower(
+                params, cache, i32(slots), i32(slots),
+                i32(slots, maxp)).compile()
+
+        def prefill(params, tokens, cache, page_ids, row):
+            return nh.paged_insert_prefill(
+                cache, *nh.paged_prefill_kv(cfg, params, tokens), page_ids,
+                page, row)
+
+        return jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(1, prompt), cache, i32(maxp), i32()).compile()
+    finally:
+        jax.default_backend = real_backend
+
+
 def _child_main() -> int:
     """Compile every case against the described topology; one JSON
     object on stdout. Never raises: a refusal is that case's entry."""
@@ -179,12 +247,19 @@ def _child_main() -> int:
 
     report = {}
     for name, (kind, kwargs, _) in CASES.items():
-        compile_case = _compile_flash if kind == "flash" else _compile_paged
+        compile_case = {"flash": _compile_flash, "paged": _compile_paged,
+                        "nemotron_h": _compile_nemotron_h}[kind]
         t0 = time.time()
         try:
-            compiled = compile_case(topo, **kwargs)
-            report[name] = {"ok": True,
-                            "kernels": pallas_kernels(compiled.as_text())}
+            text = compile_case(topo, **kwargs).as_text()
+            kernels = pallas_kernels(text)
+            # The compiler's own grouped matmul carries no pallas_call
+            # name: it is counted by its custom call's.
+            grouped = len(re.findall(r"^\s*(?:ROOT )?%ragged-dot[\w.\-]* = "
+                                     r"(?!\()", text, re.MULTILINE))
+            if grouped:
+                kernels["ragged-dot"] = grouped
+            report[name] = {"ok": True, "kernels": kernels}
         except Exception as exc:  # noqa: BLE001 — the refusal IS the result
             report[name] = {"ok": False,
                             "error": f"{type(exc).__name__}: {exc}"[:600]}

@@ -389,16 +389,48 @@ class TestEngine:
         assert outs[0] == outs[1]
 
 
-def test_page_bytes_reads_the_caches_structure():
-    from polyaxon_tpu.models import llama
+def _page_bytes_cases():
+    """(cache, (bytes a page holds of tokens, of per-page state, bytes a
+    row holds)) by the three kinds of leaf."""
+    from polyaxon_tpu.models import llama, nemotron_h
 
-    cfg = dataclasses.replace(llama.CONFIGS["llama_tiny"], dtype=jnp.float32)
-    plain = llama.paged_init_cache(cfg, 9, PAGE)
-    tokens, state = page_bytes(plain, 9, PAGE)
-    assert state == 0
-    assert tokens == 2 * cfg.n_layers * cfg.n_kv_heads * PAGE * cfg.head_dim * 4
-    hybrid = lfm2.paged_init_cache(_cfg(), 9, PAGE)
-    assert page_bytes(hybrid, 9, PAGE)[1] > 0
+    plain_cfg = dataclasses.replace(llama.CONFIGS["llama_tiny"],
+                                    dtype=jnp.float32)
+    kv = (2 * plain_cfg.n_layers * plain_cfg.n_kv_heads * PAGE
+          * plain_cfg.head_dim * 4)
+    yield "per-token", llama.paged_init_cache(plain_cfg, 9, PAGE), (kv, 0, 0)
+    cfg = _cfg()
+    n = lfm2.kind_counts(cfg)
+    yield "per-page", lfm2.paged_init_cache(cfg, 9, PAGE), (
+        2 * n["attn"] * cfg.n_kv_heads * PAGE * cfg.head_dim * 4,
+        n["conv"] * (cfg.conv_kernel - 1) * cfg.dim * 4, 0)
+    rowed = dataclasses.replace(nemotron_h.CONFIGS["nemotron_h_tiny"],
+                                dtype=jnp.float32)
+    m = nemotron_h.kind_counts(rowed)
+    per_row = m["ssm"] * (
+        rowed.ssm_heads * rowed.ssm_head_dim * rowed.ssm_state * 4
+        + (rowed.conv_kernel - 1) * rowed.conv_dim * 4)
+    tokens = 2 * m["attn"] * rowed.n_kv_heads * PAGE * rowed.head_dim * 4
+    for rows in (3, 9):      # as many rows as pages tells nothing apart
+        yield f"per-row-{rows}", {
+            **nemotron_h.paged_init_cache(rowed, 9, PAGE),
+            "rows": nemotron_h.paged_init_rows(rowed, rows)}, (
+                tokens, 0, per_row)
+    # The counters beside them ([L, E], [L]) are no page's and no row's.
+    yield "counters-only", {
+        name: leaf for name, leaf in nemotron_h.paged_init_cache(
+            rowed, 9, PAGE).items() if name.startswith("moe_")}, (0, 0, 0)
+
+
+@pytest.mark.parametrize("case", ["per-token", "per-page", "per-row-3",
+                                  "per-row-9", "counters-only"])
+def test_page_bytes_reads_the_caches_structure(case):
+    cache, want = next((c, w) for name, c, w in _page_bytes_cases()
+                       if name == case)
+    assert page_bytes(cache, 9, PAGE) == want
+
+
+def test_a_match_inside_a_page_rounds_down_to_whole_pages():
     pool = PagePool(2, 32, PAGE, 9)
     a = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10]
     b = [1, 2, 3, 4, 5, 6, 77, 88, 99, 100]      # diverges inside page 1

@@ -172,6 +172,31 @@ class TestPagePool:
         assert pool.free_pages == 2
         assert (pool.tables[0] == -1).all()
 
+    def test_match_nothing_keeps_no_retired_page_and_matches_no_prompt(self):
+        """For a cache with per-row leaves (a recurrent state a row,
+        `page_bytes`' third number): a matched prefix would have no
+        state to resume from."""
+        prompt = list(range(1, 14))
+        shared = PagePool(slots=2, max_len=16, page_size=4, n_pages=9)
+        assert shared.admit(0, len(prompt), prompt).matched_tokens == 0
+        shared.commit_prefix(0)
+        assert shared.peek_matched_tokens(len(prompt), prompt) == 12
+        pool = PagePool(slots=2, max_len=16, page_size=4, n_pages=9)
+        pool.match_nothing()
+        assert pool.admit(0, len(prompt), prompt).matched_tokens == 0
+        pool.commit_prefix(0)
+        assert pool.peek_matched_tokens(len(prompt), prompt) == 0
+        again = pool.admit(1, len(prompt), prompt)
+        assert (again.matched_tokens, again.matched_pages, again.cow) == (
+            0, 0, None)
+        assert pool.free_pages == 8 - 2 * 4
+        pool.release(0)
+        pool.release(1)
+        assert pool.free_pages == 8 and pool.radix_stats()["pages"] == 0
+        assert pool.check_invariants() == []
+        with pytest.raises(AssertionError, match="before match_nothing"):
+            shared.match_nothing()          # only before any admission
+
     def test_dense_equivalent_sizing(self):
         pool = PagePool.dense_equivalent(slots=4, max_len=32, page_size=8)
         assert pool.n_pages == 4 * 4 + 1

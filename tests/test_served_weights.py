@@ -13,18 +13,32 @@ import numpy as np
 import pytest
 from jax.extend import core as jex_core
 
-from polyaxon_tpu.models import lfm2, llama, moe
+from polyaxon_tpu.models import lfm2, llama, moe, nemotron_h
 from polyaxon_tpu.models.common import served_params
 from polyaxon_tpu.serving.quantize import tree_bytes, weight_bytes
 from polyaxon_tpu.serving.server import _Engine, load_params
 
 FAMILIES = {"llama": ("llama_tiny", llama), "moe": ("moe_tiny", moe),
-            "lfm2": ("lfm2_tiny", lfm2)}
+            "lfm2": ("lfm2_tiny", lfm2),
+            "nemotron_h": ("nemotron_h_tiny", nemotron_h)}
 PAGE = 4
 N_PAGES = 8
 PROMPT = [5, 6, 7, 1, 2, 3, 4, 9]          # two whole pages
 SUFFIX = [8, 2, 11, 3]
 PAGE_IDS = jnp.asarray([1, 2, 3, -1], jnp.int32)
+
+
+def _row(fam) -> tuple:
+    """What a prefill program is told beside the block table by a
+    family whose cache has per-row leaves: the engine's row."""
+    return (jnp.int32(0),) if hasattr(fam, "paged_init_rows") else ()
+
+
+def _pool(fam, cfg) -> dict:
+    cache = fam.paged_init_cache(cfg, N_PAGES, PAGE)
+    if hasattr(fam, "paged_init_rows"):
+        cache["rows"] = fam.paged_init_rows(cfg, 2)
+    return cache
 
 
 def _leaf_name(path) -> str:
@@ -66,10 +80,10 @@ def _pool_after_prefill(family: str):
     from, whichever tree they then run on."""
     _, fam = FAMILIES[family]
     cfg, full, _, _ = _trees(family)
-    cache = fam.paged_init_cache(cfg, N_PAGES, PAGE)
     prompt = jnp.asarray([PROMPT], jnp.int32)
     return jax.jit(lambda p, c: fam.paged_insert_prefill(
-        c, *fam.paged_prefill_kv(cfg, p, prompt), PAGE_IDS, PAGE))(full, cache)
+        c, *fam.paged_prefill_kv(cfg, p, prompt), PAGE_IDS, PAGE,
+        *_row(fam)))(full, _pool(fam, cfg))
 
 
 def _programs(family: str):
@@ -82,14 +96,17 @@ def _programs(family: str):
     tables = jnp.stack([PAGE_IDS, jnp.full_like(PAGE_IDS, -1)])
     prompt = jnp.asarray([PROMPT], jnp.int32)
     suffix = jnp.asarray([SUFFIX], jnp.int32)
+    row = _row(fam)
+    extent = (jnp.int32(len(PROMPT)),) + (
+        (jnp.int32(len(SUFFIX)),) if row else ())
     return {
         "decode_step_paged": lambda p, c: fam.decode_step_paged(
             cfg, p, c, tokens, pos, tables),
         "prefill": lambda p, c: fam.paged_insert_prefill(
-            c, *fam.paged_prefill_kv(cfg, p, prompt), PAGE_IDS, PAGE),
+            c, *fam.paged_prefill_kv(cfg, p, prompt), PAGE_IDS, PAGE, *row),
         "suffix_prefill": lambda p, c: fam.paged_prefill_suffix_kv(
-            cfg, p, suffix, *fam.paged_gather_prefix(c, PAGE_IDS[:2]),
-            jnp.int32(len(PROMPT))),
+            cfg, p, suffix, *fam.paged_gather_prefix(c, PAGE_IDS[:2], *row),
+            *extent),
     }
 
 
