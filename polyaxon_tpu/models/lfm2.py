@@ -490,10 +490,9 @@ def decode_step_paged(cfg: Lfm2Config, params: dict, cache: dict,
     routed = cache["moe_expert_tokens"]
     for op, oi, ffn, fi in layer_plan(cfg):
         if op == "attn":
-            x, k, v = llama.paged_attn_step(
-                cfg, _at(params["attn"], oi), x, k_pool[oi], v_pool[oi],
+            x, k_pool, v_pool = llama.paged_attn_step(
+                cfg, _at(params["attn"], oi), x, k_pool, v_pool, oi,
                 positions, write_page, write_off, tables, valid)
-            k_pool, v_pool = k_pool.at[oi].set(k), v_pool.at[oi].set(v)
         else:
             state = jnp.where(carried, conv[oi, read_page], 0)
             x, z = conv_op(cfg, _at(params["conv"], oi), x, state)

@@ -809,9 +809,21 @@ def chunk_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
 # coordinates write there, and masks keep it unread.
 #
 # The layout is kv-head-major within a page because the decode kernel
-# (ops/paged_attention.py) DMAs one (page, Hd) tile per kv head, and a
-# Mosaic block's two trailing dims must be whole. It is known to the
-# four functions below and to the kernel, and to nothing else.
+# (ops/paged_attention.py) takes a page as one contiguous [KV, page, Hd]
+# block, and a Mosaic block's two trailing dims must be whole. It is
+# known to the five functions below and to the kernel, and to nothing
+# else.
+#
+# In the decode program only the kernel and `paged_write_step` touch the
+# pool, and the latter writes whole pages. A write whose unit is one
+# token (a scatter at (page, :, offset), or a dynamic_update_slice of
+# [KV, 1, Hd]) makes the TPU compiler lay the pool out token-major for
+# it and copy all of it into the kernel's order and back around every
+# call; with the pool carried through the layer scan that is two whole
+# pools copied a layer (jax 0.9.0 / libtpu 0.0.34, compiled for a
+# described v5e). A page is contiguous in the kernel's order, so a
+# page-wise write leaves the layout alone and updates the pool in place
+# (tests/test_aot_tpu_compile.py holds the decode programs to that).
 
 def paged_pool_shape(cfg, n_pages: int, page_size: int) -> tuple:
     return (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
@@ -847,6 +859,29 @@ def paged_scatter(pool: jax.Array, kv: jax.Array, page_idx: jax.Array,
     return pool.at[:, page_idx, :, off].set(jnp.moveaxis(kv, 1, 0))
 
 
+def paged_write_step(pool: jax.Array, layer, kv: jax.Array,
+                     write_page: jax.Array, write_off: jax.Array) -> jax.Array:
+    """A decode step's ``kv`` [B, KV, Hd] into layer ``layer`` (int or
+    traced scalar) of the whole pool [L, P, KV, page, Hd], row b's at
+    (write_page[b], write_off[b]), by whole pages: the B pages are read,
+    each takes its row's token at its offset, and they are put back.
+
+    What that leans on (serving/paged.py): a live row appends only to a
+    page it alone references (the radix tree holds whole pages, and a
+    divergence inside a page forks a copy), so no two live rows name the
+    same page and each page put back is its old content and one new
+    token. Idle and unallocated rows all name scratch page 0; their
+    writes may land in any order, because that page is never read
+    unmasked and holds finite values whichever wins. Hence no
+    ``unique_indices`` hint."""
+    page = pool.shape[-2]
+    pages = pool[layer, write_page]  # [B, KV, page, Hd]
+    here = jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, page, 1), 2) == write_off[:, None, None, None]
+    pages = jnp.where(here, kv[:, :, None, :].astype(pool.dtype), pages)
+    return pool.at[layer, write_page].set(pages)
+
+
 def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
     if _window(cfg) is not None:
         raise ValueError(
@@ -856,15 +891,17 @@ def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
-                    v_pages: jax.Array, positions: jax.Array,
+def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
+                    v_pool: jax.Array, layer_idx, positions: jax.Array,
                     write_page: jax.Array, write_off: jax.Array,
                     tables: jax.Array, valid: jax.Array):
     """Paged analogue of ``cached_attn_step``: writes this step's K/V
-    into each row's current page slot and attends over the row's pages
-    gathered via its block table. ``tables`` [B, maxp] (-1 = not
-    allocated, clamped to scratch page 0 for the gather), ``valid``
-    [B, 1, 1, maxp*page] masks real positions."""
+    into each row's current page slot of layer ``layer_idx`` (int or
+    traced scalar) of the whole pools [L, P, KV, page, Hd] and attends
+    over the row's pages of that layer via its block table; returns the
+    whole pools. ``tables`` [B, maxp] (-1 = not allocated, clamped to
+    scratch page 0 for the gather), ``valid`` [B, 1, 1, maxp*page]
+    masks real positions."""
     from polyaxon_tpu.ops.attention import repeat_kv
 
     dt = cfg.dtype
@@ -880,9 +917,10 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
     scaling = getattr(cfg, "rope_scaling", None)
     q = _rope(q, positions, cfg.rope_theta, scaling)
     k = _rope(k, positions, cfg.rope_theta, scaling)
-    # One layer's pool is [P, KV, page, Hd]; row b writes [KV, Hd].
-    k_pages = k_pages.at[write_page, :, write_off].set(k[:, 0])
-    v_pages = v_pages.at[write_page, :, write_off].set(v[:, 0])
+    k_pool = paged_write_step(k_pool, layer_idx, k[:, 0], write_page,
+                              write_off)
+    v_pool = paged_write_step(v_pool, layer_idx, v[:, 0], write_page,
+                              write_off)
 
     impl = getattr(cfg, "paged_attention_impl", "gather")
     if impl == "auto":
@@ -897,19 +935,19 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pages: jax.Array,
         live = valid[:, 0, 0, :].any(axis=-1)  # [B] — idle rows all-False
         pos_vec = jnp.where(live, positions[:, 0], -1)
         attn = paged_decode_attention(
-            q[:, 0].reshape(B, H, Hd), k_pages, v_pages, tables,
+            q[:, 0].reshape(B, H, Hd), k_pool, v_pool, layer_idx, tables,
             pos_vec).astype(dt)[:, None]
     else:
         gathered = jnp.maximum(tables, 0)  # [B, maxp] — scratch for holes
-        keys = repeat_kv(paged_gather(k_pages, gathered), n_rep)
-        vals = repeat_kv(paged_gather(v_pages, gathered), n_rep)
+        keys = repeat_kv(paged_gather(k_pool[layer_idx], gathered), n_rep)
+        vals = repeat_kv(paged_gather(v_pool[layer_idx], gathered), n_rep)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, keys).astype(jnp.float32)
         logits = logits * (Hd ** -0.5)
         logits = jnp.where(valid, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(dt)
         attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
     return x + attn.reshape(B, 1, H * Hd) @ _w(layer["wo"], dt), \
-        k_pages, v_pages
+        k_pool, v_pool
 
 
 def paged_coords(pos: jax.Array, tables: jax.Array, page: int):
@@ -949,16 +987,21 @@ def decode_step_paged(
     positions, write_page, write_off, valid = paged_coords(pos, tables, page)
     x = _embed(cfg, params, tokens, dt)[:, None, :]
 
-    def layer_step(x, inputs):
-        layer, k_pages, v_pages = inputs
-        x, k_pages, v_pages = paged_attn_step(
-            cfg, layer, x, k_pages, v_pages, positions,
+    # The pools ride the layer walk as a carry, whole: as scanned
+    # inputs and outputs every layer's pool was sliced out and stacked
+    # back, half of what the program did (the paged surface's comment).
+    def layer_step(carry, inputs):
+        x, k_pool, v_pool = carry
+        layer, layer_idx = inputs
+        x, k_pool, v_pool = paged_attn_step(
+            cfg, layer, x, k_pool, v_pool, layer_idx, positions,
             write_page, write_off, tables, valid)
         x = ffn(cfg, x, layer)
-        return x, (k_pages, v_pages)
+        return (x, k_pool, v_pool), None
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"]))
+    (x, new_k, new_v), _ = jax.lax.scan(
+        layer_step, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cache["k"].shape[0])))
     x = _norm(cfg, x, params["final_norm"])
     logits = decode_logits(cfg, params, x[:, 0])
     return logits, {"k": new_k, "v": new_v}

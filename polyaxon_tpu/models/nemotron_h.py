@@ -588,10 +588,9 @@ def decode_step_paged(cfg: NemotronHConfig, params: dict, cache: dict,
     kv = {"k": cache["k"], "v": cache["v"]}
 
     def attend(i, layer, x):
-        x, k, v = llama.paged_attn_step(
-            cfg, layer, x, kv["k"][i], kv["v"][i], positions, write_page,
+        x, kv["k"], kv["v"] = llama.paged_attn_step(
+            cfg, layer, x, kv["k"], kv["v"], i, positions, write_page,
             write_off, tables, valid)
-        kv["k"], kv["v"] = _put(kv["k"], k, i), _put(kv["v"], v, i)
         return x
 
     x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]

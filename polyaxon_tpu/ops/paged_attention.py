@@ -8,6 +8,13 @@ the pool through VMEM with an online-softmax accumulator (the flash
 recipe from ``ops/flash.py``, specialized to q-length 1), the
 scalar-prefetched block tables and positions naming the pages.
 
+The call takes every layer's pool, stacked ``[L, P, KV, page, Hd]``,
+and is told the layer as a prefetched scalar. The decode program then
+never slices one layer's pool out to hand it over, which it did by
+copying it; with the step's K and V written by whole pages
+(``models/llama.py paged_write_step``), this kernel and that write are
+all that touches the pool there, and neither moves it.
+
 The work a call issues follows the pages that live rows hold, not
 ``slots × kv heads × table width``:
 
@@ -25,21 +32,22 @@ One algorithm in the two forms the TPU compiler lets through, chosen
 by the one shape that decides it, the pool's last dimension:
 
 - *streamed* (``Hd % 128 == 0``): grid ``(B,)``, the pools left in
-  HBM, a loop over the row's live pages with each page copied by its
-  own DMA into one of two ``[KV, G·page, Hd]`` buffers while the other
-  is computed on. Time is proportional to the live context: an idle
-  row costs about 2 µs, and at the benchmark's Mistral shape (16 rows
-  of ≈ 600 tokens of a 4,096-token table) a call took 0.12 ms on a
-  v5e where the pipelined form took 0.74 ms.
+  HBM, a loop over the row's live pages with each page
+  (``pool.at[layer, page id]``) copied by its own DMA into one of two
+  ``[KV, G·page, Hd]`` buffers while the other is computed on. Time
+  is proportional to the live context: an idle row costs about 2 µs,
+  and at the benchmark's Mistral shape (16 rows of ≈ 600 tokens of a
+  4,096-token table) a call took 0.12 ms on a v5e where the pipelined
+  form took 0.74 ms.
 - *pipelined* (any other head size): grid ``(B, maxp / G)``, the pool
   passed G times as K and G times as V, each input a
-  ``(1, KV, page, Hd)`` block whose index map names the row's page
-  ``j·G + i`` clamped to the last live page that input took, so a step
-  past the row's length names the blocks the step before named and the
-  pipeline fetches nothing anew. What such a step still costs is its
-  index maps: about 0.05 µs an input a step on a v5e, 0.4 ms a call
-  at 4,096 table entries whatever is live (which is why they shift
-  and mask where they would divide).
+  ``(None, 1, KV, page, Hd)`` block whose index map names the layer
+  and the row's page ``j·G + i`` clamped to the last live page that
+  input took, so a step past the row's length names the blocks the
+  step before named and the pipeline fetches nothing anew. What such
+  a step still costs is its index maps: about 0.05 µs an input a step
+  on a v5e, 0.4 ms a call at 4,096 table entries whatever is live
+  (which is why they shift and mask where they would divide).
 
 What the compiler refused (jax 0.9.0 / libtpu 0.0.34, asked about a
 described ``v5e:2x2``): the streamed form from a pool whose last
@@ -143,8 +151,9 @@ def _write_out(o_ref, acc_ref, l_ref):
 def _streamed_kernel(
     tables_ref,  # scalar prefetch: [B, maxp] int32 page ids (-1 = hole)
     pos_ref,  # scalar prefetch: [B] int32 row positions (-1 = idle)
+    layer_ref,  # scalar prefetch: [1] int32, the layer whose pages to read
     q_ref,  # [1, KV, rep, Hd]
-    k_hbm,  # [P, KV, page, Hd], left in HBM
+    k_hbm,  # [L, P, KV, page, Hd], left in HBM
     v_hbm,
     o_ref,  # [1, KV, rep, Hd]
     k_buf,  # VMEM [2, KV, G·page, Hd]: the step computed on, the next
@@ -164,6 +173,7 @@ def _streamed_kernel(
     b = pl.program_id(0)
     maxp = tables_ref.shape[1]
     pos = pos_ref[b]
+    layer = layer_ref[0]
     n_pages = jnp.minimum(_quot(pos + page, page), maxp)  # 0 when idle
     n_turns = _quot(n_pages + group - 1, group)
 
@@ -176,10 +186,12 @@ def _streamed_kernel(
             pid = jnp.maximum(tables_ref[b, jnp.minimum(p, maxp - 1)], 0)
             rows = pl.ds(i * page, page)
             copies = (
-                pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, :, rows],
-                                      sem.at[0, buf, i]),
-                pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, :, rows],
-                                      sem.at[1, buf, i]))
+                pltpu.make_async_copy(
+                    k_hbm.at[layer, pid], k_buf.at[buf, :, rows],
+                    sem.at[0, buf, i]),
+                pltpu.make_async_copy(
+                    v_hbm.at[layer, pid], v_buf.at[buf, :, rows],
+                    sem.at[1, buf, i]))
 
             @pl.when(p < n_pages)
             def _act():
@@ -220,6 +232,7 @@ def _streamed_kernel(
 def _pipelined_kernel(
     tables_ref,  # scalar prefetch: [B, maxp] int32 page ids (-1 = hole)
     pos_ref,  # scalar prefetch: [B] int32 row positions (-1 = idle)
+    layer_ref,  # scalar prefetch: [1] int32; only the index maps read it
     q_ref,  # [1, KV, rep, Hd]
     *refs,  # G K pages and G V pages, each [1, KV, page, Hd], selected
     # by the index maps; then o_ref [1, KV, rep, Hd] and the VMEM
@@ -258,39 +271,45 @@ def _pipelined_kernel(
 
 def paged_decode_attention(
     q: jax.Array,  # [B, H, Hd] — the single decode position per row
-    k_pages: jax.Array,  # [P, KV, page, Hd]
-    v_pages: jax.Array,
+    k_pool: jax.Array,  # [L, P, KV, page, Hd]: every layer's pages
+    v_pool: jax.Array,
+    layer,  # int or traced int32 scalar: the layer whose pages are read
     tables: jax.Array,  # [B, maxp] int32 (-1 = unallocated)
     pos: jax.Array,  # [B] int32 (-1 = idle row → zeros out)
     *,
     interpret: bool | None = None,  # None = interpret on the CPU backend
 ) -> jax.Array:
-    """Attention of each row's query against its pages (positions
-    0..pos inclusive — the current step's K/V must already be written
-    to the pool). Returns [B, H, Hd].
+    """Attention of each row's query against its pages of layer
+    ``layer`` (positions 0..pos inclusive — the current step's K/V must
+    already be written to the pool). Returns [B, H, Hd].
 
-    The pool is laid out ``[P, KV, page, Hd]`` so a page of one layer
-    is one contiguous ``[KV, page, Hd]`` block whose two trailing dims
-    are the array's own, which is what the Mosaic lowering demands of a
-    block that is not a multiple of the (8, 128) tile. Under a
-    multi-device mesh the call runs per ``tp`` shard of the kv heads
-    (``compat.shard_kernel``)."""
+    The pools come stacked over the layers and the layer as a
+    prefetched scalar, so the program around the call never slices a
+    layer's pool out (a copy of it, a layer a step; models/llama.py,
+    the paged surface's comment); one layer's pool goes in as
+    ``pool[None]`` with layer 0. A layer is laid out
+    ``[P, KV, page, Hd]`` so a page is one contiguous ``[KV, page, Hd]``
+    block whose two trailing dims are the array's own, which is what
+    the Mosaic lowering demands of a block that is not a multiple of
+    the (8, 128) tile. Under a multi-device mesh the call runs per
+    ``tp`` shard of the kv heads (``compat.shard_kernel``)."""
     interpret = resolve_interpret(interpret)
     B, H, Hd = q.shape
-    KV = k_pages.shape[1]
+    KV = k_pool.shape[2]
     _, head_axis = compat.kernel_axes(B, KV)
     heads = P(None, head_axis, None)
-    pool = P(None, head_axis, None, None)
+    pool = P(None, None, head_axis, None, None)
     return compat.shard_kernel(
         functools.partial(_paged_decode, interpret=interpret),
-        in_specs=(heads, pool, pool, P(), P()),
+        in_specs=(heads, pool, pool, P(), P(), P()),
         out_specs=heads,
-    )(q, k_pages, v_pages, tables.astype(jnp.int32), pos.astype(jnp.int32))
+    )(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1),
+      tables.astype(jnp.int32), pos.astype(jnp.int32))
 
 
-def _paged_decode(q, k_pages, v_pages, tables, pos, *, interpret: bool):
+def _paged_decode(q, k_pool, v_pool, layer, tables, pos, *, interpret: bool):
     B, H, Hd = q.shape
-    _, KV, page, _ = k_pages.shape
+    _, _, KV, page, _ = k_pool.shape
     maxp = tables.shape[1]
     rep = H // KV
     # The one thing the kernel adapts on: Mosaic copies a page out of a
@@ -312,34 +331,35 @@ def _paged_decode(q, k_pages, v_pages, tables, pos, *, interpret: bool):
     if streamed:
         kernel, grid = _streamed_kernel, (B,)
         page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        pools = (k_pages, v_pages)
+        pools = (k_pool, v_pool)
         scratch = [
-            pltpu.VMEM((2, KV, group * page, Hd), k_pages.dtype),
-            pltpu.VMEM((2, KV, group * page, Hd), v_pages.dtype),
+            pltpu.VMEM((2, KV, group * page, Hd), k_pool.dtype),
+            pltpu.VMEM((2, KV, group * page, Hd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2, group)),
             *softmax_state]
     else:
         def page_map(i):
-            def index(b, j, tables_ref, pos_ref):
-                # The page DMA: the row's block table gives the block
-                # index along the pool axis. Past the row's last live
-                # page, input i names the last live page it took itself
-                # (the largest p <= last with p % G == i), so those
-                # steps name the block the step before named and the
-                # pipeline fetches nothing. The clamp to 0 only keeps a
-                # hole's index legal.
+            def index(b, j, tables_ref, pos_ref, layer_ref):
+                # The page DMA: the layer, then the row's block table
+                # gives the block index along the pool axis. Past the
+                # row's last live page, input i names the last live
+                # page it took itself (the largest p <= last with
+                # p % G == i), so those steps name the block the step
+                # before named and the pipeline fetches nothing. The
+                # clamp to 0 only keeps a hole's index legal.
                 last = jnp.minimum(
                     _quot(jnp.maximum(pos_ref[b], 0), page), maxp - 1)
                 own = jnp.where(
                     last >= i, last - _rem(last - i, group), last)
                 p = jnp.minimum(j * group + i, own)
-                return (jnp.maximum(tables_ref[b, p], 0), 0, 0, 0)
+                return (layer_ref[0], jnp.maximum(tables_ref[b, p], 0),
+                        0, 0, 0)
             return index
 
         kernel, grid = _pipelined_kernel, (B, pl.cdiv(maxp, group))
-        page_specs = [pl.BlockSpec((1, KV, page, Hd), page_map(i))
+        page_specs = [pl.BlockSpec((None, 1, KV, page, Hd), page_map(i))
                       for i in range(group)] * 2
-        pools = (*[k_pages] * group, *[v_pages] * group)
+        pools = (*[k_pool] * group, *[v_pool] * group)
         scratch = softmax_state
 
     compiler_params = None
@@ -351,7 +371,7 @@ def _paged_decode(q, k_pages, v_pages, tables, pos, *, interpret: bool):
     out = pl.pallas_call(
         functools.partial(kernel, scale=Hd ** -0.5, page=page, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[row_spec, *page_specs],
             out_specs=row_spec,
@@ -361,5 +381,5 @@ def _paged_decode(q, k_pages, v_pages, tables, pos, *, interpret: bool):
         compiler_params=compiler_params,
         interpret=interpret,
         name="paged_decode",
-    )(tables, pos, q.reshape(B, KV, rep, Hd), *pools)
+    )(tables, pos, layer, q.reshape(B, KV, rep, Hd), *pools)
     return out.reshape(B, H, Hd)

@@ -52,13 +52,15 @@ class _FixedShapeProgram:
     """A jitted function whose argument shapes never change (the decode
     step: slots and the block-table width are fixed), compiled ahead of
     time on its first call. The owner keeps the executable, so it can
-    say which Mosaic kernels are in it, and a drifting shape raises
-    instead of compiling a second program."""
+    say which Mosaic kernels are in it and what the compiler set aside
+    for its temporaries, and a drifting shape raises instead of
+    compiling a second program."""
 
     def __init__(self, jitted):
         self._jitted = jitted
         self._compiled = None
         self.kernels: dict[str, int] = {}
+        self.temp_bytes: Optional[int] = None
 
     def __call__(self, *args):
         if self._compiled is None:
@@ -66,6 +68,8 @@ class _FixedShapeProgram:
 
             self._compiled = self._jitted.lower(*args).compile()
             self.kernels = pallas_kernels(self._compiled.as_text())
+            self.temp_bytes = getattr(self._compiled.memory_analysis(),
+                                      "temp_size_in_bytes", None)
         return self._compiled(*args)
 
 
@@ -1856,6 +1860,13 @@ class ContinuousBatchingEngine:
             "decode_kernels": {
                 name: count for program in self._decode_programs
                 for name, count in program.kernels.items()},
+            # What the compiler set aside for the decode program's
+            # temporaries (the larger of the two programs; None until
+            # one compiles). Megabytes where the program updates the
+            # page pool in place; a copy of the pool shows here whole.
+            "decode_program_temp_bytes": max(
+                (program.temp_bytes for program in self._decode_programs
+                 if program.temp_bytes is not None), default=None),
             "device": {**self._device_stats, "peak_hbm_bytes": (
                 self._device0.memory_stats() or {}).get(
                     "peak_bytes_in_use")},

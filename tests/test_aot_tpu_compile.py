@@ -17,6 +17,7 @@ cannot be described.
 
 from __future__ import annotations
 
+import contextlib
 import fcntl
 import json
 import os
@@ -80,9 +81,32 @@ CASES = {
     # kernel, the prefill the compiler's grouped-matmul kernel (two a
     # held-expert layer), not every group over every row.
     "nemotron_h-decode-step-pages-and-rows": (
-        "nemotron_h", dict(program="decode"), PAGED_KERNELS),
+        "nemotron_h", dict(program="decode", n_pages=8193), PAGED_KERNELS),
     "nemotron_h-prefill-sorted-dispatch": (
         "nemotron_h", dict(program="prefill"), {"ragged-dot": 2}),
+    # The two other decode programs of the benchmark's engines, whole
+    # (one kernel in the text is one a layer: llama's layers are a scan).
+    "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
+    "lfm2-decode-step-lfm2-cell": ("lfm2_decode", {}, PAGED_KERNELS),
+}
+
+# What a decode program may do to the page pool: name -> (one layer's
+# pool [P, KV, page, Hd], the most instructions that may give a layer's
+# pool or the whole stack as their result, the most bytes of
+# temporaries). Where the head size fills the lanes, updating the pool
+# in place (an aliasing fusion) and reading it (the kernel, whose result
+# is a row's) are the whole of it: before the pool rode the layer walk
+# and was written by whole pages, the Mistral program held 11 such
+# instructions a layer and 2.06 GiB of temporaries, and the small
+# Nemotron one 7. A pool of head size 64 arrives in the compiler's own
+# layout and is copied into the kernel's and back whatever the walk does
+# (ROADMAP S1): 6 such instructions, held to the 9 there were.
+POOL_LIMITS = {
+    "llama-decode-step-mistral7b-cells": ((3073, 8, 16, 128), 0, 64 << 20),
+    # The pool at the benchmark's size: one of 2 MB (257 pages) the
+    # compiler stages through fast memory, a copy each way.
+    "nemotron_h-decode-step-pages-and-rows": ((8193, 2, 16, 128), 0, None),
+    "lfm2-decode-step-lfm2-cell": ((3073, 8, 16, 64), 9, None),
 }
 
 
@@ -139,7 +163,8 @@ def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
 
 
 def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
-                   n_pages=None, mesh=None):
+                   n_pages=None, mesh=None, layers=2):
+    """The kernel alone, told the layer (traced) of a stacked pool."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -154,14 +179,113 @@ def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
                                     sharding=NamedSharding(mesh, spec))
 
     maxp = max_len // page
-    pool = aval((n_pages or slots * maxp + 1, kv, page, d), jnp.bfloat16,
-                P(None, heads, None, None))
+    pool = aval((layers, n_pages or slots * maxp + 1, kv, page, d),
+                jnp.bfloat16, P(None, None, heads, None, None))
     with mesh:
         return jax.jit(
             lambda *a: paged_decode_attention(*a, interpret=False)).lower(
                 aval((slots, h, d), jnp.bfloat16, P(None, heads, None)),
-                pool, pool, aval((slots, maxp), jnp.int32),
+                pool, pool, aval((), jnp.int32),
+                aval((slots, maxp), jnp.int32),
                 aval((slots,), jnp.int32)).compile()
+
+
+def _engine_avals(topo, family, cfg, slots, n_pages, page):
+    """(params, cache, i32) as the engine holds them
+    (serving/batching.py), as shapes on the first described chip:
+    served weights, the paged cache with the family's per-row leaves,
+    and a maker of int32 arguments."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from polyaxon_tpu.models.common import served_params
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def avals(build):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            jax.eval_shape(build))
+
+    def build_cache():
+        cache = family.paged_init_cache(cfg, n_pages, page)
+        if hasattr(family, "paged_init_rows"):
+            cache["rows"] = family.paged_init_rows(cfg, slots)
+        return cache
+
+    params = avals(lambda: served_params(
+        family.init(cfg, jax.random.key(0))["params"], cfg.dtype,
+        family.READ_AT_FLOAT32))
+    return params, avals(build_cache), lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=one)
+
+
+@contextlib.contextmanager
+def _kernel_path():
+    """The family code asks `jax.default_backend()` which attention to
+    run: answer for the chip while the program is traced."""
+    import jax
+
+    real_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        yield
+    finally:
+        jax.default_backend = real_backend
+
+
+def _compile_decode_step(topo, family, cfg, slots, max_len, n_pages,
+                         page=16):
+    """`family.decode_step_paged` as the engine builds it: the cache
+    donated."""
+    import jax
+
+    params, cache, i32 = _engine_avals(topo, family, cfg, slots, n_pages,
+                                       page)
+
+    def decode_step(params, cache, tokens, pos, tables):
+        return family.decode_step_paged(cfg, params, cache, tokens, pos,
+                                        tables)
+
+    with _kernel_path():
+        return jax.jit(decode_step, donate_argnums=(1,)).lower(
+            params, cache, i32(slots), i32(slots),
+            i32(slots, max_len // page)).compile()
+
+
+def _compile_llama_decode(topo):
+    """The benchmark's Mistral-7B engine: 8 of its layers, 16 slots of
+    4,096 tokens over 3,073 pages."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from polyaxon_tpu.models import llama
+
+    cfg = dataclasses.replace(
+        llama.CONFIGS["mistral_7b"], n_layers=8, vocab_size=32768,
+        sliding_window=None, dtype=jnp.bfloat16,
+        paged_attention_impl="pallas")
+    return _compile_decode_step(topo, llama, cfg, slots=16, max_len=4096,
+                                n_pages=3073)
+
+
+def _compile_lfm2_decode(topo):
+    """The benchmark's LFM2-8B-A1B engine: the dense convolution layer
+    and one period behind it (one attention layer), 32 slots of 2,048."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from polyaxon_tpu.models import lfm2
+
+    cfg = dataclasses.replace(
+        lfm2.CONFIGS["lfm2_8b_a1b"], n_layers=5, n_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+        dtype=jnp.bfloat16, paged_attention_impl="pallas")
+    return _compile_decode_step(topo, lfm2, cfg, slots=32, max_len=2048,
+                                n_pages=3073)
 
 
 def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
@@ -172,11 +296,8 @@ def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
     import dataclasses
 
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
     from polyaxon_tpu.models import nemotron_h as nh
-    from polyaxon_tpu.models.common import served_params
 
     cfg = dataclasses.replace(
         nh.CONFIGS["nemotron_h_tiny"], vocab_size=1024, dim=512,
@@ -185,41 +306,49 @@ def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
         n_experts=32, held_experts=(8, 8), experts_per_token=6,
         moe_latent_dim=256, moe_ffn_dim=384, shared_ffn_dim=512,
         paged_attention_impl="pallas")
-    one = SingleDeviceSharding(topo.devices[0])
+    if program == "decode":
+        return _compile_decode_step(topo, nh, cfg, slots, max_len, n_pages,
+                                    page)
+    params, cache, i32 = _engine_avals(topo, nh, cfg, slots, n_pages, page)
 
-    def avals(build):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
-            jax.eval_shape(build))
+    def prefill(params, tokens, cache, page_ids, row):
+        return nh.paged_insert_prefill(
+            cache, *nh.paged_prefill_kv(cfg, params, tokens), page_ids,
+            page, row)
 
-    params = avals(lambda: served_params(
-        nh.init(cfg, jax.random.key(0))["params"], cfg.dtype,
-        nh.READ_AT_FLOAT32))
-    cache = avals(lambda: {**nh.paged_init_cache(cfg, n_pages, page),
-                           "rows": nh.paged_init_rows(cfg, slots)})
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
-    maxp = max_len // page
-    real_backend = jax.default_backend
-    jax.default_backend = lambda: "tpu"  # the kernel, not its CPU path
-    try:
-        if program == "decode":
-            def step(params, cache, tokens, pos, tables):
-                return nh.decode_step_paged(cfg, params, cache, tokens, pos,
-                                            tables)
-
-            return jax.jit(step, donate_argnums=(1,)).lower(
-                params, cache, i32(slots), i32(slots),
-                i32(slots, maxp)).compile()
-
-        def prefill(params, tokens, cache, page_ids, row):
-            return nh.paged_insert_prefill(
-                cache, *nh.paged_prefill_kv(cfg, params, tokens), page_ids,
-                page, row)
-
+    with _kernel_path():
         return jax.jit(prefill, donate_argnums=(2,)).lower(
-            params, i32(1, prompt), cache, i32(maxp), i32()).compile()
-    finally:
-        jax.default_backend = real_backend
+            params, i32(1, prompt), cache, i32(max_len // page),
+            i32()).compile()
+
+
+def _pool_sized_instructions(text: str, pool_shape: tuple) -> list:
+    """The instructions a compiled program runs whose result is a
+    bfloat16 array ``[P, KV, page, Hd]`` or a stack of them, found by
+    type: all but the ones that move nothing (a parameter, a tuple's
+    element, a bitcast) and the fusions that update their operand in
+    place (``aliasing_operands``). What is inside a fusion is not an
+    instruction of its own."""
+    dims = ",".join(str(n) for n in pool_shape)
+    result = re.compile(r"^\s*(?:ROOT )?%(\S+) = bf16\[(?:\d+,)?"
+                        + re.escape(dims) + r"\]\S* ([\w\-]+)\(")
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        hit = result.match(line)
+        if not hit or inside in fused:
+            continue
+        name, op = hit.groups()
+        in_place = op == "fusion" and re.search(
+            r'"aliasing_operands":\{"lists":\[\{', line)
+        if op not in ("parameter", "get-tuple-element",
+                      "bitcast") and not in_place:
+            found.append(f"{op} %{name}")
+    return found
 
 
 def _child_main() -> int:
@@ -248,10 +377,13 @@ def _child_main() -> int:
     report = {}
     for name, (kind, kwargs, _) in CASES.items():
         compile_case = {"flash": _compile_flash, "paged": _compile_paged,
-                        "nemotron_h": _compile_nemotron_h}[kind]
+                        "nemotron_h": _compile_nemotron_h,
+                        "llama_decode": _compile_llama_decode,
+                        "lfm2_decode": _compile_lfm2_decode}[kind]
         t0 = time.time()
         try:
-            text = compile_case(topo, **kwargs).as_text()
+            compiled = compile_case(topo, **kwargs)
+            text = compiled.as_text()
             kernels = pallas_kernels(text)
             # The compiler's own grouped matmul carries no pallas_call
             # name: it is counted by its custom call's.
@@ -260,6 +392,11 @@ def _child_main() -> int:
             if grouped:
                 kernels["ragged-dot"] = grouped
             report[name] = {"ok": True, "kernels": kernels}
+            if name in POOL_LIMITS:
+                report[name]["pool_sized"] = _pool_sized_instructions(
+                    text, POOL_LIMITS[name][0])
+                report[name]["temp_bytes"] = (
+                    compiled.memory_analysis().temp_size_in_bytes)
         except Exception as exc:  # noqa: BLE001 — the refusal IS the result
             report[name] = {"ok": False,
                             "error": f"{type(exc).__name__}: {exc}"[:600]}
@@ -281,7 +418,7 @@ def aot_report(tmp_path_factory):
         if not cached.exists():
             proc = subprocess.run(
                 [sys.executable, os.path.abspath(__file__), "--child"],
-                capture_output=True, text=True, timeout=600,
+                capture_output=True, text=True, timeout=900,
                 env={**os.environ, "JAX_PLATFORMS": "cpu"})
             lines = [ln for ln in proc.stdout.splitlines()
                      if ln.startswith("{")]
@@ -301,6 +438,18 @@ def test_compiles_for_described_tpu(aot_report, name):
     assert entry["ok"], f"the TPU compiler refused {name}: {entry['error']}"
     # The kernel itself, not a reference path that happens to compile.
     assert entry["kernels"] == CASES[name][2], entry
+
+
+@pytest.mark.parametrize("name", sorted(POOL_LIMITS))
+def test_decode_program_leaves_the_pool_in_place(aot_report, name):
+    """Only the kernel and an in-place page write touch the pool in a
+    decode program (models/llama.py, the paged surface's comment)."""
+    entry = aot_report["cases"][name]
+    assert entry["ok"], entry
+    _, most, temp_bytes = POOL_LIMITS[name]
+    assert len(entry["pool_sized"]) <= most, entry["pool_sized"]
+    if temp_bytes is not None:
+        assert entry["temp_bytes"] < temp_bytes, entry["temp_bytes"]
 
 
 if __name__ == "__main__":

@@ -263,6 +263,11 @@ class TestPagedEngine:
         finally:
             engine.stop()
         assert 0 < live / stats["paged_pages_table"] < 0.25
+        # Read off the compiled decode program, once: nothing before it
+        # compiles, an integer after (on a TPU, megabytes while the
+        # program leaves the pool in place: tests/test_aot_tpu_compile).
+        assert before["decode_program_temp_bytes"] is None
+        assert isinstance(stats["decode_program_temp_bytes"], int)
 
     def test_oversubscribed_pool_backpressure(self):
         """A pool HALF the dense reservation still serves all requests
@@ -429,9 +434,15 @@ class TestPagedKernel:
         v_pages = jax.random.normal(
             ks[2], (P, page, KV, Hd), jnp.float32).astype(dtype)
 
-        got = paged_decode_attention(q, k_pages.swapaxes(1, 2),
-                                     v_pages.swapaxes(1, 2), tables, pos,
-                                     interpret=True)
+        def stacked(pages):
+            """Three layers' pools, the pages in the middle one and NaN
+            in the others: the kernel reads the layer it is told."""
+            mine = pages.swapaxes(1, 2)
+            other = jnp.full_like(mine, jnp.nan)
+            return jnp.stack([other, mine, other])
+
+        got = paged_decode_attention(q, stacked(k_pages), stacked(v_pages),
+                                     1, tables, pos, interpret=True)
         assert got.shape == (B, H, Hd) and got.dtype == dtype
 
         # Gather reference (the models/llama.py formulation), float32.
@@ -480,6 +491,52 @@ class TestPagedKernel:
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                    atol=2e-4, rtol=2e-4)
         assert np.isfinite(np.asarray(got[1])).all()
+
+    @pytest.mark.parametrize("traced", [False, True],
+                             ids=["layer-int", "layer-traced"])
+    def test_page_write_equals_token_scatter(self, traced):
+        """`paged_write_step` (whole pages read, changed and put back)
+        leaves what the token scatter it replaced leaves on every page a
+        live row owns — a row writing a page's first offset and one its
+        last among them — and nothing anywhere else but scratch page 0,
+        which the idle and the unallocated rows all name: old or new
+        values there, in whatever order, all finite."""
+        L, P, KV, page, Hd = 3, 12, 2, 4, 8
+        ks = jax.random.split(jax.random.key(7), 2)
+        pool = jax.random.normal(ks[0], (L, P, KV, page, Hd), jnp.float32)
+        pool = pool.at[:, 0].set(0.0)  # scratch starts as zeros
+        kv = jax.random.normal(ks[1], (7, KV, Hd), jnp.float32)
+        tables = np.full((7, 4), -1, np.int32)
+        tables[0, :2] = [3, 9]      # pos 4: offset 0 of its second page
+        tables[1, :3] = [5, 2, 7]   # pos 11: offset page-1 of its third
+        tables[2, :2] = [6, 4]      # pos 6
+        tables[5, :2] = [8, -1]     # pos 5 and 14 fall on pages never
+        tables[6, :3] = [10, 11, 1]  # allocated: the scratch page
+        pos = jnp.asarray([4, 11, 6, -1, -1, 5, 14], jnp.int32)
+        _, write_page, write_off, _ = llama.paged_coords(
+            pos, jnp.asarray(tables), page)
+        assert write_page.tolist() == [9, 7, 4, 0, 0, 0, 0]
+        assert write_off.tolist() == [0, 3, 2, 0, 0, 1, 2]
+
+        layer = 1
+        write = (jax.jit(llama.paged_write_step) if traced
+                 else llama.paged_write_step)
+        got = np.asarray(write(pool, jnp.int32(layer) if traced else layer,
+                               kv, write_page, write_off))
+        want = np.asarray(
+            pool.at[layer, write_page, :, write_off].set(kv))
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        for b, (pg, off) in enumerate([(9, 0), (7, 3), (4, 2)]):
+            np.testing.assert_array_equal(got[layer, pg, :, off],
+                                          np.asarray(kv[b]))
+        scratch = got[layer, 0]
+        assert np.isfinite(scratch).all()
+        np.testing.assert_array_equal(got[[0, 2], 0], 0.0)
+        wrote = {0: (3, 4), 1: (5,), 2: (6,)}  # offset -> rows that name it
+        for off in range(page):
+            allowed = [np.zeros((KV, Hd), np.float32)] + [
+                np.asarray(kv[b]) for b in wrote.get(off, ())]
+            assert any((scratch[:, off] == a).all() for a in allowed), off
 
 
 class TestPrefixCache:
@@ -597,6 +654,42 @@ class TestRadixPrefixSharing:
         assert src != dst  # the fork got its own private copy
         assert int(pool.tables[0][0]) == int(pool.tables[1][0])
         assert pool.cow_forks == 1
+        assert pool.check_invariants() == []
+
+    def test_live_rows_append_to_pages_they_alone_hold(self):
+        """What the decode step's whole-page write leans on
+        (`llama.paged_write_step`): whatever a row adopted at admission
+        — full pages of a live row's prompt, a fork of a page it
+        diverges inside, the head of a longer prompt cut inside a page —
+        the page it appends to, at admission and at every position
+        after, is in no other row's table and has one reference."""
+        page = 4
+        pool = PagePool(slots=5, max_len=32, page_size=page, n_pages=41)
+        a = list(range(100, 117))  # 17 tokens: pages 0..3 go to the tree
+        prompts = [
+            a,
+            list(a),                      # every shareable page adopted
+            a[:6] + [7] * 5,              # diverges inside page 1: a fork
+            a[:11],                       # ends inside a's page 2: a fork
+            a + [1, 2, 3, 4, 5, 6, 7],    # a's prompt is its prefix
+        ]
+        results = [pool.admit(slot, len(tokens), tokens)
+                   for slot, tokens in enumerate(prompts)]
+        assert all(results)
+        assert [r.matched_pages for r in results] == [0, 4, 1, 2, 4]
+        assert [r.cow is not None for r in results] == [
+            False, False, True, True, False]
+        for step in range(2 * page + 1):  # across two page boundaries
+            appends = []
+            for slot, tokens in enumerate(prompts):
+                pos = len(tokens) - 1 + step
+                assert pool.ensure(slot, pos)
+                appends.append(int(pool.tables[slot, pos // page]))
+            assert min(appends) > 0  # never the scratch page
+            assert [int(pool._ref[p]) for p in appends] == [1] * 5, step
+            for slot, pg in enumerate(appends):
+                others = np.delete(pool.tables, slot, axis=0)
+                assert not (others == pg).any(), (step, slot)
         assert pool.check_invariants() == []
 
     def test_fork_then_release_leaks_nothing(self):
