@@ -89,13 +89,21 @@ def rope_frequencies(d_half: int, theta: float,
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: Optional[float],
-         scaling: Optional[dict] = None) -> jax.Array:
+         scaling: Optional[dict] = None,
+         rotary_dim: Optional[int] = None) -> jax.Array:
     """Rotary position embeddings on [B, S, H, D] with fp32 trig (shared
     by the Llama decoder and the T5-style decoder self-attention).
     ``theta`` None is a model without rotary embedding (attention that
-    leaves position to other layers): ``x`` comes back as it is."""
+    leaves position to other layers): ``x`` comes back as it is.
+    ``rotary_dim`` (a published ``partial_rotary_factor`` times the
+    head size) turns the first ``rotary_dim`` of a head's dimensions,
+    rotate-half over those alone, and passes the others; None or D
+    turns the whole head."""
     if theta is None:
         return x
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = rope(x[..., :rotary_dim], positions, theta, scaling)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d_half = x.shape[-1] // 2
     freqs = rope_frequencies(d_half, theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, d_half]

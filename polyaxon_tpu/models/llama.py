@@ -260,6 +260,51 @@ def _qk_norm(cfg, layer: dict, q: jax.Array, k: jax.Array):
     return _norm(cfg, q, layer["q_norm"]), _norm(cfg, k, layer["k_norm"])
 
 
+def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array):
+    """The three projections of ``h`` [B, T, D] (already normalised) as
+    every attention walk below takes them: q [B, T, H, Hd], k and v
+    [B, T, KV, Hd], q and k normalised per head where the layer has the
+    gains (`_qk_norm`) and turned by the rotary embedding at
+    ``positions`` [B, T], and the output gate [B, T, H·Hd] or None.
+
+    Two things are read from what the walk is handed, and a layer or a
+    config without them is the plain path: a ``wq`` twice as wide holds,
+    for each head, its query and behind it the gate of that head's
+    output (`_attn_out`); ``cfg.partial_rotary_factor`` is the share of
+    a head's dimensions the rotary embedding turns (``common.rope``)."""
+    dt = cfg.dtype
+    B, T = h.shape[:2]
+    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = h @ _w(layer["wq"], dt)
+    gate = None
+    if q.shape[-1] == 2 * H * Hd:
+        q, gate = jnp.split(q.reshape(B, T, H, 2 * Hd), 2, axis=-1)
+        gate = gate.reshape(B, T, H * Hd)
+    q = q.reshape(B, T, H, Hd)
+    k = (h @ _w(layer["wk"], dt)).reshape(B, T, KV, Hd)
+    v = (h @ _w(layer["wv"], dt)).reshape(B, T, KV, Hd)
+    q, k = _qk_norm(cfg, layer, q, k)
+    scaling = getattr(cfg, "rope_scaling", None)
+    factor = getattr(cfg, "partial_rotary_factor", None)
+    turned = None if factor is None else int(Hd * factor)
+    q = _rope(q, positions, cfg.rope_theta, scaling, turned)
+    k = _rope(k, positions, cfg.rope_theta, scaling, turned)
+    return q, k, v, gate
+
+
+def _attn_out(cfg, layer: dict, x: jax.Array, attn: jax.Array,
+              gate: Optional[jax.Array]) -> jax.Array:
+    """The attention residual: ``x + W_o · attn`` for the heads' outputs
+    ``attn`` [B, T, H, Hd], each first scaled by the sigmoid of its gate
+    where `_qkv` gave one."""
+    dt = cfg.dtype
+    B, T = attn.shape[:2]
+    attn = attn.reshape(B, T, -1)
+    if gate is not None:
+        attn = (attn * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
+    return x + attn @ _w(layer["wo"], dt)
+
+
 def _act(cfg):
     """MLP gate activation: SwiGLU (silu) or Gemma's tanh-approx GeGLU."""
     kind = getattr(cfg, "mlp_activation", "silu")
@@ -309,16 +354,8 @@ def _mlp(cfg, x: jax.Array, layer: dict) -> jax.Array:
 
 def _layer(cfg: LlamaConfig, x: jax.Array, layer: dict, positions: jax.Array,
            segment_ids: Optional[jax.Array] = None) -> jax.Array:
-    B, S, D = x.shape
-    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    dt = cfg.dtype
-
     h = _norm(cfg, x, layer["attn_norm"])
-    q = (h @ _w(layer["wq"], dt)).reshape(B, S, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, S, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, S, KV, Hd)
-    q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    q, k, v, gate = _qkv(cfg, layer, h, positions)
     # dot_product_attention owns the impl support matrix (xla and flash
     # both handle packed segment_ids; ring/ulysses raise).
     attn = dot_product_attention(q, k, v, causal=True,
@@ -328,7 +365,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, layer: dict, positions: jax.Array,
                                  block_q=cfg.flash_block_q,
                                  block_k=cfg.flash_block_k,
                                  bwd_impl=cfg.flash_bwd_impl)
-    x = x + attn.reshape(B, S, H * Hd) @ _w(layer["wo"], dt)
+    x = _attn_out(cfg, layer, x, attn, gate)
 
     x = _mlp(cfg, x, layer)
     return x
@@ -545,13 +582,7 @@ def cached_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     rows = jnp.arange(B)
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q = (h @ _w(layer["wq"], dt)).reshape(B, 1, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, 1, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, 1, KV, Hd)
-    q, k = _qk_norm(cfg, layer, q, k)
-    scaling = getattr(cfg, "rope_scaling", None)
-    q = _rope(q, positions, cfg.rope_theta, scaling)
-    k = _rope(k, positions, cfg.rope_theta, scaling)
+    q, k, v, gate = _qkv(cfg, layer, h, positions)
     k_cache = k_cache.at[rows, slot].set(k[:, 0])
     v_cache = v_cache.at[rows, slot].set(v[:, 0])
 
@@ -562,8 +593,7 @@ def cached_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     logits = jnp.where(valid, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(dt)
     attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-    return x + attn.reshape(B, 1, H * Hd) @ _w(layer["wo"], dt), \
-        k_cache, v_cache
+    return _attn_out(cfg, layer, x, attn, gate), k_cache, v_cache
 
 
 def decode_step_ragged(
@@ -610,25 +640,19 @@ def _prompt_pass(cfg: LlamaConfig, params: dict, prompt: jax.Array,
     diverge between the dense and paged engines."""
     dt = cfg.dtype
     B, P = prompt.shape
-    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None], (B, P))
     x = _embed(cfg, params, prompt, dt)
-    scaling = getattr(cfg, "rope_scaling", None)
 
     def layer_step(x, layer):
         h = _norm(cfg, x, layer["attn_norm"])
-        q = (h @ _w(layer["wq"], dt)).reshape(B, P, H, Hd)
-        k = (h @ _w(layer["wk"], dt)).reshape(B, P, KV, Hd)
-        v = (h @ _w(layer["wv"], dt)).reshape(B, P, KV, Hd)
-        q = _rope(q, positions, cfg.rope_theta, scaling)
-        k = _rope(k, positions, cfg.rope_theta, scaling)
+        q, k, v, gate = _qkv(cfg, layer, h, positions)
         attn = dot_product_attention(
             q, k, v, causal=True, impl=cfg.attention_impl,
             window=_window(cfg),
             block_q=getattr(cfg, "flash_block_q", None),
             block_k=getattr(cfg, "flash_block_k", None),
             bwd_impl=getattr(cfg, "flash_bwd_impl", None))
-        x = x + attn.reshape(B, P, H * Hd) @ _w(layer["wo"], dt)
+        x = _attn_out(cfg, layer, x, attn, gate)
         x = ffn(cfg, x, layer)
         return x, (k, v)
 
@@ -774,18 +798,12 @@ def chunk_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     from polyaxon_tpu.ops.attention import repeat_kv
 
     dt = cfg.dtype
-    B, c = positions.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     n_rep = H // KV
-    rows = jnp.arange(B)
-    scaling = getattr(cfg, "rope_scaling", None)
+    rows = jnp.arange(positions.shape[0])
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q = (h @ _w(layer["wq"], dt)).reshape(B, c, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, c, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, c, KV, Hd)
-    q = _rope(q, positions, cfg.rope_theta, scaling)
-    k = _rope(k, positions, cfg.rope_theta, scaling)
+    q, k, v, gate = _qkv(cfg, layer, h, positions)
     k_cache = k_cache.at[rows[:, None], positions].set(k)
     v_cache = v_cache.at[rows[:, None], positions].set(v)
     keys = repeat_kv(k_cache, n_rep)
@@ -795,8 +813,7 @@ def chunk_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     s = jnp.where(valid, s, -1e30)
     probs = jax.nn.softmax(s, axis=-1).astype(dt)
     attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-    return x + attn.reshape(B, c, H * Hd) @ _w(layer["wo"], dt), \
-        k_cache, v_cache
+    return _attn_out(cfg, layer, x, attn, gate), k_cache, v_cache
 
 
 # ------------------------------------------------- paged KV decode surface
@@ -910,13 +927,7 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
     n_rep = H // KV
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q = (h @ _w(layer["wq"], dt)).reshape(B, 1, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, 1, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, 1, KV, Hd)
-    q, k = _qk_norm(cfg, layer, q, k)
-    scaling = getattr(cfg, "rope_scaling", None)
-    q = _rope(q, positions, cfg.rope_theta, scaling)
-    k = _rope(k, positions, cfg.rope_theta, scaling)
+    q, k, v, gate = _qkv(cfg, layer, h, positions)
     k_pool = paged_write_step(k_pool, layer_idx, k[:, 0], write_page,
                               write_off)
     v_pool = paged_write_step(v_pool, layer_idx, v[:, 0], write_page,
@@ -946,8 +957,7 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
         logits = jnp.where(valid, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(dt)
         attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-    return x + attn.reshape(B, 1, H * Hd) @ _w(layer["wo"], dt), \
-        k_pool, v_pool
+    return _attn_out(cfg, layer, x, attn, gate), k_pool, v_pool
 
 
 def paged_coords(pos: jax.Array, tables: jax.Array, page: int):
@@ -1045,18 +1055,11 @@ def suffix_attn_step(cfg, layer: dict, x: jax.Array, k_prefix: jax.Array,
     from polyaxon_tpu.ops.attention import repeat_kv
 
     dt = cfg.dtype
-    B, S = positions.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     n_rep = H // KV
-    scaling = getattr(cfg, "rope_scaling", None)
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q = (h @ _w(layer["wq"], dt)).reshape(B, S, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, S, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, S, KV, Hd)
-    q, k = _qk_norm(cfg, layer, q, k)
-    q = _rope(q, positions, cfg.rope_theta, scaling)
-    k = _rope(k, positions, cfg.rope_theta, scaling)
+    q, k, v, gate = _qkv(cfg, layer, h, positions)
     keys = repeat_kv(jnp.concatenate([k_prefix, k], axis=1), n_rep)
     vals = repeat_kv(jnp.concatenate([v_prefix, v], axis=1), n_rep)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, keys).astype(jnp.float32)
@@ -1064,7 +1067,7 @@ def suffix_attn_step(cfg, layer: dict, x: jax.Array, k_prefix: jax.Array,
     s = jnp.where(valid, s, -1e30)
     probs = jax.nn.softmax(s, axis=-1).astype(dt)
     attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-    return x + attn.reshape(B, S, H * Hd) @ _w(layer["wo"], dt), k, v
+    return _attn_out(cfg, layer, x, attn, gate), k, v
 
 
 def _suffix_mask(S: int, m_pad: int, m: jax.Array) -> jax.Array:

@@ -372,8 +372,11 @@ def route(cfg, logits: jax.Array, expert_bias: Optional[jax.Array] = None):
     """Router logits [T, E] fp32 → (chosen experts [T, K] int32, their
     combine weights [T, K] fp32, every expert's score [T, E]).
 
-    Two scorings share every dispatch below. Softmax (Mixtral's, the
-    default): the K largest probabilities, renormalised to sum to one.
+    Two scorings share every dispatch below, whatever the experts
+    behind it are (SwiGLU with a gate stack, squared-ReLU without: the
+    dispatch's business). Softmax (Mixtral's, the default): the K
+    largest probabilities over every expert scored, renormalised to sum
+    to one.
     Sigmoid (``cfg.router_score == "sigmoid"``): each expert scored on
     its own; the K experts are chosen by ``score + expert_bias`` (a
     load-balancing buffer that steers selection only) but weighted by
@@ -450,27 +453,31 @@ _RAGGED_ROWS = 128
 
 
 def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
-                    w_up, w_down, first: int, dt,
+                    w_gate, w_up, w_down, first: int, dt,
                     layer: Optional[int] = None):
     """A dispatch that pays for the pairs it routes: the (token,
-    choice) pairs sorted by expert, two grouped matmuls over them
+    choice) pairs sorted by expert, grouped matmuls over them
     (``jax.lax.ragged_dot``: the chip's compiler makes a grouped-matmul
     kernel of it that steps over the rows its groups cover), the
     weighted sum back by token. ``tokens`` [T, D], ``top_idx``/``top_w``
     [T, K] over every expert the router scores → out [T, D].
 
-    The E experts held are ``first .. first+E−1``, ungated squared-ReLU
-    MLPs ``w_up`` [E, D, F], ``w_down`` [E, F, D]. Pairs whose expert
-    lies elsewhere sort behind the last group, belong to none and add
-    nothing. No capacity: nothing is dropped, and nothing is computed
-    for a slot no pair fills (where `dense_dispatch` at the no-drop
-    capacity builds [T, E, T]).
+    The E experts held are ``first .. first+E−1``, with the stacks
+    `dense_dispatch` takes: ``w_up`` [E, D, F], ``w_down`` [E, F, D]
+    and ``w_gate`` [E, D, F] or None. With a gate stack an expert is
+    the SwiGLU of `_expert_ffn`, ``W_down(silu(W_gate x) ⊙ W_up x)``
+    (three grouped matmuls); without one the ungated squared-ReLU MLP
+    of `relu2_expert_ffn`, ``W_down(relu(W_up x)²)`` (two). Pairs whose
+    expert lies elsewhere sort behind the last group, belong to none
+    and add nothing. No capacity: nothing is dropped, and nothing is
+    computed for a slot no pair fills (where `dense_dispatch` at the
+    no-drop capacity builds [T, E, T]).
 
-    With ``layer``, ``w_up``/``w_down`` are the layers' stacked leaves
-    [L, E, ...] and the kernel is handed them whole, as L·E groups of
-    which only layer ``layer``'s hold rows: it reads that layer's
-    experts where they lie. (Handed ``w_up[layer]``, the program first
-    copies the slice: 0.7 GB a matmul at 128 experts of 1,024 x 2,688.)"""
+    With ``layer``, the stacks are the layers' stacked leaves [L, E,
+    ...] and the kernel is handed them whole, as L·E groups of which
+    only layer ``layer``'s hold rows: it reads that layer's experts
+    where they lie. (Handed ``w_up[layer]``, the program first copies
+    the slice: 0.7 GB a matmul at 128 experts of 1,024 x 2,688.)"""
     T, K = top_idx.shape
     E = w_up.shape[-3]
     local = (top_idx - first).reshape(T * K)
@@ -488,11 +495,17 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
     if layer is not None:
         L = w_up.shape[0]
         sizes = jnp.zeros((L, E), jnp.int32).at[layer].set(sizes).reshape(-1)
-        w_up = w_up.reshape(L * E, *w_up.shape[2:])
-        w_down = w_down.reshape(L * E, *w_down.shape[2:])
-    hidden = jax.lax.ragged_dot(rows, _w(w_up, dt), sizes)
-    out = jax.lax.ragged_dot(jnp.square(jax.nn.relu(hidden)),
-                             _w(w_down, dt), sizes)
+
+    def grouped(x, stack):
+        if layer is not None:
+            stack = stack.reshape(-1, *stack.shape[2:])
+        return jax.lax.ragged_dot(x, _w(stack, dt), sizes)
+
+    if w_gate is None:
+        hidden = jnp.square(jax.nn.relu(grouped(rows, w_up)))
+    else:
+        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    out = grouped(hidden, w_down)
     # Back in pair order; a row past the groups holds whatever the
     # kernel left there, and is masked, not scaled.
     back = out[jnp.argsort(order)[:T * K]].reshape(T, K, -1)

@@ -65,7 +65,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models import llama, moe
+from polyaxon_tpu.models import llama, moe, row_state
 from polyaxon_tpu.models.common import (
     Batch,
     ModelDef,
@@ -90,6 +90,14 @@ from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
     cb_admission,
     cb_validate,
     paged_gather,
+)
+# The per-row side of the engine's paged surface, shared with the other
+# family whose rows carry a state.
+from polyaxon_tpu.models.row_state import (  # noqa: F401  (re-exported hooks)
+    paged_gather_prefix,
+    paged_insert_prefill,
+    paged_insert_suffix,
+    put_layer as _put,
 )
 from polyaxon_tpu.ops import mamba2
 
@@ -343,8 +351,9 @@ def routed_experts(cfg: NemotronHConfig, stack: dict, i: int,
     latent = tokens @ _w(stack["w_latent_down"][i], dt)
     first = cfg.held[0]
     if sequence:
-        routed = moe.sorted_dispatch(latent, top_idx, top_w, stack["w1"],
-                                     stack["w2"], first, dt, layer=i)
+        routed = moe.sorted_dispatch(latent, top_idx, top_w, None,
+                                     stack["w1"], stack["w2"], first, dt,
+                                     layer=i)
         onehot = None
     else:
         routed, onehot = moe.dense_dispatch(
@@ -464,16 +473,6 @@ def prefill(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
     pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
     cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad), **carried}
     return _head(cfg, params, x[:, -1]), cache
-
-
-def _put(stack: jax.Array, new: jax.Array, i: int) -> jax.Array:
-    """``new`` [B, ...] over the first B rows of layer ``i`` of
-    ``stack`` [L, rows ≥ B, ...], as an update of that slice in place
-    (an ``.at[i, :B].set`` is a scatter, which the chip's compiler
-    turns into a pass over the whole stack: 1.25 GB a Mamba-2 layer a
-    step at 64 rows)."""
-    return jax.lax.dynamic_update_slice(
-        stack, new[None].astype(stack.dtype), (i,) + (0,) * new.ndim)
 
 
 def _decode_layers(cfg: NemotronHConfig, params: dict, x: jax.Array,
@@ -603,73 +602,13 @@ def decode_step_paged(cfg: NemotronHConfig, params: dict, cache: dict,
         **kv, **counters, "rows": {"ssm": ssm, "conv": conv}}
 
 
-def _row_of(rows: dict, row) -> dict:
-    """Row ``row`` (traced) of every per-row leaf, as a batch of one:
-    [L, 1, ...]."""
-    return {name: jax.lax.dynamic_slice_in_dim(leaf, row, 1, axis=1)
-            for name, leaf in rows.items()}
-
-
-def _set_row(rows: dict, carried: dict, row) -> dict:
-    return {name: jax.lax.dynamic_update_slice_in_dim(
-        leaf, carried[name].astype(leaf.dtype), row, axis=1)
-        for name, leaf in rows.items()}
-
-
 def paged_prefill_kv(cfg: NemotronHConfig, params: dict, prompt: jax.Array):
-    """The whole prompt [1, P] as a suffix behind nothing: (k, v
-    [L_attn, P, KV, Hd], what the row carries after it) for
-    `paged_insert_prefill`."""
-    _, k, v, carried = _sequence_pass(cfg, params, prompt)
-    return k[:, 0], v[:, 0], carried
+    return row_state.paged_prefill_kv(_sequence_pass, cfg, params, prompt)
 
 
-def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
-                         carried: dict, page_ids: jax.Array,
-                         page_size: int, row) -> dict:
-    """K and V into the row's pages as llama does, the carried leaves
-    into row ``row``."""
-    kv = llama.paged_insert_prefill(
-        {"k": cache["k"], "v": cache["v"]}, k_all, v_all, page_ids,
-        page_size)
-    return {**cache, **kv, "rows": _set_row(cache["rows"], carried, row)}
-
-
-def paged_gather_prefix(cache: dict, page_ids: jax.Array, row) -> tuple:
-    """What a suffix prefill reads of the row's earlier chunks: K and V
-    of the pages ``page_ids`` token-major [L_attn, n·page, KV, Hd], and
-    what row ``row`` carries (true where the prefix is this row's own
-    work, which is the prefill lane's case: a radix match has no state,
-    so for this cache the pool gives none)."""
-    return (paged_gather(cache["k"], page_ids),
-            paged_gather(cache["v"], page_ids),
-            _row_of(cache["rows"], row))
-
-
-def paged_prefill_suffix_kv(cfg: NemotronHConfig, params: dict,
-                            suffix: jax.Array, k_prefix: jax.Array,
-                            v_prefix: jax.Array, carried: dict, m,
-                            real_len):
-    """The tail ``suffix`` [1, S] (``real_len`` of it real, the rest
-    padding) of a prompt whose first ``m`` tokens exist
-    (`paged_gather_prefix`'s three): (k, v [L_attn, S, KV, Hd], what the
-    row carries after the last real position) for
-    `paged_insert_suffix`. At ``m`` = 0 the row starts from zeros,
-    whatever it held."""
-    carried = jax.tree.map(lambda leaf: jnp.where(m > 0, leaf, 0), carried)
-    _, k, v, carried = _sequence_pass(
-        cfg, params, suffix, k_prefix[:, None], v_prefix[:, None], carried,
-        m, real_len)
-    return k[:, 0], v[:, 0], carried
-
-
-def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
-                        carried: dict, page_ids: jax.Array, start,
-                        page_size: int, real_len, row) -> dict:
-    kv = llama.paged_insert_suffix(
-        {"k": cache["k"], "v": cache["v"]}, k_suf, v_suf, page_ids, start,
-        page_size, real_len)
-    return {**cache, **kv, "rows": _set_row(cache["rows"], carried, row)}
+def paged_prefill_suffix_kv(cfg: NemotronHConfig, params: dict, *suffix):
+    return row_state.paged_prefill_suffix_kv(_sequence_pass, cfg, params,
+                                             *suffix)
 
 
 # --------------------------------------------------------------- training

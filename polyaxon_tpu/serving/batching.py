@@ -279,6 +279,10 @@ class _Request:
     eos: frozenset = frozenset()
     out: list[int] = field(default_factory=list)
     done: threading.Event = field(default_factory=threading.Event)
+    # Set by the engine once tokens it appended to `out` (or the
+    # request's end) are worth a streaming handler's waking for
+    # (`ContinuousBatchingEngine._announce`); the handler clears it.
+    fresh: threading.Event = field(default_factory=threading.Event)
     error: Optional[str] = None
     cancelled: bool = False
     # Stamped at submit; the retire path feeds submit→done wall time
@@ -650,6 +654,9 @@ class ContinuousBatchingEngine:
         self.trace_dump_path = trace_dump_path
         self._rejected: dict[str, int] = {}
         self._cv = threading.Condition()
+        # Requests whose new tokens (or end) no handler has been woken
+        # for yet (`_announce`).
+        self._unannounced: list[_Request] = []
         self._stopped = False
         self._served = 0
         self._tokens_out = 0
@@ -2166,6 +2173,7 @@ class ContinuousBatchingEngine:
                 # replaced wholesale at the next admission).
                 fresh = fresh[:hit + 1]
             req.out.extend(fresh)
+            self._unannounced.append(req)
             if fresh:
                 if req.first_token_at is None:
                     self._observe_first_token(req)
@@ -2228,6 +2236,22 @@ class ContinuousBatchingEngine:
             self._publish_queue_depth()
             self._finish_trace(req)
             req.done.set()
+            self._unannounced.append(req)
+
+    def _announce(self) -> None:
+        """Wake the streaming handlers of the requests that got tokens
+        (or ended) since the last call. A handler takes the interpreter
+        lock to write its token out, and with a hundred rows live a
+        hundred of them do; woken at once where the tokens are
+        appended, they would hold the engine thread off the lock just
+        when it uploads and launches the next step, with the device
+        idle. So a step's tokens are announced after the *next* step
+        is launched (`_plain_step`), and the handlers write while the
+        device computes; an engine with nothing live announces at the
+        end of its tick (`_run_loop`)."""
+        for req in self._unannounced:
+            req.fresh.set()
+        self._unannounced.clear()
 
     # ------------------------------------------------------- preemption
     def _maybe_preempt(self) -> None:
@@ -2363,6 +2387,11 @@ class ContinuousBatchingEngine:
                 if alive:
                     with self._phase("observe"):
                         self._observe_tick(time.time() - t0)
+            if not alive or self.draft is not None or all(
+                    r is None for r in self._slot_req):
+                # No plain step is known to follow at once: the tick's
+                # tokens and endings are announced here.
+                self._announce()
             if not alive:
                 return
 
@@ -2561,6 +2590,8 @@ class ContinuousBatchingEngine:
                 nxt, self._cache = step_fn(
                     self.params, self._cache, cur, pos, seeds, counts,
                     temps, top_ps, top_ks, tables)
+            # The step before this one's tokens: the device is busy now.
+            self._announce()
             with self._phase("step.readback"):
                 nxt = np.asarray(nxt)
         except Exception as exc:  # noqa: BLE001 — fail live requests
@@ -2578,6 +2609,7 @@ class ContinuousBatchingEngine:
             if req is None:
                 continue
             req.out.append(int(nxt[b]))
+            self._unannounced.append(req)
             if req.first_token_at is None:
                 self._observe_first_token(req)
             self._pos[b] += 1

@@ -727,11 +727,16 @@ class _Handler(BaseHTTPRequestHandler):
                          top_k: int = 0, eos_tokens=None,
                          klass: str = "batch") -> None:
         """SSE token streaming. With the continuous engine, per-token
-        events flow as rows decode (the handler polls each request's
-        growing output — appends are GIL-atomic); the static engine
-        emits the whole batch as a burst after its compiled run."""
-        import time as _time
-
+        events flow as rows decode: the handler reads each request's
+        growing output (appends are GIL-atomic) and sleeps on the
+        request's ``fresh`` event, which the engine sets once the step
+        after the one that made the tokens is on the device
+        (``batching.py _announce``), so that a hundred handlers write
+        while the device computes and not while the engine thread needs
+        the interpreter lock to launch. The wait's timeout is the net
+        under endings that set no event (a rejection, a failure). The
+        static engine emits the whole batch as a burst after its
+        compiled run."""
         # Validate before any header goes out, so bad requests are real
         # HTTP 400s (the caller catches ValueError) rather than error
         # events on an already-open stream. Both engines expose
@@ -753,6 +758,8 @@ class _Handler(BaseHTTPRequestHandler):
                 emitted = [0] * len(reqs)
                 while True:
                     progressed = False
+                    for r in reqs:
+                        r.fresh.clear()
                     for i, r in enumerate(reqs):
                         while emitted[i] < len(r.out):
                             self._sse({"index": i,
@@ -763,7 +770,9 @@ class _Handler(BaseHTTPRequestHandler):
                            for i, r in enumerate(reqs)):
                         break
                     if not progressed:
-                        _time.sleep(0.02)
+                        waiting = next((r for r in reqs
+                                        if not r.done.is_set()), reqs[0])
+                        waiting.fresh.wait(0.1)
                 failed = [r.error for r in reqs if r.error]
                 if failed:
                     return self._sse({"error": failed[0]}, event="error")

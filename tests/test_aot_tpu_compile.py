@@ -84,6 +84,18 @@ CASES = {
         "nemotron_h", dict(program="decode", n_pages=8193), PAGED_KERNELS),
     "nemotron_h-prefill-sorted-dispatch": (
         "nemotron_h", dict(program="prefill"), {"ragged-dot": 2}),
+    # The Gated DeltaNet / gated attention / routed SwiGLU family's two
+    # serving programs at a small size with the published head sizes
+    # (attention 256, a quarter of it turned; state 128 x 128): the
+    # streamed paged kernel at head size 256 in the decode step, three
+    # grouped matmuls an expert block and the chunked form's triangular
+    # solve in the prefill (whose last layer's expert block feeds
+    # nothing the program returns, K, V and the rows' state, and is not
+    # compiled: three blocks of the four).
+    "qwen3_next-decode-step-pages-and-rows": (
+        "qwen3_next", dict(program="decode"), PAGED_KERNELS),
+    "qwen3_next-prefill-sorted-dispatch": (
+        "qwen3_next", dict(program="prefill"), {"ragged-dot": 9}),
     # The two other decode programs of the benchmark's engines, whole
     # (one kernel in the text is one a layer: llama's layers are a scan).
     "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
@@ -107,6 +119,19 @@ POOL_LIMITS = {
     # compiler stages through fast memory, a copy each way.
     "nemotron_h-decode-step-pages-and-rows": ((8193, 2, 16, 128), 0, None),
     "lfm2-decode-step-lfm2-cell": ((3073, 8, 16, 64), 9, None),
+}
+
+
+# The per-row state a decode or prefill program updates: name -> the
+# whole leaf's type. No instruction may copy it (a step reads and
+# writes each row's state where it lies: `models/row_state.py
+# put_layer`, a `dynamic_update_slice` the compiler aliases).
+# The leaf is held at the benchmark's rows and heads (128 x 32 x 128 x
+# 128 a layer: 268 MB): a leaf of a few megabytes the compiler stages
+# whole through fast memory, a copy each way, which says nothing.
+STATE_LEAVES = {
+    "qwen3_next-decode-step-pages-and-rows": "f32[3,128,32,128,128]",
+    "qwen3_next-prefill-sorted-dispatch": "f32[3,128,32,128,128]",
 }
 
 
@@ -322,6 +347,53 @@ def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
             i32()).compile()
 
 
+def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
+                        n_pages=257, prompt=127):
+    """`decode_step_paged` or the whole-prompt prefill with its insert,
+    as the engine builds them, for a one-period model of a quarter of
+    its experts."""
+    import dataclasses
+
+    import jax
+
+    from polyaxon_tpu.models import qwen3_next as qn
+
+    cfg = dataclasses.replace(
+        qn.CONFIGS["qwen3_next_tiny"], vocab_size=1024, dim=512, n_layers=4,
+        n_heads=4, n_kv_heads=2, head_dim=256, gdn_key_heads=16,
+        gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128, chunk_size=64,
+        n_experts=32, held_experts=(8, 8), experts_per_token=4,
+        moe_ffn_dim=256, shared_ffn_dim=256, paged_attention_impl="pallas")
+    if program == "decode":
+        return _compile_decode_step(topo, qn, cfg, slots, max_len, n_pages,
+                                    page)
+    params, cache, i32 = _engine_avals(topo, qn, cfg, slots, n_pages, page)
+
+    def prefill(params, tokens, cache, page_ids, row):
+        return qn.paged_insert_prefill(
+            cache, *qn.paged_prefill_kv(cfg, params, tokens), page_ids,
+            page, row)
+
+    with _kernel_path():
+        return jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(1, prompt), cache, i32(max_len // page),
+            i32()).compile()
+
+
+def _leaf_copies(text: str, leaf: str) -> list:
+    """The instructions of a compiled program whose result is the whole
+    leaf ``leaf`` (its type as the text prints it) and which copy it."""
+    out = []
+    for line in text.splitlines():
+        head = line.strip().split(" = ")
+        if len(head) < 2 or not head[1].startswith(leaf):
+            continue
+        if re.search(r"\bcopy(-start|-done)?\(|copy_fusion|kind=kCopy",
+                     head[1]) or head[0].lstrip("%").startswith("copy"):
+            out.append(line.strip()[:160])
+    return out
+
+
 def _pool_sized_instructions(text: str, pool_shape: tuple) -> list:
     """The instructions a compiled program runs whose result is a
     bfloat16 array ``[P, KV, page, Hd]`` or a stack of them, found by
@@ -378,6 +450,7 @@ def _child_main() -> int:
     for name, (kind, kwargs, _) in CASES.items():
         compile_case = {"flash": _compile_flash, "paged": _compile_paged,
                         "nemotron_h": _compile_nemotron_h,
+                        "qwen3_next": _compile_qwen3_next,
                         "llama_decode": _compile_llama_decode,
                         "lfm2_decode": _compile_lfm2_decode}[kind]
         t0 = time.time()
@@ -392,6 +465,10 @@ def _child_main() -> int:
             if grouped:
                 kernels["ragged-dot"] = grouped
             report[name] = {"ok": True, "kernels": kernels}
+            if name in STATE_LEAVES:
+                report[name]["state_copies"] = _leaf_copies(
+                    text, STATE_LEAVES[name])
+                report[name]["state_seen"] = STATE_LEAVES[name] in text
             if name in POOL_LIMITS:
                 report[name]["pool_sized"] = _pool_sized_instructions(
                     text, POOL_LIMITS[name][0])
@@ -450,6 +527,16 @@ def test_decode_program_leaves_the_pool_in_place(aot_report, name):
     assert len(entry["pool_sized"]) <= most, entry["pool_sized"]
     if temp_bytes is not None:
         assert entry["temp_bytes"] < temp_bytes, entry["temp_bytes"]
+
+
+@pytest.mark.parametrize("name", sorted(STATE_LEAVES))
+def test_program_updates_the_rows_state_where_it_lies(aot_report, name):
+    """No instruction copies the whole leaf of per-row states: a decode
+    step updates a layer's rows in place, a prefill its row."""
+    entry = aot_report["cases"][name]
+    assert entry["ok"], entry
+    assert entry["state_seen"], "the program does not hold the leaf"
+    assert entry["state_copies"] == [], entry["state_copies"]
 
 
 if __name__ == "__main__":
