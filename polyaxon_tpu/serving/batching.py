@@ -34,7 +34,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from functools import lru_cache
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +147,16 @@ class _PhaseClock:
                     **self._snapshot()}
                 self.slow = (self.slow + (record,))[-self.SLOW_KEPT:]
             self._recent.append(took)
+
+
+class _Launch(NamedTuple):
+    """A decode step launched and not read back: its token vector, still
+    on the device, and whose token each row's is: (slot, request, whether
+    this token is the request's last by its budget). The slot may have
+    been freed and filled again before the tokens are read."""
+
+    nxt: jax.Array
+    rows: list
 
 
 def step_keys(seeds, counts):
@@ -591,7 +601,25 @@ class ContinuousBatchingEngine:
         # peak KV memory grows accordingly (documented at the flag).
         self._prefilling: dict[int, list] = {}
         self._pos = np.full(slots, -1, np.int32)  # -1 = free slot
+        # The decode loop runs one step ahead of the host
+        # (`_plain_step`): a step's tokens go to the next step on the
+        # device (`_tok_dev`, None where the host's `_cur` is the whole
+        # truth) and are read back after it is launched. `_cur` is the
+        # host's word: a row's last token read, or the first token of
+        # a row that went live since the last launch (`_fresh_rows`),
+        # which overrides the device's. `_counts` is what the program
+        # folds into each row's key: the tokens launched for its
+        # request so far, the one in flight included.
         self._cur = np.zeros(slots, np.int32)
+        self._counts = np.zeros(slots, np.int32)
+        self._tok_dev: Optional[jax.Array] = None
+        self._fresh_rows: set[int] = set()
+        self._no_fresh = jnp.asarray(np.full(slots, -1, np.int32))
+        # Launched, not read back, oldest first: one between two steps,
+        # two for the moment after a launch.
+        self._unread: collections.deque[_Launch] = collections.deque()
+        self._steps_ahead = 0
+        self._tokens_dropped = 0
         # Per-slot sampling state: written at admission and at retire
         # (`_set_sampling`), never per token. The decode step reads the
         # device copy, uploaded again only after a write. Seeds stay
@@ -683,9 +711,13 @@ class ContinuousBatchingEngine:
         self.max_step_failures = 3
 
         def step(params, cache, tokens, pos, seeds, counts, temps, top_ps,
-                 top_ks, tables, *, filtered: bool):
+                 top_ks, tables, fresh, *, filtered: bool):
             from polyaxon_tpu.models.common import sample_row
 
+            # `tokens` is the step before's result, never seen by the
+            # host; `fresh` holds the host's word for the rows that went
+            # live since (their first decode token), -1 elsewhere.
+            tokens = jnp.where(fresh >= 0, fresh, tokens)
             keys = step_keys(seeds, counts)
             # Quantized trees pass through whole — weights unwrap at
             # consumption inside the model (models/llama.py _w), so
@@ -1126,6 +1158,9 @@ class ContinuousBatchingEngine:
         with self._cv:
             pending = [state[0] for state in self._prefilling.values()]
             pending += [state[0] for state in self._lane.values()]
+            # A loop that died mid-step leaves its launches unread.
+            pending += [row[1] for launch in self._unread
+                        for row in launch.rows]
             for req in self._pending_requests() + self._slot_req + pending:
                 if req is not None and not req.done.is_set():
                     req.error = "engine stopped"
@@ -1196,10 +1231,11 @@ class ContinuousBatchingEngine:
         logger.error(
             "%d consecutive device-program failures; draining queue and "
             "stopping engine", self._consec_step_failures)
-        for b in range(self.slots):
-            if self._slot_req[b] is not None:
-                self._slot_req[b].error = f"engine failed: {err}"
-                self._retire(b)
+        with self._cv:
+            # First, so that a waiter released below finds the engine
+            # stopped whenever it looks.
+            self._stopped = True
+        self._fail_live(f"engine failed: {err}")
         for b, state in list(self._prefilling.items()):
             req = state[0]
             del self._prefilling[b]
@@ -1210,7 +1246,6 @@ class ContinuousBatchingEngine:
         for p in list(self._lane):
             self._drop_lane_reservation(p, f"engine failed: {err}")
         with self._cv:
-            self._stopped = True
             for q in self._queues.values():
                 while q:
                     req = q.popleft()
@@ -1832,6 +1867,12 @@ class ContinuousBatchingEngine:
             "preemptions": dict(self._preemptions),
             "readmit_suffix_tokens": self._readmit_suffix_tokens,
             "decode_steps": self._steps_total,
+            # How often the loop ran ahead of the host: plain steps
+            # launched while the one before was still unread, and rows
+            # computed whose token was dropped (a row that ends at a
+            # stop token is seen one step late).
+            "decode_steps_ahead": self._steps_ahead,
+            "decode_tokens_dropped": self._tokens_dropped,
             # Host time of the loop by phase (the `engine:` spans of a
             # profile, docs/observability.md): cumulative ns per leaf
             # phase, which sum to the ticks' own time; the ticks longer
@@ -1951,6 +1992,8 @@ class ContinuousBatchingEngine:
         self._slot_req[b] = req
         self._pos[b] = pos0
         self._cur[b] = tok0
+        self._fresh_rows.add(b)
+        self._counts[b] = len(req.out)
         self._set_sampling(b, req.temperature, req.top_p, req.top_k,
                            req.seed)
 
@@ -2096,10 +2139,9 @@ class ContinuousBatchingEngine:
         self._step_failures += 1
         self._consec_step_failures += 1
         err = f"{type(exc).__name__}: {exc}"
-        for b in range(self.slots):
-            if self._slot_req[b] is not None:
-                self._slot_req[b].error = err
-                self._retire(b)
+        # Every program queued behind the failed one took its cache: one
+        # failure, and none of their tokens is read.
+        self._fail_live(err)
         # Lane reservations die with the cache: their staged pages
         # were in the donated buffer, so the KV they hold is gone —
         # failing them is the only honest option (pages freed, fresh
@@ -2122,6 +2164,22 @@ class ContinuousBatchingEngine:
                 self._draft_cfg, self.slots, self.max_len)
         return True
 
+    def _fail_live(self, err: str) -> None:
+        """Every request in a slot ends with the error, and the steps
+        launched and unread are forgotten: the requests that left their
+        slot at a launch and wait for their last token there end with
+        it too."""
+        for b in range(self.slots):
+            if self._slot_req[b] is not None:
+                self._slot_req[b].error = err
+                self._retire(b)
+        while self._unread:
+            for _, req, _ in self._unread.popleft().rows:
+                if not req.done.is_set():
+                    req.error = err
+                    self._complete(req)
+        self._tok_dev = None
+
     def _spec_iteration(self, k: Optional[int] = None) -> bool:
         """One draft→verify round for the pool: every live slot emits
         1..k+1 tokens (ragged acceptance, per-row budget caps). ``k``
@@ -2132,6 +2190,10 @@ class ContinuousBatchingEngine:
         transient device error (they were donated to the failed round).
         """
         k = self.spec_k if k is None else k
+        # The round takes its tokens and budgets from the host: a plain
+        # step the policy fell through to is read back first.
+        if not self._drain():
+            return False
         budget = np.zeros(self.slots, np.int32)
         for b in range(self.slots):
             req = self._slot_req[b]
@@ -2182,8 +2244,10 @@ class ContinuousBatchingEngine:
                                     emitted=len(fresh))
             self._pos[b] += n
             self._cur[b] = int(cur_nxt[b])
+            self._counts[b] = len(req.out)
             if len(req.out) >= req.max_new or hit is not None:
                 self._retire(b)
+        self._tok_dev = None  # the round's `_cur` is the whole truth
         return True
 
     def _observe_first_token(self, req: _Request) -> None:
@@ -2211,32 +2275,46 @@ class ContinuousBatchingEngine:
                              prefix_cached_tokens=req.prefix_cached_tokens)
 
     def _retire(self, b: int) -> None:
+        """Free slot ``b`` and end its request, whose tokens the host
+        holds whole (nothing of it is in a step still unread)."""
         req = self._slot_req[b]
+        self._free_slot(b)
+        if req is not None:
+            self._complete(req)
+
+    def _free_slot(self, b: int) -> None:
+        """Slot ``b`` and its pages go back. A step in flight may still
+        read and write those pages: whatever takes them next is launched
+        after it, and the device runs programs in launch order."""
         self._slot_req[b] = None
         self._pos[b] = -1
+        self._counts[b] = 0
+        self._fresh_rows.discard(b)
         if self._pool is not None:
             self._pool.release(b)
         self._set_sampling(b)
-        if req is not None:
-            if req.cancelled and not req.error:
-                req.error = "cancelled"
-            if not req.error:  # count only successfully-served requests
-                self._served += 1
-                self._tokens_out += len(req.out)
-            now = time.time()
-            obs_metrics.serving_request_hist(self._obs).observe(
-                now - req.submitted_at)
-            if (not req.error and req.first_token_at is not None
-                    and len(req.out) >= 2):
-                # TPOT = steady-state decode cadence: the first token
-                # (prefill-dominated, already TTFT's job) is excluded.
-                obs_metrics.serving_tpot_hist(self._obs).observe(
-                    (now - req.first_token_at) / (len(req.out) - 1),
-                    **{"class": req.klass})
-            self._publish_queue_depth()
-            self._finish_trace(req)
-            req.done.set()
-            self._unannounced.append(req)
+
+    def _complete(self, req: _Request) -> None:
+        """The request has its last token (or its error)."""
+        if req.cancelled and not req.error:
+            req.error = "cancelled"
+        if not req.error:  # count only successfully-served requests
+            self._served += 1
+            self._tokens_out += len(req.out)
+        now = time.time()
+        obs_metrics.serving_request_hist(self._obs).observe(
+            now - req.submitted_at)
+        if (not req.error and req.first_token_at is not None
+                and len(req.out) >= 2):
+            # TPOT = steady-state decode cadence: the first token
+            # (prefill-dominated, already TTFT's job) is excluded.
+            obs_metrics.serving_tpot_hist(self._obs).observe(
+                (now - req.first_token_at) / (len(req.out) - 1),
+                **{"class": req.klass})
+        self._publish_queue_depth()
+        self._finish_trace(req)
+        req.done.set()
+        self._unannounced.append(req)
 
     def _announce(self) -> None:
         """Wake the streaming handlers of the requests that got tokens
@@ -2245,10 +2323,10 @@ class ContinuousBatchingEngine:
         hundred of them do; woken at once where the tokens are
         appended, they would hold the engine thread off the lock just
         when it uploads and launches the next step, with the device
-        idle. So a step's tokens are announced after the *next* step
-        is launched (`_plain_step`), and the handlers write while the
-        device computes; an engine with nothing live announces at the
-        end of its tick (`_run_loop`)."""
+        idle. So the tokens read since the last launch are announced
+        after the *next* one (`_plain_step`), and the handlers write
+        while the engine thread waits for the device; an engine with
+        nothing live announces at the end of its tick (`_run_loop`)."""
         for req in self._unannounced:
             req.fresh.set()
         self._unannounced.clear()
@@ -2302,6 +2380,9 @@ class ContinuousBatchingEngine:
         fits = self._pool.can_admit(len(req.tokens), req.tokens)
         if free >= demand and fits:
             return  # capacity absorbs every urgent pending request
+        # A victim is requeued with what it has: the step in flight is
+        # read first (it may end the row that would have been chosen).
+        self._drain()
         victim = self._pick_victim(rc.priority)
         if victim is None:
             return  # nothing evictable (never touch peers/superiors)
@@ -2343,10 +2424,7 @@ class ContinuousBatchingEngine:
         rc = resolve_request_class(req.klass)
         held = self._pool.slot_pages(b)
         discarded = len(req.out)
-        self._slot_req[b] = None
-        self._pos[b] = -1
-        self._set_sampling(b)
-        self._pool.release(b)
+        self._free_slot(b)
         req.preemptions += 1
         req.out.clear()
         req.first_token_at = None
@@ -2375,8 +2453,12 @@ class ContinuousBatchingEngine:
                        and all(r is None for r in self._slot_req)
                        and not self._expert_tokens_wanted.is_set()):
                     self._cv.wait()
-                if self._stopped:
-                    return
+                stopped = self._stopped
+            if stopped:
+                # The requests that wait only for the token of the step
+                # in flight get it.
+                self._drain()
+                return
             self._serve_expert_tokens()
             # Idle waiting above is excluded from the tick duration:
             # the histogram measures work per iteration (admission +
@@ -2387,6 +2469,10 @@ class ContinuousBatchingEngine:
                 if alive:
                     with self._phase("observe"):
                         self._observe_tick(time.time() - t0)
+            if not alive:
+                # A `stop()` seen inside the tick: as above (after a
+                # fail-fast nothing is left unread).
+                self._drain()
             if not alive or self.draft is not None or all(
                     r is None for r in self._slot_req):
                 # No plain step is known to follow at once: the tick's
@@ -2465,11 +2551,17 @@ class ContinuousBatchingEngine:
         steps. Returns False when fail-fast stopped the engine (the
         loop exits); True otherwise — including idle iterations."""
         with self._phase("sweep"):
-            for b in range(self.slots):  # drop cancelled live requests
-                req = self._slot_req[b]
-                if req is not None and req.cancelled:
-                    self._retire(b)
+            if any(r is not None and r.cancelled for r in self._slot_req):
+                # Drop cancelled live requests, with the tokens of the
+                # step in flight (a failed readback retires them all).
+                self._drain()
+                for b in range(self.slots):
+                    req = self._slot_req[b]
+                    if req is not None and req.cancelled:
+                        self._retire(b)
             self._maybe_preempt()
+        if self._stopped:  # a drain may fail-fast
+            return False
         with self._phase("admit", book="admit.other"):
             if self.prefill_slots:
                 self._lane_handoff()  # free lane rows before admission
@@ -2499,13 +2591,16 @@ class ContinuousBatchingEngine:
             live = sum(1 for r in self._slot_req if r is not None)
         if live == 0:
             self._last_decode_at = None
-            return True
+            # Nothing to launch behind it: the step in flight is read.
+            return self._drain()
         if self.prefill_slots and self.decode_lane_budget < 1:
             # Red-team knob (bench --inject lane-starve): a zeroed
             # decode budget means staged work goes live and then sits
             # emitting nothing — the lane gate must catch this, so the
             # engine honors it rather than quietly clamping to 1.
             self._last_decode_at = None
+            if not self._drain():
+                return False
             time.sleep(0.005)  # don't spin hot while starved
             return True
         obs_metrics.serving_lane_ticks_total(self._obs).inc(lane="decode")
@@ -2534,6 +2629,10 @@ class ContinuousBatchingEngine:
             if not self._plain_step():
                 return False
             self._note_decode_step()
+        if all(r is None for r in self._slot_req):
+            # The last rows left their slots at the launch: no step
+            # follows for theirs to be read behind.
+            return self._drain()
         return True
 
     def _note_decode_step(self) -> None:
@@ -2550,78 +2649,152 @@ class ContinuousBatchingEngine:
         self._last_decode_at = now
 
     def _plain_step(self) -> bool:
-        """One ragged decode step for the decode pool. Returns False
-        when fail-fast stopped the engine."""
+        """One ragged decode step for the decode pool, launched before
+        the step before it is read back: the tokens go from step to
+        step on the device, and what the host does with them (the
+        readback, `_emit_step`, and the next tick's sweep, admission
+        and uploads) runs while the device has a program queued.
+        Returns False when fail-fast stopped the engine."""
+        if self._unread:
+            # Counted where `_tick` counts the step, so that a reader
+            # of both finds neither a step ahead of the other.
+            self._steps_ahead += 1
         try:
-            with self._phase("step.keys"):
-                # What the program folds into each slot's base key: the
-                # tokens its request has so far (0 for a free slot).
-                counts = np.fromiter(
-                    (len(r.out) if r is not None else 0
-                     for r in self._slot_req), np.int32, self.slots)
-            filtered = any(
-                r is not None and (r.top_p < 1.0 or r.top_k > 0)
-                for r in self._slot_req)
-            step_fn = (self._step_filtered if filtered
-                       else self._step_plain)
-            with self._phase("step.upload"):
-                # Decode sees ONLY the decode-pool rows: lane rows sit
-                # past self.slots and belong to staged prefills.
-                tables = (jnp.asarray(self._pool.tables[:self.slots])
-                          if self._pool is not None else None)
-                cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
-                if tables is not None:
-                    live = self._pos[self._pos >= 0]
-                    self._paged_pages_live += int(
-                        (live // self._pool.page_size + 1).sum())
-                    self._paged_pages_table += tables.size
-                counts = jnp.asarray(counts)
-                if self._sampling_dev is None:
-                    # Copies, made on the host (`jnp.array` would run a
-                    # device program for each): the CPU backend aliases
-                    # what it is handed, and the next admission writes
-                    # these.
-                    self._sampling_dev = tuple(
-                        jnp.asarray(a.copy())
-                        for a in (self._seeds, self._temps, self._top_ps,
-                                  self._top_ks))
-                seeds, temps, top_ps, top_ks = self._sampling_dev
-            with self._phase("step.dispatch"):
-                nxt, self._cache = step_fn(
-                    self.params, self._cache, cur, pos, seeds, counts,
-                    temps, top_ps, top_ks, tables)
+            rows = self._launch()
             # The step before this one's tokens: the device is busy now.
             self._announce()
-            with self._phase("step.readback"):
-                nxt = np.asarray(nxt)
+            before = self._read_oldest() if len(self._unread) > 1 else None
         except Exception as exc:  # noqa: BLE001 — fail live requests
             return self._handle_step_failure(exc, "decode step")
-        self._consec_step_failures = 0
         with self._phase("step.emit"):
-            self._emit_step(nxt)
+            if before is not None:
+                self._emit_step(*before)
+            return self._advance(rows)
+
+    def _launch(self) -> list:
+        """Upload what the host knows without the tokens in flight and
+        dispatch the step; returns the launch's rows."""
+        with self._phase("step.keys"):
+            # What the program folds into each slot's base key: the
+            # tokens launched for its request so far (0 for a free slot).
+            counts = self._counts.copy()
+            rows = [(b, req, counts[b] + 1 >= req.max_new)
+                    for b, req in enumerate(self._slot_req)
+                    if req is not None]
+        filtered = any(req.top_p < 1.0 or req.top_k > 0
+                       for _, req, _ in rows)
+        step_fn = self._step_filtered if filtered else self._step_plain
+        with self._phase("step.upload"):
+            # Copies, made on the host: the host writes these vectors
+            # while the program that reads them runs (and the CPU
+            # backend aliases what it is handed).
+            # Decode sees ONLY the decode-pool rows: lane rows sit past
+            # self.slots and belong to staged prefills.
+            tables = (jnp.asarray(self._pool.tables[:self.slots].copy())
+                      if self._pool is not None else None)
+            pos = jnp.asarray(self._pos.copy())
+            if tables is not None:
+                live = self._pos[self._pos >= 0]
+                self._paged_pages_live += int(
+                    (live // self._pool.page_size + 1).sum())
+                self._paged_pages_table += tables.size
+            counts = jnp.asarray(counts)
+            tokens, fresh = self._tok_dev, self._no_fresh
+            if tokens is None:
+                tokens = jnp.asarray(self._cur.copy())
+            elif self._fresh_rows:
+                went_live = list(self._fresh_rows)
+                word = np.full(self.slots, -1, np.int32)
+                word[went_live] = self._cur[went_live]
+                fresh = jnp.asarray(word)
+            self._fresh_rows.clear()
+            if self._sampling_dev is None:
+                # (`jnp.array` would run a device program for each.)
+                self._sampling_dev = tuple(
+                    jnp.asarray(a.copy())
+                    for a in (self._seeds, self._temps, self._top_ps,
+                              self._top_ks))
+            seeds, temps, top_ps, top_ks = self._sampling_dev
+        with self._phase("step.dispatch"):
+            nxt, self._cache = step_fn(
+                self.params, self._cache, tokens, pos, seeds, counts,
+                temps, top_ps, top_ks, tables, fresh)
+        self._tok_dev = nxt
+        self._unread.append(_Launch(nxt, rows))
+        return rows
+
+    def _read_oldest(self) -> tuple:
+        """(tokens, rows) of the oldest unread step. A device error of
+        that step (or of one before it) surfaces here."""
+        launch = self._unread[0]
+        with self._phase("step.readback"):
+            nxt = np.asarray(launch.nxt)
+        self._unread.popleft()
+        self._consec_step_failures = 0
+        return nxt, launch.rows
+
+    def _drain(self) -> bool:
+        """Read back and hand out every step launched and unread:
+        whatever needs the host's tokens whole calls this first.
+        Returns False when fail-fast stopped the engine."""
+        while self._unread:
+            try:
+                before = self._read_oldest()
+            except Exception as exc:  # noqa: BLE001 — fail live requests
+                return self._handle_step_failure(exc, "decode step")
+            with self._phase("step.emit"):
+                self._emit_step(*before)
         return True
 
-    def _emit_step(self, nxt: np.ndarray) -> None:
-        """Hand each live slot its token of the step just read back:
-        append, retire at budget or eos, or take the next page."""
-        for b in range(self.slots):
-            req = self._slot_req[b]
-            if req is None:
+    def _emit_step(self, nxt: np.ndarray, rows: list) -> None:
+        """Hand each row of a launch its token, by the request it was
+        launched for: append, end the request at its budget or at a
+        stop token. The slot may be another request's by now."""
+        for b, req, last in rows:
+            if req.done.is_set():
+                # Ended by a stop token in the step before, which was
+                # read after this one was launched: computed, dropped.
+                self._tokens_dropped += 1
                 continue
-            req.out.append(int(nxt[b]))
+            tok = int(nxt[b])
+            req.out.append(tok)
             self._unannounced.append(req)
             if req.first_token_at is None:
                 self._observe_first_token(req)
+            if last:
+                self._complete(req)  # its slot went at the launch
+            elif tok in req.eos:
+                if self._slot_req[b] is req:
+                    self._free_slot(b)
+                self._complete(req)
+            elif self._slot_req[b] is req:
+                self._cur[b] = tok
+
+    def _advance(self, rows: list) -> bool:
+        """What the host knows of the step just launched without its
+        tokens: each row moves one position on; a row whose budget this
+        step reaches leaves its slot and pages at once (only its
+        request's end waits for the token); any other takes the page of
+        its next position. Returns False when fail-fast stopped the
+        engine."""
+        for b, req, last in rows:
+            if self._slot_req[b] is not req:
+                continue  # ended by a stop token in the step just read
             self._pos[b] += 1
-            self._cur[b] = int(nxt[b])
-            if len(req.out) >= req.max_new or int(nxt[b]) in req.eos:
-                self._retire(b)
+            self._counts[b] += 1
+            if last:
+                self._free_slot(b)
             elif (self._pool is not None
                   and not self._pool.ensure(b, int(self._pos[b]))):
-                # An oversubscribed pool ran dry mid-generation:
-                # fail THIS row loudly (its output so far is
-                # surfaced in the error path) rather than let it
+                # An oversubscribed pool ran dry mid-generation. Whether
+                # the row ends at its token anyway is decided with the
+                # token; if not, fail THIS row loudly (its output so
+                # far is surfaced in the error path) rather than let it
                 # scribble over a neighbour's pages.
+                if not self._drain():
+                    return False
+                if self._slot_req[b] is not req:
+                    continue
                 obs_metrics.serving_evictions_total(self._obs).inc(
                     reason="pool_exhausted")
                 if req.trace is not None:
@@ -2632,3 +2805,4 @@ class ContinuousBatchingEngine:
                     f"(pos {int(self._pos[b])}); raise --kv-pages "
                     "or lower concurrency")
                 self._retire(b)
+        return True

@@ -78,8 +78,13 @@ def test_mixed_batch_draws_each_request_as_if_alone(model, kv):
     def recorded(real, variant):
         def call(*args):
             live = [r for r in engine._slot_req if r is not None]
-            for r in live:
-                variants.setdefault(r.id, {})[len(r.out)] = variant
+            # A step is launched before the one before it is read, so
+            # which token this is, is the engine's count of launches,
+            # not `len(r.out)`.
+            for b, r in enumerate(engine._slot_req):
+                if r is not None:
+                    variants.setdefault(r.id, {})[
+                        int(engine._counts[b])] = variant
             kinds_seen.append({(r.temperature > 0, r.top_p < 1.0)
                                for r in live})
             return real(*args)

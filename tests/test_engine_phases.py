@@ -211,19 +211,26 @@ def test_slow_tick_rule(case):
             pass
 
 
-def test_a_profile_holds_the_engine_spans_on_a_host_plane(model, tmp_path):
+@pytest.fixture(scope="module")
+def profile(model, tmp_path_factory):
+    """One request of 60 tokens under `jax.profiler`: the engine's spans
+    on the host plane, in order of their start, and the counters over
+    the traced run."""
     from jax.profiler import ProfileData
 
+    tmp_path = tmp_path_factory.mktemp("profile")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     engine = _engine(model)
     try:
         engine.generate([[5, 6, 7]], max_new_tokens=4, timeout=300)
+        before = engine.stats()
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            engine.generate([[5, 6, 7]], max_new_tokens=8, timeout=300)
+            engine.generate([[5, 6, 7]], max_new_tokens=60, timeout=300)
         finally:
             jax.profiler.stop_trace()
+        after = engine.stats()
     finally:
         engine.stop()
     path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
@@ -234,21 +241,32 @@ def test_a_profile_holds_the_engine_spans_on_a_host_plane(model, tmp_path):
              if plane.name.startswith("/host:") for line in plane.lines]
     lines = [events for events in lines if events]
     assert len(lines) == 1          # one thread: the engine's
-    events = sorted(lines[0], key=lambda ev: ev[1])
+    return {"events": sorted(lines[0], key=lambda ev: ev[1]),
+            "before": before, "after": after}
+
+
+def test_a_profile_holds_the_engine_spans_on_a_host_plane(profile):
+    events = profile["events"]
     ticks = [ev for ev in events if ev[0] == "engine:tick"]
-    assert len(ticks) >= 6
+    assert len(ticks) >= 60
     whole = 0
     for _, t0, t1 in ticks:
         inside = [ev for ev in events
                   if ev[0] in STEP_PHASES and t0 <= ev[1] < t1]
         if not inside:
-            continue            # the tick that only retires
-        whole += 1
-        assert [ev[0] for ev in inside] == STEP_PHASES
+            continue            # a tick without a step
+        names = [ev[0] for ev in inside]
+        # The launch, then the step before it read and handed out; the
+        # first step after an idle engine has none before it, and the
+        # tick whose launch ends the last row reads that one too.
+        assert names[:3] == STEP_PHASES[:3]
+        assert names[3:] in (STEP_PHASES[4:], STEP_PHASES[3:],
+                             STEP_PHASES[3:] * 2)
+        whole += names == STEP_PHASES
         for (_, _, stop), (_, start, _) in zip(inside, inside[1:]):
             assert stop <= start
         assert inside[-1][2] <= t1
-    assert whole >= 6
+    assert whole >= 58
     leaves = [ev for ev in events
               if ev[0] not in ("engine:tick", "engine:admit")]
     for (_, _, stop), (_, start, _) in zip(leaves, leaves[1:]):
@@ -257,3 +275,33 @@ def test_a_profile_holds_the_engine_spans_on_a_host_plane(model, tmp_path):
     assert {"engine:sweep", "engine:admit", "engine:admit.pick",
             "engine:admit.match", "engine:admit.prefill",
             "engine:observe"} <= names
+
+
+def test_a_step_is_launched_before_the_one_before_it_is_read(profile):
+    """ISSUE 34: `engine:step.dispatch` of step n+1 begins (and ends)
+    before `engine:step.readback` of step n begins; the leaves still
+    fill their tick; nearly every step ran ahead."""
+    events = profile["events"]
+    launches = [ev for ev in events if ev[0] == "engine:step.dispatch"]
+    reads = [ev for ev in events if ev[0] == "engine:step.readback"]
+    assert len(launches) == len(reads) == 60    # every step read once
+    for n in range(59):
+        assert launches[n + 1][2] <= reads[n][1]
+        assert launches[n][2] <= launches[n + 1][1]
+    assert launches[59][2] <= reads[59][1]      # the last: by the drain
+    for _, t0, t1 in (ev for ev in events if ev[0] == "engine:tick"):
+        inside = [ev for ev in events
+                  if ev[0] not in ("engine:tick", "engine:admit")
+                  and t0 <= ev[1] < t1]
+        if not any(ev[0] == "engine:step.dispatch" for ev in inside):
+            continue
+        # leaves apart from each other (checked above) and inside their
+        # tick: their sum and `tick.other` are the tick
+        assert all(t0 <= a and b <= t1 for _, a, b in inside)
+        assert sum(b - a for _, a, b in inside) <= t1 - t0
+    steps = (profile["after"]["decode_steps"]
+             - profile["before"]["decode_steps"])
+    ahead = (profile["after"]["decode_steps_ahead"]
+             - profile["before"]["decode_steps_ahead"])
+    assert steps == 60 and ahead / steps >= 0.95
+    assert profile["after"]["decode_tokens_dropped"] == 0

@@ -305,7 +305,8 @@ class TestContinuousBatching:
 
         engine._compiled_prefill = broken_prefill
         try:
-            reqs = [engine.submit([1, 2, 3], 4) for _ in range(6)]
+            with engine._cv:    # all queued before the third one fails
+                reqs = [engine.submit([1, 2, 3], 4) for _ in range(6)]
             for r in reqs:
                 with pytest.raises(RuntimeError):
                     r.wait(timeout=120)
@@ -1501,3 +1502,293 @@ class TestTracingOverhead:
         assert traced <= untraced * 1.05 + 0.025, (
             f"tracing overhead: {traced:.3f}s traced vs "
             f"{untraced:.3f}s untraced")
+
+
+class TestRunAhead:
+    """ISSUE 34: the decode loop runs one step ahead of the host. A
+    step is launched before the one before it is read back, the tokens
+    go from step to step on the device, and whatever needs the host's
+    tokens whole reads the step in flight first."""
+
+    MAX_LEN = 64
+
+    @pytest.fixture(scope="class")
+    def model(self):
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+
+        from polyaxon_tpu.models import llama
+
+        # float32: a row decoded alone and in a batch pick one argmax
+        cfg = dataclasses.replace(llama.CONFIGS["llama_tiny"],
+                                  dtype=jnp.float32)
+        return cfg, llama.init(cfg, jax.random.key(0))["params"]
+
+    def _alone(self, model, prompt, n, temperature=0.0, seed=0):
+        """One request, one row, no engine: the ragged decode step's
+        logits, the draw keyed by `step_keys(seed, tokens so far)`."""
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from polyaxon_tpu.models import llama
+        from polyaxon_tpu.serving.batching import step_keys
+
+        cfg, params = model
+        cache = llama.cb_init_cache(cfg, 1, self.MAX_LEN)
+        pos, cur, pre = llama.cb_admission(prompt)
+        row = llama.cb_prefill(cfg, params, jnp.asarray([pre], jnp.int32),
+                               self.MAX_LEN)
+        cache = llama.insert_cache_row(cache, row, jnp.int32(0))
+        keys = step_keys(jnp.asarray(np.full(n, seed, np.int64)),
+                         jnp.arange(n, dtype=jnp.int32))
+        step = jax.jit(functools.partial(llama.decode_step_ragged, cfg))
+        out = []
+        for i in range(n):
+            logits, cache = step(params, cache,
+                                 jnp.asarray([cur], jnp.int32),
+                                 jnp.asarray([pos], jnp.int32))
+            if temperature > 0:
+                cur = int(jax.random.categorical(
+                    keys[i], logits[0] / temperature))
+            else:
+                cur = int(jnp.argmax(logits[0]))
+            out.append(cur)
+            pos += 1
+        return out
+
+    def _engine(self, model, **kwargs):
+        from polyaxon_tpu.serving.batching import ContinuousBatchingEngine
+
+        cfg, params = model
+        kwargs.setdefault("slots", 2)
+        engine = ContinuousBatchingEngine(
+            "llama_tiny", cfg, params, max_len=self.MAX_LEN, kv="paged",
+            page_size=4, **kwargs)
+        # Which requests each launch computed a token for, in order.
+        engine.launched = []
+        for name in ("_step_plain", "_step_filtered"):
+            def call(*args, real=getattr(engine, name)):
+                engine.launched.append(
+                    [r.id for r in engine._slot_req if r is not None])
+                return real(*args)
+            setattr(engine, name, call)
+        return engine
+
+    @staticmethod
+    def _launches(engine, req):
+        return [i for i, ids in enumerate(engine.launched) if req.id in ids]
+
+    @staticmethod
+    def _wait_for_tokens(req, n):
+        import time
+
+        deadline = time.monotonic() + 120
+        while len(req.out) < n and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert len(req.out) >= n
+
+    @staticmethod
+    def _sound(stats, dropped=0):
+        assert stats["decode_tokens_dropped"] == dropped
+        assert stats["kv_invariant_violations"] == 0
+        assert stats["kv_pages_free"] == stats["kv_pages_total"]
+        assert stats["step_failures"] == 0
+
+    @pytest.mark.parametrize("budgets", [(9, 9, 9), (7, 8, 9)],
+                             ids=["same_step", "consecutive_steps"])
+    def test_rows_ending_by_budget_decode_as_if_alone(self, model, budgets):
+        prompts = [[5, 6, 7], [1, 2, 3, 4], [9, 8, 7, 6, 5],
+                   [2, 4, 6], [7, 1], [3, 3, 3, 3]]
+        engine = self._engine(model, slots=3)
+        try:
+            with engine._cv:    # all queued before the loop picks one
+                reqs = [engine.submit(p, budgets[i % 3])
+                        for i, p in enumerate(prompts)]
+            outs = [r.wait(timeout=300) for r in reqs]
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        for req, prompt, out in zip(reqs, prompts, outs):
+            assert out == self._alone(model, prompt, req.max_new), prompt
+        # the first three went live together and ended as the case says
+        ends = [self._launches(engine, r)[-1] for r in reqs[:3]]
+        assert [e - ends[0] for e in ends] == [b - budgets[0]
+                                               for b in budgets]
+        # a slot freed at the launch that reached its row's budget is
+        # filled for the launch after: no step ran with a row missing
+        assert all(len(ids) == 3 for ids in engine.launched[:ends[0] + 1])
+        self._sound(stats)
+        assert stats["requests_served"] == len(prompts)
+        assert stats["tokens_generated"] == sum(len(o) for o in outs)
+        # every step but the first after an idle engine ran ahead
+        assert stats["decode_steps_ahead"] >= stats["decode_steps"] - 2
+
+    def test_seeded_sampling_draws_by_the_hosts_count(self, model):
+        asks = [([5, 6, 7], 20, 0.8, 42), ([1, 2, 3, 4], 14, 1.1, 2**40 + 7),
+                ([9, 8, 7], 9, 0.0, 0), ([2, 4, 6], 11, 0.7, 2**31)]
+        engine = self._engine(model, slots=2)
+        try:
+            with engine._cv:
+                reqs = [engine.submit(p, n, temperature=t, seed=s)
+                        for p, n, t, s in asks]
+            outs = [r.wait(timeout=300) for r in reqs]
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        for (prompt, n, temperature, seed), out in zip(asks, outs):
+            assert out == self._alone(model, prompt, n, temperature, seed)
+        self._sound(stats)
+
+    def test_a_stop_token_is_seen_one_step_late_and_nothing_leaks(
+            self, model):
+        """The stopped row's one extra step is computed and dropped;
+        its slot is filled between that launch and its readback, and
+        the new tenant gets its own tokens only."""
+        stopper, runner, tenant = [5, 6, 7], [1, 2, 3, 4], [9, 8, 7, 6, 5]
+        full = self._alone(model, stopper, 12)
+        at = next(i for i in range(2, 12) if full[i] not in full[:i])
+        engine = self._engine(model, slots=2)
+        try:
+            with engine._cv:
+                a = engine.submit(stopper, 12, eos_tokens=[full[at]])
+                c = engine.submit(runner, 40)
+                b = engine.submit(tenant, 10)
+            got = [r.wait(timeout=300) for r in (a, c, b)]
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        assert got[0] == full[:at + 1]
+        assert got[1] == self._alone(model, runner, 40)
+        assert got[2] == self._alone(model, tenant, 10)
+        # one more launch than tokens: the step behind the stop token
+        assert len(self._launches(engine, a)) == at + 2
+        # ... and the tenant's first launch is the one right after it
+        assert (self._launches(engine, b)[0]
+                == self._launches(engine, a)[-1] + 1)
+        self._sound(stats, dropped=1)
+        assert stats["tokens_generated"] == sum(len(g) for g in got)
+
+    def test_a_cancelled_row_keeps_the_tokens_of_the_step_in_flight(
+            self, model):
+        engine = self._engine(model, slots=2)
+        try:
+            with engine._cv:
+                a = engine.submit([5, 6, 7], 50)
+                c = engine.submit([1, 2, 3, 4], 30)
+            self._wait_for_tokens(a, 5)
+            engine.cancel(a)
+            with pytest.raises(RuntimeError, match="cancelled"):
+                a.wait(timeout=300)
+            out_c = c.wait(timeout=300)
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        # every token computed for the cancelled row was read: none lost
+        assert 5 <= len(a.out) == len(self._launches(engine, a)) < 50
+        assert a.out == self._alone(model, [5, 6, 7], len(a.out))
+        assert out_c == self._alone(model, [1, 2, 3, 4], 30)
+        self._sound(stats)
+
+    def test_a_preempted_row_is_requeued_with_its_tokens_whole(self, model):
+        engine = self._engine(model, slots=1)
+        seen = []
+        real = engine._evict_slot
+
+        def evict(b, reason):
+            req = engine._slot_req[b]
+            seen.append((len(engine._unread), len(req.out),
+                         len(self._launches(engine, req))))
+            return real(b, reason)
+
+        engine._evict_slot = evict
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]
+        try:
+            be = engine.submit(prompt, 24, klass="best-effort")
+            self._wait_for_tokens(be, 4)
+            ia = engine.submit([7, 7, 7], 3, klass="interactive")
+            out_ia = ia.wait(timeout=300)
+            out_be = be.wait(timeout=300)
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        assert be.preemptions >= 1 and len(seen) == be.preemptions
+        for unread, have, launched in seen[:1]:
+            assert unread == 0 and have == launched >= 4
+        assert out_ia == self._alone(model, [7, 7, 7], 3)
+        assert out_be == self._alone(model, prompt, 24)
+        self._sound(stats)
+
+    def test_stop_reads_the_step_in_flight(self, model):
+        engine = self._engine(model, slots=1)
+        try:
+            a = engine.submit([5, 6, 7], 50)
+            self._wait_for_tokens(a, 3)
+        finally:
+            engine.stop()
+        assert a.done.is_set() and not engine._unread
+        assert 3 <= len(a.out) == len(self._launches(engine, a))
+        assert a.out == self._alone(model, [5, 6, 7], 50)[:len(a.out)]
+        if len(a.out) < 50:
+            assert a.error == "engine stopped"
+
+    def test_an_error_at_the_readback_is_one_failure(self, model):
+        """A device error surfaces where the tokens are read, with a
+        step already queued behind the failed one: one failure, every
+        request in either step fails with it (the one that had left
+        its slot for its last token too), the next is served."""
+        engine = self._engine(model, slots=2)
+        real = engine._read_oldest
+        armed = [True]
+
+        def read_oldest():
+            if armed[0] and any(last for _, _, last in
+                                engine._unread[0].rows):
+                armed[0] = False
+                assert len(engine._unread) == 2
+                raise RuntimeError("device lost")
+            return real()
+
+        engine._read_oldest = read_oldest
+        try:
+            with engine._cv:
+                a = engine.submit([5, 6, 7], 4)     # leaves its slot first
+                b = engine.submit([1, 2, 3, 4], 30)
+                c = engine.submit([9, 8, 7], 6)     # takes a's slot
+            for req in (a, b, c):
+                with pytest.raises(RuntimeError, match="device lost"):
+                    req.wait(timeout=300)
+            assert len(a.out) == 3
+            d = engine.submit([2, 4, 6], 8)
+            out_d = d.wait(timeout=300)
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        assert out_d == self._alone(model, [2, 4, 6], 8)
+        assert stats["step_failures"] == 1 and stats["stopped"] is False
+        assert stats["kv_invariant_violations"] == 0
+        assert stats["kv_pages_free"] == stats["kv_pages_total"]
+
+    def test_a_pool_run_dry_fails_that_row_with_its_token_read(self, model):
+        """4 usable pages of 4: both rows hold two and want a third at
+        position 8. The first to ask fails loudly, with every token
+        computed for it read; its pages let the other finish."""
+        engine = self._engine(model, slots=2, kv_pages=4)
+        try:
+            with engine._cv:
+                a = engine.submit([5, 6, 7], 8)
+                b = engine.submit([9, 8, 7], 8)
+            with pytest.raises(RuntimeError, match="pool exhausted"):
+                a.wait(timeout=300)
+            out_b = b.wait(timeout=300)
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        assert out_b == self._alone(model, [9, 8, 7], 8)
+        assert len(a.out) == len(self._launches(engine, a)) == 6
+        assert a.out == self._alone(model, [5, 6, 7], 6)
+        assert stats["decode_tokens_dropped"] == 0
+        assert stats["kv_invariant_violations"] == 0
