@@ -29,6 +29,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import logging
+import math
 import statistics
 import threading
 import time
@@ -73,6 +74,31 @@ class _FixedShapeProgram:
         return self._compiled(*args)
 
 
+# The engine's own histograms (a tick's length, a token's way from the
+# readback to its handler's socket): bucket k holds [FIRST x 2^(k/4),
+# FIRST x 2^((k+1)/4)), 19% a bucket from 0.25 ms to 16 s; what lies
+# under the first edge is in bucket 0, what lies past the last in 63.
+LOG_FIRST_EDGE_MS = 0.25
+LOG_PER_OCTAVE = 4
+LOG_BUCKETS = 64
+
+
+def log_bucket(ns: int) -> int:
+    """The bucket of a duration in nanoseconds."""
+    if ns <= LOG_FIRST_EDGE_MS * 1e6:
+        return 0
+    octaves = math.log2(ns / (LOG_FIRST_EDGE_MS * 1e6))
+    return min(int(LOG_PER_OCTAVE * octaves), LOG_BUCKETS - 1)
+
+
+def _log_hist(**counts) -> dict:
+    """Counts by `log_bucket` as `/v1/stats` carries them: copies, with
+    the buckets' description beside them."""
+    return {"first_edge_ms": LOG_FIRST_EDGE_MS,
+            "per_octave": LOG_PER_OCTAVE,
+            **{name: list(by_bucket) for name, by_bucket in counts.items()}}
+
+
 class _PhaseClock:
     """Where an engine tick's host time goes. ``phase(name)`` is entered
     from the engine thread only: it opens a
@@ -87,24 +113,47 @@ class _PhaseClock:
     median of the last 64)`` leaves a record in ``slow`` with the
     engine's state from ``snapshot()``: the black box of a stall that
     nobody was tracing. (An engine's first tick has no median to be
-    held against, and compiles: it is never recorded.)"""
+    held against, and compiles: it is never recorded.)
+
+    One tick in ``CPU_EVERY`` is *sampled*: its spans also read the
+    engine thread's own CPU time (``thread_time_ns``) and book it to
+    ``cpu_ns``, and their wall time once more to ``sampled_ns``, both
+    under the leaves' keys: ``sampled_ns`` less ``cpu_ns`` of a leaf is
+    what the thread spent off the processor there, waiting for the
+    interpreter lock or inside a call that blocked. (Not every tick:
+    the CPU clock is a real system call, 0.5 us on a plain kernel and
+    6-37 us under a sandbox that intercepts system calls, twenty of
+    them a tick.) Every tick's length goes into ``tick_hist`` (the
+    buckets of `log_bucket`), the ticks that ran a prefill into
+    ``prefill_tick_hist`` as well; the loop's wait for work is no tick
+    and no phase: `dry`."""
 
     LEAVES = ("sweep", "admit.pick", "admit.match", "admit.prefill",
               "admit.other", "prefill_chunk", "step.keys", "step.upload",
-              "step.dispatch", "step.readback", "step.emit", "spec",
-              "observe", "tick.other")
+              "step.dispatch", "step.announce", "step.readback",
+              "step.emit", "spec", "observe", "tick.other")
     SLOW_FLOOR_S = 1.0
     SLOW_FACTOR = 8.0
     SLOW_KEPT = 16
+    CPU_EVERY = 8
 
     def __init__(self, snapshot):
         self._snapshot = snapshot
         # Every key is there from the start: readers on other threads
         # copy these dicts while the engine thread adds to their values.
         self.total_ns = dict.fromkeys(self.LEAVES, 0)
+        self.cpu_ns = dict.fromkeys(self.LEAVES, 0)
+        self.sampled_ns = dict.fromkeys(self.LEAVES, 0)
         self._tick_ns = dict.fromkeys(self.LEAVES, 0)
         self.ticks = 0
-        self._open: list[list] = []  # per open span: [start, children's ns]
+        self.sampled_ticks = 0
+        self._sampled = False
+        self.tick_hist = [0] * LOG_BUCKETS
+        self.prefill_tick_hist = [0] * LOG_BUCKETS
+        self.dry_ns = 0
+        self.dry_waits = 0
+        # per open span: [start, children's ns, CPU at start, children's]
+        self._open: list[list] = []
         self._recent: collections.deque = collections.deque(maxlen=64)
         self.slow: tuple = ()  # replaced whole, so a reader never races
 
@@ -112,30 +161,60 @@ class _PhaseClock:
     def phase(self, name: str, book: Optional[str] = None):
         """A span `engine:<name>`; its time outside its children is
         booked under `book` (default: its name)."""
-        frame = [time.perf_counter_ns(), 0]
+        # The CPU clock is read inside the wall clock's two readings, at
+        # either end: a leaf's CPU time never exceeds its wall time.
+        sampled = self._sampled
+        frame = [time.perf_counter_ns(), 0,
+                 time.thread_time_ns() if sampled else 0, 0]
         self._open.append(frame)
         try:
             with jax.profiler.TraceAnnotation("engine:" + name):
                 yield
         finally:
             self._open.pop()
+            cpu = time.thread_time_ns() - frame[2] if sampled else 0
             took = time.perf_counter_ns() - frame[0]
             if self._open:
-                self._open[-1][1] += took
+                parent = self._open[-1]
+                parent[1] += took
+                parent[3] += cpu
             key = book or name
             self.total_ns[key] += took - frame[1]
             self._tick_ns[key] += took - frame[1]
+            if sampled:
+                self.sampled_ns[key] += took - frame[1]
+                self.cpu_ns[key] += cpu - frame[3]
+
+    @contextlib.contextmanager
+    def dry(self):
+        """The loop's wait for work, entered from the engine thread with
+        nothing live, queued or asked: a span `engine:dry` beside the
+        `engine:tick`s and a counter of its own, in no leaf."""
+        start = time.perf_counter_ns()
+        try:
+            with jax.profiler.TraceAnnotation("engine:dry"):
+                yield
+        finally:
+            self.dry_ns += time.perf_counter_ns() - start
+            self.dry_waits += 1
 
     @contextlib.contextmanager
     def tick(self):
         """One loop iteration: the parent span of every phase."""
         for key in self.LEAVES:
             self._tick_ns[key] = 0
+        self._sampled = self.ticks % self.CPU_EVERY == 0
         try:
             with self.phase("tick", book="tick.other"):
                 yield
         finally:
+            self.sampled_ticks += self._sampled
+            self._sampled = False
             took = sum(self._tick_ns.values())
+            bucket = log_bucket(took)
+            self.tick_hist[bucket] += 1
+            if self._tick_ns["admit.prefill"]:
+                self.prefill_tick_hist[bucket] += 1
             self.ticks += 1
             if (took > self.SLOW_FLOOR_S * 1e9 and self._recent
                     and took > self.SLOW_FACTOR
@@ -293,6 +372,10 @@ class _Request:
     # request's end) are worth a streaming handler's waking for
     # (`ContinuousBatchingEngine._announce`); the handler clears it.
     fresh: threading.Event = field(default_factory=threading.Event)
+    # `perf_counter_ns` at which the engine read the newest token of
+    # `out` off the device (`_emit_step`); the streaming handler takes
+    # the token's way out from it (`stats()["deliver_lag_hist"]`).
+    read_ns: int = 0
     error: Optional[str] = None
     cancelled: bool = False
     # Stamped at submit; the retire path feeds submit→done wall time
@@ -685,6 +768,10 @@ class ContinuousBatchingEngine:
         # Requests whose new tokens (or end) no handler has been woken
         # for yet (`_announce`).
         self._unannounced: list[_Request] = []
+        # What the streaming handlers measured of a token's way out
+        # (`merge_deliver_lags`), under a lock of its own.
+        self._deliver_lags = [0] * LOG_BUCKETS
+        self._deliver_lock = threading.Lock()
         self._stopped = False
         self._served = 0
         self._tokens_out = 0
@@ -1880,7 +1967,28 @@ class ContinuousBatchingEngine:
             # phase split.
             "ticks_total": self._clock.ticks,
             "tick_phase_ns": dict(self._clock.total_ns),
+            # One tick in eight also reads the engine thread's CPU
+            # clock: those ticks' CPU and wall time under the same
+            # keys. Wall less CPU is what the thread spent off the
+            # processor there (the interpreter lock, or a call that
+            # blocked). (CPU copied first: never ahead of its wall.)
+            "tick_phase_cpu_ns": dict(self._clock.cpu_ns),
+            "tick_phase_sampled_ns": dict(self._clock.sampled_ns),
+            "ticks_sampled": self._clock.sampled_ticks,
             "slow_ticks": list(self._clock.slow),
+            # A tick's length (every tick; those that ran a prefill),
+            # and a token's way from its readback to the end of its
+            # handler's write: counts by bucket, bucket k from
+            # first_edge_ms x 2^(k / per_octave).
+            "tick_ms_hist": _log_hist(
+                all=self._clock.tick_hist,
+                with_prefill=self._clock.prefill_tick_hist),
+            "deliver_lag_hist": _log_hist(
+                counts=self._deliver_lags_copy()),
+            # The loop's waits with nothing live, queued or asked: in
+            # no tick, so in no key of `tick_phase_ns`.
+            "dry_ns": self._clock.dry_ns,
+            "dry_waits": self._clock.dry_waits,
             "admissions_total": self._admissions,
             # Mean fraction of slots live per decode step: ~1.0 means
             # continuous batching is actually winning; low values with
@@ -2213,6 +2321,7 @@ class ContinuousBatchingEngine:
             return self._handle_step_failure(exc, "speculative round")
         self._consec_step_failures = 0
         self._spec_rounds += 1
+        read_ns = time.perf_counter_ns()
         for b in range(self.slots):
             req = self._slot_req[b]
             if req is None:
@@ -2234,6 +2343,7 @@ class ContinuousBatchingEngine:
                 # past the retire point is irrelevant (the row is
                 # replaced wholesale at the next admission).
                 fresh = fresh[:hit + 1]
+            req.read_ns = read_ns
             req.out.extend(fresh)
             self._unannounced.append(req)
             if fresh:
@@ -2327,9 +2437,24 @@ class ContinuousBatchingEngine:
         after the *next* one (`_plain_step`), and the handlers write
         while the engine thread waits for the device; an engine with
         nothing live announces at the end of its tick (`_run_loop`)."""
-        for req in self._unannounced:
-            req.fresh.set()
-        self._unannounced.clear()
+        with self._phase("step.announce"):
+            for req in self._unannounced:
+                req.fresh.set()
+            self._unannounced.clear()
+
+    def merge_deliver_lags(self, counts: list) -> None:
+        """A streaming handler's own histogram of `perf_counter_ns() -
+        req.read_ns` after its writes (`log_bucket`'s buckets), added
+        to the engine's: every 32 tokens or so and at a stream's end,
+        never a token."""
+        with self._deliver_lock:
+            for k, n in enumerate(counts):
+                if n:
+                    self._deliver_lags[k] += n
+
+    def _deliver_lags_copy(self) -> list:
+        with self._deliver_lock:
+            return list(self._deliver_lags)
 
     # ------------------------------------------------------- preemption
     def _maybe_preempt(self) -> None:
@@ -2452,7 +2577,8 @@ class ContinuousBatchingEngine:
                        and not self._prefilling and not self._lane
                        and all(r is None for r in self._slot_req)
                        and not self._expert_tokens_wanted.is_set()):
-                    self._cv.wait()
+                    with self._clock.dry():
+                        self._cv.wait()
                 stopped = self._stopped
             if stopped:
                 # The requests that wait only for the token of the step
@@ -2460,24 +2586,25 @@ class ContinuousBatchingEngine:
                 self._drain()
                 return
             self._serve_expert_tokens()
-            # Idle waiting above is excluded from the tick duration:
-            # the histogram measures work per iteration (admission +
-            # prefill chunk + decode step), not queue quiet time.
+            # Idle waiting above is excluded from the tick duration
+            # (it is `engine:dry`): the histograms measure work per
+            # iteration (admission + prefill chunk + decode step), not
+            # queue quiet time.
             with self._clock.tick():
                 t0 = time.time()
                 alive = self._tick()
                 if alive:
                     with self._phase("observe"):
                         self._observe_tick(time.time() - t0)
-            if not alive:
-                # A `stop()` seen inside the tick: as above (after a
-                # fail-fast nothing is left unread).
-                self._drain()
-            if not alive or self.draft is not None or all(
-                    r is None for r in self._slot_req):
-                # No plain step is known to follow at once: the tick's
-                # tokens and endings are announced here.
-                self._announce()
+                else:
+                    # A `stop()` seen inside the tick: as above (after
+                    # a fail-fast nothing is left unread).
+                    self._drain()
+                if not alive or self.draft is not None or all(
+                        r is None for r in self._slot_req):
+                    # No plain step is known to follow at once: the
+                    # tick's tokens and endings are announced here.
+                    self._announce()
             if not alive:
                 return
 
@@ -2750,6 +2877,7 @@ class ContinuousBatchingEngine:
         """Hand each row of a launch its token, by the request it was
         launched for: append, end the request at its budget or at a
         stop token. The slot may be another request's by now."""
+        read_ns = time.perf_counter_ns()
         for b, req, last in rows:
             if req.done.is_set():
                 # Ended by a stop token in the step before, which was
@@ -2757,6 +2885,7 @@ class ContinuousBatchingEngine:
                 self._tokens_dropped += 1
                 continue
             tok = int(nxt[b])
+            req.read_ns = read_ns
             req.out.append(tok)
             self._unannounced.append(req)
             if req.first_token_at is None:

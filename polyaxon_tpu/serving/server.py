@@ -47,6 +47,7 @@ import json
 import logging
 import re
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Optional
 
@@ -55,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from polyaxon_tpu.models.common import served_params
-from polyaxon_tpu.serving.batching import QueueFull, validate_sampling
+from polyaxon_tpu.serving.batching import (LOG_BUCKETS, QueueFull, log_bucket,
+                                           validate_sampling)
 from polyaxon_tpu.serving.quantize import (quantize_tree, tree_bytes,
                                            weight_bytes)
 
@@ -525,6 +527,9 @@ setInterval(refresh, 2000);
 class _Handler(BaseHTTPRequestHandler):
     engine: _Engine
     protocol_version = "HTTP/1.1"
+    # A streaming handler hands its delivery lags to the engine once it
+    # holds this many (`_stream_generate`).
+    LAG_MERGE_EVERY = 32
 
     def log_message(self, *args):
         pass
@@ -736,7 +741,15 @@ class _Handler(BaseHTTPRequestHandler):
         the interpreter lock to launch. The wait's timeout is the net
         under endings that set no event (a rejection, a failure). The
         static engine emits the whole batch as a burst after its
-        compiled run."""
+        compiled run.
+
+        After the write that brings a request level with its output the
+        handler takes the time since the engine read that token off the
+        device (``req.read_ns``) into a histogram of its own, and hands
+        the counts to the engine every `LAG_MERGE_EVERY` of them and at
+        the stream's end (``/v1/stats`` ``deliver_lag_hist``). A token
+        written together with a later one is not measured: its reading
+        time was overwritten."""
         # Validate before any header goes out, so bad requests are real
         # HTTP 400s (the caller catches ValueError) rather than error
         # events on an already-open stream. Both engines expose
@@ -756,18 +769,31 @@ class _Handler(BaseHTTPRequestHandler):
                     token_rows, max_new, temperature, seed, top_p, top_k,
                     eos_tokens=eos_tokens, klass=klass)
                 emitted = [0] * len(reqs)
+                lags, unmerged = [0] * LOG_BUCKETS, 0
                 while True:
                     progressed = False
                     for r in reqs:
                         r.fresh.clear()
                     for i, r in enumerate(reqs):
+                        if emitted[i] >= len(r.out):
+                            continue
                         while emitted[i] < len(r.out):
                             self._sse({"index": i,
                                        "token": r.out[emitted[i]]})
                             emitted[i] += 1
-                            progressed = True
+                        progressed = True
+                        # (read first: the engine may stamp the next)
+                        read_ns = r.read_ns
+                        lags[log_bucket(time.perf_counter_ns()
+                                        - read_ns)] += 1
+                        unmerged += 1
+                    if unmerged >= self.LAG_MERGE_EVERY:
+                        self.engine.merge_deliver_lags(lags)
+                        lags, unmerged = [0] * LOG_BUCKETS, 0
                     if all(r.done.is_set() and emitted[i] == len(r.out)
                            for i, r in enumerate(reqs)):
+                        if unmerged:
+                            self.engine.merge_deliver_lags(lags)
                         break
                     if not progressed:
                         waiting = next((r for r in reqs
