@@ -60,6 +60,8 @@ from polyaxon_tpu.models import llama
 from polyaxon_tpu.models.common import _embed_rows, _w
 from polyaxon_tpu.models.llama import _rope
 from polyaxon_tpu.ops.attention import dot_product_attention
+from polyaxon_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+from polyaxon_tpu.parallel import compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -449,16 +451,27 @@ def relu2_expert_ffn(expert_in: jax.Array, w_gate, w_up, w_down,
                       _w(w_down, dt))
 
 
-_RAGGED_ROWS = 128
+# A sorted dispatch's row count is padded to a multiple of this: the
+# Pallas kernel's row tile, and on other backends the multiple of 8 the
+# compiler wants before it makes a grouped kernel of ``ragged_dot``.
+_RAGGED_ROWS = ROW_TILE
+
+
+def _grouped_kernel() -> bool:
+    """Whether `sorted_dispatch`'s grouped matmuls are the Pallas kernel
+    (``ops/grouped_matmul.py``): on a TPU, decided from the backend as
+    ``llama.paged_attn_step``'s ``"auto"`` is, where the call is handed
+    the stacks whole. Elsewhere (the CPU of every test; a mesh that
+    shards them, where the partitioner can split ``ragged_dot`` and
+    cannot split a kernel) they are ``jax.lax.ragged_dot``."""
+    return jax.default_backend() == "tpu" and compat.unsharded()
 
 
 def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
                     w_gate, w_up, w_down, first: int, dt,
                     layer: Optional[int] = None):
     """A dispatch that pays for the pairs it routes: the (token,
-    choice) pairs sorted by expert, grouped matmuls over them
-    (``jax.lax.ragged_dot``: the chip's compiler makes a grouped-matmul
-    kernel of it that steps over the rows its groups cover), the
+    choice) pairs sorted by expert, grouped matmuls over them, the
     weighted sum back by token. ``tokens`` [T, D], ``top_idx``/``top_w``
     [T, K] over every expert the router scores → out [T, D].
 
@@ -473,32 +486,44 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
     computed for a slot no pair fills (where `dense_dispatch` at the
     no-drop capacity builds [T, E, T]).
 
+    A grouped matmul is ``ops/grouped_matmul.py``'s kernel on a TPU
+    (`_grouped_kernel`): row tiles of 128, each multiplied by the block
+    of every expert that holds one of its rows, so a call reads each
+    expert that holds a pair once and works no tile past the held
+    pairs. Elsewhere it is ``jax.lax.ragged_dot``, bfloat16 operands
+    and float32 sums alike (on a TPU the compiler's kernel for it took
+    2.0-5.2 ms a call where the weights take 0.86 to read: `PERF.md`
+    §5-6, PR 37). Either way the row count is padded to a multiple of
+    `_RAGGED_ROWS`: the kernel's row tile, and off the multiple of 8
+    the compiler falls back to every group multiplying every row (at
+    22,506 rows 128 times the work, AOT for a described v5e). Padding
+    belongs to no group.
+
     With ``layer``, the stacks are the layers' stacked leaves [L, E,
-    ...] and the kernel is handed them whole, as L·E groups of which
-    only layer ``layer``'s hold rows: it reads that layer's experts
-    where they lie. (Handed ``w_up[layer]``, the program first copies
-    the slice: 0.7 GB a matmul at 128 experts of 1,024 x 2,688.)"""
+    ...] and the grouped matmul is handed them whole, as L·E groups
+    with layer ``layer``'s first: it reads that layer's experts where
+    they lie. (Handed ``w_up[layer]``, the program first copies the
+    slice: 0.7 GB a matmul at 128 experts of 1,024 x 2,688.)"""
     T, K = top_idx.shape
     E = w_up.shape[-3]
     local = (top_idx - first).reshape(T * K)
     held = (local >= 0) & (local < E)
-    # The chip's compiler makes its grouped kernel only of a row count
-    # that is a multiple of 8 (at 22,506 rows it fell back to every
-    # group multiplying every row: 128 times the work, AOT for a
-    # described v5e): rows of padding, which belong to no group either.
     pad = -(T * K) % _RAGGED_ROWS
     group = jnp.pad(jnp.where(held, local, E), (0, pad),
                     constant_values=E)               # E: held elsewhere
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), axis=0)
     rows = tokens[jnp.minimum(order, T * K - 1) // K]  # by expert
-    if layer is not None:
+    kernel = _grouped_kernel()
+    if layer is not None and not kernel:
         L = w_up.shape[0]
         sizes = jnp.zeros((L, E), jnp.int32).at[layer].set(sizes).reshape(-1)
 
     def grouped(x, stack):
         if layer is not None:
             stack = stack.reshape(-1, *stack.shape[2:])
+        if kernel:
+            return grouped_matmul(x, _w(stack, dt), sizes, (layer or 0) * E)
         return jax.lax.ragged_dot(x, _w(stack, dt), sizes)
 
     if w_gate is None:
@@ -507,7 +532,7 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
         hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
     out = grouped(hidden, w_down)
     # Back in pair order; a row past the groups holds whatever the
-    # kernel left there, and is masked, not scaled.
+    # grouped matmul left there, and is masked, not scaled.
     back = out[jnp.argsort(order)[:T * K]].reshape(T, K, -1)
     weight = jnp.where(held, top_w.reshape(T * K), 0.0).reshape(T, K, 1)
     return jnp.sum(jnp.where(weight > 0, back.astype(jnp.float32) * weight,

@@ -30,7 +30,9 @@ elsewhere adds nothing here, and the partial sum goes up through
 chips or their exchange. A decode step dispatches its rows through the
 one-hot buffers at the no-drop capacity (``moe.dense_dispatch``), a
 sequence through sorted pairs and grouped matmuls
-(``moe.sorted_dispatch``).
+(``moe.sorted_dispatch``: two a layer over the stacks handed whole, on
+a TPU ``ops/grouped_matmul.py``'s kernel, which reads each expert that
+holds a pair once; elsewhere ``jax.lax.ragged_dot``).
 
 **Layers of different kinds.** Parameters are stacked by kind
 (``attn``, ``ssm``, ``moe``) and a static plan (`layer_plan`) walks the

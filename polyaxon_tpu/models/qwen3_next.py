@@ -35,7 +35,9 @@ the next layer. The shared expert is whole here. No code stands in for
 the absent chips or their exchange. A decode step dispatches its rows
 through the one-hot buffers at the no-drop capacity
 (``moe.dense_dispatch``), a sequence through sorted pairs and grouped
-matmuls (``moe.sorted_dispatch``).
+matmuls (``moe.sorted_dispatch``: three a block over the stacks handed
+whole, on a TPU ``ops/grouped_matmul.py``'s kernel, which reads each
+expert that holds a pair once; elsewhere ``jax.lax.ragged_dot``).
 
 **Layers of different kinds.** The mixers' parameters are stacked by
 kind (``gdn``, ``attn``), the expert blocks' over every layer

@@ -23,7 +23,8 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-__all__ = ["ambient_mesh", "batch_axes_in", "kernel_axes", "shard_kernel"]
+__all__ = ["ambient_mesh", "batch_axes_in", "kernel_axes", "shard_kernel",
+           "unsharded"]
 
 # Mesh axes that carry the batch dimension of activations (the rule
 # tables map logical "batch" onto these — parallel/sharding.py; "ep"
@@ -103,10 +104,16 @@ def kernel_axes(batch: int, heads: int):
     return _spec_entry(batch_axes), head_entry
 
 
+def unsharded() -> bool:
+    """Whether a kernel traced here is handed whole arrays: no mesh,
+    one device, or an enclosing region that already bound every axis."""
+    _, free = _unbound_axes()
+    return all(size == 1 for size in free.values())
+
+
 def shard_kernel(fn, in_specs, out_specs):
     """``fn`` run per shard over every ambient mesh axis not yet manual
-    (``fn`` itself when there is nothing to bind: no mesh, one device,
-    or an enclosing region that already bound every axis)."""
+    (``fn`` itself when there is nothing to bind: `unsharded`)."""
     mesh, free = _unbound_axes()
     if all(size == 1 for size in free.values()):
         return fn

@@ -78,12 +78,13 @@ CASES = {
     # The Mamba-2 / attention / latent-expert family's two serving
     # programs at a small size with the published head sizes (128; state
     # 64 x 128): the decode step over pages and rows holds the paged
-    # kernel, the prefill the compiler's grouped-matmul kernel (two a
-    # held-expert layer), not every group over every row.
+    # kernel, the prefill the grouped-matmul kernel (two a held-expert
+    # layer, ops/grouped_matmul.py) and no ``ragged-dot`` of the
+    # compiler's beside it.
     "nemotron_h-decode-step-pages-and-rows": (
         "nemotron_h", dict(program="decode", n_pages=8193), PAGED_KERNELS),
     "nemotron_h-prefill-sorted-dispatch": (
-        "nemotron_h", dict(program="prefill"), {"ragged-dot": 2}),
+        "nemotron_h", dict(program="prefill"), {"grouped_matmul": 2}),
     # The Gated DeltaNet / gated attention / routed SwiGLU family's two
     # serving programs at a small size with the published head sizes
     # (attention 256, a quarter of it turned; state 128 x 128): the
@@ -95,7 +96,7 @@ CASES = {
     "qwen3_next-decode-step-pages-and-rows": (
         "qwen3_next", dict(program="decode"), PAGED_KERNELS),
     "qwen3_next-prefill-sorted-dispatch": (
-        "qwen3_next", dict(program="prefill"), {"ragged-dot": 9}),
+        "qwen3_next", dict(program="prefill"), {"grouped_matmul": 9}),
     # The two other decode programs of the benchmark's engines, whole
     # (one kernel in the text is one a layer: llama's layers are a scan).
     "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
@@ -458,8 +459,11 @@ def _child_main() -> int:
             compiled = compile_case(topo, **kwargs)
             text = compiled.as_text()
             kernels = pallas_kernels(text)
-            # The compiler's own grouped matmul carries no pallas_call
-            # name: it is counted by its custom call's.
+            # The compiler's own grouped matmul (what `sorted_dispatch`
+            # ran on the chip before ops/grouped_matmul.py, and runs off
+            # it) carries no pallas_call name: it is counted by its
+            # custom call's, so a program that fell back to it fails
+            # its case.
             grouped = len(re.findall(r"^\s*(?:ROOT )?%ragged-dot[\w.\-]* = "
                                      r"(?!\()", text, re.MULTILINE))
             if grouped:

@@ -27,7 +27,7 @@ if BENCH not in sys.path:
 
 from reference import nemotron_h as ref  # noqa: E402
 
-from polyaxon_tpu.models import nemotron_h as nh  # noqa: E402
+from polyaxon_tpu.models import moe, nemotron_h as nh  # noqa: E402
 from polyaxon_tpu.ops import mamba2  # noqa: E402
 from polyaxon_tpu.serving.batching import ContinuousBatchingEngine  # noqa: E402
 from polyaxon_tpu.serving.paged import PagePool, page_bytes  # noqa: E402
@@ -159,9 +159,13 @@ def test_chunked_scan_matches_the_sequential_one(length):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_sorted_dispatch_is_the_dense_one(model):
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged-dot", "pallas-kernel-interpreted"])
+def test_sorted_dispatch_is_the_dense_one(model, monkeypatch, kernel):
     """Sorted pairs and grouped matmuls give what the one-hot buffers at
-    the no-drop capacity give, for a share of the experts too."""
+    the no-drop capacity give, for a share of the experts too: through
+    ``ragged_dot``, this backend's, and through the chip's kernel."""
+    monkeypatch.setattr(moe, "_grouped_kernel", lambda: kernel)
     cfg, params, _, _ = model
     stack = params["moe"]
     tokens = jnp.asarray(np.random.default_rng(2).normal(size=(37, cfg.dim)),

@@ -325,10 +325,15 @@ def _share(cfg, stack, first, count):
                          for name in ("w_gate", "w_up", "w_down")}})
 
 
-def test_sorted_dispatch_of_gated_experts_is_the_dense_one(model):
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged-dot", "pallas-kernel-interpreted"])
+def test_sorted_dispatch_of_gated_experts_is_the_dense_one(
+        model, monkeypatch, kernel):
     """Sorted pairs and three grouped matmuls give what the one-hot
     buffers at the no-drop capacity give, for a share of the experts
-    too."""
+    too: through ``ragged_dot``, this backend's, and through the chip's
+    kernel."""
+    monkeypatch.setattr(moe, "_grouped_kernel", lambda: kernel)
     cfg, params, _, _ = model
     tokens = jnp.asarray(np.random.default_rng(2).normal(size=(37, cfg.dim)),
                          jnp.float32)
