@@ -48,8 +48,10 @@ P, KV, page, Hd]``; the delta layers' state is *per row* (``models/
 row_state.py``): ``rows`` holds ``gdn`` ``[L_gdn, rows, Hv, dk, dv]``
 float32 and ``conv`` ``[L_gdn, rows, K−1, conv_dim]``, indexed by the
 engine's row. A decode step reads and writes each live row's state in
-place, a layer at a time; a prefill writes its row's; the pool matches
-nothing for such a cache. ``moe_expert_tokens`` ``[L, count]`` counts
+place, a layer at a time (``ops/gated_delta.py step_rows``: on a TPU
+``ops/gdn_update.py``'s kernel over the leaf, one read and one write of
+a row's state); a prefill writes its row's; the pool matches nothing
+for such a cache. ``moe_expert_tokens`` ``[L, count]`` counts
 the decode steps' (row, choice) pairs by held expert,
 ``moe_pairs_elsewhere`` ``[L]`` those routed to experts this chip does
 not hold.
@@ -324,6 +326,20 @@ def gdn_layer(cfg: Qwen3NextConfig, layer: dict, x: jax.Array,
     return x + out, tail, state
 
 
+def gdn_decode_layer(cfg: Qwen3NextConfig, layer: dict, x: jax.Array,
+                     conv_tail: jax.Array, gdn: jax.Array, i: int,
+                     started: jax.Array):
+    """`gdn_layer` for one position a row ([B, 1, D]) over the decode
+    cache's leaf ``gdn`` [L_gdn, rows ≥ B, Hv, dk, dv], whose layer
+    ``i`` is updated where it lies (``ops/gated_delta.py
+    decode_mixer``). Returns (x after the residual, new convolution
+    tail, the leaf)."""
+    u = llama._norm(cfg, x, layer["gdn_norm"])
+    out, tail, gdn = gated_delta.decode_mixer(cfg, layer, u, conv_tail, gdn,
+                                              i, started)
+    return x + out, tail, gdn
+
+
 def routed_experts(cfg: Qwen3NextConfig, stack: dict, i: int,
                    tokens: jax.Array, sequence: bool):
     """The held experts' part of the routed sum in layer ``i`` of
@@ -483,11 +499,10 @@ def _decode_layers(cfg: Qwen3NextConfig, params: dict, x: jax.Array,
             with jax.named_scope("gated_attention"):
                 x = attend(i, _at(params["attn"], i), x)
         else:
-            state = jnp.where(started[:, None, None, None], gdn[i, :B], 0.0)
             tail = jnp.where(started[:, None, None], conv[i, :B], 0)
-            x, tail, state = gdn_layer(cfg, _at(params["gdn"], i), x, tail,
-                                       state)
-            gdn, conv = put_layer(gdn, state, i), put_layer(conv, tail, i)
+            x, tail, gdn = gdn_decode_layer(cfg, _at(params["gdn"], i), x,
+                                            tail, gdn, i, started)
+            conv = put_layer(conv, tail, i)
         x, onehot = expert_block(cfg, params["moe"], layer, x)
         if counters is not None:
             held = jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live)

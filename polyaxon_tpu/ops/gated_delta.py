@@ -35,6 +35,19 @@ then carries ``S``: ``V' = U − W S``, ``O = (Q ⊙ e^γ) S + tril(Q Kᵀ ⊙
 Γ) V'``, ``S ← e^{γ_C} S + (K ⊙ e^{γ_C − γ})ᵀ V'``. `step` is one
 position. The sequential recurrence above is what decides: the tests
 hold both to it.
+
+A decode step's position goes through `step_rows`, over the cache's
+leaf of every layer's rows in place. Which code runs it is read from
+where it is traced (`update_kernel`): on a TPU that holds the leaf whole,
+the Pallas kernel of ``ops/gdn_update.py``, which brings a block of
+(rows x heads) into fast memory once, takes both products and the
+update from that copy and writes it back once (`step`'s one-read form,
+without the second pass the compiler needs for it); everywhere else
+(the CPU of the tests, a mesh that shards rows or heads) `step` on the
+rows' slice and ``row_state.put_layer``. `step` is the kernel's oracle
+in ``tests/test_gdn_update.py`` and what ``benchmark/kernels/
+gdn_update.py`` counts the floor from. Every sequence (a prefill, a
+training batch) is `chunked` on either.
 """
 
 from __future__ import annotations
@@ -43,6 +56,9 @@ import jax
 import jax.numpy as jnp
 
 from polyaxon_tpu.models.common import _w, rms_norm
+from polyaxon_tpu.models.row_state import put_layer
+from polyaxon_tpu.ops.gdn_update import gdn_update
+from polyaxon_tpu.parallel import compat
 
 HI = jax.lax.Precision.HIGHEST
 L2_EPS = 1e-6
@@ -160,7 +176,11 @@ def step(q, k, v, g, beta, state):
     the output, which by the rule is ``o = S_tᵀq = α·S_{t−1}ᵀq + δ·(k·q)``
     with ``δ = β(v − α·S_{t−1}ᵀk)`` what is written. So the state is
     read once for the two products and once more for its update, where
-    reading the new state back for ``o`` would be a third pass."""
+    reading the new state back for ``o`` would be a third pass. (Compiled
+    for a TPU those are two operations, 0.365 + 0.815 ms a layer at 128
+    rows of 32 heads of 128 x 128: the write needs the whole reduction
+    first. ``ops/gdn_update.py`` is this function with the state held in
+    fast memory between the two, 0.82 ms: `step_rows`.)"""
     alpha = jnp.exp(g)[..., None]                   # [B, H, 1]
     kq = jnp.stack([k, q], axis=-1)                 # [B, H, dk, 2]
     read = jnp.einsum("bhkv,bhkj->bhjv", state, kq, precision=HI)
@@ -181,6 +201,33 @@ def _gated_out(cfg, layer: dict, o: jax.Array, z: jax.Array) -> jax.Array:
     return gated.reshape(*lead, -1).astype(dt) @ _w(layer["w_out"], dt)
 
 
+def _recurrence_inputs(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
+                       real_len=None):
+    """``u`` [B, S, D] behind ``conv_tail`` → what the rule takes: (q, k
+    [B, S, Hv, dk], v [B, S, Hv, dv], g, beta [B, S, Hv], float32; the
+    gate z; the new tail). Positions at or past ``real_len`` have g and
+    beta 0."""
+    dt_ = cfg.dtype
+    S, K = u.shape[1], cfg.conv_kernel
+    qkv, z, b, a = split_projections(
+        cfg, u @ _w(layer["w_qkvz"], dt_), u @ _w(layer["w_ba"], dt_))
+    seq = jnp.concatenate([conv_tail.astype(dt_), qkv], axis=1)
+    taps = layer["conv_w"].astype(jnp.float32)      # [conv_dim, K]
+    conv = sum(taps[:, j] * seq[:, j:j + S].astype(jnp.float32)
+               for j in range(K))
+    q, k, v = _heads(cfg, jax.nn.silu(conv))
+    beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        a.astype(jnp.float32) + layer["dt_bias"].astype(jnp.float32))
+    if real_len is None:
+        tail = seq[:, S:]
+    else:
+        real = (jnp.arange(S) < real_len)[None, :, None]
+        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+        tail = jax.lax.dynamic_slice_in_dim(seq, real_len, K - 1, axis=1)
+    return q, k, v, g, beta, z, tail
+
+
 def mixer(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
           state: jax.Array, real_len=None):
     """The mixer over ``u`` [B, S, D] (already normalised) behind what
@@ -191,25 +238,10 @@ def mixer(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
     padding: they leave the state alone, and the tail returned is that
     of the last real position. Returns (out [B, S, D], new tail, new
     state)."""
-    dt_ = cfg.dtype
-    S, K = u.shape[1], cfg.conv_kernel
+    S = u.shape[1]
     with jax.named_scope("gated_delta"):
-        qkv, z, b, a = split_projections(
-            cfg, u @ _w(layer["w_qkvz"], dt_), u @ _w(layer["w_ba"], dt_))
-        seq = jnp.concatenate([conv_tail.astype(dt_), qkv], axis=1)
-        taps = layer["conv_w"].astype(jnp.float32)      # [conv_dim, K]
-        conv = sum(taps[:, j] * seq[:, j:j + S].astype(jnp.float32)
-                   for j in range(K))
-        q, k, v = _heads(cfg, jax.nn.silu(conv))
-        beta = jax.nn.sigmoid(b.astype(jnp.float32))
-        g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-            a.astype(jnp.float32) + layer["dt_bias"].astype(jnp.float32))
-        if real_len is None:
-            tail = seq[:, S:]
-        else:
-            real = (jnp.arange(S) < real_len)[None, :, None]
-            g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
-            tail = jax.lax.dynamic_slice_in_dim(seq, real_len, K - 1, axis=1)
+        q, k, v, g, beta, z, tail = _recurrence_inputs(
+            cfg, layer, u, conv_tail, real_len)
         if S == 1:
             o, state = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                             state)
@@ -217,3 +249,43 @@ def mixer(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
         else:
             o, state = chunked(q, k, v, g, beta, cfg.chunk_size, state)
         return _gated_out(cfg, layer, o, z), tail, state
+
+
+def update_kernel() -> bool:
+    """Whether `step_rows` is the Pallas kernel (``ops/gdn_update.py``):
+    on a TPU, decided from the backend as ``models/moe.py
+    _grouped_kernel`` decides its own, where the call is handed the leaf
+    whole. Elsewhere (the CPU of every test; a mesh that shards rows or
+    heads, where the partitioner can split `step` and cannot split a
+    kernel) it is `step` and ``row_state.put_layer``."""
+    return jax.default_backend() == "tpu" and compat.unsharded()
+
+
+def step_rows(stack: jax.Array, i: int, q, k, v, g, beta, started):
+    """`step` for the first B rows of layer ``i`` of the decode cache's
+    leaf ``stack`` [L, rows ≥ B, Hv, dk, dv], in place: a row that has
+    not ``started`` ([B] bool) starts from zeros whatever the leaf
+    holds. Returns (o [B, Hv, dv], the leaf). On a TPU one kernel that
+    reads each row's state once and writes it once (`update_kernel`);
+    elsewhere the rows' slice through `step` and back."""
+    if update_kernel():
+        return gdn_update(stack, i, q, k, v, g, beta, started)
+    B = q.shape[0]
+    state = jnp.where(started[:, None, None, None], stack[i, :B], 0.0)
+    o, state = step(q, k, v, g, beta, state)
+    return o, put_layer(stack, state, i)
+
+
+def decode_mixer(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
+                 stack: jax.Array, i: int, started: jax.Array):
+    """`mixer` for one position a row over the decode cache's leaf:
+    ``u`` [B, 1, D], ``conv_tail`` [B, K−1, conv_dim] (zeros for a row
+    that has not started), ``stack`` [L, rows ≥ B, Hv, dk, dv] whose
+    layer ``i`` holds the rows' states, updated in place (`step_rows`).
+    Returns (out [B, 1, D], new tail, the leaf)."""
+    with jax.named_scope("gated_delta"):
+        q, k, v, g, beta, z, tail = _recurrence_inputs(cfg, layer, u,
+                                                       conv_tail)
+        o, stack = step_rows(stack, i, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                             beta[:, 0], started)
+        return _gated_out(cfg, layer, o[:, None], z), tail, stack

@@ -88,13 +88,16 @@ CASES = {
     # The Gated DeltaNet / gated attention / routed SwiGLU family's two
     # serving programs at a small size with the published head sizes
     # (attention 256, a quarter of it turned; state 128 x 128): the
-    # streamed paged kernel at head size 256 in the decode step, three
+    # streamed paged kernel at head size 256 and the three delta layers'
+    # update kernel (ops/gdn_update.py, over the leaf in place:
+    # STATE_LEAVES) in the decode step, three
     # grouped matmuls an expert block and the chunked form's triangular
     # solve in the prefill (whose last layer's expert block feeds
     # nothing the program returns, K, V and the rows' state, and is not
     # compiled: three blocks of the four).
     "qwen3_next-decode-step-pages-and-rows": (
-        "qwen3_next", dict(program="decode"), PAGED_KERNELS),
+        "qwen3_next", dict(program="decode"),
+        {**PAGED_KERNELS, "gdn_update": 3}),
     "qwen3_next-prefill-sorted-dispatch": (
         "qwen3_next", dict(program="prefill"), {"grouped_matmul": 9}),
     # The two other decode programs of the benchmark's engines, whole
@@ -125,8 +128,9 @@ POOL_LIMITS = {
 
 # The per-row state a decode or prefill program updates: name -> the
 # whole leaf's type. No instruction may copy it (a step reads and
-# writes each row's state where it lies: `models/row_state.py
-# put_layer`, a `dynamic_update_slice` the compiler aliases).
+# writes each row's state where it lies: the delta layers' kernel takes
+# the leaf as an aliased operand, a prefill's `models/row_state.py
+# set_row` is a `dynamic_update_slice` the compiler aliases).
 # The leaf is held at the benchmark's rows and heads (128 x 32 x 128 x
 # 128 a layer: 268 MB): a leaf of a few megabytes the compiler stages
 # whole through fast memory, a copy each way, which says nothing.
