@@ -15,13 +15,13 @@ import functools
 from typing import Callable
 
 from polyaxon_tpu.models import (bert, lfm2, llama, mnist, moe, nemotron_h,
-                                 qwen3_next, resnet, t5, vit)
+                                 qwen3_next, resnet, smallthinker, t5, vit)
 from polyaxon_tpu.models.common import ModelDef
 
 # Decoders first: `serving/server.py` lists the servable names in this
 # order.
-FAMILIES = (llama, moe, lfm2, nemotron_h, qwen3_next, t5, vit, bert, resnet,
-            mnist)
+FAMILIES = (llama, moe, lfm2, nemotron_h, qwen3_next, smallthinker, t5, vit,
+            bert, resnet, mnist)
 
 _FACTORIES: dict[str, Callable[..., ModelDef]] = {}
 

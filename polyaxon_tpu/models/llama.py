@@ -260,7 +260,8 @@ def _qk_norm(cfg, layer: dict, q: jax.Array, k: jax.Array):
     return _norm(cfg, q, layer["q_norm"]), _norm(cfg, k, layer["k_norm"])
 
 
-def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array):
+def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array,
+         rotary: bool = True):
     """The three projections of ``h`` [B, T, D] (already normalised) as
     every attention walk below takes them: q [B, T, H, Hd], k and v
     [B, T, KV, Hd], q and k normalised per head where the layer has the
@@ -271,7 +272,10 @@ def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array):
     config without them is the plain path: a ``wq`` twice as wide holds,
     for each head, its query and behind it the gate of that head's
     output (`_attn_out`); ``cfg.partial_rotary_factor`` is the share of
-    a head's dimensions the rotary embedding turns (``common.rope``)."""
+    a head's dimensions the rotary embedding turns (``common.rope``).
+    ``rotary`` False is a layer without positions in a model whose
+    other layers have them (a static layer plan's word, ``models/
+    smallthinker.py``): q and k go on unturned."""
     dt = cfg.dtype
     B, T = h.shape[:2]
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -287,8 +291,9 @@ def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array):
     scaling = getattr(cfg, "rope_scaling", None)
     factor = getattr(cfg, "partial_rotary_factor", None)
     turned = None if factor is None else int(Hd * factor)
-    q = _rope(q, positions, cfg.rope_theta, scaling, turned)
-    k = _rope(k, positions, cfg.rope_theta, scaling, turned)
+    theta = cfg.rope_theta if rotary else None
+    q = _rope(q, positions, theta, scaling, turned)
+    k = _rope(k, positions, theta, scaling, turned)
     return q, k, v, gate
 
 
@@ -566,7 +571,7 @@ def ragged_cache_coords(pos: jax.Array, C: int):
 
 def cached_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
                      v_cache: jax.Array, positions: jax.Array,
-                     slot: jax.Array, valid: jax.Array):
+                     slot: jax.Array, valid: jax.Array, rotary: bool = True):
     """One cached-attention sublayer for ragged decode — the shared
     QKV/RoPE/cache-write/masked-softmax kernel both decoder families
     (llama dense MLP, moe expert FFN) build their decode steps on.
@@ -582,7 +587,7 @@ def cached_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
     rows = jnp.arange(B)
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q, k, v, gate = _qkv(cfg, layer, h, positions)
+    q, k, v, gate = _qkv(cfg, layer, h, positions, rotary)
     k_cache = k_cache.at[rows, slot].set(k[:, 0])
     v_cache = v_cache.at[rows, slot].set(v[:, 0])
 
@@ -899,11 +904,30 @@ def paged_write_step(pool: jax.Array, layer, kv: jax.Array,
     return pool.at[layer, write_page].set(pages)
 
 
+def paged_write_pages(pool: jax.Array, kv: jax.Array,
+                      page_ids: jax.Array) -> jax.Array:
+    """A prefill's ``kv`` [L, n·page, KV, Hd], token-major and whole
+    pages long, into pages ``page_ids`` [n] (real ids, no two alike) of
+    the whole pool [L, P, KV, page, Hd], by whole pages: each page is
+    written as the contiguous block it is in the kernel's order, so the
+    pool keeps its layout and is updated in place, where the token-wise
+    `paged_scatter` has all of it copied into the scatter's order and
+    back (the paged surface's comment)."""
+    L, T, KV, Hd = kv.shape
+    page = pool.shape[-2]
+    pages = kv.reshape(L, T // page, page, KV, Hd).swapaxes(2, 3)
+    return pool.at[:, page_ids].set(pages.astype(pool.dtype))
+
+
 def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
     if _window(cfg) is not None:
         raise ValueError(
-            "paged KV does not support sliding_window yet — the ring "
-            "buffer already bounds that cache; use kv='dense'")
+            "this family's paged cache keeps every layer's pages in one "
+            "space and its decode attends all of them: a sliding_window "
+            "in every layer is not served from it. A family that "
+            "declares window layers (`paged_window`, models/"
+            "smallthinker.py) is given a window space beside the full "
+            "one (serving/paged.py WindowedPagePool)")
     shape = paged_pool_shape(cfg, n_pages, page_size)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -911,14 +935,18 @@ def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
 def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
                     v_pool: jax.Array, layer_idx, positions: jax.Array,
                     write_page: jax.Array, write_off: jax.Array,
-                    tables: jax.Array, valid: jax.Array):
+                    tables: jax.Array, valid: jax.Array, *,
+                    window: Optional[int] = None, rotary: bool = True):
     """Paged analogue of ``cached_attn_step``: writes this step's K/V
     into each row's current page slot of layer ``layer_idx`` (int or
     traced scalar) of the whole pools [L, P, KV, page, Hd] and attends
     over the row's pages of that layer via its block table; returns the
     whole pools. ``tables`` [B, maxp] (-1 = not allocated, clamped to
     scratch page 0 for the gather), ``valid`` [B, 1, 1, maxp*page]
-    masks real positions."""
+    masks real positions. A window layer of a static layer plan says
+    so: ``window`` (the kernel's lower bound; ``valid`` and ``tables``
+    are then `paged_coords`' for that window and the window space's),
+    ``rotary`` (`_qkv`)."""
     from polyaxon_tpu.ops.attention import repeat_kv
 
     dt = cfg.dtype
@@ -927,7 +955,7 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
     n_rep = H // KV
 
     h = _norm(cfg, x, layer["attn_norm"])
-    q, k, v, gate = _qkv(cfg, layer, h, positions)
+    q, k, v, gate = _qkv(cfg, layer, h, positions, rotary)
     k_pool = paged_write_step(k_pool, layer_idx, k[:, 0], write_page,
                               write_off)
     v_pool = paged_write_step(v_pool, layer_idx, v[:, 0], write_page,
@@ -947,7 +975,7 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
         pos_vec = jnp.where(live, positions[:, 0], -1)
         attn = paged_decode_attention(
             q[:, 0].reshape(B, H, Hd), k_pool, v_pool, layer_idx, tables,
-            pos_vec).astype(dt)[:, None]
+            pos_vec, window=window).astype(dt)[:, None]
     else:
         gathered = jnp.maximum(tables, 0)  # [B, maxp] — scratch for holes
         keys = repeat_kv(paged_gather(k_pool[layer_idx], gathered), n_rep)
@@ -960,12 +988,14 @@ def paged_attn_step(cfg, layer: dict, x: jax.Array, k_pool: jax.Array,
     return _attn_out(cfg, layer, x, attn, gate), k_pool, v_pool
 
 
-def paged_coords(pos: jax.Array, tables: jax.Array, page: int):
+def paged_coords(pos: jax.Array, tables: jax.Array, page: int,
+                 window: Optional[int] = None):
     """Shared paged addressing: per-row positions [B] (-1 = idle) +
     block tables [B, maxp] → (positions [B,1] for RoPE, write_page [B],
     write_off [B], attention mask [B,1,1,maxp*page]). Idle/unallocated
     writes land on scratch page 0; the mask admits exactly positions
-    0..pos through allocated pages."""
+    0..pos through allocated pages, under a ``window`` the last that
+    many of them."""
     B, maxp = tables.shape
     pos_safe = jnp.maximum(pos, 0)
     rows = jnp.arange(B)
@@ -975,9 +1005,10 @@ def paged_coords(pos: jax.Array, tables: jax.Array, page: int):
     write_off = pos_safe % page
     j = jnp.arange(maxp * page)[None, :]  # global position per column
     allocated = jnp.repeat(tables >= 0, page, axis=1)  # [B, maxp*page]
-    valid = ((j <= pos_safe[:, None]) & (pos[:, None] >= 0)
-             & allocated)[:, None, None, :]
-    return pos_safe[:, None], write_page, write_off, valid
+    valid = (j <= pos_safe[:, None]) & (pos[:, None] >= 0) & allocated
+    if window is not None:
+        valid &= j > pos_safe[:, None] - window
+    return pos_safe[:, None], write_page, write_off, valid[:, None, None, :]
 
 
 def decode_step_paged(

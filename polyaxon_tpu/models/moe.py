@@ -214,12 +214,19 @@ def _router_aux_loss(cfg: MoEConfig, frac_tokens: jax.Array,
     return cfg.n_experts * jnp.sum(frac_tokens * frac_probs)
 
 
-def _expert_ffn(expert_in: jax.Array, w_gate, w_up, w_down, dt) -> jax.Array:
-    """Every expert's SwiGLU over its own buffer: [E, C, D] → [E, C, D],
-    weights read at the point of use (``_w``)."""
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
+def _expert_ffn(expert_in: jax.Array, w_gate, w_up, w_down, dt,
+                gate_act=jax.nn.silu) -> jax.Array:
+    """Every expert's gated MLP over its own buffer: [E, C, D] → [E, C,
+    D], weights read at the point of use (``_w``). SwiGLU as it stands;
+    with ``gate_act=jax.nn.relu`` ReGLU, ``W_down(relu(W_gate x) ⊙
+    W_up x)`` (`reglu_expert_ffn`)."""
+    gate = gate_act(jnp.einsum("ecd,edf->ecf", expert_in, _w(w_gate, dt)))
     up = jnp.einsum("ecd,edf->ecf", expert_in, _w(w_up, dt))
     return jnp.einsum("ecf,efd->ecd", gate * up, _w(w_down, dt))
+
+
+# `dense_dispatch`'s ``experts`` for a family whose experts are ReGLU.
+reglu_expert_ffn = functools.partial(_expert_ffn, gate_act=jax.nn.relu)
 
 
 def _moe_ragged_sharded(cfg: MoEConfig, x, router_w, w_gate, w_up, w_down,
@@ -469,7 +476,7 @@ def _grouped_kernel() -> bool:
 
 def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
                     w_gate, w_up, w_down, first: int, dt,
-                    layer: Optional[int] = None):
+                    layer: Optional[int] = None, gate_act=jax.nn.silu):
     """A dispatch that pays for the pairs it routes: the (token,
     choice) pairs sorted by expert, grouped matmuls over them, the
     weighted sum back by token. ``tokens`` [T, D], ``top_idx``/``top_w``
@@ -479,8 +486,10 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
     `dense_dispatch` takes: ``w_up`` [E, D, F], ``w_down`` [E, F, D]
     and ``w_gate`` [E, D, F] or None. With a gate stack an expert is
     the SwiGLU of `_expert_ffn`, ``W_down(silu(W_gate x) ⊙ W_up x)``
-    (three grouped matmuls); without one the ungated squared-ReLU MLP
-    of `relu2_expert_ffn`, ``W_down(relu(W_up x)²)`` (two). Pairs whose
+    (three grouped matmuls; ``gate_act`` is what stands where silu
+    does, ``jax.nn.relu`` for ReGLU experts); without one the ungated
+    squared-ReLU MLP of `relu2_expert_ffn`, ``W_down(relu(W_up x)²)``
+    (two). Pairs whose
     expert lies elsewhere sort behind the last group, belong to none
     and add nothing. No capacity: nothing is dropped, and nothing is
     computed for a slot no pair fills (where `dense_dispatch` at the
@@ -529,7 +538,7 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
     if w_gate is None:
         hidden = jnp.square(jax.nn.relu(grouped(rows, w_up)))
     else:
-        hidden = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        hidden = gate_act(grouped(rows, w_gate)) * grouped(rows, w_up)
     out = grouped(hidden, w_down)
     # Back in pair order; a row past the groups holds whatever the
     # grouped matmul left there, and is masked, not scaled.

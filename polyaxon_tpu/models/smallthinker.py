@@ -1,0 +1,532 @@
+"""SmallThinker-style decoder: full attention without positions and
+rotary sliding-window attention in one period, every layer followed by
+a block of softmax-routed ReGLU experts whose router reads the layer's
+input.
+
+The published ``smallthinker`` architecture (PowerInfer/SmallThinker-
+21BA3B-Instruct ``config.json``). With ``rms(x, g) = x / sqrt(mean(x²) +
+eps) · g``, layer ``l`` is::
+
+    h  = rms(x, attn_norm_l)
+    r  = h · W_r                       # router logits, before attention
+    q, k, v = h · W_q, h · W_k, h · W_v
+    if rope_layout[l]:  q, k = rope(q), rope(k)      # the whole head
+    a  = causal softmax(q·k / √Hd), keys within the last `sliding_window`
+         positions if window_layout[l]; GQA
+    x  = x + a · W_o
+    g  = rms(x, moe_norm_l)
+    x  = x + Σ_{e in top K of softmax(r), renormalised}
+             p_e · W_down,e(relu(W_gate,e g) ⊙ W_up,e g)
+
+and after the last layer ``rms(x, final_norm)`` and the untied head.
+The published layouts give a period of four, ``G W W W``: G is full
+causal attention with no position encoding, W rotary attention over the
+last 4,096 positions.
+
+- *Attention* is llama's walks (``_qkv``, ``_attn_out``,
+  ``paged_attn_step``, ``cached_attn_step``), told two things a layer
+  from the static plan (`layer_plan`): whether the rotary embedding
+  turns q and k, and the window.
+- *Experts* go through ``models/moe.py``: a decode step through the
+  one-hot buffers at the no-drop capacity (``dense_dispatch``), a
+  sequence through sorted pairs and grouped matmuls
+  (``sorted_dispatch``), both with ``relu`` on the gate stack and the
+  routing (`routing`) handed in, because its input is not the block's.
+  Every expert is held here.
+
+**The cache.** Two page spaces (``serving/paged.py WindowedPagePool``,
+which the engine builds for a family with `paged_window`): ``k``/``v``
+hold the full layers' pages ``[L_full, P, KV, page, Hd]``, every
+position of a row; ``window`` holds the window layers' ``[L_window,
+P_w, KV, page, Hd]``, the last ``sliding_window`` positions of a row
+and nothing older. A decode step is handed both block tables; in a
+window layer ``ops/paged_attention.py``'s kernel starts at the window's
+first page (a call named ``window_decode``). A prefill runs flash
+attention (``window=`` on the W layers) over the prompt padded to whole
+tiles and writes its K and V by whole pages (``llama.paged_write_pages``),
+the window layers' only the pages the row holds. ``moe_expert_tokens``
+``[L, E]`` counts the decode steps' (row, choice) pairs by expert.
+
+The pool matches no prefix for this family, and the engine's chunked
+prefill and speculation refuse a ``sliding_window`` as they do llama's.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+from polyaxon_tpu.models import llama, moe
+from polyaxon_tpu.models.common import (
+    Batch,
+    ModelDef,
+    Variables,
+    _embed_rows,
+    chunked_lm_loss,
+    lm_logits,
+    scaled_init,
+    shift_right,
+    truncated_normal_init,
+)
+from polyaxon_tpu.models.lfm2 import (  # noqa: F401  (re-exported hook)
+    _at,
+    insert_cache_row,
+)
+from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
+    cb_admission,
+    cb_validate,
+)
+from polyaxon_tpu.ops.attention import dot_product_attention
+
+SEQ2SEQ = False
+# A prefill's sequence is padded to a multiple of this: the flash
+# kernel tiles a sequence into blocks of at least 128 and gives way to
+# the einsum reference (a [S, S] score matrix a head) where it cannot.
+PREFILL_TILE = 256
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig:
+    vocab_size: int = 151_936
+    dim: int = 2560
+    n_layers: int = 52
+    n_heads: int = 28
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1_500_000.0
+    # Per layer, 1 where the rotary embedding turns q and k / where the
+    # keys are those of the last `sliding_window` positions. None: the
+    # published period of four, 0 1 1 1, for both.
+    rope_layout: Optional[tuple] = None
+    window_layout: Optional[tuple] = None
+    sliding_window: int = 4096
+    n_experts: int = 64
+    experts_per_token: int = 6
+    moe_ffn_dim: int = 768
+    norm_eps: float = 1e-6
+    max_seq_len: int = 16_384
+    dtype: Any = jnp.bfloat16
+    attention_impl: str = "auto"  # the sequence passes': as LlamaConfig's
+    paged_attention_impl: str = "auto"  # as LlamaConfig's
+    loss_chunk: int = 256
+    lm_logits_chunk: int = 4096
+
+    def __post_init__(self):
+        for name in ("rope_layout", "window_layout"):
+            layout = getattr(self, name)
+            if layout is None:
+                layout = tuple(int(i % 4 != 0) for i in range(self.n_layers))
+            if len(layout) != self.n_layers:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.n_layers} layers")
+            object.__setattr__(self, name, tuple(int(v) for v in layout))
+        if len(set(self.window_layout)) != 2:
+            raise ValueError(
+                "a smallthinker model has window and full layers side by "
+                "side; a window in every layer or in none is llama's")
+        if self.sliding_window < 1:
+            raise ValueError("sliding_window must be at least 1")
+
+
+CONFIGS: dict[str, SmallThinkerConfig] = {
+    "smallthinker_21b_a3b": SmallThinkerConfig(),
+    "smallthinker_tiny": SmallThinkerConfig(
+        vocab_size=256, dim=64, n_layers=4, n_heads=4, n_kv_heads=2,
+        head_dim=16, sliding_window=32, n_experts=8, experts_per_token=2,
+        moe_ffn_dim=32, max_seq_len=128),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _plan(rope_layout: tuple, window_layout: tuple) -> tuple:
+    seen = {"full": 0, "window": 0}
+    out = []
+    for rotary, windowed in zip(rope_layout, window_layout):
+        kind = "window" if windowed else "full"
+        out.append((kind, seen[kind], bool(rotary)))
+        seen[kind] += 1
+    return tuple(out)
+
+
+def layer_plan(cfg: SmallThinkerConfig) -> tuple:
+    """Per layer, in published order: (its attention's kind, ``full`` or
+    ``window``; its index among that kind's layers, which is its layer
+    of that kind's page pool; whether the rotary embedding turns its q
+    and k). The parameters are stacked over every layer."""
+    return _plan(cfg.rope_layout, cfg.window_layout)
+
+
+def kind_counts(cfg: SmallThinkerConfig) -> dict:
+    kinds = [kind for kind, _, _ in layer_plan(cfg)]
+    return {"full": kinds.count("full"), "window": kinds.count("window")}
+
+
+def _layer_window(cfg: SmallThinkerConfig, kind: str) -> Optional[int]:
+    return cfg.sliding_window if kind == "window" else None
+
+
+def init(cfg: SmallThinkerConfig, rng: jax.Array) -> Variables:
+    """Seeded float32 weights as the zoo draws them (truncated normal,
+    1/sqrt(fan_in); the tables std 0.02), norm gains at ones."""
+    keys = jax.random.split(rng, 10)
+    L, D, H, KV, Hd = (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.n_kv_heads,
+                       cfg.head_dim)
+    E, F = cfg.n_experts, cfg.moe_ffn_dim
+    params = {
+        "embed": truncated_normal_init(keys[0], (cfg.vocab_size, D)),
+        "attn": {
+            "attn_norm": jnp.ones((L, D)),
+            "router": scaled_init(keys[1], (L, D, E), fan_in=D),
+            "wq": scaled_init(keys[2], (L, D, H * Hd), fan_in=D),
+            "wk": scaled_init(keys[3], (L, D, KV * Hd), fan_in=D),
+            "wv": scaled_init(keys[4], (L, D, KV * Hd), fan_in=D),
+            "wo": scaled_init(keys[5], (L, H * Hd, D), fan_in=H * Hd),
+        },
+        "moe": {
+            "moe_norm": jnp.ones((L, D)),
+            "w_gate": scaled_init(keys[6], (L, E, D, F), fan_in=D),
+            "w_up": scaled_init(keys[7], (L, E, D, F), fan_in=D),
+            "w_down": scaled_init(keys[8], (L, E, F, D), fan_in=F),
+        },
+        "final_norm": jnp.ones((D,)),
+        "lm_head": truncated_normal_init(keys[9], (D, cfg.vocab_size)),
+    }
+    return {"params": params, "state": {}}
+
+
+def logical_axes(cfg: SmallThinkerConfig) -> Variables:
+    del cfg
+    return {
+        "params": {
+            "embed": ("vocab", "embed"),
+            "attn": {
+                "attn_norm": ("layers", "embed"),
+                "router": ("layers", "embed", None),
+                "wq": ("layers", "embed", "heads"),
+                "wk": ("layers", "embed", "kv_heads"),
+                "wv": ("layers", "embed", "kv_heads"),
+                "wo": ("layers", "heads", "embed"),
+            },
+            "moe": {
+                "moe_norm": ("layers", "embed"),
+                "w_gate": ("layers", "expert", "embed", "mlp"),
+                "w_up": ("layers", "expert", "embed", "mlp"),
+                "w_down": ("layers", "expert", "mlp", "embed"),
+            },
+            "final_norm": ("embed",),
+            "lm_head": ("embed", "vocab"),
+        },
+        "state": {},
+    }
+
+
+# Leaves read at float32: the norm gains, and the router (its scores
+# decide a top-k, so that matmul is float32 at full precision, as the
+# other routed families'). The rest are read at ``cfg.dtype`` and a
+# server holds them so (``common.served_params``).
+READ_AT_FLOAT32 = frozenset({"attn_norm", "moe_norm", "final_norm", "router"})
+
+
+# ------------------------------------------------------------ the layers
+def routing(cfg: SmallThinkerConfig, layer: dict, h: jax.Array) -> tuple:
+    """(chosen experts [T, K], their weights [T, K]) for the layer's
+    normed input ``h`` [..., D], T its positions: the softmax over every
+    expert's logit, the K largest, renormalised (``moe.route``). The
+    scores decide a top-k, where a rounding flips an expert: the
+    router's own matmul runs in float32 at full precision."""
+    logits = jnp.dot(h.reshape(-1, h.shape[-1]).astype(jnp.float32),
+                     layer["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_idx, top_w, _ = moe.route(cfg, logits)
+    return top_idx, top_w
+
+
+def expert_block(cfg: SmallThinkerConfig, stack: dict, i: int, x: jax.Array,
+                 routed: tuple):
+    """Layer ``i``'s expert residual over ``x`` [B, S, D] under the
+    routing the layer's input gave (`routing`), its B·S tokens one
+    dispatch group; nothing is dropped. A single position a row (a
+    decode step) goes through the one-hot buffers, a sequence through
+    sorted pairs. Returns (x after the residual, the choices' one-hot
+    [B·S, K, E] or None)."""
+    dt = cfg.dtype
+    B, S, D = x.shape
+    tokens = llama._norm(cfg, x, stack["moe_norm"][i]).reshape(B * S, D)
+    top_idx, top_w = routed
+    if S > 1:
+        out, onehot = moe.sorted_dispatch(
+            tokens, top_idx, top_w, stack["w_gate"], stack["w_up"],
+            stack["w_down"], 0, dt, layer=i, gate_act=jax.nn.relu), None
+    else:
+        out, onehot = moe.dense_dispatch(
+            tokens, top_idx, top_w, stack["w_gate"][i], stack["w_up"][i],
+            stack["w_down"][i], B * S, dt, experts=moe.reglu_expert_ffn)
+    return x + out.reshape(B, S, D), onehot
+
+
+def _head(cfg: SmallThinkerConfig, params: dict, x: jax.Array) -> jax.Array:
+    """Final norm and the untied head: hidden [..., D] → fp32 logits."""
+    x = llama._norm(cfg, x, params["final_norm"])
+    return lm_logits(x, params["lm_head"], cfg.dtype,
+                     chunk=cfg.lm_logits_chunk)
+
+
+def _sequence_pass(cfg: SmallThinkerConfig, params: dict, tokens: jax.Array):
+    """One causal pass over ``tokens`` [B, S] at positions 0..S−1:
+    (hidden before the final norm [B, S, D], every layer's k and v
+    [B, S, KV, Hd], in layer order)."""
+    B, S = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+    x = _embed_rows(params["embed"], tokens, cfg.dtype)
+    ks, vs = [], []
+    for layer, (kind, _, rotary) in enumerate(layer_plan(cfg)):
+        weights = _at(params["attn"], layer)
+        h = llama._norm(cfg, x, weights["attn_norm"])
+        routed = routing(cfg, weights, h)
+        with jax.named_scope(kind + "_attention"):
+            q, k, v, gate = llama._qkv(cfg, weights, h, positions, rotary)
+            attn = dot_product_attention(
+                q, k, v, causal=True, impl=cfg.attention_impl,
+                window=_layer_window(cfg, kind))
+            x = llama._attn_out(cfg, weights, x, attn, gate)
+        x, _ = expert_block(cfg, params["moe"], layer, x, routed)
+        ks.append(k)
+        vs.append(v)
+    return x, ks, vs
+
+
+def forward(cfg: SmallThinkerConfig, params: dict,
+            tokens: jax.Array) -> jax.Array:
+    """Token ids [B, S] → logits [B, S, vocab] fp32."""
+    x, _, _ = _sequence_pass(cfg, params, tokens)
+    return _head(cfg, params, x)
+
+
+# ------------------------------------------------------- dense slot cache
+def init_cache(cfg: SmallThinkerConfig, batch: int, max_len: int) -> dict:
+    """The slot cache: K/V [L, B, C, KV, Hd], every layer at the full
+    length (slot == position; a window layer masks what lies behind its
+    window and keeps it)."""
+    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def prefill(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
+            max_len: int):
+    """One pass over the prompt [B, P]: (last-position logits [B, V]
+    fp32, the slot cache holding it)."""
+    P = prompt.shape[1]
+    if P > max_len:
+        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
+    x, ks, vs = _sequence_pass(cfg, params, prompt)
+    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
+    cache = {"k": jnp.pad(jnp.stack(ks), pad), "v": jnp.pad(jnp.stack(vs), pad)}
+    return _head(cfg, params, x[:, -1]), cache
+
+
+def _decode_layers(cfg: SmallThinkerConfig, params: dict, x: jax.Array,
+                   pos: jax.Array, attend, counts=None):
+    """One position a row ([B, 1, D]) through every layer.
+    ``attend(layer, kind, i, rotary, weights, x)`` is the attention
+    layer over the cache in use (``i``: the layer's index among its
+    kind's). Live rows' routed pairs are added to ``counts``
+    [L, E] where given."""
+    live = (pos >= 0).astype(jnp.int32)
+    for layer, (kind, i, rotary) in enumerate(layer_plan(cfg)):
+        weights = _at(params["attn"], layer)
+        routed = routing(cfg, weights,
+                         llama._norm(cfg, x, weights["attn_norm"]))
+        with jax.named_scope(kind + "_attention"):
+            x = attend(layer, kind, i, rotary, weights, x)
+        x, onehot = expert_block(cfg, params["moe"], layer, x, routed)
+        if counts is not None:
+            counts = counts.at[layer].add(
+                jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live))
+    return x, counts
+
+
+def decode_step_ragged(cfg: SmallThinkerConfig, params: dict, cache: dict,
+                       tokens: jax.Array, pos: jax.Array):
+    """One step with per-row positions ([B], −1 = idle) over the slot
+    cache: llama's ``cached_attn_step``, a window layer's mask cut to
+    its window."""
+    C = cache["k"].shape[2]
+    positions, slot, valid = llama.ragged_cache_coords(pos, C)
+    behind = jnp.arange(C)[None, :] <= (positions - cfg.sliding_window)
+    valid_window = valid & ~behind[:, None, None, :]
+    kv = {"k": cache["k"], "v": cache["v"]}
+
+    def attend(layer, kind, _, rotary, weights, x):
+        x, k, v = llama.cached_attn_step(
+            cfg, weights, x, kv["k"][layer], kv["v"][layer], positions, slot,
+            valid_window if kind == "window" else valid, rotary)
+        kv["k"] = kv["k"].at[layer].set(k)
+        kv["v"] = kv["v"].at[layer].set(v)
+        return x
+
+    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
+    x, _ = _decode_layers(cfg, params, x, pos, attend)
+    return _head(cfg, params, x[:, 0]), kv
+
+
+def decode_step(cfg: SmallThinkerConfig, params: dict, cache: dict,
+                tokens: jax.Array, pos: jax.Array):
+    """Scalar-position decode: every row at the same position."""
+    return decode_step_ragged(
+        cfg, params, cache, tokens,
+        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
+
+
+def generate(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
+             **sampling):
+    """Greedy or sampled continuation [B, max_new]: llama's
+    ``generate_loop`` over this family's prefill and decode step."""
+    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
+                               **sampling)
+
+
+def cb_init_cache(cfg: SmallThinkerConfig, slots: int, max_len: int) -> dict:
+    return init_cache(cfg, slots, max_len)
+
+
+def cb_prefill(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
+               max_len: int) -> dict:
+    return prefill(cfg, params, prompt, max_len)[1]
+
+
+# ------------------------------------------------------------ paged cache
+def paged_window(cfg: SmallThinkerConfig) -> int:
+    """The window of this family's window layers: what tells the engine
+    to build the pool with a window space (``serving/paged.py
+    WindowedPagePool``) and to hand `paged_init_cache` its size and
+    `decode_step_paged` both block tables."""
+    return cfg.sliding_window
+
+
+def paged_init_cache(cfg: SmallThinkerConfig, n_pages: int, page_size: int,
+                     window_pages: int) -> dict:
+    """The two page spaces (module docstring) and the decode steps'
+    routed pairs by expert."""
+    n = kind_counts(cfg)
+
+    def pool(layers, pages):
+        return jnp.zeros((layers, pages, cfg.n_kv_heads, page_size,
+                          cfg.head_dim), cfg.dtype)
+
+    return {"k": pool(n["full"], n_pages), "v": pool(n["full"], n_pages),
+            "window": {"k": pool(n["window"], window_pages),
+                       "v": pool(n["window"], window_pages)},
+            "moe_expert_tokens": jnp.zeros((cfg.n_layers, cfg.n_experts),
+                                           jnp.int32)}
+
+
+def decode_step_paged(cfg: SmallThinkerConfig, params: dict, cache: dict,
+                      tokens: jax.Array, pos: jax.Array, tables: tuple):
+    """`decode_step_ragged` over the two page spaces: ``tables`` is (the
+    full space's block tables, the window space's), [B, maxp] each and
+    indexed by the same logical page; a window layer writes and reads
+    through the second, from its window's first page on."""
+    page = cache["k"].shape[-2]
+    window = cfg.sliding_window
+    coords = {
+        "full": (tables[0], *llama.paged_coords(pos, tables[0], page)),
+        "window": (tables[1],
+                   *llama.paged_coords(pos, tables[1], page, window))}
+    pools = {"full": [cache["k"], cache["v"]],
+             "window": [cache["window"]["k"], cache["window"]["v"]]}
+
+    def attend(_, kind, i, rotary, weights, x):
+        table, positions, write_page, write_off, valid = coords[kind]
+        x, *pools[kind] = llama.paged_attn_step(
+            cfg, weights, x, *pools[kind], i, positions, write_page,
+            write_off, table, valid, window=_layer_window(cfg, kind),
+            rotary=rotary)
+        return x
+
+    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
+    x, counts = _decode_layers(cfg, params, x, pos, attend,
+                               cache["moe_expert_tokens"])
+    return _head(cfg, params, x[:, 0]), {
+        "k": pools["full"][0], "v": pools["full"][1],
+        "window": {"k": pools["window"][0], "v": pools["window"][1]},
+        "moe_expert_tokens": counts}
+
+
+def paged_prefill_kv(cfg: SmallThinkerConfig, params: dict,
+                     prompt: jax.Array):
+    """The prompt pass for one row [1, P], padded to whole flash tiles
+    (causal: what lies behind the prompt changes nothing in it): (the
+    full layers' k [L_full, P, KV, Hd], their v, the window layers' k
+    [L_window, P, KV, Hd], their v, the window), the last a plain
+    number for `paged_insert_prefill`, which is handed no config."""
+    P = prompt.shape[1]
+    padded = jnp.pad(prompt, ((0, 0), (0, -P % PREFILL_TILE)))
+    _, ks, vs = _sequence_pass(cfg, params, padded)
+
+    def of(kind, leaves):
+        return jnp.stack([leaf[0, :P] for leaf, (k, _, _)
+                          in zip(leaves, layer_plan(cfg)) if k == kind])
+
+    return (of("full", ks), of("full", vs), of("window", ks),
+            of("window", vs), cfg.sliding_window)
+
+
+def paged_insert_prefill(cache: dict, k_full: jax.Array, v_full: jax.Array,
+                         k_window: jax.Array, v_window: jax.Array,
+                         window: int, page_ids: jax.Array,
+                         page_size: int) -> dict:
+    """A prefilled row's K and V into its pages, by whole pages.
+    ``page_ids`` [2, maxp] are the row's two block-table rows
+    (``WindowedPagePool.padded_row``). The full layers take every page
+    of the prompt; the window layers the pages the row was admitted
+    with, the last ``window // page_size + 1`` of the prompt and its
+    first decode position (``WindowedPagePool._window_span``), and
+    nothing of what lies before them."""
+    P = k_full.shape[1]
+    n = -(-P // page_size)                      # pages the prompt reaches
+    held = -(-(P + 1) // page_size)             # with the first decode position
+    first = max(0, held - (window // page_size + 1))
+    tail = ((0, 0), (0, n * page_size - P), (0, 0), (0, 0))
+
+    def put(pool, kv, ids, lo):
+        kv = jnp.pad(kv[:, lo * page_size:], tail)
+        return llama.paged_write_pages(pool, kv, jnp.maximum(ids[lo:n], 0))
+
+    return {**cache,
+            "k": put(cache["k"], k_full, page_ids[0], 0),
+            "v": put(cache["v"], v_full, page_ids[0], 0),
+            "window": {
+                "k": put(cache["window"]["k"], k_window, page_ids[1], first),
+                "v": put(cache["window"]["v"], v_window, page_ids[1], first)}}
+
+
+# --------------------------------------------------------------- training
+def apply(cfg: SmallThinkerConfig, variables: Variables, batch: Batch,
+          train: bool = True, rng: Optional[jax.Array] = None):
+    """Next-token loss (chunked head), no auxiliary loss."""
+    tokens = batch["tokens"]
+    if batch.get("segments") is not None:
+        raise ValueError("smallthinker models do not support packed "
+                         "sequences (segments)")
+    params = variables["params"]
+    x, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
+    x = llama._norm(cfg, x, params["final_norm"])
+    loss, acc = chunked_lm_loss(x, params["lm_head"].astype(cfg.dtype),
+                                tokens, batch.get("mask"),
+                                chunk=cfg.loss_chunk)
+    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
+
+
+def model_def(name: str, **overrides) -> ModelDef:
+    cfg = dataclasses.replace(CONFIGS[name], **overrides)
+    return ModelDef(
+        name=name,
+        init=functools.partial(init, cfg),
+        apply=functools.partial(apply, cfg),
+        logical_axes=functools.partial(logical_axes, cfg),
+        unit="tokens",
+    )

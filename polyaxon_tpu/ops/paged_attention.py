@@ -98,9 +98,11 @@ def _rem(x, n: int):
     return jax.lax.rem(x, jnp.int32(n))
 
 
-def _live_columns(tables_ref, b, first, pos, *, page: int, group: int):
+def _live_columns(tables_ref, b, first, pos, *, page: int, group: int,
+                  window=None):
     """[1, 1, G·page] mask of the step's columns that count: at or
-    before the row's position, in a page that is allocated (a hole, or
+    before the row's position (and, under a ``window``, less than that
+    many positions before it), in a page that is allocated (a hole, or
     an entry past the table's width, reads -1). ``first`` is the step's
     first page of the row."""
     maxp = tables_ref.shape[1]
@@ -111,7 +113,10 @@ def _live_columns(tables_ref, b, first, pos, *, page: int, group: int):
         p = first + i
         t = jnp.where(p < maxp, tables_ref[b, jnp.minimum(p, maxp - 1)], -1)
         entry = jnp.where(slot == i, t, entry)
-    return (first * page + col <= pos) & (entry >= 0)
+    live = (first * page + col <= pos) & (entry >= 0)
+    if window is not None:
+        live &= first * page + col > pos - window
+    return live
 
 
 def _accumulate(q, k, v, mask, acc_ref, m_ref, l_ref, *, scale: float):
@@ -166,16 +171,23 @@ def _streamed_kernel(
     scale: float,
     page: int,
     group: int,
+    window=None,
 ):
     """One row a grid step; a loop over the row's live pages, G a turn,
     each page copied from the pool by its own DMA into the buffer the
-    next turn computes on. An idle row starts no copy and no turn."""
+    next turn computes on. An idle row starts no copy and no turn.
+    Under a ``window`` the loop starts at the turn that holds the
+    window's first page, and no page before that one is copied."""
     b = pl.program_id(0)
     maxp = tables_ref.shape[1]
     pos = pos_ref[b]
     layer = layer_ref[0]
     n_pages = jnp.minimum(_quot(pos + page, page), maxp)  # 0 when idle
     n_turns = _quot(n_pages + group - 1, group)
+    first_page = first_turn = 0
+    if window is not None:
+        first_page = _quot(jnp.maximum(pos - (window - 1), 0), page)
+        first_turn = _quot(first_page, group)
 
     def each_live_copy(turn, buf, act):
         """`act` ("start" or "wait") on the K and the V copy of each of
@@ -193,7 +205,11 @@ def _streamed_kernel(
                     v_hbm.at[layer, pid], v_buf.at[buf, :, rows],
                     sem.at[1, buf, i]))
 
-            @pl.when(p < n_pages)
+            held = p < n_pages
+            if window is not None:
+                held &= p >= first_page
+
+            @pl.when(held)
             def _act():
                 for copy in copies:
                     getattr(copy, act)()
@@ -209,7 +225,7 @@ def _streamed_kernel(
 
     @pl.when(n_turns > 0)
     def _first():
-        each_live_copy(0, 0, "start")
+        each_live_copy(first_turn, _rem(first_turn, 2), "start")
 
     def turn_body(turn, carry):
         buf = _rem(turn, 2)
@@ -220,12 +236,12 @@ def _streamed_kernel(
 
         each_live_copy(turn, buf, "wait")
         mask = _live_columns(tables_ref, b, turn * group, pos,
-                             page=page, group=group)
+                             page=page, group=group, window=window)
         _accumulate(q_ref[0], k_buf[buf], v_buf[buf], mask,
                     acc_ref, m_ref, l_ref, scale=scale)
         return carry
 
-    jax.lax.fori_loop(0, n_turns, turn_body, None)
+    jax.lax.fori_loop(first_turn, n_turns, turn_body, None)
     _write_out(o_ref, acc_ref, l_ref)
 
 
@@ -240,10 +256,14 @@ def _pipelined_kernel(
     scale: float,
     page: int,
     group: int,
+    window=None,
 ):
     """Grid (rows, table width / G): the BlockSpec pipeline brings a
     step's G pages; a step past the row's length computes nothing and,
-    by the index maps, fetches nothing."""
+    by the index maps, fetches nothing. Under a ``window`` a step whose
+    pages all lie behind it computes nothing either (its pages are
+    still fetched: the streamed form is the one a window is served
+    by)."""
     k_refs, v_refs = refs[:group], refs[group:2 * group]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * group:]
     b, j = pl.program_id(0), pl.program_id(1)
@@ -254,13 +274,17 @@ def _pipelined_kernel(
     def _init():
         _reset(acc_ref, m_ref, l_ref)
 
-    @pl.when((pos >= 0) & (first * page <= pos))
+    reached = (pos >= 0) & (first * page <= pos)
+    if window is not None:
+        reached &= (first + group) * page > pos - (window - 1)
+
+    @pl.when(reached)
     def _compute():
         def pages(page_refs):  # [KV, G·page, Hd]
             return jnp.concatenate([r[0] for r in page_refs], axis=1)
 
         mask = _live_columns(tables_ref, b, first, pos,
-                             page=page, group=group)
+                             page=page, group=group, window=window)
         _accumulate(q_ref[0], pages(k_refs), pages(v_refs), mask,
                     acc_ref, m_ref, l_ref, scale=scale)
 
@@ -277,11 +301,20 @@ def paged_decode_attention(
     tables: jax.Array,  # [B, maxp] int32 (-1 = unallocated)
     pos: jax.Array,  # [B] int32 (-1 = idle row → zeros out)
     *,
+    window: int | None = None,  # attend the last `window` positions only
     interpret: bool | None = None,  # None = interpret on the CPU backend
 ) -> jax.Array:
     """Attention of each row's query against its pages of layer
     ``layer`` (positions 0..pos inclusive — the current step's K/V must
     already be written to the pool). Returns [B, H, Hd].
+
+    With ``window`` (static) a row attends positions ``pos - window +
+    1 .. pos``: the streamed loop starts at the window's first page, so
+    a table whose entries before it were released (-1;
+    ``serving/paged.py WindowedPagePool``) is never read there. Such a
+    call is named ``window_decode`` and not ``paged_decode``: a reader
+    of the device trace that counts a row's whole length for every call
+    of the latter name would count too much for this one.
 
     The pools come stacked over the layers and the layer as a
     prefetched scalar, so the program around the call never slices a
@@ -300,14 +333,15 @@ def paged_decode_attention(
     heads = P(None, head_axis, None)
     pool = P(None, None, head_axis, None, None)
     return compat.shard_kernel(
-        functools.partial(_paged_decode, interpret=interpret),
+        functools.partial(_paged_decode, window=window, interpret=interpret),
         in_specs=(heads, pool, pool, P(), P(), P()),
         out_specs=heads,
     )(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32).reshape(1),
       tables.astype(jnp.int32), pos.astype(jnp.int32))
 
 
-def _paged_decode(q, k_pool, v_pool, layer, tables, pos, *, interpret: bool):
+def _paged_decode(q, k_pool, v_pool, layer, tables, pos, *, window,
+                  interpret: bool):
     B, H, Hd = q.shape
     _, _, KV, page, _ = k_pool.shape
     maxp = tables.shape[1]
@@ -369,7 +403,8 @@ def _paged_decode(q, k_pool, v_pool, layer, tables, pos, *, interpret: bool):
         compiler_params = pltpu.CompilerParams(dimension_semantics=(
             ("arbitrary",) if streamed else ("parallel", "arbitrary")))
     out = pl.pallas_call(
-        functools.partial(kernel, scale=Hd ** -0.5, page=page, group=group),
+        functools.partial(kernel, scale=Hd ** -0.5, page=page, group=group,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -380,6 +415,6 @@ def _paged_decode(q, k_pool, v_pool, layer, tables, pos, *, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, Hd), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "window_decode",
     )(tables, pos, layer, q.reshape(B, KV, rep, Hd), *pools)
     return out.reshape(B, H, Hd)

@@ -137,8 +137,12 @@ class _PhaseClock:
     SLOW_KEPT = 16
     CPU_EVERY = 8
 
-    def __init__(self, snapshot):
+    def __init__(self, snapshot, more_leaves: tuple = ()):
         self._snapshot = snapshot
+        # An engine whose pool has a window space books its roll under a
+        # leaf of its own (`step.window`); no other engine has the key.
+        if more_leaves:
+            self.LEAVES = self.LEAVES + more_leaves
         # Every key is there from the start: readers on other threads
         # copy these dicts while the engine thread adds to their values.
         self.total_ns = dict.fromkeys(self.LEAVES, 0)
@@ -575,7 +579,21 @@ class ContinuousBatchingEngine:
         # a row move inside the same pool.
         self.prefill_slots = int(prefill_slots or 0)
         n_rows = slots + self.prefill_slots
-        if kv == "paged":
+        # A family with window layers (`paged_window`) is given a pool
+        # with a window space beside the full one. Decided here, once:
+        # with `_window_tables` None every line below runs as it did.
+        self._window_tables: Optional[np.ndarray] = None
+        window = getattr(family, "paged_window", None)
+        if kv == "paged" and window is not None:
+            from polyaxon_tpu.serving.paged import WindowedPagePool
+
+            maxp = -(-self.max_len // page_size)
+            self._pool = WindowedPagePool(
+                n_rows, self.max_len, page_size,
+                (n_rows * maxp if kv_pages is None else kv_pages) + 1,
+                window=window(cfg))
+            self._window_tables = self._pool.window_tables
+        elif kv == "paged":
             from polyaxon_tpu.serving.paged import PagePool
 
             if kv_pages is None:
@@ -616,6 +634,10 @@ class ContinuousBatchingEngine:
             self._pool.whole_page_matches = self._page_bytes[1] > 0
             if self._page_bytes[2]:
                 self._pool.match_nothing()
+            if self._window_tables is not None:
+                from polyaxon_tpu.serving.paged import window_page_bytes
+
+                self._window_page_bytes = window_page_bytes(self._cache)
         # A family may keep, with its cache, the (row, choice) pairs its
         # decode steps routed to each expert. The engine thread reads it
         # between ticks, when `stats()` has asked (`_serve_expert_tokens`).
@@ -789,7 +811,9 @@ class ContinuousBatchingEngine:
         self._queue_depth_peak = 0
         # Host time of the loop by phase (always on): `/v1/stats`
         # `tick_phase_ns`, the `engine:` spans of a profile, `slow_ticks`.
-        self._clock = _PhaseClock(self._tick_snapshot)
+        self._clock = _PhaseClock(
+            self._tick_snapshot,
+            ("step.window",) if self._window_tables is not None else ())
         self._phase = self._clock.phase
         self._admissions = 0
         # A device that throws persistently (e.g. OOM) would otherwise
@@ -1636,7 +1660,10 @@ class ContinuousBatchingEngine:
                         "prefill", mode="monolithic",
                         prompt_tokens=len(prefill_tokens),
                         state_pages_written=self._state_pages(
-                            0, len(prefill_tokens)))
+                            0, len(prefill_tokens)),
+                        **({"window_pages": int(np.count_nonzero(
+                            self._window_tables[b] >= 0))}
+                           if self._window_tables is not None else {}))
                 row = jnp.asarray([prefill_tokens], jnp.int32)
                 fn = self._compiled_prefill(len(prefill_tokens))
                 if self._pool is not None:
@@ -2086,7 +2113,21 @@ class ContinuousBatchingEngine:
                 "kv_invariant_violations": len(
                     self._pool.check_invariants())}
                if self._pool is not None else {}),
+            **(self._window_stats()
+               if self._window_tables is not None else {}),
         }
+
+    def _window_stats(self) -> dict:
+        """The window space of a `WindowedPagePool` (`kv_pages_*` are
+        the full space's, which is the one admission waits for)."""
+        seen = self._pool.window_stats()
+        return {"kv_window": self._pool.window,
+                "kv_window_pages_total": seen["total"],
+                "kv_window_pages_free": seen["free"],
+                "kv_window_pages_live": seen["live"],
+                "kv_window_pages_released": seen["released"],
+                "kv_window_row_pages_max": seen["row_max"],
+                "kv_window_page_bytes": self._window_page_bytes}
 
     def _go_live(self, b: int, req: _Request, pos0: int, tok0: int) -> None:
         """Mark a slot live for decode — the ONE place slot state is
@@ -2201,9 +2242,13 @@ class ContinuousBatchingEngine:
         if self._pool is not None:
             rows = self.slots + self.prefill_slots
 
+            spaces = (self._pool.n_pages,)
+            if self._window_tables is not None:
+                spaces += (self._pool.window_n_pages,)
+
             def build():
                 cache = family.paged_init_cache(
-                    cfg, self._pool.n_pages, self._pool.page_size)
+                    cfg, spaces[0], self._pool.page_size, *spaces[1:])
                 if hasattr(family, "paged_init_rows"):
                     # What a sequence carries whatever its length, by
                     # the engine's row (lane rows behind the slots).
@@ -2825,6 +2870,9 @@ class ContinuousBatchingEngine:
                 self._paged_pages_live += int(
                     (live // self._pool.page_size + 1).sum())
                 self._paged_pages_table += tables.size
+                if self._window_tables is not None:
+                    tables = (tables, jnp.asarray(
+                        self._window_tables[:self.slots].copy()))
             counts = jnp.asarray(counts)
             tokens, fresh = self._tok_dev, self._no_fresh
             if tokens is None:
@@ -2934,4 +2982,9 @@ class ContinuousBatchingEngine:
                     f"(pos {int(self._pos[b])}); raise --kv-pages "
                     "or lower concurrency")
                 self._retire(b)
+        if self._window_tables is not None:
+            with self._phase("step.window"):
+                for b, req, _ in rows:
+                    if self._slot_req[b] is req:
+                        self._pool.roll(b, int(self._pos[b]))
         return True

@@ -263,10 +263,13 @@ def page_bytes(cache: dict, n_pages: int, page_size: int) -> tuple:
     shape one fixed-size state a page. What a sequence carries whatever
     its length (a recurrent state too large to keep a page) lies under
     ``rows``, leaves ``[L, rows, ...]`` indexed by the engine's row. A
-    leaf without a page axis (a counter) is no page's content."""
+    leaf without a page axis (a counter) is no page's content, and the
+    window layers' pages (``window``) are a space of their own
+    (`window_page_bytes`)."""
     tokens = state = 0
     for name, leaf in cache.items():
-        if name == "rows" or leaf.ndim < 3 or leaf.shape[1] != n_pages:
+        if (name in ("rows", "window") or leaf.ndim < 3
+                or leaf.shape[1] != n_pages):
             continue
         per_page = leaf.size * leaf.dtype.itemsize // n_pages
         if leaf.ndim == 5 and leaf.shape[3] == page_size:
@@ -276,6 +279,14 @@ def page_bytes(cache: dict, n_pages: int, page_size: int) -> tuple:
     row = sum(leaf.size * leaf.dtype.itemsize // leaf.shape[1]
               for leaf in cache.get("rows", {}).values())
     return tokens, state, row
+
+
+def window_page_bytes(cache: dict) -> int:
+    """Bytes one page id of the window space holds, over the window
+    layers: the leaves under ``cache["window"]``, ``[L_w, P_w, KV,
+    page_size, Hd]`` each; 0 for a cache without such layers."""
+    return sum(leaf.size * leaf.dtype.itemsize // leaf.shape[1]
+               for leaf in cache.get("window", {}).values())
 
 
 class PagePool:
@@ -656,3 +667,164 @@ class PagePool:
         row has moved on (a handoff, the next page), and `jnp.asarray`
         of a view can alias the table itself on the CPU backend."""
         return self.tables[slot].copy()
+
+
+class WindowedPagePool(PagePool):
+    """A pool for a model whose layers are of two kinds: *full* layers
+    keep every position of a row, *window* layers attend the last
+    ``window`` positions and nothing older. Each kind has a page space
+    of its own (its own device leaves, free list and block table), so a
+    row holds two chains of pages with different lives.
+
+    The full space is the base class's, as it is. The window space is a
+    table as wide as the full one, indexed by the same logical page
+    ``position // page_size``, **whose head is released**: a row holds
+    at most ``window // page_size + 1`` window pages, the last ones of
+    its chain (at a position not on a page boundary the window reaches
+    one token into that many pages), every entry before them is -1
+    again, and its page is on the free list from the moment `roll`
+    moved past it. A logical page is then found by its index in either
+    table, which is what lets the decode kernel and the masks take a
+    window as a lower bound and nothing else. The space is sized for
+    every row's longest chain (``slots`` x that many pages, plus
+    scratch page 0), so only the full space ever makes a request wait.
+
+    A matched prefix could be adopted only where its last ``window``
+    positions are still resident in the window layers, which no retired
+    row's are for long: this pool matches nothing (`match_nothing`).
+
+    Which kind a pool is is decided once, where the engine builds it; a
+    model without window layers gets a plain `PagePool` and runs none of
+    this."""
+
+    def __init__(self, slots: int, max_len: int, page_size: int,
+                 n_pages: int, window: int):
+        super().__init__(slots, max_len, page_size, n_pages,
+                         prefix_cache=False)
+        if window < page_size or window % page_size:
+            raise ValueError(f"window {window} is not whole pages of "
+                             f"{page_size}")
+        self.window = window
+        self.window_pages_per_row = min(window // page_size + 1,
+                                        self.max_pages_per_row)
+        self.window_n_pages = slots * self.window_pages_per_row + 1
+        self._window_free = list(range(self.window_n_pages - 1, 0, -1))
+        self.window_tables = np.full(
+            (slots, self.max_pages_per_row), -1, np.int32)
+        self.window_pages_released = 0   # handed back by `roll`
+        self.window_row_pages_max = 0    # most any row has held
+
+    # ---------------------------------------------------------- planning
+    def _window_span(self, length: int) -> tuple:
+        """[first, stop) of the logical pages a row of ``length``
+        positions holds in the window space."""
+        stop = self.pages_for(length)
+        return max(0, stop - self.window_pages_per_row), stop
+
+    def _window_room(self, length: int) -> bool:
+        first, stop = self._window_span(length)
+        with self._lock:
+            return stop - first <= len(self._window_free)
+
+    def can_admit(self, length: int, tokens=None) -> bool:
+        return self._window_room(length) and super().can_admit(length, None)
+
+    # -------------------------------------------------------- allocation
+    def admit(self, slot: int, length: int,
+              tokens: Optional[list] = None) -> Optional[AdmitResult]:
+        """Every page of positions 0..length-1 in the full space, the
+        last `window_pages_per_row` of them at most in the window
+        space; None = nothing allocated in either."""
+        assert (self.window_tables[slot] < 0).all(), \
+            f"slot {slot} admitted while still holding window pages"
+        if not self._window_room(length):
+            return None
+        result = super().admit(slot, length, None)
+        if result is None:
+            return None
+        first, stop = self._window_span(length)
+        with self._lock:
+            taken = self._window_free[first - stop:]
+            del self._window_free[first - stop:]
+            self.window_tables[slot, first:stop] = taken
+            self.window_row_pages_max = max(self.window_row_pages_max,
+                                            stop - first)
+        return result
+
+    def roll(self, slot: int, pos: int) -> None:
+        """Make ``pos`` writable in the window space (`ensure` is the
+        full space's): at a page boundary the row takes a page for it
+        and hands back the one that no position within ``window`` of
+        ``pos`` lies in, which is on the free list at once."""
+        idx = pos // self.page_size
+        row = self.window_tables[slot]
+        if idx >= self.max_pages_per_row or row[idx] >= 0:
+            return
+        with self._lock:
+            old = idx - self.window_pages_per_row
+            if old >= 0 and row[old] >= 0:
+                self._window_free.append(int(row[old]))
+                row[old] = -1
+                self.window_pages_released += 1
+            row[idx] = self._window_free.pop()
+            held = min(idx + 1, self.window_pages_per_row)
+            if held > self.window_row_pages_max:
+                self.window_row_pages_max = held
+
+    def handoff(self, src_slot: int, dst_slot: int) -> int:
+        moved = super().handoff(src_slot, dst_slot)
+        with self._lock:
+            self.window_tables[dst_slot] = self.window_tables[src_slot]
+            self.window_tables[src_slot] = -1
+        return moved
+
+    def _release_locked(self, slot: int, invalidate_prefix: bool) -> None:
+        super()._release_locked(slot, invalidate_prefix)
+        row = self.window_tables[slot]
+        self._window_free.extend(int(p) for p in row[row >= 0])
+        row[:] = -1
+
+    # ------------------------------------------------------------- views
+    def padded_row(self, slot: int) -> np.ndarray:
+        """Both of the slot's block-table rows, ``[2, max_pages_per_row]``
+        (the full space's, the window space's), as a copy."""
+        return np.stack([self.tables[slot], self.window_tables[slot]])
+
+    def window_stats(self) -> dict:
+        total = self.window_n_pages - 1
+        with self._lock:
+            free = len(self._window_free)
+            return {"total": total, "free": free, "live": total - free,
+                    "released": self.window_pages_released,
+                    "row_max": self.window_row_pages_max}
+
+    def check_invariants(self) -> list[str]:
+        """The base class's checks of the full space, and of the window
+        space: every usable page free or in exactly one row, never
+        both; no row over its limit, and none holding a page that lies
+        behind its window."""
+        out = super().check_invariants()
+        with self._lock:
+            held = self.window_tables[self.window_tables >= 0]
+            counts = np.bincount(held.ravel(), minlength=self.window_n_pages)
+            free = np.zeros(self.window_n_pages, bool)
+            free[self._window_free] = True
+            if counts[0] or free[0]:
+                out.append("window space: scratch page 0 allocated")
+            if int(free.sum()) != len(self._window_free):
+                out.append("window space: duplicate pages on the free list")
+            usable = np.arange(self.window_n_pages) > 0
+            for what, bad in (
+                    ("in several table entries", counts > 1),
+                    ("held and on the free list", (counts > 0) & free),
+                    ("leaked", (counts == 0) & ~free & usable)):
+                out.extend(f"window page {page}: {what}"
+                           for page in np.flatnonzero(bad))
+            for slot, row in enumerate(self.window_tables):
+                at = np.flatnonzero(row >= 0)
+                if len(at) > self.window_pages_per_row:
+                    out.append(f"slot {slot}: {len(at)} window pages")
+                if len(at) and at[-1] - at[0] >= self.window_pages_per_row:
+                    out.append(f"slot {slot}: a window page behind the "
+                               "window")
+        return out

@@ -104,6 +104,28 @@ CASES = {
     # (one kernel in the text is one a layer: llama's layers are a scan).
     "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
     "lfm2-decode-step-lfm2-cell": ("lfm2_decode", {}, PAGED_KERNELS),
+    # The windowed form of the streamed kernel at the SmallThinker
+    # cell's own shapes (28 query heads on 4 KV heads of 128, 32 slots
+    # of 16,384 tokens, the window space's 32 x 257 + 1 pages), named so
+    # that no reader of `paged_decode` takes it for a full layer's call.
+    "window-smallthinker-cell-32x16384-kv4-d128": (
+        "paged", dict(h=28, kv=4, d=128, slots=32, max_len=16384,
+                      n_pages=8225, window=4096, layers=6),
+        {"window_decode": 1}),
+    # The window / full attention / routed ReGLU family's two serving
+    # programs, one period (G W W W) at the published head sizes and a
+    # quarter of the widths: the decode step over both page spaces holds
+    # one `paged_decode` (the full layer) and three `window_decode`, the
+    # prefill a flash kernel and three grouped matmuls a layer but the
+    # last, whose attention and expert block feed nothing the program
+    # returns (its K and V do); neither moves a pool (POOL_LIMITS: both
+    # spaces' pools).
+    "smallthinker-decode-step-two-page-spaces": (
+        "smallthinker", dict(program="decode"),
+        {"paged_decode": 1, "window_decode": 3}),
+    "smallthinker-prefill-page-writes": (
+        "smallthinker", dict(program="prefill"),
+        {"flash_fwd": 3, "grouped_matmul": 9}),
 }
 
 # What a decode program may do to the page pool: name -> (one layer's
@@ -123,6 +145,11 @@ POOL_LIMITS = {
     # compiler stages through fast memory, a copy each way.
     "nemotron_h-decode-step-pages-and-rows": ((8193, 2, 16, 128), 0, None),
     "lfm2-decode-step-lfm2-cell": ((3073, 8, 16, 64), 9, None),
+    # Both spaces' pools (`_compile_smallthinker` gives them one size):
+    # the decode step writes a page a row, the prefill the prompt's
+    # pages (`llama.paged_write_pages`), each where it lies.
+    "smallthinker-decode-step-two-page-spaces": ((2057, 4, 16, 128), 0, None),
+    "smallthinker-prefill-page-writes": ((2057, 4, 16, 128), 0, None),
 }
 
 
@@ -193,7 +220,7 @@ def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
 
 
 def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
-                   n_pages=None, mesh=None, layers=2):
+                   n_pages=None, mesh=None, layers=2, window=None):
     """The kernel alone, told the layer (traced) of a stacked pool."""
     import jax
     import jax.numpy as jnp
@@ -213,7 +240,8 @@ def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
                 jnp.bfloat16, P(None, None, heads, None, None))
     with mesh:
         return jax.jit(
-            lambda *a: paged_decode_attention(*a, interpret=False)).lower(
+            lambda *a: paged_decode_attention(
+                *a, window=window, interpret=False)).lower(
                 aval((slots, h, d), jnp.bfloat16, P(None, heads, None)),
                 pool, pool, aval((), jnp.int32),
                 aval((slots, maxp), jnp.int32),
@@ -385,6 +413,60 @@ def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
             i32()).compile()
 
 
+def _compile_smallthinker(topo, program, slots=8, max_len=8192, page=16,
+                          prompt=4607):
+    """`decode_step_paged` over both page spaces, or the whole-prompt
+    prefill with its page-wise insert, as the engine builds them: one
+    period at the published head sizes, the window 4,096, a prompt
+    longer than it. Both spaces hold ``slots`` x 257 + 1 pages, so one
+    shape finds either pool."""
+    import dataclasses
+
+    import jax
+
+    from polyaxon_tpu.models import smallthinker as st
+
+    cfg = dataclasses.replace(
+        st.CONFIGS["smallthinker_tiny"], vocab_size=1024, dim=512,
+        n_heads=8, n_kv_heads=4, head_dim=128, sliding_window=4096,
+        n_experts=16, experts_per_token=4, moe_ffn_dim=256,
+        max_seq_len=max_len, attention_impl="flash",
+        paged_attention_impl="pallas")
+    n_pages = slots * (cfg.sliding_window // page + 1) + 1
+    class _TwoSpaces:
+        """`st` with `paged_init_cache` told the window space's size,
+        which `_engine_avals` does not know to pass."""
+
+        def __getattr__(self, name):
+            return getattr(st, name)
+
+        @staticmethod
+        def paged_init_cache(cfg, n, page_size):
+            return st.paged_init_cache(cfg, n, page_size, n)
+
+    family = _TwoSpaces()
+    params, cache, i32 = _engine_avals(topo, family, cfg, slots, n_pages,
+                                       page)
+    maxp = max_len // page
+    if program == "decode":
+        def decode_step(params, cache, tokens, pos, full, window):
+            return st.decode_step_paged(cfg, params, cache, tokens, pos,
+                                        (full, window))
+
+        with _kernel_path():
+            return jax.jit(decode_step, donate_argnums=(1,)).lower(
+                params, cache, i32(slots), i32(slots), i32(slots, maxp),
+                i32(slots, maxp)).compile()
+
+    def prefill(params, tokens, cache, page_ids):
+        return st.paged_insert_prefill(
+            cache, *st.paged_prefill_kv(cfg, params, tokens), page_ids, page)
+
+    with _kernel_path():
+        return jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(1, prompt), cache, i32(2, maxp)).compile()
+
+
 def _leaf_copies(text: str, leaf: str) -> list:
     """The instructions of a compiled program whose result is the whole
     leaf ``leaf`` (its type as the text prints it) and which copy it."""
@@ -456,6 +538,7 @@ def _child_main() -> int:
         compile_case = {"flash": _compile_flash, "paged": _compile_paged,
                         "nemotron_h": _compile_nemotron_h,
                         "qwen3_next": _compile_qwen3_next,
+                        "smallthinker": _compile_smallthinker,
                         "llama_decode": _compile_llama_decode,
                         "lfm2_decode": _compile_lfm2_decode}[kind]
         t0 = time.time()
