@@ -149,7 +149,45 @@ def _w(w, dt):
     return w.astype(dt)
 
 
-def served_params(params, dt, read_at_float32):
+# A projection whose product is split into heads is held by a server
+# with its last two dimensions swapped, ``[.., N, D]``, under its name
+# plus this suffix (`served_params`); `project` reads either.
+HELD_TRANSPOSED_SUFFIX = "_t"
+
+
+def project(layer: dict, name: str, h: jax.Array, dt) -> jax.Array:
+    """``h`` [..., D] times the projection ``name`` of ``layer``
+    → [..., N], whichever way the tree holds it: ``[D, N]`` under
+    ``name`` (``family.init``, training, a quantized tree) or ``[N, D]``
+    under ``name + "_t"`` (a served tree, `served_params`). Read from
+    the tree that is handed in: a square ``wq`` cannot be told by its
+    shape, hence the key. Same operands, same sums, same product; what
+    differs is that the chip's compiler, which folds the reshape into
+    heads that follows into the dot and then wants the contracted
+    dimension minor in the weight, finds it so and does not copy a
+    layer's slice of the stack transposed in every program."""
+    held = layer.get(name + HELD_TRANSPOSED_SUFFIX)
+    if held is None:
+        return h @ _w(layer[name], dt)
+    return jnp.einsum("...d,nd->...n", h, _w(held, dt))
+
+
+def hold_transposed(tree: dict, names, swap) -> dict:
+    """``tree`` (nested dicts) with every leaf named in ``names`` moved
+    to ``name + "_t"`` as ``swap(leaf)``: the arrays of a params tree
+    (`served_params`), its logical axes or its shapes alike."""
+    out = {}
+    for name, leaf in tree.items():
+        if isinstance(leaf, dict):
+            out[name] = hold_transposed(leaf, names, swap)
+        elif name in names:
+            out[name + HELD_TRANSPOSED_SUFFIX] = swap(leaf)
+        else:
+            out[name] = leaf
+    return out
+
+
+def served_params(params, dt, read_at_float32, held_transposed=()):
     """The params tree as a server holds it: every leaf in the compute
     dtype ``dt`` that all of its consumption sites cast it to (``_w``,
     ``lm_logits``, ``_embed_rows``), so that the cast runs once at load
@@ -157,13 +195,20 @@ def served_params(params, dt, read_at_float32):
     named in ``read_at_float32`` (the family's ``READ_AT_FLOAT32`` table
     beside its ``logical_axes``: norm gains, whatever else a body reads
     with ``.astype(float32)``) stay as they are; rounding those would
-    change the result. Training keeps float32 masters and never comes
-    here."""
+    change the result. Leaves named in ``held_transposed`` (the family's
+    ``HELD_TRANSPOSED`` beside that table: the projections its walks
+    read through `project`) come back ``[.., N, D]`` under ``name_t``,
+    the drawn values swapped after the cast; without the argument
+    nothing is. Training keeps float32 masters and never comes here."""
     def cast(path, leaf):
         name = str(getattr(path[-1], "key", path[-1]))
         return leaf if name in read_at_float32 else leaf.astype(dt)
 
-    return jax.tree_util.tree_map_with_path(cast, params)
+    served = jax.tree_util.tree_map_with_path(cast, params)
+    if not held_transposed:
+        return served
+    return hold_transposed(served, held_transposed,
+                           lambda leaf: jnp.swapaxes(leaf, -1, -2))
 
 
 def _lm_chunk_len(V: int, chunk: int):

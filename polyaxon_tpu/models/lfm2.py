@@ -265,6 +265,10 @@ READ_AT_FLOAT32 = frozenset(
     {"attn_norm", "q_norm", "k_norm", "conv_norm", "mlp_norm", "moe_norm",
      "final_norm", "w_conv", "router", "expert_bias"})
 
+# Leaves a server holds ``[.., N, D]``: the attention layers' three
+# projections, read by llama's `_qkv` (its table says why).
+HELD_TRANSPOSED = llama.HELD_TRANSPOSED
+
 
 def _at(stack: dict, i: int) -> dict:
     """Layer `i` of one kind's stacked parameters."""

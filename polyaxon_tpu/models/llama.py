@@ -30,6 +30,7 @@ from polyaxon_tpu.models.common import (
     Variables,
     chunked_lm_loss,
     lm_logits,
+    project,
     rms_norm,
     rope,
     sample_logits,
@@ -239,6 +240,15 @@ def logical_axes(cfg: LlamaConfig) -> Variables:
 READ_AT_FLOAT32 = frozenset(
     {"attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm"})
 
+# Leaves a server holds ``[.., N, D]`` (``common.served_params``): the
+# three projections `_qkv` splits into heads. The chip's compiler folds
+# that split into the dot and wants the contracted dimension minor in
+# the weight; held ``[D, N]`` it copies each layer's slice transposed in
+# every decode and prefill program (0.29 ms a step for Mistral's ``wq``
+# alone). ``wo``, the MLP's and the head stream straight from the stack
+# into their dots as they are and stay out.
+HELD_TRANSPOSED = frozenset({"wq", "wk", "wv"})
+
 
 _rope = rope  # shared impl (models.common.rope)
 
@@ -268,25 +278,28 @@ def _qkv(cfg, layer: dict, h: jax.Array, positions: jax.Array,
     gains (`_qk_norm`) and turned by the rotary embedding at
     ``positions`` [B, T], and the output gate [B, T, H·Hd] or None.
 
-    Two things are read from what the walk is handed, and a layer or a
-    config without them is the plain path: a ``wq`` twice as wide holds,
-    for each head, its query and behind it the gate of that head's
-    output (`_attn_out`); ``cfg.partial_rotary_factor`` is the share of
-    a head's dimensions the rotary embedding turns (``common.rope``).
+    Three things are read from what the walk is handed, and a layer or
+    a config without them is the plain path: a ``wq`` twice as wide
+    holds, for each head, its query and behind it the gate of that
+    head's output (`_attn_out`); ``cfg.partial_rotary_factor`` is the
+    share of a head's dimensions the rotary embedding turns
+    (``common.rope``); a layer that carries ``wq_t`` / ``wk_t`` /
+    ``wv_t`` holds the projections ``[N, D]`` as a server does
+    (``common.project``: the same product either way).
     ``rotary`` False is a layer without positions in a model whose
     other layers have them (a static layer plan's word, ``models/
     smallthinker.py``): q and k go on unturned."""
     dt = cfg.dtype
     B, T = h.shape[:2]
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = h @ _w(layer["wq"], dt)
+    q = project(layer, "wq", h, dt)
     gate = None
     if q.shape[-1] == 2 * H * Hd:
         q, gate = jnp.split(q.reshape(B, T, H, 2 * Hd), 2, axis=-1)
         gate = gate.reshape(B, T, H * Hd)
     q = q.reshape(B, T, H, Hd)
-    k = (h @ _w(layer["wk"], dt)).reshape(B, T, KV, Hd)
-    v = (h @ _w(layer["wv"], dt)).reshape(B, T, KV, Hd)
+    k = project(layer, "wk", h, dt).reshape(B, T, KV, Hd)
+    v = project(layer, "wv", h, dt).reshape(B, T, KV, Hd)
     q, k = _qk_norm(cfg, layer, q, k)
     scaling = getattr(cfg, "rope_scaling", None)
     factor = getattr(cfg, "partial_rotary_factor", None)

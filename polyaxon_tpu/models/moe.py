@@ -202,6 +202,13 @@ def logical_axes(cfg: MoEConfig) -> Variables:
 # server holds them so (``common.served_params``).
 READ_AT_FLOAT32 = frozenset({"attn_norm", "moe_norm", "final_norm"})
 
+# Leaves a server holds ``[.., N, D]``: every walk this family serves
+# through is llama's (``prefill`` to ``paged_prefill_suffix_kv`` below),
+# whose `_qkv` reads the three projections either way (its table says
+# why). `_layer`, the training forward, reads ``[D, N]`` by name and is
+# never handed a served tree.
+HELD_TRANSPOSED = llama.HELD_TRANSPOSED
+
 
 def _router_aux_loss(cfg: MoEConfig, frac_tokens: jax.Array,
                      frac_probs: jax.Array) -> jax.Array:

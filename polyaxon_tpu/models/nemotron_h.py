@@ -324,6 +324,11 @@ READ_AT_FLOAT32 = frozenset(
     {"attn_norm", "ssm_norm", "gate_norm", "moe_norm", "final_norm",
      "A_log", "dt_bias", "D", "conv_w", "conv_b", "router", "expert_bias"})
 
+# Leaves a server holds ``[.., N, D]``: the attention layers' three
+# projections, read by llama's `_qkv` (its table says why). Mamba-2's
+# projections and the experts' stream from their stacks as they are.
+HELD_TRANSPOSED = llama.HELD_TRANSPOSED
+
 
 # ------------------------------------------------------------ the layers
 def ssm_layer(cfg: NemotronHConfig, layer: dict, x: jax.Array,

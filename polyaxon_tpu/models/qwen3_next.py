@@ -313,6 +313,14 @@ READ_AT_FLOAT32 = frozenset(
     {"gdn_norm", "attn_norm", "moe_norm", "final_norm", "q_norm", "k_norm",
      "out_norm", "A_log", "dt_bias", "conv_w", "router"})
 
+# Leaves a server holds ``[.., N, D]``: the attention layers' three
+# projections, read by llama's `_qkv` (its table says why), and the
+# delta layers' ``w_qkvz``, whose product ``ops/gated_delta.py
+# split_projections`` splits by key head (the decode program copied
+# each of the six layers' 50 MB transposed every step). ``w_ba``,
+# ``w_out`` and the experts' stream from their stacks as they are.
+HELD_TRANSPOSED = llama.HELD_TRANSPOSED | {"w_qkvz"}
+
 
 # ------------------------------------------------------------ the layers
 def gdn_layer(cfg: Qwen3NextConfig, layer: dict, x: jax.Array,

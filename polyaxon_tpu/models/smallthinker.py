@@ -230,6 +230,10 @@ def logical_axes(cfg: SmallThinkerConfig) -> Variables:
 # server holds them so (``common.served_params``).
 READ_AT_FLOAT32 = frozenset({"attn_norm", "moe_norm", "final_norm", "router"})
 
+# Leaves a server holds ``[.., N, D]``: the three projections of every
+# layer, read by llama's `_qkv` (its table says why).
+HELD_TRANSPOSED = llama.HELD_TRANSPOSED
+
 
 # ------------------------------------------------------------ the layers
 def routing(cfg: SmallThinkerConfig, layer: dict, h: jax.Array) -> tuple:

@@ -55,7 +55,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models.common import _w, rms_norm
+from polyaxon_tpu.models.common import _w, project, rms_norm
 from polyaxon_tpu.models.row_state import put_layer
 from polyaxon_tpu.ops.gdn_update import gdn_update
 from polyaxon_tpu.parallel import compat
@@ -210,7 +210,7 @@ def _recurrence_inputs(cfg, layer: dict, u: jax.Array, conv_tail: jax.Array,
     dt_ = cfg.dtype
     S, K = u.shape[1], cfg.conv_kernel
     qkv, z, b, a = split_projections(
-        cfg, u @ _w(layer["w_qkvz"], dt_), u @ _w(layer["w_ba"], dt_))
+        cfg, project(layer, "w_qkvz", u, dt_), u @ _w(layer["w_ba"], dt_))
     seq = jnp.concatenate([conv_tail.astype(dt_), qkv], axis=1)
     taps = layer["conv_w"].astype(jnp.float32)      # [conv_dim, K]
     conv = sum(taps[:, j] * seq[:, j:j + S].astype(jnp.float32)

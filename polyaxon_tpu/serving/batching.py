@@ -43,7 +43,7 @@ import numpy as np
 
 from polyaxon_tpu.obs import metrics as obs_metrics
 from polyaxon_tpu.obs import reqtrace
-from polyaxon_tpu.serving.quantize import weight_bytes
+from polyaxon_tpu.serving.quantize import held_transposed_bytes, weight_bytes
 from polyaxon_tpu.serving.speculative import LaneView, SpeculationPolicy
 
 logger = logging.getLogger(__name__)
@@ -617,6 +617,7 @@ class ContinuousBatchingEngine:
         self._device0, self._device_stats = _device_stats(
             self.params, self._cache)
         self._weight_bytes = weight_bytes(self.params)
+        self._held_transposed_bytes = held_transposed_bytes(self.params)
         # What a page holds: page_size tokens of K and V and, for a
         # family whose cache has such a leaf, one fixed-size state. A
         # radix match that ends inside a page has no true state, so the
@@ -2037,6 +2038,10 @@ class ContinuousBatchingEngine:
             # Bytes of the weights by the dtype they are held in, read
             # once at start-up (docs/observability.md).
             "weight_bytes": dict(self._weight_bytes),
+            # Of those, the projections held [N, D] as the decode dot
+            # reads them (0: a plain tree), so a run says which tree it
+            # measured.
+            "weights_held_transposed_bytes": self._held_transposed_bytes,
             # Mosaic kernels in the compiled decode step (empty until
             # the first step compiles, and wherever attention runs a
             # reference path: the CPU mesh, or the gather formulation).

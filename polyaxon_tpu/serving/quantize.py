@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from polyaxon_tpu.models.common import HELD_TRANSPOSED_SUFFIX
+
 _QMAX = 127.0
 
 
@@ -163,3 +165,13 @@ def weight_bytes(params: Any) -> dict[str, int]:
             name = str(leaf.dtype)
             held[name] = held.get(name, 0) + int(leaf.nbytes)
     return dict(sorted(held.items()))
+
+
+def held_transposed_bytes(params: Any) -> int:
+    """Bytes of the projections a tree holds ``[N, D]`` (``models/
+    common.py served_params``; 0 on a plain tree): `/v1/stats`
+    ``weights_held_transposed_bytes``, which tree a run measured."""
+    return sum(
+        int(leaf.nbytes)
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]
+        if str(getattr(path[-1], "key", "")).endswith(HELD_TRANSPOSED_SUFFIX))

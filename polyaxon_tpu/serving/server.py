@@ -55,10 +55,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from polyaxon_tpu.models.common import served_params
+from polyaxon_tpu.models.common import hold_transposed, served_params
 from polyaxon_tpu.serving.batching import (LOG_BUCKETS, QueueFull, log_bucket,
                                            validate_sampling)
-from polyaxon_tpu.serving.quantize import (quantize_tree, tree_bytes,
+from polyaxon_tpu.serving.quantize import (held_transposed_bytes,
+                                           quantize_tree, tree_bytes,
                                            weight_bytes)
 
 logger = logging.getLogger(__name__)
@@ -109,9 +110,16 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
     ``READ_AT_FLOAT32`` names what stays float32), so no decode or
     prefill program repeats the cast. The values are drawn or restored
     in float32 first and then rounded: the very values the programs'
-    own casts gave. A family that states nothing keeps float32 (t5).
-    ``quantize`` ("int8"): the float32 tree goes to ``quantize_tree``
-    instead and nothing is cast, which is the tree it always gave.
+    own casts gave. The projections a family names in its
+    ``HELD_TRANSPOSED`` (those its walks split into heads) are then held
+    ``[.., N, D]`` under ``name_t``, which is how the chip's dot reads
+    them: the same values, swapped after the cast (inside the jitted
+    draw; on the host for a restored leaf, whose checkpoint is
+    ``[D, N]`` and is validated so), and the walks read whichever they
+    are handed (``models/common.py project``). A family that states
+    nothing keeps float32 (t5). ``quantize`` ("int8"): the float32 tree
+    goes to ``quantize_tree`` instead and nothing is cast or swapped,
+    which is the tree it always gave.
 
     ``mesh``: shard the weights over it using the model's logical axes
     and the mesh's rule table (the same tables training uses) — serving
@@ -130,26 +138,34 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
     family = _family(model)
     cfg = family.CONFIGS[model]
 
+    read_at_float32 = getattr(family, "READ_AT_FLOAT32", None)
+    plain = bool(quantize) or read_at_float32 is None
+    held = () if plain else getattr(family, "HELD_TRANSPOSED", ())
+
     shardings = None
     if mesh is not None:
         from polyaxon_tpu.parallel import rules_for_mesh
         from polyaxon_tpu.parallel.sharding import tree_shardings
 
+        # A held-transposed leaf's logical axes are the plain leaf's
+        # with the last two swapped: the same rule table shards it.
         shardings = tree_shardings(
-            family.logical_axes(cfg)["params"], mesh, rules_for_mesh(mesh))
+            hold_transposed(family.logical_axes(cfg)["params"], held,
+                            lambda axes: (*axes[:-2], axes[-1], axes[-2])),
+            mesh, rules_for_mesh(mesh))
 
-    read_at_float32 = getattr(family, "READ_AT_FLOAT32", None)
-
-    def init_params(key):
+    def init_params(key, held=held):
         params = family.init(cfg, key)["params"]
-        if quantize or read_at_float32 is None:
+        if plain:
             return params
-        return served_params(params, cfg.dtype, read_at_float32)
+        return served_params(params, cfg.dtype, read_at_float32, held)
 
-    # Shape/dtype template: no memory, used for structure validation
-    # and dtype casts either way (the served dtypes: a restored leaf is
-    # rounded on the host, after any LoRA merge in float32).
-    template = jax.eval_shape(init_params, jax.random.key(0))
+    # Shape/dtype template of the tree as a checkpoint holds it (every
+    # projection ``[D, N]``): no memory, used for structure validation
+    # and dtype casts (the served dtypes: a restored leaf is rounded on
+    # the host, after any LoRA merge in float32, and swapped there).
+    template = jax.eval_shape(
+        functools.partial(init_params, held=()), jax.random.key(0))
 
     if checkpoint:
         import orbax.checkpoint as ocp
@@ -181,15 +197,24 @@ def load_params(model: str, checkpoint: Optional[str] = None, seed: int = 0,
                 raise ValueError(
                     f"checkpoint {checkpoint} step {step} does not match "
                     f"model `{model}`: params tree structure differs")
+            # Leaf by leaf, so that no rounded copy of the whole tree
+            # sits on the host beside the restored one: round, swap a
+            # held leaf, place.
+            def rounded(ref, x):
+                return lambda: np.asarray(x, ref.dtype)
+
+            def swapped(leaf):
+                return lambda: np.swapaxes(leaf(), -1, -2)
+
+            staged = hold_transposed(
+                jax.tree.map(rounded, template, loaded), held, swapped)
             if shardings is not None:
                 params = jax.tree.map(
-                    lambda ref, x, sh: jax.device_put(
-                        np.asarray(x, ref.dtype), sh),
-                    template, loaded, shardings)
+                    lambda leaf, sh: jax.device_put(leaf(), sh),
+                    staged, shardings)
             else:
-                params = jax.tree.map(
-                    lambda ref, x: jnp.asarray(x, ref.dtype),
-                    template, loaded)
+                params = jax.tree.map(lambda leaf: jnp.asarray(leaf()),
+                                      staged)
             logger.info("restored %s step=%s", checkpoint, step)
     else:
         # One program, sharded or not: op-by-op init compiles a program
@@ -229,6 +254,7 @@ class _Engine:
         self.params = params
         self.draft = draft
         self._weight_bytes = weight_bytes(params)
+        self._held_transposed_bytes = held_transposed_bytes(params)
         self._served = 0
         self._tokens_out = 0
         self._lock = threading.Lock()  # one TPU program at a time
@@ -403,6 +429,7 @@ class _Engine:
             "requests_served": self._served,
             "tokens_generated": self._tokens_out,
             "weight_bytes": dict(self._weight_bytes),
+            "weights_held_transposed_bytes": self._held_transposed_bytes,
         }
 
 

@@ -104,6 +104,9 @@ CASES = {
     # (one kernel in the text is one a layer: llama's layers are a scan).
     "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
     "lfm2-decode-step-lfm2-cell": ("lfm2_decode", {}, PAGED_KERNELS),
+    # The same engine's whole-prompt prefill with its insert (383
+    # tokens: the cells' median prompt), for PROJECTION_SLICES.
+    "llama-prefill-mistral7b-cells": ("llama_prefill", {}, {}),
     # The windowed form of the streamed kernel at the SmallThinker
     # cell's own shapes (28 query heads on 4 KV heads of 128, 32 slots
     # of 16,384 tokens, the window space's 32 x 257 + 1 pages), named so
@@ -126,6 +129,18 @@ CASES = {
     "smallthinker-prefill-page-writes": (
         "smallthinker", dict(program="prefill"),
         {"flash_fwd": 3, "grouped_matmul": 9}),
+    # PROJECTION_SLICES' controls: the same programs over the tree with
+    # every projection ``[D, N]`` as ``family.init`` draws it.
+    "llama-decode-step-mistral7b-cells-plain-tree": (
+        "llama_decode", dict(held=False), PAGED_KERNELS),
+    "llama-prefill-mistral7b-cells-plain-tree": (
+        "llama_prefill", dict(held=False), {}),
+    "qwen3_next-decode-step-pages-and-rows-plain-tree": (
+        "qwen3_next", dict(program="decode", held=False),
+        {**PAGED_KERNELS, "gdn_update": 3}),
+    "smallthinker-decode-step-two-page-spaces-plain-tree": (
+        "smallthinker", dict(program="decode", held=False),
+        {"paged_decode": 1, "window_decode": 3}),
 }
 
 # What a decode program may do to the page pool: name -> (one layer's
@@ -151,6 +166,37 @@ POOL_LIMITS = {
     "smallthinker-decode-step-two-page-spaces": ((2057, 4, 16, 128), 0, None),
     "smallthinker-prefill-page-writes": ((2057, 4, 16, 128), 0, None),
 }
+
+
+# The projections a server holds ``[N, D]`` (each family's
+# ``HELD_TRANSPOSED``; ``models/common.py served_params``): name -> the
+# types, by the case's own widths, of one layer's slice of each, as the
+# walk's ``[1, D, N]`` slice of a scanned stack or as the ``[N, D]`` a
+# static plan's dot takes. The chip's compiler folds the split into
+# heads into the projection's dot and wants the contracted dimension
+# minor in the weight: over a tree that holds it ``[D, N]`` every decode
+# and prefill program copies each layer's slice transposed (0.29 ms a
+# step for Mistral's ``wq``), over the served tree none does. No
+# ``copy`` instruction's result may have one of these types; the
+# ``-plain-tree`` case of the same program has to show them, so that a
+# case cannot pass by looking for the wrong type. (The slices the
+# compiler brings into fast memory with ``copy-start`` / ``copy-done``
+# are the weight's one read and stay.)
+PROJECTION_SLICES = {
+    # wq [4096, 32 x 128]; wk, wv [4096, 8 x 128].
+    "llama-decode-step-mistral7b-cells": (
+        "bf16[1,4096,4096]", "bf16[1,4096,1024]"),
+    "llama-prefill-mistral7b-cells": (
+        "bf16[1,4096,4096]", "bf16[1,4096,1024]"),
+    # w_qkvz [512, 2 x 16 x 128 + 2 x 32 x 128]; wq [512, 2 x 4 x 256]
+    # (query and gate); wk, wv [512, 2 x 256].
+    "qwen3_next-decode-step-pages-and-rows": (
+        "bf16[12288,512]", "bf16[2048,512]", "bf16[512,512]"),
+    # wq [512, 8 x 128]; wk, wv [512, 4 x 128].
+    "smallthinker-decode-step-two-page-spaces": (
+        "bf16[1024,512]", "bf16[512,512]"),
+}
+PLAIN_TREE = "-plain-tree"
 
 
 # The per-row state a decode or prefill program updates: name -> the
@@ -248,11 +294,12 @@ def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,
                 aval((slots,), jnp.int32)).compile()
 
 
-def _engine_avals(topo, family, cfg, slots, n_pages, page):
+def _engine_avals(topo, family, cfg, slots, n_pages, page, held=True):
     """(params, cache, i32) as the engine holds them
     (serving/batching.py), as shapes on the first described chip:
-    served weights, the paged cache with the family's per-row leaves,
-    and a maker of int32 arguments."""
+    served weights (``held`` False: every projection ``[D, N]``, the
+    tree before a server held any ``[N, D]``), the paged cache with the
+    family's per-row leaves, and a maker of int32 arguments."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -274,7 +321,7 @@ def _engine_avals(topo, family, cfg, slots, n_pages, page):
 
     params = avals(lambda: served_params(
         family.init(cfg, jax.random.key(0))["params"], cfg.dtype,
-        family.READ_AT_FLOAT32))
+        family.READ_AT_FLOAT32, family.HELD_TRANSPOSED if held else ()))
     return params, avals(build_cache), lambda *shape: jax.ShapeDtypeStruct(
         shape, jnp.int32, sharding=one)
 
@@ -294,13 +341,13 @@ def _kernel_path():
 
 
 def _compile_decode_step(topo, family, cfg, slots, max_len, n_pages,
-                         page=16):
+                         page=16, held=True):
     """`family.decode_step_paged` as the engine builds it: the cache
     donated."""
     import jax
 
     params, cache, i32 = _engine_avals(topo, family, cfg, slots, n_pages,
-                                       page)
+                                       page, held)
 
     def decode_step(params, cache, tokens, pos, tables):
         return family.decode_step_paged(cfg, params, cache, tokens, pos,
@@ -312,21 +359,48 @@ def _compile_decode_step(topo, family, cfg, slots, max_len, n_pages,
             i32(slots, max_len // page)).compile()
 
 
-def _compile_llama_decode(topo):
-    """The benchmark's Mistral-7B engine: 8 of its layers, 16 slots of
-    4,096 tokens over 3,073 pages."""
+def _mistral_cells_cfg():
+    """The benchmark's Mistral-7B engine: 8 of its layers (16 slots of
+    4,096 tokens over 3,073 pages)."""
     import dataclasses
 
     import jax.numpy as jnp
 
     from polyaxon_tpu.models import llama
 
-    cfg = dataclasses.replace(
+    return dataclasses.replace(
         llama.CONFIGS["mistral_7b"], n_layers=8, vocab_size=32768,
         sliding_window=None, dtype=jnp.bfloat16,
         paged_attention_impl="pallas")
-    return _compile_decode_step(topo, llama, cfg, slots=16, max_len=4096,
-                                n_pages=3073)
+
+
+def _compile_llama_decode(topo, held=True):
+    from polyaxon_tpu.models import llama
+
+    return _compile_decode_step(topo, llama, _mistral_cells_cfg(), slots=16,
+                                max_len=4096, n_pages=3073, held=held)
+
+
+def _compile_llama_prefill(topo, held=True, prompt=383, max_len=4096,
+                           page=16):
+    """That engine's whole-prompt prefill with its insert, the cache
+    donated."""
+    import jax
+
+    from polyaxon_tpu.models import llama
+
+    cfg = _mistral_cells_cfg()
+    params, cache, i32 = _engine_avals(topo, llama, cfg, 16, 3073, page,
+                                       held)
+
+    def prefill(params, tokens, cache, page_ids):
+        return llama.paged_insert_prefill(
+            cache, *llama.paged_prefill_kv(cfg, params, tokens), page_ids,
+            page)
+
+    with _kernel_path():
+        return jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(1, prompt), cache, i32(max_len // page)).compile()
 
 
 def _compile_lfm2_decode(topo):
@@ -381,7 +455,7 @@ def _compile_nemotron_h(topo, program, slots=8, max_len=512, page=16,
 
 
 def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
-                        n_pages=257, prompt=127):
+                        n_pages=257, prompt=127, held=True):
     """`decode_step_paged` or the whole-prompt prefill with its insert,
     as the engine builds them, for a one-period model of a quarter of
     its experts."""
@@ -399,7 +473,7 @@ def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
         moe_ffn_dim=256, shared_ffn_dim=256, paged_attention_impl="pallas")
     if program == "decode":
         return _compile_decode_step(topo, qn, cfg, slots, max_len, n_pages,
-                                    page)
+                                    page, held)
     params, cache, i32 = _engine_avals(topo, qn, cfg, slots, n_pages, page)
 
     def prefill(params, tokens, cache, page_ids, row):
@@ -414,7 +488,7 @@ def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
 
 
 def _compile_smallthinker(topo, program, slots=8, max_len=8192, page=16,
-                          prompt=4607):
+                          prompt=4607, held=True):
     """`decode_step_paged` over both page spaces, or the whole-prompt
     prefill with its page-wise insert, as the engine builds them: one
     period at the published head sizes, the window 4,096, a prompt
@@ -446,7 +520,7 @@ def _compile_smallthinker(topo, program, slots=8, max_len=8192, page=16,
 
     family = _TwoSpaces()
     params, cache, i32 = _engine_avals(topo, family, cfg, slots, n_pages,
-                                       page)
+                                       page, held)
     maxp = max_len // page
     if program == "decode":
         def decode_step(params, cache, tokens, pos, full, window):
@@ -479,6 +553,15 @@ def _leaf_copies(text: str, leaf: str) -> list:
                      head[1]) or head[0].lstrip("%").startswith("copy"):
             out.append(line.strip()[:160])
     return out
+
+
+def _copies_of(text: str, types: tuple) -> list:
+    """The ``copy`` instructions of a compiled program whose result has
+    one of ``types`` (whatever its layout): the instruction's name and
+    its result as the text prints it."""
+    found = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                       r"copy\(", text, re.MULTILINE)
+    return [f"{name} {result}" for name, result in found if result in types]
 
 
 def _pool_sized_instructions(text: str, pool_shape: tuple) -> list:
@@ -540,6 +623,7 @@ def _child_main() -> int:
                         "qwen3_next": _compile_qwen3_next,
                         "smallthinker": _compile_smallthinker,
                         "llama_decode": _compile_llama_decode,
+                        "llama_prefill": _compile_llama_prefill,
                         "lfm2_decode": _compile_lfm2_decode}[kind]
         t0 = time.time()
         try:
@@ -560,6 +644,12 @@ def _child_main() -> int:
                 report[name]["state_copies"] = _leaf_copies(
                     text, STATE_LEAVES[name])
                 report[name]["state_seen"] = STATE_LEAVES[name] in text
+            held_case = name.removesuffix(PLAIN_TREE)
+            if held_case in PROJECTION_SLICES:
+                report[name]["projection_copies"] = _copies_of(
+                    text, PROJECTION_SLICES[held_case])
+                report[name]["temp_bytes"] = (
+                    compiled.memory_analysis().temp_size_in_bytes)
             if name in POOL_LIMITS:
                 report[name]["pool_sized"] = _pool_sized_instructions(
                     text, POOL_LIMITS[name][0])
@@ -628,6 +718,21 @@ def test_program_updates_the_rows_state_where_it_lies(aot_report, name):
     assert entry["ok"], entry
     assert entry["state_seen"], "the program does not hold the leaf"
     assert entry["state_copies"] == [], entry["state_copies"]
+
+
+@pytest.mark.parametrize("name", sorted(PROJECTION_SLICES))
+def test_no_program_copies_a_projection_transposed(aot_report, name):
+    """Over the served tree no `copy` gives a layer's slice of a
+    head-split projection; over the plain tree the same program does
+    (the control), and keeps no fewer bytes of temporaries."""
+    entry = aot_report["cases"][name]
+    control = aot_report["cases"][name + PLAIN_TREE]
+    assert entry["ok"] and control["ok"], (entry, control)
+    assert entry["projection_copies"] == [], entry["projection_copies"]
+    seen = {copy.split(" ")[1] for copy in control["projection_copies"]}
+    assert seen == set(PROJECTION_SLICES[name]), control["projection_copies"]
+    assert entry["temp_bytes"] <= control["temp_bytes"], (
+        entry["temp_bytes"], control["temp_bytes"])
 
 
 if __name__ == "__main__":

@@ -127,7 +127,13 @@ class TestServing:
         from polyaxon_tpu.runtime.checkpoint import CheckpointManager
         from polyaxon_tpu.polyflow.runs import V1JaxCheckpointing
 
-        cfg, params = load_params("llama_tiny", seed=3)
+        from polyaxon_tpu.models import llama
+
+        # A checkpoint is what training saves: float32, every projection
+        # `[D, N]` under its own name (a served tree, `[N, D]` under
+        # `<name>_t`, is not one: tests/test_served_weights.py).
+        cfg, _ = load_params("llama_tiny", seed=3)
+        params = llama.init(cfg, jax.random.key(3))["params"]
         mutated = jax.tree.map(lambda x: x + 1.0, params)
         ckpt = CheckpointManager(
             str(tmp_path / "ck"),
@@ -136,12 +142,13 @@ class TestServing:
         ckpt.close()
 
         _, restored = load_params("llama_tiny", str(tmp_path / "ck"), seed=3)
-        # What was saved comes back (the served tree is in cfg.dtype, so
-        # `+ 1.0` rounded there: compare with the saved leaf itself).
+        # What was saved comes back, rounded to the dtype a server holds
+        # it in (`embed`: cfg.dtype).
         leaf = jax.tree.leaves(restored)[0]
         saved = jax.tree.leaves(mutated)[0]
-        assert leaf.dtype == saved.dtype
-        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(saved))
+        assert leaf.dtype == cfg.dtype
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.asarray(saved.astype(cfg.dtype)))
 
 
 class TestContinuousBatching:
