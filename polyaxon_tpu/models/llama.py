@@ -846,19 +846,23 @@ def chunk_attn_step(cfg, layer: dict, x: jax.Array, k_cache: jax.Array,
 # The layout is kv-head-major within a page because the decode kernel
 # (ops/paged_attention.py) takes a page as one contiguous [KV, page, Hd]
 # block, and a Mosaic block's two trailing dims must be whole. It is
-# known to the five functions below and to the kernel, and to nothing
-# else.
+# known to the functions below, as far as `paged_init_cache`, and to
+# the kernel, and to nothing else.
 #
-# In the decode program only the kernel and `paged_write_step` touch the
-# pool, and the latter writes whole pages. A write whose unit is one
-# token (a scatter at (page, :, offset), or a dynamic_update_slice of
-# [KV, 1, Hd]) makes the TPU compiler lay the pool out token-major for
-# it and copy all of it into the kernel's order and back around every
-# call; with the pool carried through the layer scan that is two whole
-# pools copied a layer (jax 0.9.0 / libtpu 0.0.34, compiled for a
-# described v5e). A page is contiguous in the kernel's order, so a
-# page-wise write leaves the layout alone and updates the pool in place
-# (tests/test_aot_tpu_compile.py holds the decode programs to that).
+# Every program writes the pool by whole pages: the decode program
+# through `paged_write_step` (only it and the kernel touch the pool
+# there), a prefill program through `paged_write_span` (or
+# `paged_write_pages`, where the pages are fresh). A write whose unit
+# is one token (a scatter at (page, :, offset), or a
+# dynamic_update_slice of [KV, 1, Hd]) makes the TPU compiler lay the
+# pool out token-major for it and copy all of it into the kernel's
+# order and back around every call: two whole pools copied a layer with
+# the pool carried through the decode program's layer scan, four a
+# prefill program (jax 0.9.0 / libtpu 0.0.34, compiled for a described
+# v5e). A page is contiguous in the kernel's order, so a page-wise
+# write leaves the layout alone and updates the pool in place
+# (tests/test_aot_tpu_compile.py holds the decode and the prefill
+# programs to that).
 
 def paged_pool_shape(cfg, n_pages: int, page_size: int) -> tuple:
     return (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
@@ -884,14 +888,6 @@ def paged_gather_prefix(cache: dict, page_ids: jax.Array) -> tuple:
     ``paged_prefill_suffix_kv`` takes them."""
     return (paged_gather(cache["k"], page_ids),
             paged_gather(cache["v"], page_ids))
-
-
-def paged_scatter(pool: jax.Array, kv: jax.Array, page_idx: jax.Array,
-                  off: jax.Array) -> jax.Array:
-    """Write token-major ``kv`` [L, T, KV, Hd] into the whole pool
-    [L, P, KV, page, Hd] at (page_idx[t], off[t]). (Two index arrays
-    split by a slice put the indexed dim first, hence the moveaxis.)"""
-    return pool.at[:, page_idx, :, off].set(jnp.moveaxis(kv, 1, 0))
 
 
 def paged_write_step(pool: jax.Array, layer, kv: jax.Array,
@@ -923,13 +919,57 @@ def paged_write_pages(pool: jax.Array, kv: jax.Array,
     pages long, into pages ``page_ids`` [n] (real ids, no two alike) of
     the whole pool [L, P, KV, page, Hd], by whole pages: each page is
     written as the contiguous block it is in the kernel's order, so the
-    pool keeps its layout and is updated in place, where the token-wise
-    `paged_scatter` has all of it copied into the scatter's order and
-    back (the paged surface's comment)."""
+    pool keeps its layout and is updated in place (the paged surface's
+    comment). Nothing is read: every slot of every page named is
+    written, so the pages are the row's own and fresh
+    (`paged_write_span` merges into what a page holds)."""
     L, T, KV, Hd = kv.shape
     page = pool.shape[-2]
     pages = kv.reshape(L, T // page, page, KV, Hd).swapaxes(2, 3)
     return pool.at[:, page_ids].set(pages.astype(pool.dtype))
+
+
+def paged_write_span(pool: jax.Array, kv: jax.Array, page_ids: jax.Array,
+                     start, real_len=None) -> jax.Array:
+    """A prefill's ``kv`` [L, S, KV, Hd] into the whole pool
+    [L, P, KV, page, Hd] at absolute positions start..start+S-1 of the
+    row whose block-table row is ``page_ids`` [maxp] (-1 = not
+    allocated), by whole pages: the pages the span touches are read,
+    every slot whose position lies in [start, start + real_len) takes
+    its token, every other slot keeps what the page held, and the pages
+    are put back. ``start`` is a plain int or a traced scalar,
+    ``real_len`` (traced, or None: all S) how much of ``kv`` is real.
+
+    The count of pages is static: a span from inside a page touches at
+    most ceil(S / page) + 1. What a page holds outside the span stays
+    because it is somebody's: before ``start`` the matched prefix the
+    engine forked into the row's own page (serving/batching.py
+    `_admit_prefill`), past the end whatever a page other rows share
+    holds there. A touched page past the row's table, wholly past the
+    last real position, or not allocated names scratch page 0; several
+    may, and land in any order, as `paged_write_step`'s idle rows do.
+    Hence no ``unique_indices`` hint."""
+    L, S, KV, Hd = kv.shape
+    page = pool.shape[-2]
+    maxp = page_ids.shape[0]
+    n = (-(-(start % page + S) // page) if isinstance(start, int)
+         else -(-S // page) + 1)
+    end = start + (S if real_len is None else real_len)
+    slots = start // page + jnp.arange(n)  # the row's logical pages
+    ids = jnp.where(
+        (slots < maxp) & (slots * page < end),
+        jnp.maximum(page_ids[jnp.minimum(slots, maxp - 1)], 0), 0)
+    # Slot j of touched page i is position (start // page + i)·page + j,
+    # which is token i·page + j - start % page of `kv`: the span shifted
+    # into its first page, cut into pages.
+    shifted = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(kv, ((0, 0), (page, n * page - S), (0, 0), (0, 0))),
+        page - start % page, n * page, axis=1)
+    new = shifted.reshape(L, n, page, KV, Hd).swapaxes(2, 3)
+    at = slots[:, None] * page + jnp.arange(page)[None, :]  # [n, page]
+    real = ((at >= start) & (at < end))[None, :, None, :, None]
+    pages = jnp.where(real, new.astype(pool.dtype), pool[:, ids])
+    return pool.at[:, ids].set(pages)
 
 
 def paged_init_cache(cfg: LlamaConfig, n_pages: int, page_size: int) -> dict:
@@ -1073,16 +1113,13 @@ def paged_prefill_kv(cfg: LlamaConfig, params: dict, prompt: jax.Array,
 
 def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
                          page_ids: jax.Array, page_size: int) -> dict:
-    """Scatter a prefilled row's KV ([L, P, KV, Hd]) into its allocated
-    pages. ``page_ids`` [maxp] int32 (-1 padding beyond the row's
-    pages; positions < P always map into real ids)."""
-    P = k_all.shape[1]
-    t = jnp.arange(P)
-    pidx = jnp.maximum(page_ids[t // page_size], 0)
-    off = t % page_size
+    """A prefilled row's KV ([L, P, KV, Hd]) into its allocated pages,
+    by whole pages (`paged_write_span`). ``page_ids`` [maxp] int32 (-1
+    padding beyond the row's pages; positions < P always map into real
+    ids). What the last page holds past the prompt stays."""
     return {
-        "k": paged_scatter(cache["k"], k_all, pidx, off),
-        "v": paged_scatter(cache["v"], v_all, pidx, off),
+        "k": paged_write_span(cache["k"], k_all, page_ids, 0),
+        "v": paged_write_span(cache["v"], v_all, page_ids, 0),
     }
 
 
@@ -1158,29 +1195,23 @@ def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
                         page_ids: jax.Array, start: jax.Array,
                         page_size: int,
                         real_len: Optional[jax.Array] = None) -> dict:
-    """Scatter suffix KV ([L, S, KV, Hd]) into the row's pages at
-    absolute positions start..start+S-1 (``start`` traced int32 — the
-    cached-token count varies per admission without recompiling).
+    """Suffix KV ([L, S, KV, Hd]) into the row's pages at absolute
+    positions start..start+S-1 (``start`` traced int32 — the
+    cached-token count varies per admission without recompiling), by
+    whole pages (`paged_write_span`): what the first page holds before
+    ``start`` stays.
 
     ``real_len`` (traced int32) supports BUCKETED suffixes: positions
-    at or past it are padding whose KV is garbage — they are routed to
-    scratch page 0 (never allocated, never read; serving/paged.py), so
-    a padded suffix writes exactly the same real pages as the unpadded
-    one. Without it every position is real (the pre-bucketing shape).
-    The page lookup clips explicitly: a padded tail can index past the
-    row's block table, and the gather's implicit clamp would otherwise
-    land on the table's LAST entry — a real page."""
-    S = k_suf.shape[1]
-    idx = jnp.arange(S)
-    t = start + idx
-    slot = jnp.minimum(t // page_size, page_ids.shape[0] - 1)
-    pidx = jnp.maximum(page_ids[slot], 0)
-    if real_len is not None:
-        pidx = jnp.where(idx < real_len, pidx, 0)
-    off = t % page_size
+    at or past it are padding whose KV is garbage — they are written
+    nowhere (a page that holds none but them is scratch page 0, never
+    allocated, never read; serving/paged.py), so a padded suffix
+    writes exactly the same real pages as the unpadded one. Without it
+    every position is real (the pre-bucketing shape). A padded tail can
+    reach past the row's block table: such a page is scratch too, not
+    the table's last entry, a real page."""
     return {
-        "k": paged_scatter(cache["k"], k_suf, pidx, off),
-        "v": paged_scatter(cache["v"], v_suf, pidx, off),
+        "k": paged_write_span(cache["k"], k_suf, page_ids, start, real_len),
+        "v": paged_write_span(cache["v"], v_suf, page_ids, start, real_len),
     }
 
 

@@ -51,26 +51,34 @@ logger = logging.getLogger(__name__)
 
 class _FixedShapeProgram:
     """A jitted function whose argument shapes never change (the decode
-    step: slots and the block-table width are fixed), compiled ahead of
-    time on its first call. The owner keeps the executable, so it can
-    say which Mosaic kernels are in it and what the compiler set aside
-    for its temporaries, and a drifting shape raises instead of
-    compiling a second program."""
+    step: slots and the block-table width are fixed; a paged prefill
+    program of one prompt length), compiled ahead of time on its first
+    call. The owner keeps the executable, so it can say what the
+    compiler set aside for its temporaries and, where it asks
+    (``read_kernels``: the program's text is parsed for them), which
+    Mosaic kernels are in it, and a drifting shape raises instead of
+    compiling a second program. ``on_compile`` is told the temporaries'
+    bytes once, when the program exists."""
 
-    def __init__(self, jitted):
+    def __init__(self, jitted, read_kernels: bool = True, on_compile=None):
         self._jitted = jitted
+        self._read_kernels = read_kernels
+        self._on_compile = on_compile
         self._compiled = None
         self.kernels: dict[str, int] = {}
         self.temp_bytes: Optional[int] = None
 
     def __call__(self, *args):
         if self._compiled is None:
-            from polyaxon_tpu.perf.hlo import pallas_kernels
-
             self._compiled = self._jitted.lower(*args).compile()
-            self.kernels = pallas_kernels(self._compiled.as_text())
+            if self._read_kernels:
+                from polyaxon_tpu.perf.hlo import pallas_kernels
+
+                self.kernels = pallas_kernels(self._compiled.as_text())
             self.temp_bytes = getattr(self._compiled.memory_analysis(),
                                       "temp_size_in_bytes", None)
+            if self._on_compile is not None:
+                self._on_compile(self.temp_bytes)
         return self._compiled(*args)
 
 
@@ -874,8 +882,19 @@ class ContinuousBatchingEngine:
             decode_step_filtered, donate_argnums=(1,)))
         self._decode_programs = (self._step_plain, self._step_filtered)
 
+        # The most bytes of temporaries among the paged prefill
+        # programs, whole-prompt and suffix, compiled so far (one that
+        # its lru has dropped since counts): each is one shape, the
+        # cache donated.
+        self._prefill_temp_bytes: Optional[int] = None
+
+        def prefill_program(run):
+            return _FixedShapeProgram(
+                jax.jit(run, donate_argnums=(2,)), read_kernels=False,
+                on_compile=self._note_prefill_temp)
+
         # One lru-bounded executable per prompt length for BOTH kv
-        # modes; paged folds the page scatter into the same program
+        # modes; a paged one writes the row's pages in the same program
         # (a separate jit of the [L, P, ...] insert would accumulate
         # an unbounded compile cache over prompt-length diversity).
         @lru_cache(maxsize=16)
@@ -892,7 +911,7 @@ class ContinuousBatchingEngine:
                         cache, *family.paged_prefill_kv(
                             cfg, params, prompt), page_ids, ps, *row)
 
-                return jax.jit(run, donate_argnums=(2,))
+                return prefill_program(run)
 
             def run(params, prompt):
                 return family.cb_prefill(cfg, params,
@@ -962,7 +981,7 @@ class ContinuousBatchingEngine:
                         return family.paged_insert_suffix(
                             cache, *novel, page_ids, m, ps, real_len, *row)
 
-                    return jax.jit(run, donate_argnums=(2,))
+                    return prefill_program(run)
 
                 self._suffix_prefill = compiled_suffix_prefill
         # Cache-aware admission: scan a bounded window of the pending
@@ -1693,6 +1712,11 @@ class ContinuousBatchingEngine:
             self._pool.commit_prefix(b)
         self._go_live(b, req, pos0, tok0)
 
+    def _note_prefill_temp(self, temp_bytes: Optional[int]) -> None:
+        if temp_bytes is not None:
+            self._prefill_temp_bytes = max(
+                self._prefill_temp_bytes or 0, temp_bytes)
+
     def _row_arg(self, row: int) -> tuple:
         """What a prefill program is told beside the block table: the
         row itself, for a cache with per-row leaves; nothing else."""
@@ -2055,6 +2079,11 @@ class ContinuousBatchingEngine:
             "decode_program_temp_bytes": max(
                 (program.temp_bytes for program in self._decode_programs
                  if program.temp_bytes is not None), default=None),
+            # The same of the paged prefill programs, whole-prompt and
+            # suffix (the largest compiled so far; None until one is):
+            # the prompt's own K and V and its activations where the
+            # insert writes by whole pages (llama.paged_write_span).
+            "prefill_program_temp_bytes": self._prefill_temp_bytes,
             "device": {**self._device_stats, "peak_hbm_bytes": (
                 self._device0.memory_stats() or {}).get(
                     "peak_bytes_in_use")},

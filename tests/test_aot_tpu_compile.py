@@ -84,7 +84,8 @@ CASES = {
     "nemotron_h-decode-step-pages-and-rows": (
         "nemotron_h", dict(program="decode", n_pages=8193), PAGED_KERNELS),
     "nemotron_h-prefill-sorted-dispatch": (
-        "nemotron_h", dict(program="prefill"), {"grouped_matmul": 2}),
+        "nemotron_h", dict(program="prefill", n_pages=8193),
+        {"grouped_matmul": 2}),
     # The Gated DeltaNet / gated attention / routed SwiGLU family's two
     # serving programs at a small size with the published head sizes
     # (attention 256, a quarter of it turned; state 128 x 128): the
@@ -99,14 +100,24 @@ CASES = {
         "qwen3_next", dict(program="decode"),
         {**PAGED_KERNELS, "gdn_update": 3}),
     "qwen3_next-prefill-sorted-dispatch": (
-        "qwen3_next", dict(program="prefill"), {"grouped_matmul": 9}),
+        "qwen3_next", dict(program="prefill", n_pages=20481),
+        {"grouped_matmul": 9}),
     # The two other decode programs of the benchmark's engines, whole
     # (one kernel in the text is one a layer: llama's layers are a scan).
     "llama-decode-step-mistral7b-cells": ("llama_decode", {}, PAGED_KERNELS),
     "lfm2-decode-step-lfm2-cell": ("lfm2_decode", {}, PAGED_KERNELS),
     # The same engine's whole-prompt prefill with its insert (383
-    # tokens: the cells' median prompt), for PROJECTION_SLICES.
+    # tokens: the cells' median prompt), for PROJECTION_SLICES and
+    # POOL_LIMITS, and its suffix prefill (a 128-token bucket behind
+    # 128 matched pages, where it starts and how much of it is real
+    # traced: the shared-prefix cell's program), for POOL_LIMITS.
     "llama-prefill-mistral7b-cells": ("llama_prefill", {}, {}),
+    "llama-suffix-mistral7b-cells": (
+        "llama_prefill", dict(prompt=128, n_pref=128), {}),
+    # POOL_LIMITS' control (TOKEN_WISE): the same prefill with its K and
+    # V written token by token (`_token_wise_insert`).
+    "llama-prefill-mistral7b-cells-token-wise": (
+        "llama_prefill", dict(token_wise=True), {}),
     # The windowed form of the streamed kernel at the SmallThinker
     # cell's own shapes (28 query heads on 4 KV heads of 128, 32 slots
     # of 16,384 tokens, the window space's 32 x 257 + 1 pages), named so
@@ -143,22 +154,30 @@ CASES = {
         {"paged_decode": 1, "window_decode": 3}),
 }
 
-# What a decode program may do to the page pool: name -> (one layer's
-# pool [P, KV, page, Hd], the most instructions that may give a layer's
-# pool or the whole stack as their result, the most bytes of
+# What a decode or prefill program may do to the page pool: name -> (one
+# layer's pool [P, KV, page, Hd], the most instructions that may give a
+# layer's pool or the whole stack as their result, the most bytes of
 # temporaries). Where the head size fills the lanes, updating the pool
 # in place (an aliasing fusion) and reading it (the kernel, whose result
-# is a row's) are the whole of it: before the pool rode the layer walk
-# and was written by whole pages, the Mistral program held 11 such
-# instructions a layer and 2.06 GiB of temporaries, and the small
-# Nemotron one 7. A pool of head size 64 arrives in the compiler's own
-# layout and is copied into the kernel's and back whatever the walk does
-# (ROADMAP S1): 6 such instructions, held to the 9 there were.
+# is a row's; a prefill's gather of the pages it merges into) are the
+# whole of it: before the pool rode the layer walk and was written by
+# whole pages, the Mistral decode program held 11 such instructions a
+# layer and 2.06 GiB of temporaries, and the small Nemotron one 7;
+# before a prefill wrote by whole pages (`llama.paged_write_span`) the
+# Mistral prefill copied each pool into the token scatter's order and
+# back, 0.77 GiB of temporaries (TOKEN_WISE). A pool of head size 64
+# arrives in the compiler's own layout and is copied into the kernel's
+# and back whatever the walk does (ROADMAP S1): 6 such instructions,
+# held to the 9 there were.
 POOL_LIMITS = {
     "llama-decode-step-mistral7b-cells": ((3073, 8, 16, 128), 0, 64 << 20),
-    # The pool at the benchmark's size: one of 2 MB (257 pages) the
+    "llama-prefill-mistral7b-cells": ((3073, 8, 16, 128), 0, 64 << 20),
+    "llama-suffix-mistral7b-cells": ((3073, 8, 16, 128), 0, 64 << 20),
+    # The pools at the benchmark's sizes: one of 2 MB (257 pages) the
     # compiler stages through fast memory, a copy each way.
     "nemotron_h-decode-step-pages-and-rows": ((8193, 2, 16, 128), 0, None),
+    "nemotron_h-prefill-sorted-dispatch": ((8193, 2, 16, 128), 0, None),
+    "qwen3_next-prefill-sorted-dispatch": ((20481, 2, 16, 256), 0, None),
     "lfm2-decode-step-lfm2-cell": ((3073, 8, 16, 64), 9, None),
     # Both spaces' pools (`_compile_smallthinker` gives them one size):
     # the decode step writes a page a row, the prefill the prompt's
@@ -166,6 +185,12 @@ POOL_LIMITS = {
     "smallthinker-decode-step-two-page-spaces": ((2057, 4, 16, 128), 0, None),
     "smallthinker-prefill-page-writes": ((2057, 4, 16, 128), 0, None),
 }
+
+
+# The control of a case above: the case of this suffix beside it, whose
+# program writes token by token, has to show instructions of the same
+# pool's size, so that a case cannot pass by looking for the wrong type.
+TOKEN_WISE = "-token-wise"
 
 
 # The projections a server holds ``[N, D]`` (each family's
@@ -381,26 +406,52 @@ def _compile_llama_decode(topo, held=True):
                                 max_len=4096, n_pages=3073, held=held)
 
 
+def _token_wise_insert(cache, k_all, v_all, page_ids, page):
+    """The whole-prompt insert there was before a prefill wrote by whole
+    pages: every token scattered to its (page, offset)."""
+    import jax.numpy as jnp
+
+    t = jnp.arange(k_all.shape[1])
+    pidx = jnp.maximum(page_ids[t // page], 0)
+    return {name: cache[name].at[:, pidx, :, t % page].set(
+        jnp.moveaxis(kv, 1, 0)) for name, kv in (("k", k_all), ("v", v_all))}
+
+
 def _compile_llama_prefill(topo, held=True, prompt=383, max_len=4096,
-                           page=16):
+                           page=16, n_pref=None, token_wise=False):
     """That engine's whole-prompt prefill with its insert, the cache
-    donated."""
+    donated (``token_wise``: with `_token_wise_insert` in its place);
+    with ``n_pref``, its suffix prefill of a ``prompt``-token bucket
+    behind that many matched pages (serving/batching.py
+    `compiled_suffix_prefill`)."""
     import jax
+    import jax.numpy as jnp
 
     from polyaxon_tpu.models import llama
 
     cfg = _mistral_cells_cfg()
     params, cache, i32 = _engine_avals(topo, llama, cfg, 16, 3073, page,
                                        held)
+    insert = _token_wise_insert if token_wise else llama.paged_insert_prefill
 
     def prefill(params, tokens, cache, page_ids):
-        return llama.paged_insert_prefill(
+        return insert(
             cache, *llama.paged_prefill_kv(cfg, params, tokens), page_ids,
             page)
 
+    def suffix(params, tokens, cache, page_ids, m, real_len):
+        pref = jnp.maximum(page_ids[:n_pref], 0)
+        novel = llama.paged_prefill_suffix_kv(
+            cfg, params, tokens, *llama.paged_gather_prefix(cache, pref), m)
+        return llama.paged_insert_suffix(
+            cache, *novel, page_ids, m, page, real_len)
+
+    extent = () if n_pref is None else (i32(), i32())
     with _kernel_path():
-        return jax.jit(prefill, donate_argnums=(2,)).lower(
-            params, i32(1, prompt), cache, i32(max_len // page)).compile()
+        return jax.jit(prefill if n_pref is None else suffix,
+                       donate_argnums=(2,)).lower(
+            params, i32(1, prompt), cache, i32(max_len // page),
+            *extent).compile()
 
 
 def _compile_lfm2_decode(topo):
@@ -650,9 +701,10 @@ def _child_main() -> int:
                     text, PROJECTION_SLICES[held_case])
                 report[name]["temp_bytes"] = (
                     compiled.memory_analysis().temp_size_in_bytes)
-            if name in POOL_LIMITS:
+            pool_case = name.removesuffix(TOKEN_WISE)
+            if pool_case in POOL_LIMITS:
                 report[name]["pool_sized"] = _pool_sized_instructions(
-                    text, POOL_LIMITS[name][0])
+                    text, POOL_LIMITS[pool_case][0])
                 report[name]["temp_bytes"] = (
                     compiled.memory_analysis().temp_size_in_bytes)
         except Exception as exc:  # noqa: BLE001 — the refusal IS the result
@@ -699,15 +751,23 @@ def test_compiles_for_described_tpu(aot_report, name):
 
 
 @pytest.mark.parametrize("name", sorted(POOL_LIMITS))
-def test_decode_program_leaves_the_pool_in_place(aot_report, name):
-    """Only the kernel and an in-place page write touch the pool in a
-    decode program (models/llama.py, the paged surface's comment)."""
+def test_program_leaves_the_pool_in_place(aot_report, name):
+    """Only the kernel and in-place page writes touch the pool in a
+    decode program, only the reads of pages and in-place page writes in
+    a prefill program (models/llama.py, the paged surface's comment);
+    the same prefill writing token by token copies the pool whole (the
+    control)."""
     entry = aot_report["cases"][name]
     assert entry["ok"], entry
     _, most, temp_bytes = POOL_LIMITS[name]
     assert len(entry["pool_sized"]) <= most, entry["pool_sized"]
     if temp_bytes is not None:
         assert entry["temp_bytes"] < temp_bytes, entry["temp_bytes"]
+    if name + TOKEN_WISE in CASES:
+        control = aot_report["cases"][name + TOKEN_WISE]
+        assert control["ok"], control
+        assert len(control["pool_sized"]) > most, control
+        assert control["temp_bytes"] > temp_bytes, control["temp_bytes"]
 
 
 @pytest.mark.parametrize("name", sorted(STATE_LEAVES))

@@ -98,6 +98,38 @@ def _kernel_tables(spec) -> jax.Array:
     return jnp.asarray(tables)
 
 
+# Spans for `test_span_write_equals_token_scatter`, over pages of 4:
+# where the span starts (``plain``: the Python 0 a whole-prompt insert
+# passes; else traced), how long ``kv`` is, how much of it is real
+# (None: `real_len` absent) and the row's block-table row (-1 = not
+# allocated).
+_SPAN_TABLE = [7, 3, 12, 5, 9, 14, 2, 11] + list(range(15, 33)) + [-1] * 4
+_SPAN_CASES = {
+    "whole-prompt-shorter-than-a-page": dict(start=0, plain=True, S=3),
+    "whole-prompt-to-a-pages-edge": dict(start=0, plain=True, S=4),
+    "whole-prompt-3-pages": dict(start=0, plain=True, S=11),
+    "whole-prompt-25-pages": dict(start=0, plain=True, S=98),
+    "traced-0-one-page": dict(start=0, S=4),
+    "page-aligned-start": dict(start=8, S=7),
+    "inside-a-page-and-short-of-its-end": dict(start=5, S=2),
+    "inside-a-page-to-its-edge": dict(start=6, S=6),
+    "inside-a-page-3-pages": dict(start=5, S=9),
+    "inside-a-page-25-pages": dict(start=3, S=97),
+    "bucket-padding-goes-nowhere": dict(start=5, S=8, real=3),
+    "bucket-padding-page-aligned": dict(start=8, S=16, real=9),
+    "whole-bucket-real": dict(start=2, S=8, real=8),
+    # The padded tail reaches positions 9 .. 24 of a table that ends at
+    # 16: what lies past it is scratch, not the table's last page.
+    "padded-tail-past-the-table": dict(
+        start=9, S=16, real=5, table=[7, 3, 12, 5]),
+    "real-tail-ends-with-the-table": dict(
+        start=9, S=16, real=7, table=[7, 3, 12, 5]),
+    # Real positions on a page never allocated land on scratch page 0.
+    "unallocated-page-inside-the-span": dict(
+        start=2, S=9, table=[7, -1, 12, -1]),
+}
+
+
 class TestPagedDecodeParity:
     def test_matches_dense_ragged_step_by_step(self):
         """A row whose pages cover 0..p must produce the dense ragged
@@ -538,6 +570,43 @@ class TestPagedKernel:
                 np.asarray(kv[b]) for b in wrote.get(off, ())]
             assert any((scratch[:, off] == a).all() for a in allowed), off
 
+    @pytest.mark.parametrize("case", sorted(_SPAN_CASES))
+    def test_span_write_equals_token_scatter(self, case):
+        """`paged_write_span` (a prefill's insert: the touched pages
+        read, merged and put back) leaves at every real position of an
+        allocated page what a token-wise scatter of the real tokens
+        leaves, and everywhere else what was there: the slots before
+        ``start`` and past the span's end in the pages it touches, every
+        page it does not touch, and the padding past ``real_len``, which
+        goes nowhere but scratch page 0."""
+        spec = _SPAN_CASES[case]
+        L, P, KV, page, Hd = 2, 40, 2, 4, 8
+        start, S, real = spec["start"], spec["S"], spec.get("real")
+        table = np.asarray(spec.get("table", _SPAN_TABLE), np.int32)
+        # The marker: what every slot held before, no two alike, so
+        # that equality with `want` below holds every slot of every page
+        # but scratch to "its token, or what was there".
+        pool = 100.0 + jnp.arange(L * P * KV * page * Hd,
+                                  dtype=jnp.float32).reshape(
+                                      L, P, KV, page, Hd)
+        kv = jax.random.normal(jax.random.key(11), (L, S, KV, Hd),
+                               jnp.float32)
+
+        t = start + np.arange(S if real is None else real)
+        pidx = np.maximum(table[t // page], 0)
+        want = np.asarray(pool.at[:, pidx, :, t % page].set(
+            jnp.moveaxis(kv[:, :len(t)], 1, 0)))
+
+        if spec.get("plain"):
+            got = llama.paged_write_span(pool, kv, jnp.asarray(table), start)
+        else:
+            got = jax.jit(llama.paged_write_span)(
+                pool, kv, jnp.asarray(table), jnp.int32(start),
+                None if real is None else jnp.int32(real))
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        assert np.isfinite(got[:, 0]).all()
+
 
 class TestPrefixCache:
     def test_shared_prompt_pages_reused(self):
@@ -814,6 +883,47 @@ class TestRadixPrefixSharing:
         assert stats["kv_cow_forks"] >= 1
         assert stats["prefill_tokens_skipped"] > 0
         assert stats["kv_invariant_violations"] == 0
+
+    def test_suffix_from_inside_a_forked_page_matches_whole_prompt(self):
+        """A prompt that leaves another's inside a page: the shared page
+        is forked and the suffix insert merges the novel tokens into
+        the copy, behind what it holds of the match. The tokens decoded
+        are those of the same prompt prefilled whole (an engine that
+        shares nothing), and the first prompt's pages still hold what
+        they held: asked again, it decodes the same."""
+        cfg = _cfg()
+        params = llama.init(cfg, jax.random.key(0))["params"]
+        p1 = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]
+        p2 = p1[:6] + [7, 7, 5, 3, 2, 3, 8, 4]  # leaves at 6: page 1, slot 2
+        whole = ContinuousBatchingEngine("llama_tiny", cfg, params,
+                                         slots=1, max_len=32, kv="paged",
+                                         page_size=4, prefix_cache=False)
+        try:
+            want1 = whole.generate([p1], max_new_tokens=5, timeout=300)
+            want2 = whole.generate([p2], max_new_tokens=5, timeout=300)
+            assert whole.stats()["prefill_tokens_skipped"] == 0
+        finally:
+            whole.stop()
+        engine = ContinuousBatchingEngine("llama_tiny", cfg, params,
+                                          slots=1, max_len=32,
+                                          kv="paged", page_size=4)
+        try:
+            before = engine.stats()
+            got1 = engine.generate([p1], max_new_tokens=5, timeout=300)
+            got2 = engine.generate([p2], max_new_tokens=5, timeout=300)
+            again1 = engine.generate([p1], max_new_tokens=5, timeout=300)
+            stats = engine.stats()
+        finally:
+            engine.stop()
+        assert (got1, got2, again1) == (want1, want2, want1)
+        assert stats["kv_cow_forks"] >= 1
+        assert stats["prefill_tokens_skipped"] >= 6
+        assert stats["kv_invariant_violations"] == 0
+        # One whole-prompt and one suffix program have compiled: the
+        # larger's temporaries (on a TPU, megabytes while the insert
+        # writes by whole pages: tests/test_aot_tpu_compile).
+        assert before["prefill_program_temp_bytes"] is None
+        assert isinstance(stats["prefill_program_temp_bytes"], int)
 
     def test_engine_full_prefill_cache_hit(self):
         """A prompt whose whole prefill sits in the tree (a previous
