@@ -4,7 +4,11 @@ A family is one module of this package with a ``CONFIGS`` dict (name →
 its config dataclass) and ``model_def(name, **overrides)``. ``FAMILIES``
 below is the one list of them: the factory table, the server's and the
 engine's lookup (``family_of``) and the train loop's (``config_of``) all
-read it, so a new family is its file and its name in that tuple.
+read it, so a new family is its file and its name in that tuple. A
+decoder whose layers follow a static plan (lfm2, nemotron_h, qwen3_next,
+smallthinker) is its config, draw, mixers and expert block and one
+table for ``models/plan.py``, which holds the walks and the engine's
+surfaces they share.
 Factories accept config overrides (e.g. ``seq_len``/``remat``) from the
 JAXJob runtime section.
 """

@@ -40,41 +40,29 @@ whole pages for a cache with such a leaf. ``moe_expert_tokens``
 ``[L_moe, E]`` counts, on the device, the (row, choice) pairs the
 decode steps routed to each expert.
 
-One sequence pass (`_sequence_pass`: a suffix behind an optional
-prefix) serves ``forward``, the whole-prompt prefill and the suffix
-prefill; speculation and chunked dense prefill need ``decode_chunk``,
-which this family does not have (the state has no rollback), and the
-engine refuses them by that.
+The walks over the plan and the surfaces that do not touch the state's
+pages are ``models/plan.py``'s, bound below to this family's table
+(`FAMILY`). Speculation and chunked dense prefill need
+``decode_chunk``, which this family does not have (the state has no
+rollback), and the engine refuses them by that.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models import llama, moe
+from polyaxon_tpu.models import llama, moe, plan
 from polyaxon_tpu.models.common import (
-    Batch,
-    ModelDef,
     Variables,
-    _embed_rows,
     _w,
-    chunked_lm_loss,
-    lm_logits,
     rms_norm,
     scaled_init,
-    shift_right,
     truncated_normal_init,
-)
-# Decoder-only admission and the K/V page gather are llama's as they are.
-from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
-    cb_admission,
-    cb_validate,
-    paged_gather,
 )
 
 SEQ2SEQ = False
@@ -142,31 +130,26 @@ CONFIGS: dict[str, Lfm2Config] = {
 }
 
 
-@functools.lru_cache(maxsize=None)
-def _plan(layer_types: tuple, n_dense: int) -> tuple:
-    seen = {"attn": 0, "conv": 0, "dense": 0, "moe": 0}
-    out = []
-    for l, kind in enumerate(layer_types):
-        op = "attn" if kind == "full_attention" else "conv"
-        ffn = "dense" if l < n_dense else "moe"
-        out.append((op, seen[op], ffn, seen[ffn]))
-        seen[op] += 1
-        seen[ffn] += 1
-    return tuple(out)
+def _kinds(cfg: Lfm2Config) -> tuple:
+    """(every layer's operator kind, every layer's FFN kind)."""
+    return (tuple("attn" if kind == "full_attention" else "conv"
+                  for kind in cfg.layer_types),
+            tuple("dense" if l < cfg.n_dense_layers else "moe"
+                  for l in range(cfg.n_layers)))
 
 
 def layer_plan(cfg: Lfm2Config) -> tuple:
     """Per layer, in published order: (operator kind, its index in that
     kind's stack, FFN kind, its index in that kind's stack)."""
-    return _plan(tuple(cfg.layer_types), cfg.n_dense_layers)
+    ops, ffns = _kinds(cfg)
+    return tuple(op + ffn for op, ffn
+                 in zip(plan.indexed(ops), plan.indexed(ffns)))
 
 
 def kind_counts(cfg: Lfm2Config) -> dict:
-    plan = layer_plan(cfg)
-    return {"attn": sum(p[0] == "attn" for p in plan),
-            "conv": sum(p[0] == "conv" for p in plan),
-            "dense": sum(p[2] == "dense" for p in plan),
-            "moe": sum(p[2] == "moe" for p in plan)}
+    ops, ffns = _kinds(cfg)
+    return {**plan.kind_counts(ops, ("attn", "conv")),
+            **plan.kind_counts(ffns, ("dense", "moe"))}
 
 
 def init(cfg: Lfm2Config, rng: jax.Array) -> Variables:
@@ -270,11 +253,6 @@ READ_AT_FLOAT32 = frozenset(
 HELD_TRANSPOSED = llama.HELD_TRANSPOSED
 
 
-def _at(stack: dict, i: int) -> dict:
-    """Layer `i` of one kind's stacked parameters."""
-    return {name: leaf[i] for name, leaf in stack.items()}
-
-
 # ------------------------------------------------------------ the layers
 def conv_op(cfg: Lfm2Config, layer: dict, x: jax.Array, state: jax.Array):
     """The gated short convolution over ``x`` [B, S, D] behind the
@@ -315,161 +293,76 @@ def expert_ffn(cfg: Lfm2Config, layer: dict, x: jax.Array):
     return x + out.reshape(B, S, D), onehot
 
 
-def _ffn(cfg: Lfm2Config, params: dict, kind: str, i: int, x: jax.Array):
-    """(x after layer's FFN residual, the expert choices or None)."""
-    if kind == "dense":
-        return llama._mlp(cfg, x, _at(params["dense"], i)), None
-    return expert_ffn(cfg, _at(params["moe"], i), x)
+def init_rows(cfg: Lfm2Config, rows: int) -> dict:
+    """What ``rows`` sequences carry through the convolution layers,
+    zeroed: the ``z`` of the last K−1 positions."""
+    return {"conv": jnp.zeros((kind_counts(cfg)["conv"], rows,
+                               cfg.conv_kernel - 1, cfg.dim), cfg.dtype)}
 
 
-def _head(cfg: Lfm2Config, params: dict, x: jax.Array) -> jax.Array:
-    """Final norm and the tied head: hidden [..., D] → fp32 logits."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return lm_logits(x, params["embed"], cfg.dtype, transpose=True,
-                     chunk=cfg.lm_logits_chunk)
+def _conv_sequence(cfg: Lfm2Config, layer: dict, x: jax.Array, i: int,
+                   behind: plan.Behind):
+    """Keeps ``z`` whole, the carried state in front [B, K−1+S, D]: a
+    prefill leaves a snapshot in every page it touches."""
+    x, z = conv_op(cfg, layer, x, behind.carried["conv"][i])
+    return x, {"conv": z}
 
 
-def _sequence_pass(cfg: Lfm2Config, params: dict, tokens: jax.Array,
-                   k_prefix: Optional[jax.Array] = None,
-                   v_prefix: Optional[jax.Array] = None,
-                   conv_state: Optional[jax.Array] = None, m=0):
-    """One causal pass over ``tokens`` [B, S] at absolute positions
-    m..m+S−1, behind a prefix that already exists: its K/V
-    ``k_prefix``/``v_prefix`` [L_attn, B, Mpad, KV, Hd] (columns at or
-    past ``m`` masked) and the convolution state after position m−1
-    ``conv_state`` [L_conv, B, K−1, D]. Without a prefix (all None, m =
-    0) it is the whole-sequence forward. Returns (hidden before the
-    final norm [B, S, D], k [L_attn, B, S, KV, Hd], v, z [L_conv, B,
-    K−1+S, D] with each layer's carried state in front)."""
-    dt = cfg.dtype
-    B, S = tokens.shape
-    n = kind_counts(cfg)
-    if k_prefix is None:
-        shape = (n["attn"], B, 0, cfg.n_kv_heads, cfg.head_dim)
-        k_prefix = v_prefix = jnp.zeros(shape, dt)
-    if conv_state is None:
-        conv_state = jnp.zeros(
-            (n["conv"], B, cfg.conv_kernel - 1, cfg.dim), dt)
-    positions = jnp.broadcast_to(
-        m + jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    valid = llama._suffix_mask(S, k_prefix.shape[2], m)
-    x = _embed_rows(params["embed"], tokens, dt)
-    ks, vs, zs = [], [], []
-    for op, oi, ffn, fi in layer_plan(cfg):
-        if op == "attn":
-            x, k, v = llama.suffix_attn_step(
-                cfg, _at(params["attn"], oi), x, k_prefix[oi], v_prefix[oi],
-                positions, valid)
-            ks.append(k)
-            vs.append(v)
-        else:
-            x, z = conv_op(cfg, _at(params["conv"], oi), x, conv_state[oi])
-            zs.append(z)
-        x, _ = _ffn(cfg, params, ffn, fi, x)
-    return x, jnp.stack(ks), jnp.stack(vs), jnp.stack(zs)
+def _conv_step(cfg: Lfm2Config, layer: dict, x: jax.Array, i: int,
+               state: dict, started: jax.Array):
+    """`conv_op` for one position a row over the cache's ``conv`` leaf:
+    the slot cache's [L_conv, B, K−1, D], read and written by row, or
+    the pool's [L_conv, P, K−1, D] at ``state["pages"]``, (the page of
+    each row's last position, the page of this one)."""
+    read, write = state.get("pages", (slice(None), slice(None)))
+    carried = jnp.where(started[:, None, None], state["conv"][i, read], 0)
+    x, z = conv_op(cfg, layer, x, carried)
+    return x, {**state, "conv": state["conv"].at[i, write].set(z[:, 1:])}
 
 
-def forward(cfg: Lfm2Config, params: dict, tokens: jax.Array) -> jax.Array:
-    """Token ids [B, S] → logits [B, S, vocab] fp32."""
-    x, _, _, _ = _sequence_pass(cfg, params, tokens)
-    return _head(cfg, params, x)
+FAMILY = plan.Family(
+    name=__name__, configs=CONFIGS, init=init,
+    logical_axes=logical_axes, layers=layer_plan,
+    mixers={"attn": plan.ATTENTION,
+            "conv": plan.Mixer("conv", _conv_sequence, _conv_step, None)},
+    ffns={"dense": plan.DENSE,
+          "moe": plan.Ffn(None, lambda cfg, params, i, x, _: expert_ffn(
+              cfg, plan._at(params["moe"], i), x))},
+    init_rows=init_rows)
 
-
-# ------------------------------------------------------- dense slot cache
-def init_cache(cfg: Lfm2Config, batch: int, max_len: int) -> dict:
-    """The slot cache: K/V [L_attn, B, C, KV, Hd] and the convolution
-    state [L_conv, B, K−1, D], compute dtype."""
-    n = kind_counts(cfg)
-    kv = (n["attn"], batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "conv": jnp.zeros((n["conv"], batch, cfg.conv_kernel - 1,
-                               cfg.dim), cfg.dtype)}
+# The engine's names (``serving/batching.py`` finds a surface by
+# ``hasattr``), those the state's pages do not touch: `plan`'s
+# functions over this family's table; admission and the K/V page gather
+# are llama's as they are.
+forward = functools.partial(plan.forward, FAMILY)
+init_cache = cb_init_cache = functools.partial(plan.init_cache, FAMILY)
+decode_step_ragged = functools.partial(plan.decode_step_ragged, FAMILY)
+decode_step = functools.partial(plan.decode_step, decode_step_ragged)
+insert_cache_row = plan.insert_cache_row
+cb_admission, cb_validate = llama.cb_admission, llama.cb_validate
+paged_gather = llama.paged_gather
+apply = functools.partial(plan.apply, FAMILY)
+model_def = functools.partial(plan.model_def, FAMILY)
 
 
 def prefill(cfg: Lfm2Config, params: dict, prompt: jax.Array, max_len: int):
-    """One pass over the prompt [B, P]: (last-position logits [B, V]
-    fp32, the slot cache holding it)."""
-    P = prompt.shape[1]
-    if P > max_len:
-        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
-    x, k, v, z = _sequence_pass(cfg, params, prompt)
-    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
-    cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad),
-             "conv": z[:, :, P:]}
-    return _head(cfg, params, x[:, -1]), cache
+    """`plan.prefill`; of the pass's ``z`` a slot keeps the state after
+    the prompt's last position."""
+    logits, cache = plan.prefill(FAMILY, cfg, params, prompt, max_len)
+    return logits, {**cache, "conv": cache["conv"][:, :, prompt.shape[1]:]}
 
 
-def decode_step_ragged(cfg: Lfm2Config, params: dict, cache: dict,
-                       tokens: jax.Array, pos: jax.Array):
-    """One step with per-row positions ([B], −1 = idle) over the slot
-    cache: llama's ``cached_attn_step`` in the attention layers, the
-    row's own carried state in the convolution layers (zeros at
-    position 0; an idle row's is garbage the next admission's insert
-    replaces)."""
-    dt = cfg.dtype
-    positions, slot, valid = llama.ragged_cache_coords(pos,
-                                                       cache["k"].shape[2])
-    carried = (pos > 0)[:, None, None]
-    x = _embed_rows(params["embed"], tokens, dt)[:, None, :]
-    k_all, v_all, conv = cache["k"], cache["v"], cache["conv"]
-    for op, oi, ffn, fi in layer_plan(cfg):
-        if op == "attn":
-            x, k, v = llama.cached_attn_step(
-                cfg, _at(params["attn"], oi), x, k_all[oi], v_all[oi],
-                positions, slot, valid)
-            k_all, v_all = k_all.at[oi].set(k), v_all.at[oi].set(v)
-        else:
-            state = jnp.where(carried, conv[oi], 0)
-            x, z = conv_op(cfg, _at(params["conv"], oi), x, state)
-            conv = conv.at[oi].set(z[:, 1:])
-        x, _ = _ffn(cfg, params, ffn, fi, x)
-    return _head(cfg, params, x[:, 0]), {"k": k_all, "v": v_all,
-                                         "conv": conv}
-
-
-def decode_step(cfg: Lfm2Config, params: dict, cache: dict,
-                tokens: jax.Array, pos: jax.Array):
-    """Scalar-position decode: every row at the same position."""
-    return decode_step_ragged(
-        cfg, params, cache, tokens,
-        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
-
-
-def generate(cfg: Lfm2Config, params: dict, prompt: jax.Array, **sampling):
-    """Greedy or sampled continuation [B, max_new]: llama's
-    ``generate_loop`` over this family's prefill and decode step."""
-    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
-                               **sampling)
-
-
-def cb_init_cache(cfg: Lfm2Config, slots: int, max_len: int) -> dict:
-    return init_cache(cfg, slots, max_len)
-
-
-def cb_prefill(cfg: Lfm2Config, params: dict, prompt: jax.Array,
-               max_len: int) -> dict:
-    return prefill(cfg, params, prompt, max_len)[1]
-
-
-def insert_cache_row(cache: dict, row: dict, b) -> dict:
-    """A prefilled row into slot `b`: every leaf's axis 1 is the slot."""
-    return {name: jax.lax.dynamic_update_slice(
-        leaf, row[name], (0, b) + (0,) * (leaf.ndim - 2))
-        for name, leaf in cache.items()}
+cb_prefill = functools.partial(plan.cb_prefill, prefill)
+generate = functools.partial(llama.generate_loop, prefill, decode_step)
 
 
 # ------------------------------------------------------------ paged cache
 def paged_init_cache(cfg: Lfm2Config, n_pages: int, page_size: int) -> dict:
     """The hybrid pool (module docstring): K/V pages of the attention
-    layers, one convolution state a page a convolution layer, and the
-    decode steps' routed pairs by expert."""
-    n = kind_counts(cfg)
-    kv = (n["attn"], n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "conv": jnp.zeros((n["conv"], n_pages, cfg.conv_kernel - 1,
-                               cfg.dim), cfg.dtype),
-            "moe_expert_tokens": jnp.zeros((n["moe"], cfg.n_experts),
-                                           jnp.int32)}
+    layers, the decode steps' routed pairs by expert, and one
+    convolution state a page a convolution layer."""
+    return {**plan.paged_init_cache(FAMILY, cfg, n_pages, page_size),
+            "conv": init_rows(cfg, n_pages)["conv"]}
 
 
 def decode_step_paged(cfg: Lfm2Config, params: dict, cache: dict,
@@ -480,33 +373,17 @@ def decode_step_paged(cfg: Lfm2Config, params: dict, cache: dict,
     and leaves the new one in the page of t; idle rows write the
     scratch page. Live rows' routed (row, choice) pairs are added to
     ``moe_expert_tokens``."""
-    dt = cfg.dtype
-    B = tokens.shape[0]
     page = cache["k"].shape[-2]
-    positions, write_page, write_off, valid = llama.paged_coords(
-        pos, tables, page)
+    coords = llama.paged_coords(pos, tables, page)
     before = jnp.maximum(pos - 1, 0)
-    read_page = jnp.maximum(tables[jnp.arange(B), before // page], 0)
-    carried = (pos > 0)[:, None, None]
-    live = (pos >= 0).astype(jnp.int32)
-    x = _embed_rows(params["embed"], tokens, dt)[:, None, :]
-    k_pool, v_pool, conv = cache["k"], cache["v"], cache["conv"]
-    routed = cache["moe_expert_tokens"]
-    for op, oi, ffn, fi in layer_plan(cfg):
-        if op == "attn":
-            x, k_pool, v_pool = llama.paged_attn_step(
-                cfg, _at(params["attn"], oi), x, k_pool, v_pool, oi,
-                positions, write_page, write_off, tables, valid)
-        else:
-            state = jnp.where(carried, conv[oi, read_page], 0)
-            x, z = conv_op(cfg, _at(params["conv"], oi), x, state)
-            conv = conv.at[oi, write_page].set(z[:, 1:])
-        x, onehot = _ffn(cfg, params, ffn, fi, x)
-        if onehot is not None:
-            routed = routed.at[fi].add(jnp.einsum(
-                "tke,t->e", onehot.astype(jnp.int32), live))
-    return _head(cfg, params, x[:, 0]), {
-        "k": k_pool, "v": v_pool, "conv": conv, "moe_expert_tokens": routed}
+    read_page = jnp.maximum(
+        tables[jnp.arange(tokens.shape[0]), before // page], 0)
+    kv, attend = plan.paged_attend(cfg, cache, tables, coords)
+    logits, state, counters = plan.decode(
+        FAMILY, cfg, params, tokens, pos, attend,
+        {"conv": cache["conv"], "pages": (read_page, coords[1])},
+        plan.counters_of(cache))
+    return logits, {**kv, "conv": state["conv"], **counters}
 
 
 def paged_gather_prefix(cache: dict, page_ids: jax.Array) -> tuple:
@@ -530,13 +407,14 @@ def paged_prefill_suffix_kv(cfg: Lfm2Config, params: dict,
     own chunks both give."""
     page = k_prefix.shape[1] // max(conv_pages.shape[1], 1)
     if conv_pages.shape[1]:
-        state = jnp.where(m > 0, conv_pages[:, jnp.maximum(m - 1, 0) // page],
-                          0)[:, None]
+        carried = {"conv": jnp.where(
+            m > 0, conv_pages[:, jnp.maximum(m - 1, 0) // page], 0)[:, None]}
     else:
-        state = None
-    _, k, v, z = _sequence_pass(cfg, params, suffix, k_prefix[:, None],
-                                v_prefix[:, None], state, m)
-    return k[:, 0], v[:, 0], z[:, 0]
+        carried = None
+    _, k, v, kept = plan.sequence_pass(
+        FAMILY, cfg, params, suffix, k_prefix[:, None], v_prefix[:, None],
+        carried, m)
+    return k[:, 0], v[:, 0], kept["conv"][:, 0]
 
 
 def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
@@ -568,8 +446,8 @@ def paged_insert_suffix(cache: dict, k_suf: jax.Array, v_suf: jax.Array,
 def paged_prefill_kv(cfg: Lfm2Config, params: dict, prompt: jax.Array):
     """The whole prompt [1, P] as a suffix behind nothing: (k, v, z) for
     `paged_insert_prefill`."""
-    _, k, v, z = _sequence_pass(cfg, params, prompt)
-    return k[:, 0], v[:, 0], z[:, 0]
+    k, v, kept = plan.paged_prefill_kv(FAMILY, cfg, params, prompt)
+    return k, v, kept["conv"][:, 0]
 
 
 def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
@@ -577,33 +455,3 @@ def paged_insert_prefill(cache: dict, k_all: jax.Array, v_all: jax.Array,
                          page_size: int) -> dict:
     return paged_insert_suffix(cache, k_all, v_all, z, page_ids,
                                jnp.int32(0), page_size)
-
-
-# --------------------------------------------------------------- training
-def apply(cfg: Lfm2Config, variables: Variables, batch: Batch,
-          train: bool = True, rng: Optional[jax.Array] = None):
-    """Next-token loss (chunked head). No auxiliary loss: the published
-    model balances its experts through ``expert_bias``, which this
-    objective leaves alone."""
-    tokens = batch["tokens"]
-    if batch.get("segments") is not None:
-        raise ValueError("lfm2 models do not support packed sequences "
-                         "(segments): the convolution state would cross them")
-    params = variables["params"]
-    x, _, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    loss, acc = chunked_lm_loss(x, params["embed"].T.astype(cfg.dtype),
-                                tokens, batch.get("mask"),
-                                chunk=cfg.loss_chunk)
-    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
-
-
-def model_def(name: str, **overrides) -> ModelDef:
-    cfg = dataclasses.replace(CONFIGS[name], **overrides)
-    return ModelDef(
-        name=name,
-        init=functools.partial(init, cfg),
-        apply=functools.partial(apply, cfg),
-        logical_axes=functools.partial(logical_axes, cfg),
-        unit="tokens",
-    )

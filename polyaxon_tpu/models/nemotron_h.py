@@ -50,11 +50,11 @@ for such a cache (``serving/paged.py``). ``moe_expert_tokens``
 expert, ``moe_pairs_elsewhere`` ``[L_moe]`` those routed to experts
 this chip does not hold.
 
-One sequence pass (`_sequence_pass`: a suffix behind an optional
-prefix) serves ``forward``, the whole-prompt prefill and the suffix
-prefill; speculation and chunked dense prefill need ``decode_chunk``,
-which this family does not have (the state has no rollback), and the
-engine refuses them by that.
+The walks over the plan and the engine's surfaces are ``models/
+plan.py``'s, bound below to this family's table (`FAMILY`). Speculation
+and chunked dense prefill need ``decode_chunk``, which this family does
+not have (the state has no rollback), and the engine refuses them by
+that.
 """
 
 from __future__ import annotations
@@ -67,39 +67,14 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models import llama, moe, row_state
+from polyaxon_tpu.models import llama, moe, plan
 from polyaxon_tpu.models.common import (
-    Batch,
-    ModelDef,
     Variables,
-    _embed_rows,
     _w,
-    chunked_lm_loss,
-    lm_logits,
+    put_layer,
     rms_norm,
     scaled_init,
-    shift_right,
     truncated_normal_init,
-)
-# A prefilled row goes into its slot as the other hybrid family's does
-# (every leaf's axis 1 is the slot); decoder-only admission and the K/V
-# page gather are llama's as they are.
-from polyaxon_tpu.models.lfm2 import (  # noqa: F401  (re-exported hook)
-    _at,
-    insert_cache_row,
-)
-from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
-    cb_admission,
-    cb_validate,
-    paged_gather,
-)
-# The per-row side of the engine's paged surface, shared with the other
-# family whose rows carry a state.
-from polyaxon_tpu.models.row_state import (  # noqa: F401  (re-exported hooks)
-    paged_gather_prefix,
-    paged_insert_prefill,
-    paged_insert_suffix,
-    put_layer as _put,
 )
 from polyaxon_tpu.ops import mamba2
 
@@ -192,25 +167,18 @@ CONFIGS: dict[str, NemotronHConfig] = {
 _KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
 
 
-@functools.lru_cache(maxsize=None)
-def _plan(pattern: str) -> tuple:
-    seen = {"ssm": 0, "attn": 0, "moe": 0}
-    out = []
-    for char in pattern:
-        kind = _KINDS[char]
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return tuple(out)
+def _kinds(cfg: NemotronHConfig) -> tuple:
+    return tuple(_KINDS[char] for char in cfg.pattern)
 
 
 def layer_plan(cfg: NemotronHConfig) -> tuple:
     """Per layer, in published order: (kind, its index in that kind's
     stack)."""
-    return _plan(cfg.pattern)
+    return plan.indexed(_kinds(cfg))
 
 
 def kind_counts(cfg: NemotronHConfig) -> dict:
-    return {kind: cfg.pattern.count(char) for char, kind in _KINDS.items()}
+    return plan.kind_counts(_kinds(cfg), tuple(_KINDS.values()))
 
 
 def init(cfg: NemotronHConfig, rng: jax.Array) -> Variables:
@@ -390,13 +358,6 @@ def expert_layer(cfg: NemotronHConfig, stack: dict, i: int, x: jax.Array):
     return x + out.reshape(B, S, D), onehot
 
 
-def _head(cfg: NemotronHConfig, params: dict, x: jax.Array) -> jax.Array:
-    """Final norm and the untied head: hidden [..., D] → fp32 logits."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return lm_logits(x, params["lm_head"], cfg.dtype,
-                     chunk=cfg.lm_logits_chunk)
-
-
 def init_rows(cfg: NemotronHConfig, rows: int) -> dict:
     """What ``rows`` sequences carry through the Mamba-2 layers, zeroed:
     the state, float32, and the convolution's last K−1 inputs."""
@@ -407,242 +368,63 @@ def init_rows(cfg: NemotronHConfig, rows: int) -> dict:
                               cfg.dtype)}
 
 
-def _sequence_pass(cfg: NemotronHConfig, params: dict, tokens: jax.Array,
-                   k_prefix: Optional[jax.Array] = None,
-                   v_prefix: Optional[jax.Array] = None,
-                   carried: Optional[dict] = None, m=0, real_len=None):
-    """One causal pass over ``tokens`` [B, S] at absolute positions
-    m..m+S−1, behind a prefix that already exists: its K/V
-    ``k_prefix``/``v_prefix`` [L_attn, B, Mpad, KV, Hd] (columns at or
-    past ``m`` masked) and what the Mamba-2 layers carry after position
-    m−1, ``carried`` (`init_rows`' two leaves for B rows). Without a
-    prefix (all None, m = 0) it is the whole-sequence forward.
-    Positions at or past ``real_len`` are padding (``mamba2.mixer``).
-    Returns (hidden before the final norm [B, S, D], k [L_attn, B, S,
-    KV, Hd], v, what the layers carry after the last real position)."""
-    dt = cfg.dtype
-    B, S = tokens.shape
-    if k_prefix is None:
-        shape = (kind_counts(cfg)["attn"], B, 0, cfg.n_kv_heads,
-                 cfg.head_dim)
-        k_prefix = v_prefix = jnp.zeros(shape, dt)
-    if carried is None:
-        carried = init_rows(cfg, B)
-    positions = jnp.broadcast_to(
-        m + jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    valid = llama._suffix_mask(S, k_prefix.shape[2], m)
-    x = _embed_rows(params["embed"], tokens, dt)
-    ks, vs, tails, states = [], [], [], []
-    for kind, i in layer_plan(cfg):
-        if kind == "attn":
-            x, k, v = llama.suffix_attn_step(
-                cfg, _at(params["attn"], i), x, k_prefix[i], v_prefix[i],
-                positions, valid)
-            ks.append(k)
-            vs.append(v)
-        elif kind == "ssm":
-            x, tail, state = ssm_layer(
-                cfg, _at(params["ssm"], i), x, carried["conv"][i],
-                carried["ssm"][i], real_len)
-            tails.append(tail)
-            states.append(state)
-        else:
-            x, _ = expert_layer(cfg, params["moe"], i, x)
-    return x, jnp.stack(ks), jnp.stack(vs), {
-        "ssm": jnp.stack(states), "conv": jnp.stack(tails)}
+def _ssm_sequence(cfg: NemotronHConfig, layer: dict, x: jax.Array, i: int,
+                  behind: plan.Behind):
+    x, tail, state = ssm_layer(cfg, layer, x, behind.carried["conv"][i],
+                               behind.carried["ssm"][i], behind.real_len)
+    return x, {"ssm": state, "conv": tail}
 
 
-def forward(cfg: NemotronHConfig, params: dict,
-            tokens: jax.Array) -> jax.Array:
-    """Token ids [B, S] → logits [B, S, vocab] fp32."""
-    x, _, _, _ = _sequence_pass(cfg, params, tokens)
-    return _head(cfg, params, x)
-
-
-# ------------------------------------------------------- dense slot cache
-def init_cache(cfg: NemotronHConfig, batch: int, max_len: int) -> dict:
-    """The slot cache: K/V [L_attn, B, C, KV, Hd] and what each slot
-    carries through the Mamba-2 layers (`init_rows`)."""
-    kv = (kind_counts(cfg)["attn"], batch, max_len, cfg.n_kv_heads,
-          cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            **init_rows(cfg, batch)}
-
-
-def prefill(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
-            max_len: int):
-    """One pass over the prompt [B, P]: (last-position logits [B, V]
-    fp32, the slot cache holding it)."""
-    P = prompt.shape[1]
-    if P > max_len:
-        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
-    x, k, v, carried = _sequence_pass(cfg, params, prompt)
-    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
-    cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad), **carried}
-    return _head(cfg, params, x[:, -1]), cache
-
-
-def _decode_layers(cfg: NemotronHConfig, params: dict, x: jax.Array,
-                   pos: jax.Array, attend, ssm: jax.Array, conv: jax.Array,
-                   counters: Optional[dict] = None):
-    """One position a row through every layer. ``attend(i, layer, x)``
-    is the attention layer over the cache in use; ``ssm``/``conv`` are
-    the rows' carried leaves ([L_ssm, rows ≥ B, ...]; a row at position
-    0 starts from zeros, an idle row's is garbage the next admission's
-    prefill replaces), updated in place a layer at a time. Live rows'
-    routed pairs are added to ``counters`` where given."""
+def _ssm_step(cfg: NemotronHConfig, layer: dict, x: jax.Array, i: int,
+              rows: dict, started: jax.Array):
+    """`ssm_layer` for one position a row over the rows' leaves
+    ([L_ssm, rows ≥ B, ...]), layer ``i`` of each updated in place."""
     B = x.shape[0]
-    started = pos > 0
-    live = (pos >= 0).astype(jnp.int32)
-    for kind, i in layer_plan(cfg):
-        if kind == "attn":
-            x = attend(i, _at(params["attn"], i), x)
-        elif kind == "ssm":
-            state = jnp.where(started[:, None, None, None], ssm[i, :B], 0.0)
-            tail = jnp.where(started[:, None, None], conv[i, :B], 0)
-            x, tail, state = ssm_layer(cfg, _at(params["ssm"], i), x, tail,
-                                       state)
-            ssm, conv = _put(ssm, state, i), _put(conv, tail, i)
-        else:
-            x, onehot = expert_layer(cfg, params["moe"], i, x)
-            if counters is not None:
-                held = jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live)
-                counters = {
-                    "moe_expert_tokens":
-                        counters["moe_expert_tokens"].at[i].add(held),
-                    "moe_pairs_elsewhere":
-                        counters["moe_pairs_elsewhere"].at[i].add(
-                            cfg.experts_per_token * jnp.sum(live)
-                            - jnp.sum(held))}
-    return x, ssm, conv, counters
+    state = jnp.where(started[:, None, None, None], rows["ssm"][i, :B], 0.0)
+    tail = jnp.where(started[:, None, None], rows["conv"][i, :B], 0)
+    x, tail, state = ssm_layer(cfg, layer, x, tail, state)
+    return x, {"ssm": put_layer(rows["ssm"], state, i),
+               "conv": put_layer(rows["conv"], tail, i)}
 
 
-def decode_step_ragged(cfg: NemotronHConfig, params: dict, cache: dict,
-                       tokens: jax.Array, pos: jax.Array):
-    """One step with per-row positions ([B], −1 = idle) over the slot
-    cache: llama's ``cached_attn_step`` in the attention layers, the
-    row's own carried state in the Mamba-2 layers."""
-    positions, slot, valid = llama.ragged_cache_coords(pos,
-                                                       cache["k"].shape[2])
-    kv = {"k": cache["k"], "v": cache["v"]}
-
-    def attend(i, layer, x):
-        x, k, v = llama.cached_attn_step(cfg, layer, x, kv["k"][i],
-                                         kv["v"][i], positions, slot, valid)
-        kv["k"], kv["v"] = _put(kv["k"], k, i), _put(kv["v"], v, i)
-        return x
-
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, ssm, conv, _ = _decode_layers(cfg, params, x, pos, attend,
-                                     cache["ssm"], cache["conv"])
-    return _head(cfg, params, x[:, 0]), {**kv, "ssm": ssm, "conv": conv}
+def _layers(cfg: NemotronHConfig) -> tuple:
+    """Each layer one mixer alone or one expert layer alone."""
+    return tuple((None, None, kind, i) if kind == "moe"
+                 else (kind, i, None, None) for kind, i in layer_plan(cfg))
 
 
-def decode_step(cfg: NemotronHConfig, params: dict, cache: dict,
-                tokens: jax.Array, pos: jax.Array):
-    """Scalar-position decode: every row at the same position."""
-    return decode_step_ragged(
-        cfg, params, cache, tokens,
-        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
+FAMILY = plan.Family(
+    name=__name__, configs=CONFIGS, init=init, logical_axes=logical_axes,
+    layers=_layers,
+    mixers={"attn": plan.ATTENTION,
+            "ssm": plan.Mixer("ssm", _ssm_sequence, _ssm_step, None)},
+    ffns={"moe": plan.Ffn(None, lambda cfg, params, i, x, _: expert_layer(
+        cfg, params["moe"], i, x))},
+    init_rows=init_rows)
 
-
-def generate(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
-             **sampling):
-    """Greedy or sampled continuation [B, max_new]: llama's
-    ``generate_loop`` over this family's prefill and decode step."""
-    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
-                               **sampling)
-
-
-def cb_init_cache(cfg: NemotronHConfig, slots: int, max_len: int) -> dict:
-    return init_cache(cfg, slots, max_len)
-
-
-def cb_prefill(cfg: NemotronHConfig, params: dict, prompt: jax.Array,
-               max_len: int) -> dict:
-    return prefill(cfg, params, prompt, max_len)[1]
-
-
-# ------------------------------------------------------------ paged cache
-def paged_init_cache(cfg: NemotronHConfig, n_pages: int,
-                     page_size: int) -> dict:
-    """The paged part of the cache (module docstring): K/V pages of the
-    attention layers and the decode steps' routed pairs. The engine
-    adds `paged_init_rows` under ``rows``."""
-    n = kind_counts(cfg)
-    kv = (n["attn"], n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "moe_expert_tokens": jnp.zeros((n["moe"], cfg.held[1]),
-                                           jnp.int32),
-            "moe_pairs_elsewhere": jnp.zeros((n["moe"],), jnp.int32)}
-
-
-# What each of the engine's rows carries beside its pages: the engine
-# keeps it under ``cache["rows"]``, leaves ``[L, rows, ...]``.
+# The engine's names (``serving/batching.py`` finds a surface by
+# ``hasattr``): `plan`'s functions over this family's table; admission
+# and the K/V page gather are llama's as they are. What each of the
+# engine's rows carries beside its pages is kept under
+# ``cache["rows"]``, leaves ``[L, rows, ...]`` (`paged_init_rows`).
+forward = functools.partial(plan.forward, FAMILY)
+init_cache = cb_init_cache = functools.partial(plan.init_cache, FAMILY)
+prefill = functools.partial(plan.prefill, FAMILY)
+cb_prefill = functools.partial(plan.cb_prefill, prefill)
+decode_step_ragged = functools.partial(plan.decode_step_ragged, FAMILY)
+decode_step = functools.partial(plan.decode_step, decode_step_ragged)
+generate = functools.partial(llama.generate_loop, prefill, decode_step)
+insert_cache_row = plan.insert_cache_row
+cb_admission, cb_validate = llama.cb_admission, llama.cb_validate
+paged_init_cache = functools.partial(plan.paged_init_cache, FAMILY)
 paged_init_rows = init_rows
-
-
-def decode_step_paged(cfg: NemotronHConfig, params: dict, cache: dict,
-                      tokens: jax.Array, pos: jax.Array,
-                      tables: jax.Array):
-    """`decode_step_ragged` over the paged pool: row b's K and V in its
-    pages, its Mamba-2 state in row b of ``cache["rows"]``, read and
-    written in place."""
-    page = cache["k"].shape[-2]
-    positions, write_page, write_off, valid = llama.paged_coords(
-        pos, tables, page)
-    kv = {"k": cache["k"], "v": cache["v"]}
-
-    def attend(i, layer, x):
-        x, kv["k"], kv["v"] = llama.paged_attn_step(
-            cfg, layer, x, kv["k"], kv["v"], i, positions, write_page,
-            write_off, tables, valid)
-        return x
-
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, ssm, conv, counters = _decode_layers(
-        cfg, params, x, pos, attend, cache["rows"]["ssm"],
-        cache["rows"]["conv"],
-        counters={name: cache[name] for name in (
-            "moe_expert_tokens", "moe_pairs_elsewhere")})
-    return _head(cfg, params, x[:, 0]), {
-        **kv, **counters, "rows": {"ssm": ssm, "conv": conv}}
-
-
-def paged_prefill_kv(cfg: NemotronHConfig, params: dict, prompt: jax.Array):
-    return row_state.paged_prefill_kv(_sequence_pass, cfg, params, prompt)
-
-
-def paged_prefill_suffix_kv(cfg: NemotronHConfig, params: dict, *suffix):
-    return row_state.paged_prefill_suffix_kv(_sequence_pass, cfg, params,
-                                             *suffix)
-
-
-# --------------------------------------------------------------- training
-def apply(cfg: NemotronHConfig, variables: Variables, batch: Batch,
-          train: bool = True, rng: Optional[jax.Array] = None):
-    """Next-token loss (chunked head). No auxiliary loss: the published
-    model balances its experts through the selection bias, which this
-    objective leaves alone."""
-    tokens = batch["tokens"]
-    if batch.get("segments") is not None:
-        raise ValueError("nemotron_h models do not support packed sequences "
-                         "(segments): the recurrent state would cross them")
-    params = variables["params"]
-    x, _, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    loss, acc = chunked_lm_loss(x, params["lm_head"].astype(cfg.dtype),
-                                tokens, batch.get("mask"),
-                                chunk=cfg.loss_chunk)
-    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
-
-
-def model_def(name: str, **overrides) -> ModelDef:
-    cfg = dataclasses.replace(CONFIGS[name], **overrides)
-    return ModelDef(
-        name=name,
-        init=functools.partial(init, cfg),
-        apply=functools.partial(apply, cfg),
-        logical_axes=functools.partial(logical_axes, cfg),
-        unit="tokens",
-    )
+decode_step_paged = functools.partial(plan.decode_step_paged, FAMILY)
+paged_gather = llama.paged_gather
+paged_gather_prefix = plan.paged_gather_prefix
+paged_prefill_kv = functools.partial(plan.paged_prefill_kv, FAMILY)
+paged_prefill_suffix_kv = functools.partial(plan.paged_prefill_suffix_kv,
+                                            FAMILY)
+paged_insert_prefill = plan.paged_insert_prefill
+paged_insert_suffix = plan.paged_insert_suffix
+apply = functools.partial(plan.apply, FAMILY)
+model_def = functools.partial(plan.model_def, FAMILY)

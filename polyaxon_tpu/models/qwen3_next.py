@@ -45,7 +45,7 @@ kind (``gdn``, ``attn``), the expert blocks' over every layer
 
 **The cache.** ``k``/``v`` hold the attention layers' pages ``[L_attn,
 P, KV, page, Hd]``; the delta layers' state is *per row* (``models/
-row_state.py``): ``rows`` holds ``gdn`` ``[L_gdn, rows, Hv, dk, dv]``
+plan.py``): ``rows`` holds ``gdn`` ``[L_gdn, rows, Hv, dk, dv]``
 float32 and ``conv`` ``[L_gdn, rows, K−1, conv_dim]``, indexed by the
 engine's row. A decode step reads and writes each live row's state in
 place, a layer at a time (``ops/gated_delta.py step_rows``: on a TPU
@@ -56,12 +56,12 @@ the decode steps' (row, choice) pairs by held expert,
 ``moe_pairs_elsewhere`` ``[L]`` those routed to experts this chip does
 not hold.
 
-One sequence pass (`_sequence_pass`: a suffix behind an optional
-prefix) serves ``forward``, the whole-prompt prefill and the suffix
-prefill; speculation and chunked dense prefill need ``decode_chunk``,
-which this family does not have (the state has no rollback), and the
-engine refuses them by that. The published multi-token-prediction
-module is no part of the next-token pass and is not here.
+The walks over the plan and the engine's surfaces are ``models/
+plan.py``'s, bound below to this family's table (`FAMILY`). Speculation
+and chunked dense prefill need ``decode_chunk``, which this family does
+not have (the state has no rollback), and the engine refuses them by
+that. The published multi-token-prediction module is no part of the
+next-token pass and is not here.
 """
 
 from __future__ import annotations
@@ -74,37 +74,13 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models import llama, moe, row_state
+from polyaxon_tpu.models import llama, moe, plan
 from polyaxon_tpu.models.common import (
-    Batch,
-    ModelDef,
     Variables,
-    _embed_rows,
     _w,
-    chunked_lm_loss,
-    lm_logits,
-    scaled_init,
-    shift_right,
-    truncated_normal_init,
-)
-# A prefilled row goes into its slot as the other hybrid families' does
-# (every leaf's axis 1 is the slot); decoder-only admission and the K/V
-# page gather are llama's as they are; the per-row side of the paged
-# surface is `row_state`'s.
-from polyaxon_tpu.models.lfm2 import (  # noqa: F401  (re-exported hook)
-    _at,
-    insert_cache_row,
-)
-from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
-    cb_admission,
-    cb_validate,
-    paged_gather,
-)
-from polyaxon_tpu.models.row_state import (  # noqa: F401  (re-exported hooks)
-    paged_gather_prefix,
-    paged_insert_prefill,
-    paged_insert_suffix,
     put_layer,
+    scaled_init,
+    truncated_normal_init,
 )
 from polyaxon_tpu.ops import gated_delta
 
@@ -175,26 +151,19 @@ CONFIGS: dict[str, Qwen3NextConfig] = {
 }
 
 
-@functools.lru_cache(maxsize=None)
-def _plan(n_layers: int, interval: int) -> tuple:
-    seen = {"gdn": 0, "attn": 0}
-    out = []
-    for i in range(n_layers):
-        kind = "attn" if (i + 1) % interval == 0 else "gdn"
-        out.append((kind, seen[kind]))
-        seen[kind] += 1
-    return tuple(out)
+def _kinds(cfg: Qwen3NextConfig) -> tuple:
+    return tuple("attn" if (l + 1) % cfg.full_attention_interval == 0
+                 else "gdn" for l in range(cfg.n_layers))
 
 
 def layer_plan(cfg: Qwen3NextConfig) -> tuple:
     """Per layer, in published order: (its mixer's kind, its index in
     that kind's stack). Layer l's expert block is ``moe``'s l-th."""
-    return _plan(cfg.n_layers, cfg.full_attention_interval)
+    return plan.indexed(_kinds(cfg))
 
 
 def kind_counts(cfg: Qwen3NextConfig) -> dict:
-    kinds = [kind for kind, _ in layer_plan(cfg)]
-    return {"gdn": kinds.count("gdn"), "attn": kinds.count("attn")}
+    return plan.kind_counts(_kinds(cfg), ("gdn", "attn"))
 
 
 def init(cfg: Qwen3NextConfig, rng: jax.Array) -> Variables:
@@ -397,13 +366,6 @@ def expert_block(cfg: Qwen3NextConfig, stack: dict, i: int, x: jax.Array):
     return x + out.reshape(B, S, D), onehot
 
 
-def _head(cfg: Qwen3NextConfig, params: dict, x: jax.Array) -> jax.Array:
-    """Final norm and the untied head: hidden [..., D] → fp32 logits."""
-    x = llama._norm(cfg, x, params["final_norm"])
-    return lm_logits(x, params["lm_head"], cfg.dtype,
-                     chunk=cfg.lm_logits_chunk)
-
-
 def init_rows(cfg: Qwen3NextConfig, rows: int) -> dict:
     """What ``rows`` sequences carry through the delta layers, zeroed:
     the matrix state, float32, and the convolution's last K−1 inputs."""
@@ -414,240 +376,62 @@ def init_rows(cfg: Qwen3NextConfig, rows: int) -> dict:
                                gated_delta.conv_dim(cfg)), cfg.dtype)}
 
 
-def _sequence_pass(cfg: Qwen3NextConfig, params: dict, tokens: jax.Array,
-                   k_prefix: Optional[jax.Array] = None,
-                   v_prefix: Optional[jax.Array] = None,
-                   carried: Optional[dict] = None, m=0, real_len=None):
-    """One causal pass over ``tokens`` [B, S] at absolute positions
-    m..m+S−1, behind a prefix that already exists: its K/V
-    ``k_prefix``/``v_prefix`` [L_attn, B, Mpad, KV, Hd] (columns at or
-    past ``m`` masked) and what the delta layers carry after position
-    m−1, ``carried`` (`init_rows`' two leaves for B rows). Without a
-    prefix (all None, m = 0) it is the whole-sequence forward.
-    Positions at or past ``real_len`` are padding
-    (``gated_delta.mixer``). Returns (hidden before the final norm [B,
-    S, D], k [L_attn, B, S, KV, Hd], v, what the layers carry after the
-    last real position)."""
-    dt = cfg.dtype
-    B, S = tokens.shape
-    if k_prefix is None:
-        shape = (kind_counts(cfg)["attn"], B, 0, cfg.n_kv_heads,
-                 cfg.head_dim)
-        k_prefix = v_prefix = jnp.zeros(shape, dt)
-    if carried is None:
-        carried = init_rows(cfg, B)
-    positions = jnp.broadcast_to(
-        m + jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    valid = llama._suffix_mask(S, k_prefix.shape[2], m)
-    x = _embed_rows(params["embed"], tokens, dt)
-    ks, vs, tails, states = [], [], [], []
-    for layer, (kind, i) in enumerate(layer_plan(cfg)):
-        if kind == "attn":
-            with jax.named_scope("gated_attention"):
-                x, k, v = llama.suffix_attn_step(
-                    cfg, _at(params["attn"], i), x, k_prefix[i],
-                    v_prefix[i], positions, valid)
-            ks.append(k)
-            vs.append(v)
-        else:
-            x, tail, state = gdn_layer(
-                cfg, _at(params["gdn"], i), x, carried["conv"][i],
-                carried["gdn"][i], real_len)
-            tails.append(tail)
-            states.append(state)
-        x, _ = expert_block(cfg, params["moe"], layer, x)
-    return x, jnp.stack(ks), jnp.stack(vs), {
-        "gdn": jnp.stack(states), "conv": jnp.stack(tails)}
+def _gdn_sequence(cfg: Qwen3NextConfig, layer: dict, x: jax.Array, i: int,
+                  behind: plan.Behind):
+    x, tail, state = gdn_layer(cfg, layer, x, behind.carried["conv"][i],
+                               behind.carried["gdn"][i], behind.real_len)
+    return x, {"gdn": state, "conv": tail}
 
 
-def forward(cfg: Qwen3NextConfig, params: dict,
-            tokens: jax.Array) -> jax.Array:
-    """Token ids [B, S] → logits [B, S, vocab] fp32."""
-    x, _, _, _ = _sequence_pass(cfg, params, tokens)
-    return _head(cfg, params, x)
-
-
-# ------------------------------------------------------- dense slot cache
-def init_cache(cfg: Qwen3NextConfig, batch: int, max_len: int) -> dict:
-    """The slot cache: K/V [L_attn, B, C, KV, Hd] and what each slot
-    carries through the delta layers (`init_rows`)."""
-    kv = (kind_counts(cfg)["attn"], batch, max_len, cfg.n_kv_heads,
-          cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            **init_rows(cfg, batch)}
-
-
-def prefill(cfg: Qwen3NextConfig, params: dict, prompt: jax.Array,
-            max_len: int):
-    """One pass over the prompt [B, P]: (last-position logits [B, V]
-    fp32, the slot cache holding it)."""
-    P = prompt.shape[1]
-    if P > max_len:
-        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
-    x, k, v, carried = _sequence_pass(cfg, params, prompt)
-    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
-    cache = {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad), **carried}
-    return _head(cfg, params, x[:, -1]), cache
-
-
-def _decode_layers(cfg: Qwen3NextConfig, params: dict, x: jax.Array,
-                   pos: jax.Array, attend, gdn: jax.Array, conv: jax.Array,
-                   counters: Optional[dict] = None):
-    """One position a row through every layer. ``attend(i, layer, x)``
-    is the attention layer over the cache in use; ``gdn``/``conv`` are
-    the rows' carried leaves ([L_gdn, rows ≥ B, ...]; a row at position
-    0 starts from zeros, an idle row's is garbage the next admission's
-    prefill replaces), updated in place a layer at a time. Live rows'
-    routed pairs are added to ``counters`` where given."""
+def _gdn_step(cfg: Qwen3NextConfig, layer: dict, x: jax.Array, i: int,
+              rows: dict, started: jax.Array):
+    """`gdn_decode_layer` over the rows' leaves ([L_gdn, rows ≥ B,
+    ...]), layer ``i`` of each updated in place."""
     B = x.shape[0]
-    started = pos > 0
-    live = (pos >= 0).astype(jnp.int32)
-    for layer, (kind, i) in enumerate(layer_plan(cfg)):
-        if kind == "attn":
-            with jax.named_scope("gated_attention"):
-                x = attend(i, _at(params["attn"], i), x)
-        else:
-            tail = jnp.where(started[:, None, None], conv[i, :B], 0)
-            x, tail, gdn = gdn_decode_layer(cfg, _at(params["gdn"], i), x,
-                                            tail, gdn, i, started)
-            conv = put_layer(conv, tail, i)
-        x, onehot = expert_block(cfg, params["moe"], layer, x)
-        if counters is not None:
-            held = jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live)
-            counters = {
-                "moe_expert_tokens":
-                    counters["moe_expert_tokens"].at[layer].add(held),
-                "moe_pairs_elsewhere":
-                    counters["moe_pairs_elsewhere"].at[layer].add(
-                        cfg.experts_per_token * jnp.sum(live)
-                        - jnp.sum(held))}
-    return x, gdn, conv, counters
+    tail = jnp.where(started[:, None, None], rows["conv"][i, :B], 0)
+    x, tail, gdn = gdn_decode_layer(cfg, layer, x, tail, rows["gdn"], i,
+                                    started)
+    return x, {"gdn": gdn, "conv": put_layer(rows["conv"], tail, i)}
 
 
-def decode_step_ragged(cfg: Qwen3NextConfig, params: dict, cache: dict,
-                       tokens: jax.Array, pos: jax.Array):
-    """One step with per-row positions ([B], −1 = idle) over the slot
-    cache: llama's ``cached_attn_step`` in the attention layers, the
-    row's own carried state in the delta layers."""
-    positions, slot, valid = llama.ragged_cache_coords(pos,
-                                                       cache["k"].shape[2])
-    kv = {"k": cache["k"], "v": cache["v"]}
-
-    def attend(i, layer, x):
-        x, k, v = llama.cached_attn_step(cfg, layer, x, kv["k"][i],
-                                         kv["v"][i], positions, slot, valid)
-        kv["k"], kv["v"] = put_layer(kv["k"], k, i), put_layer(kv["v"], v, i)
-        return x
-
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, gdn, conv, _ = _decode_layers(cfg, params, x, pos, attend,
-                                     cache["gdn"], cache["conv"])
-    return _head(cfg, params, x[:, 0]), {**kv, "gdn": gdn, "conv": conv}
+def _layers(cfg: Qwen3NextConfig) -> tuple:
+    """A mixer and an expert block in every layer."""
+    return tuple((kind, i, "moe", l)
+                 for l, (kind, i) in enumerate(layer_plan(cfg)))
 
 
-def decode_step(cfg: Qwen3NextConfig, params: dict, cache: dict,
-                tokens: jax.Array, pos: jax.Array):
-    """Scalar-position decode: every row at the same position."""
-    return decode_step_ragged(
-        cfg, params, cache, tokens,
-        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
+FAMILY = plan.Family(
+    name=__name__, configs=CONFIGS, init=init,
+    logical_axes=logical_axes, layers=_layers,
+    mixers={"attn": plan.ATTENTION._replace(scope="gated_attention"),
+            "gdn": plan.Mixer("gdn", _gdn_sequence, _gdn_step, None)},
+    ffns={"moe": plan.Ffn(None, lambda cfg, params, i, x, _: expert_block(
+        cfg, params["moe"], i, x))},
+    init_rows=init_rows)
 
-
-def generate(cfg: Qwen3NextConfig, params: dict, prompt: jax.Array,
-             **sampling):
-    """Greedy or sampled continuation [B, max_new]: llama's
-    ``generate_loop`` over this family's prefill and decode step."""
-    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
-                               **sampling)
-
-
-def cb_init_cache(cfg: Qwen3NextConfig, slots: int, max_len: int) -> dict:
-    return init_cache(cfg, slots, max_len)
-
-
-def cb_prefill(cfg: Qwen3NextConfig, params: dict, prompt: jax.Array,
-               max_len: int) -> dict:
-    return prefill(cfg, params, prompt, max_len)[1]
-
-
-# ------------------------------------------------------------ paged cache
-def paged_init_cache(cfg: Qwen3NextConfig, n_pages: int,
-                     page_size: int) -> dict:
-    """The paged part of the cache (module docstring): K/V pages of the
-    attention layers and the decode steps' routed pairs. The engine
-    adds `paged_init_rows` under ``rows``."""
-    kv = (kind_counts(cfg)["attn"], n_pages, cfg.n_kv_heads, page_size,
-          cfg.head_dim)
-    return {"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
-            "moe_expert_tokens": jnp.zeros((cfg.n_layers, cfg.held[1]),
-                                           jnp.int32),
-            "moe_pairs_elsewhere": jnp.zeros((cfg.n_layers,), jnp.int32)}
-
-
-# What each of the engine's rows carries beside its pages: the engine
-# keeps it under ``cache["rows"]``, leaves ``[L, rows, ...]``.
+# The engine's names (``serving/batching.py`` finds a surface by
+# ``hasattr``): `plan`'s functions over this family's table; admission
+# and the K/V page gather are llama's as they are. What each of the
+# engine's rows carries beside its pages is kept under
+# ``cache["rows"]``, leaves ``[L, rows, ...]`` (`paged_init_rows`).
+forward = functools.partial(plan.forward, FAMILY)
+init_cache = cb_init_cache = functools.partial(plan.init_cache, FAMILY)
+prefill = functools.partial(plan.prefill, FAMILY)
+cb_prefill = functools.partial(plan.cb_prefill, prefill)
+decode_step_ragged = functools.partial(plan.decode_step_ragged, FAMILY)
+decode_step = functools.partial(plan.decode_step, decode_step_ragged)
+generate = functools.partial(llama.generate_loop, prefill, decode_step)
+insert_cache_row = plan.insert_cache_row
+cb_admission, cb_validate = llama.cb_admission, llama.cb_validate
+paged_init_cache = functools.partial(plan.paged_init_cache, FAMILY)
 paged_init_rows = init_rows
-
-
-def decode_step_paged(cfg: Qwen3NextConfig, params: dict, cache: dict,
-                      tokens: jax.Array, pos: jax.Array,
-                      tables: jax.Array):
-    """`decode_step_ragged` over the paged pool: row b's K and V in its
-    pages, its delta state in row b of ``cache["rows"]``, read and
-    written in place."""
-    page = cache["k"].shape[-2]
-    positions, write_page, write_off, valid = llama.paged_coords(
-        pos, tables, page)
-    kv = {"k": cache["k"], "v": cache["v"]}
-
-    def attend(i, layer, x):
-        x, kv["k"], kv["v"] = llama.paged_attn_step(
-            cfg, layer, x, kv["k"], kv["v"], i, positions, write_page,
-            write_off, tables, valid)
-        return x
-
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, gdn, conv, counters = _decode_layers(
-        cfg, params, x, pos, attend, cache["rows"]["gdn"],
-        cache["rows"]["conv"],
-        counters={name: cache[name] for name in (
-            "moe_expert_tokens", "moe_pairs_elsewhere")})
-    return _head(cfg, params, x[:, 0]), {
-        **kv, **counters, "rows": {"gdn": gdn, "conv": conv}}
-
-
-def paged_prefill_kv(cfg: Qwen3NextConfig, params: dict, prompt: jax.Array):
-    return row_state.paged_prefill_kv(_sequence_pass, cfg, params, prompt)
-
-
-def paged_prefill_suffix_kv(cfg: Qwen3NextConfig, params: dict, *suffix):
-    return row_state.paged_prefill_suffix_kv(_sequence_pass, cfg, params,
-                                             *suffix)
-
-
-# --------------------------------------------------------------- training
-def apply(cfg: Qwen3NextConfig, variables: Variables, batch: Batch,
-          train: bool = True, rng: Optional[jax.Array] = None):
-    """Next-token loss (chunked head), no auxiliary loss."""
-    tokens = batch["tokens"]
-    if batch.get("segments") is not None:
-        raise ValueError("qwen3_next models do not support packed sequences "
-                         "(segments): the recurrent state would cross them")
-    params = variables["params"]
-    x, _, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
-    x = llama._norm(cfg, x, params["final_norm"])
-    loss, acc = chunked_lm_loss(x, params["lm_head"].astype(cfg.dtype),
-                                tokens, batch.get("mask"),
-                                chunk=cfg.loss_chunk)
-    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
-
-
-def model_def(name: str, **overrides) -> ModelDef:
-    cfg = dataclasses.replace(CONFIGS[name], **overrides)
-    return ModelDef(
-        name=name,
-        init=functools.partial(init, cfg),
-        apply=functools.partial(apply, cfg),
-        logical_axes=functools.partial(logical_axes, cfg),
-        unit="tokens",
-    )
+decode_step_paged = functools.partial(plan.decode_step_paged, FAMILY)
+paged_gather = llama.paged_gather
+paged_gather_prefix = plan.paged_gather_prefix
+paged_prefill_kv = functools.partial(plan.paged_prefill_kv, FAMILY)
+paged_prefill_suffix_kv = functools.partial(plan.paged_prefill_suffix_kv,
+                                            FAMILY)
+paged_insert_prefill = plan.paged_insert_prefill
+paged_insert_suffix = plan.paged_insert_suffix
+apply = functools.partial(plan.apply, FAMILY)
+model_def = functools.partial(plan.model_def, FAMILY)

@@ -49,6 +49,8 @@ the window layers' only the pages the row holds. ``moe_expert_tokens``
 
 The pool matches no prefix for this family, and the engine's chunked
 prefill and speculation refuse a ``sliding_window`` as they do llama's.
+The walks over the plan and the surfaces no page space touches are
+``models/plan.py``'s, bound below to this family's table (`FAMILY`).
 """
 
 from __future__ import annotations
@@ -60,25 +62,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models import llama, moe
+from polyaxon_tpu.models import llama, moe, plan
 from polyaxon_tpu.models.common import (
-    Batch,
-    ModelDef,
     Variables,
-    _embed_rows,
-    chunked_lm_loss,
-    lm_logits,
+    put_layer,
     scaled_init,
-    shift_right,
     truncated_normal_init,
-)
-from polyaxon_tpu.models.lfm2 import (  # noqa: F401  (re-exported hook)
-    _at,
-    insert_cache_row,
-)
-from polyaxon_tpu.models.llama import (  # noqa: F401  (re-exported hooks)
-    cb_admission,
-    cb_validate,
 )
 from polyaxon_tpu.ops.attention import dot_product_attention
 
@@ -141,15 +130,9 @@ CONFIGS: dict[str, SmallThinkerConfig] = {
 }
 
 
-@functools.lru_cache(maxsize=None)
-def _plan(rope_layout: tuple, window_layout: tuple) -> tuple:
-    seen = {"full": 0, "window": 0}
-    out = []
-    for rotary, windowed in zip(rope_layout, window_layout):
-        kind = "window" if windowed else "full"
-        out.append((kind, seen[kind], bool(rotary)))
-        seen[kind] += 1
-    return tuple(out)
+def _kinds(cfg: SmallThinkerConfig) -> tuple:
+    return tuple("window" if windowed else "full"
+                 for windowed in cfg.window_layout)
 
 
 def layer_plan(cfg: SmallThinkerConfig) -> tuple:
@@ -157,12 +140,12 @@ def layer_plan(cfg: SmallThinkerConfig) -> tuple:
     ``window``; its index among that kind's layers, which is its layer
     of that kind's page pool; whether the rotary embedding turns its q
     and k). The parameters are stacked over every layer."""
-    return _plan(cfg.rope_layout, cfg.window_layout)
+    return tuple((kind, i, bool(rotary)) for (kind, i), rotary
+                 in zip(plan.indexed(_kinds(cfg)), cfg.rope_layout))
 
 
 def kind_counts(cfg: SmallThinkerConfig) -> dict:
-    kinds = [kind for kind, _, _ in layer_plan(cfg)]
-    return {"full": kinds.count("full"), "window": kinds.count("window")}
+    return plan.kind_counts(_kinds(cfg), ("full", "window"))
 
 
 def _layer_window(cfg: SmallThinkerConfig, kind: str) -> Optional[int]:
@@ -272,85 +255,54 @@ def expert_block(cfg: SmallThinkerConfig, stack: dict, i: int, x: jax.Array,
     return x + out.reshape(B, S, D), onehot
 
 
-def _head(cfg: SmallThinkerConfig, params: dict, x: jax.Array) -> jax.Array:
-    """Final norm and the untied head: hidden [..., D] → fp32 logits."""
-    x = llama._norm(cfg, x, params["final_norm"])
-    return lm_logits(x, params["lm_head"], cfg.dtype,
-                     chunk=cfg.lm_logits_chunk)
+def _attention(kind: str, cfg: SmallThinkerConfig, layer: dict,
+               x: jax.Array, l: int, behind: plan.Behind):
+    """Layer ``l``'s attention over a whole sequence at
+    ``behind.positions`` (flash attention, under the window in a window
+    layer; no prefix: the pool matches none for this family)."""
+    h = llama._norm(cfg, x, layer["attn_norm"])
+    q, k, v, gate = llama._qkv(cfg, layer, h, behind.positions,
+                               bool(cfg.rope_layout[l]))
+    attn = dot_product_attention(
+        q, k, v, causal=True, impl=cfg.attention_impl,
+        window=_layer_window(cfg, kind))
+    return llama._attn_out(cfg, layer, x, attn, gate), {"k": k, "v": v}
 
 
-def _sequence_pass(cfg: SmallThinkerConfig, params: dict, tokens: jax.Array):
-    """One causal pass over ``tokens`` [B, S] at positions 0..S−1:
-    (hidden before the final norm [B, S, D], every layer's k and v
-    [B, S, KV, Hd], in layer order)."""
-    B, S = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)
-    ks, vs = [], []
-    for layer, (kind, _, rotary) in enumerate(layer_plan(cfg)):
-        weights = _at(params["attn"], layer)
-        h = llama._norm(cfg, x, weights["attn_norm"])
-        routed = routing(cfg, weights, h)
-        with jax.named_scope(kind + "_attention"):
-            q, k, v, gate = llama._qkv(cfg, weights, h, positions, rotary)
-            attn = dot_product_attention(
-                q, k, v, causal=True, impl=cfg.attention_impl,
-                window=_layer_window(cfg, kind))
-            x = llama._attn_out(cfg, weights, x, attn, gate)
-        x, _ = expert_block(cfg, params["moe"], layer, x, routed)
-        ks.append(k)
-        vs.append(v)
-    return x, ks, vs
+def _layers(cfg: SmallThinkerConfig) -> tuple:
+    """Attention and an expert block in every layer, both stacked over
+    every layer: a layer's index in either stack is its number."""
+    return tuple((kind, l, "moe", l)
+                 for l, (kind, _, _) in enumerate(layer_plan(cfg)))
 
 
-def forward(cfg: SmallThinkerConfig, params: dict,
-            tokens: jax.Array) -> jax.Array:
-    """Token ids [B, S] → logits [B, S, vocab] fp32."""
-    x, _, _ = _sequence_pass(cfg, params, tokens)
-    return _head(cfg, params, x)
+FAMILY = plan.Family(
+    name=__name__, configs=CONFIGS, init=init,
+    logical_axes=logical_axes, layers=_layers,
+    mixers={kind: plan.Mixer("attn", functools.partial(_attention, kind),
+                             None, kind + "_attention")
+            for kind in ("full", "window")},
+    # The router reads the layer's normed input, before attention.
+    ffns={"moe": plan.Ffn(
+        lambda cfg, layer, x: routing(
+            cfg, layer, llama._norm(cfg, x, layer["attn_norm"])),
+        lambda cfg, params, l, x, routed: expert_block(
+            cfg, params["moe"], l, x, routed))},
+    init_rows=lambda cfg, rows: {})
 
-
-# ------------------------------------------------------- dense slot cache
-def init_cache(cfg: SmallThinkerConfig, batch: int, max_len: int) -> dict:
-    """The slot cache: K/V [L, B, C, KV, Hd], every layer at the full
-    length (slot == position; a window layer masks what lies behind its
-    window and keeps it)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def prefill(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
-            max_len: int):
-    """One pass over the prompt [B, P]: (last-position logits [B, V]
-    fp32, the slot cache holding it)."""
-    P = prompt.shape[1]
-    if P > max_len:
-        raise ValueError(f"prompt length {P} exceeds cache length {max_len}")
-    x, ks, vs = _sequence_pass(cfg, params, prompt)
-    pad = ((0, 0), (0, 0), (0, max_len - P), (0, 0), (0, 0))
-    cache = {"k": jnp.pad(jnp.stack(ks), pad), "v": jnp.pad(jnp.stack(vs), pad)}
-    return _head(cfg, params, x[:, -1]), cache
-
-
-def _decode_layers(cfg: SmallThinkerConfig, params: dict, x: jax.Array,
-                   pos: jax.Array, attend, counts=None):
-    """One position a row ([B, 1, D]) through every layer.
-    ``attend(layer, kind, i, rotary, weights, x)`` is the attention
-    layer over the cache in use (``i``: the layer's index among its
-    kind's). Live rows' routed pairs are added to ``counts``
-    [L, E] where given."""
-    live = (pos >= 0).astype(jnp.int32)
-    for layer, (kind, i, rotary) in enumerate(layer_plan(cfg)):
-        weights = _at(params["attn"], layer)
-        routed = routing(cfg, weights,
-                         llama._norm(cfg, x, weights["attn_norm"]))
-        with jax.named_scope(kind + "_attention"):
-            x = attend(layer, kind, i, rotary, weights, x)
-        x, onehot = expert_block(cfg, params["moe"], layer, x, routed)
-        if counts is not None:
-            counts = counts.at[layer].add(
-                jnp.einsum("tke,t->e", onehot.astype(jnp.int32), live))
-    return x, counts
+# The engine's names (``serving/batching.py`` finds a surface by
+# ``hasattr``), those no page space touches: `plan`'s functions over
+# this family's table. The slot cache holds K/V [L, B, C, KV, Hd], every
+# layer at the full length (slot == position; a window layer masks what
+# lies behind its window and keeps it).
+forward = functools.partial(plan.forward, FAMILY)
+init_cache = cb_init_cache = functools.partial(plan.init_cache, FAMILY)
+prefill = functools.partial(plan.prefill, FAMILY)
+cb_prefill = functools.partial(plan.cb_prefill, prefill)
+insert_cache_row = plan.insert_cache_row
+cb_admission, cb_validate = llama.cb_admission, llama.cb_validate
+apply = functools.partial(plan.apply, FAMILY)
+model_def = functools.partial(plan.model_def, FAMILY)
 
 
 def decode_step_ragged(cfg: SmallThinkerConfig, params: dict, cache: dict,
@@ -364,42 +316,21 @@ def decode_step_ragged(cfg: SmallThinkerConfig, params: dict, cache: dict,
     valid_window = valid & ~behind[:, None, None, :]
     kv = {"k": cache["k"], "v": cache["v"]}
 
-    def attend(layer, kind, _, rotary, weights, x):
+    def attend(kind, l, layer, x):
         x, k, v = llama.cached_attn_step(
-            cfg, weights, x, kv["k"][layer], kv["v"][layer], positions, slot,
-            valid_window if kind == "window" else valid, rotary)
-        kv["k"] = kv["k"].at[layer].set(k)
-        kv["v"] = kv["v"].at[layer].set(v)
+            cfg, layer, x, kv["k"][l], kv["v"][l], positions, slot,
+            valid_window if kind == "window" else valid,
+            bool(cfg.rope_layout[l]))
+        kv["k"], kv["v"] = put_layer(kv["k"], k, l), put_layer(kv["v"], v, l)
         return x
 
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, _ = _decode_layers(cfg, params, x, pos, attend)
-    return _head(cfg, params, x[:, 0]), kv
+    logits, _, _ = plan.decode(FAMILY, cfg, params, tokens, pos, attend,
+                               {}, {})
+    return logits, kv
 
 
-def decode_step(cfg: SmallThinkerConfig, params: dict, cache: dict,
-                tokens: jax.Array, pos: jax.Array):
-    """Scalar-position decode: every row at the same position."""
-    return decode_step_ragged(
-        cfg, params, cache, tokens,
-        jnp.broadcast_to(jnp.asarray(pos, jnp.int32), tokens.shape[:1]))
-
-
-def generate(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
-             **sampling):
-    """Greedy or sampled continuation [B, max_new]: llama's
-    ``generate_loop`` over this family's prefill and decode step."""
-    return llama.generate_loop(prefill, decode_step, cfg, params, prompt,
-                               **sampling)
-
-
-def cb_init_cache(cfg: SmallThinkerConfig, slots: int, max_len: int) -> dict:
-    return init_cache(cfg, slots, max_len)
-
-
-def cb_prefill(cfg: SmallThinkerConfig, params: dict, prompt: jax.Array,
-               max_len: int) -> dict:
-    return prefill(cfg, params, prompt, max_len)[1]
+decode_step = functools.partial(plan.decode_step, decode_step_ragged)
+generate = functools.partial(llama.generate_loop, prefill, decode_step)
 
 
 # ------------------------------------------------------------ paged cache
@@ -442,22 +373,23 @@ def decode_step_paged(cfg: SmallThinkerConfig, params: dict, cache: dict,
                    *llama.paged_coords(pos, tables[1], page, window))}
     pools = {"full": [cache["k"], cache["v"]],
              "window": [cache["window"]["k"], cache["window"]["v"]]}
+    entries = layer_plan(cfg)
 
-    def attend(_, kind, i, rotary, weights, x):
+    def attend(kind, l, layer, x):
         table, positions, write_page, write_off, valid = coords[kind]
+        _, i, rotary = entries[l]
         x, *pools[kind] = llama.paged_attn_step(
-            cfg, weights, x, *pools[kind], i, positions, write_page,
+            cfg, layer, x, *pools[kind], i, positions, write_page,
             write_off, table, valid, window=_layer_window(cfg, kind),
             rotary=rotary)
         return x
 
-    x = _embed_rows(params["embed"], tokens, cfg.dtype)[:, None, :]
-    x, counts = _decode_layers(cfg, params, x, pos, attend,
-                               cache["moe_expert_tokens"])
-    return _head(cfg, params, x[:, 0]), {
+    logits, _, counters = plan.decode(FAMILY, cfg, params, tokens, pos,
+                                      attend, {}, plan.counters_of(cache))
+    return logits, {
         "k": pools["full"][0], "v": pools["full"][1],
         "window": {"k": pools["window"][0], "v": pools["window"][1]},
-        "moe_expert_tokens": counts}
+        **counters}
 
 
 def paged_prefill_kv(cfg: SmallThinkerConfig, params: dict,
@@ -469,7 +401,7 @@ def paged_prefill_kv(cfg: SmallThinkerConfig, params: dict,
     number for `paged_insert_prefill`, which is handed no config."""
     P = prompt.shape[1]
     padded = jnp.pad(prompt, ((0, 0), (0, -P % PREFILL_TILE)))
-    _, ks, vs = _sequence_pass(cfg, params, padded)
+    _, ks, vs, _ = plan.sequence_layers(FAMILY, cfg, params, padded)
 
     def of(kind, leaves):
         return jnp.stack([leaf[0, :P] for leaf, (k, _, _)
@@ -506,31 +438,3 @@ def paged_insert_prefill(cache: dict, k_full: jax.Array, v_full: jax.Array,
             "window": {
                 "k": put(cache["window"]["k"], k_window, page_ids[1], first),
                 "v": put(cache["window"]["v"], v_window, page_ids[1], first)}}
-
-
-# --------------------------------------------------------------- training
-def apply(cfg: SmallThinkerConfig, variables: Variables, batch: Batch,
-          train: bool = True, rng: Optional[jax.Array] = None):
-    """Next-token loss (chunked head), no auxiliary loss."""
-    tokens = batch["tokens"]
-    if batch.get("segments") is not None:
-        raise ValueError("smallthinker models do not support packed "
-                         "sequences (segments)")
-    params = variables["params"]
-    x, _, _ = _sequence_pass(cfg, params, shift_right(tokens))
-    x = llama._norm(cfg, x, params["final_norm"])
-    loss, acc = chunked_lm_loss(x, params["lm_head"].astype(cfg.dtype),
-                                tokens, batch.get("mask"),
-                                chunk=cfg.loss_chunk)
-    return loss, {"loss": loss, "accuracy": acc}, variables["state"]
-
-
-def model_def(name: str, **overrides) -> ModelDef:
-    cfg = dataclasses.replace(CONFIGS[name], **overrides)
-    return ModelDef(
-        name=name,
-        init=functools.partial(init, cfg),
-        apply=functools.partial(apply, cfg),
-        logical_axes=functools.partial(logical_axes, cfg),
-        unit="tokens",
-    )

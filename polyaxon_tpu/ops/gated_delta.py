@@ -44,7 +44,7 @@ the Pallas kernel of ``ops/gdn_update.py``, which brings a block of
 update from that copy and writes it back once (`step`'s one-read form,
 without the second pass the compiler needs for it); everywhere else
 (the CPU of the tests, a mesh that shards rows or heads) `step` on the
-rows' slice and ``row_state.put_layer``. `step` is the kernel's oracle
+rows' slice and ``common.put_layer``. `step` is the kernel's oracle
 in ``tests/test_gdn_update.py`` and what ``benchmark/kernels/
 gdn_update.py`` counts the floor from. Every sequence (a prefill, a
 training batch) is `chunked` on either.
@@ -55,8 +55,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from polyaxon_tpu.models.common import _w, project, rms_norm
-from polyaxon_tpu.models.row_state import put_layer
+from polyaxon_tpu.models.common import _w, project, put_layer, rms_norm
 from polyaxon_tpu.ops.gdn_update import gdn_update
 from polyaxon_tpu.parallel import compat
 
@@ -257,7 +256,7 @@ def update_kernel() -> bool:
     _grouped_kernel`` decides its own, where the call is handed the leaf
     whole. Elsewhere (the CPU of every test; a mesh that shards rows or
     heads, where the partitioner can split `step` and cannot split a
-    kernel) it is `step` and ``row_state.put_layer``."""
+    kernel) it is `step` and ``common.put_layer``."""
     return jax.default_backend() == "tpu" and compat.unsharded()
 
 

@@ -227,7 +227,7 @@ PLAIN_TREE = "-plain-tree"
 # The per-row state a decode or prefill program updates: name -> the
 # whole leaf's type. No instruction may copy it (a step reads and
 # writes each row's state where it lies: the delta layers' kernel takes
-# the leaf as an aliased operand, a prefill's `models/row_state.py
+# the leaf as an aliased operand, a prefill's `models/plan.py
 # set_row` is a `dynamic_update_slice` the compiler aliases).
 # The leaf is held at the benchmark's rows and heads (128 x 32 x 128 x
 # 128 a layer: 268 MB): a leaf of a few megabytes the compiler stages

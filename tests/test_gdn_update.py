@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from polyaxon_tpu.models.row_state import put_layer
+from polyaxon_tpu.models.common import put_layer
 from polyaxon_tpu.ops import gated_delta
 from polyaxon_tpu.ops import gdn_update as gu
 

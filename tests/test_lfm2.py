@@ -25,7 +25,7 @@ if BENCH not in sys.path:
 
 from reference import lfm2 as ref  # noqa: E402
 
-from polyaxon_tpu.models import lfm2, moe  # noqa: E402
+from polyaxon_tpu.models import lfm2, moe, plan  # noqa: E402
 from polyaxon_tpu.serving.batching import ContinuousBatchingEngine  # noqa: E402
 from polyaxon_tpu.serving.paged import PagePool, page_bytes  # noqa: E402
 
@@ -288,7 +288,7 @@ class TestRouter:
         `s` to choose nor `s + b` to weigh."""
         cfg, params, config, weights = model
         bias = 0.5 * jnp.sign(params["moe"]["expert_bias"][0])
-        layer = {**lfm2._at(params["moe"], 0), "expert_bias": bias}
+        layer = {**plan._at(params["moe"], 0), "expert_bias": bias}
         x = jax.random.normal(jax.random.key(1), (1, 12, cfg.dim))
         got, onehot = lfm2.expert_ffn(cfg, layer, x)
         want = ref.expert_ffn(config, layer, x[0], "highest")

@@ -27,7 +27,7 @@ if BENCH not in sys.path:
 
 from reference import nemotron_h as ref  # noqa: E402
 
-from polyaxon_tpu.models import moe, nemotron_h as nh  # noqa: E402
+from polyaxon_tpu.models import moe, nemotron_h as nh, plan  # noqa: E402
 from polyaxon_tpu.ops import mamba2  # noqa: E402
 from polyaxon_tpu.serving.batching import ContinuousBatchingEngine  # noqa: E402
 from polyaxon_tpu.serving.paged import PagePool, page_bytes  # noqa: E402
@@ -244,7 +244,7 @@ def test_state_after_prefill_is_the_references(model):
     the last position and its convolution's last inputs, a layer."""
     cfg, params, config, weights = model
     prompt = jnp.asarray([_tokens(19)], jnp.int32)
-    _, _, _, carried = nh._sequence_pass(cfg, params, prompt)
+    _, _, _, carried = plan.sequence_pass(nh.FAMILY, cfg, params, prompt)
     keep = {}
     ref.hidden(config, weights, prompt, keep=keep)
     for i in range(nh.kind_counts(cfg)["ssm"]):

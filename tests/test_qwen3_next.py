@@ -30,7 +30,7 @@ if BENCH not in sys.path:
 from reference import qwen3_next as ref  # noqa: E402
 
 from polyaxon_tpu.models import (  # noqa: E402
-    lfm2, llama, moe, nemotron_h, row_state)
+    lfm2, llama, moe, nemotron_h, plan)
 from polyaxon_tpu.models import qwen3_next as qn  # noqa: E402
 from polyaxon_tpu.models.common import _w, rope  # noqa: E402
 from polyaxon_tpu.ops import gated_delta  # noqa: E402
@@ -205,7 +205,7 @@ def test_padding_past_real_len_leaves_the_state_alone(model):
     """The mixer over a padded piece gives, for its real positions and
     for what the row carries on, what the unpadded piece gives."""
     cfg, params, _, _ = model
-    layer = qn._at(params["gdn"], 1)
+    layer = plan._at(params["gdn"], 1)
     rng = np.random.default_rng(4)
     u = jnp.asarray(rng.normal(size=(1, 16, cfg.dim)), jnp.float32)
     carried = qn.init_rows(cfg, 1)
@@ -224,7 +224,7 @@ def test_padding_past_real_len_leaves_the_state_alone(model):
 def test_state_after_prefill_is_the_references(model):
     cfg, params, config, weights = model
     prompt = jnp.asarray([_tokens(19)], jnp.int32)
-    _, _, _, carried = qn._sequence_pass(cfg, params, prompt)
+    _, _, _, carried = plan.sequence_pass(qn.FAMILY, cfg, params, prompt)
     keep = {}
     ref.hidden(config, weights, prompt, keep=keep)
     for i in range(qn.kind_counts(cfg)["gdn"]):
@@ -243,7 +243,7 @@ def test_gate_partial_rotary_and_qk_norms_against_the_references_attention(
     cfg, params, config, weights = model
     x = jnp.asarray(np.random.default_rng(6).normal(size=(1, 23, cfg.dim)),
                     jnp.float32)
-    layer = qn._at(params["attn"], 0)
+    layer = plan._at(params["attn"], 0)
     positions = jnp.arange(23, dtype=jnp.int32)[None]
     empty = jnp.zeros((1, 0, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
     valid = llama._suffix_mask(23, 0, 0)
@@ -426,4 +426,4 @@ def test_family_is_registered_and_the_per_row_surface_is_one_place():
     for name in ("paged_insert_prefill", "paged_gather_prefix",
                  "paged_insert_suffix"):
         assert getattr(qn, name) is getattr(nemotron_h, name) \
-            is getattr(row_state, name)
+            is getattr(plan, name)
