@@ -19,6 +19,7 @@ compile, remat-friendly).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional
 
 import jax
@@ -74,6 +75,8 @@ def rope_frequencies(d_half: int, theta: float,
     freqs = 1.0 / (theta ** (jnp.arange(0, d_half, dtype=jnp.float32) / d_half))
     if not scaling:
         return freqs
+    if scaling.get("type", scaling.get("rope_type")) == "yarn":
+        return yarn_frequencies(d_half, theta, scaling)
     factor = float(scaling.get("factor", 8.0))
     low = float(scaling.get("low_freq_factor", 1.0))
     high = float(scaling.get("high_freq_factor", 4.0))
@@ -86,6 +89,54 @@ def rope_frequencies(d_half: int, theta: float,
     smooth = jnp.clip(smooth, 0.0, 1.0)
     scaled = freqs / factor
     return (1.0 - smooth) * scaled + smooth * freqs
+
+
+def yarn_frequencies(d_half: int, theta: float, scaling: dict) -> jax.Array:
+    """Inverse RoPE frequencies under the public "yarn" rope_scaling
+    rule (``{"type": "yarn", "factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow"}``): pair i turns at ``θ^(-i/d_half)`` where
+    it completes more than ``beta_fast`` turns over the original context
+    (kept), at that over ``factor`` where it completes fewer than
+    ``beta_slow`` (interpolated), and at a linear blend of the two
+    between: ``r_i = 1 - clip((i - low) / (high - low), 0, 1)`` with
+    ``low = floor(d(beta_fast))``, ``high = ceil(d(beta_slow))``, ``d(n) =
+    d_half · ln(orig / (2π n)) / ln θ`` the pair that completes n
+    turns. The attention's own scale under the rule is
+    `yarn_softmax_scale`."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def pair(turns: float) -> float:
+        return d_half * math.log(orig / (2 * math.pi * turns)) / math.log(theta)
+
+    low = max(math.floor(pair(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(pair(float(scaling.get("beta_slow", 1)))),
+               2 * d_half - 1)
+    freqs = 1.0 / (theta ** (jnp.arange(0, d_half, dtype=jnp.float32) / d_half))
+    ramp = jnp.clip((jnp.arange(d_half, dtype=jnp.float32) - low)
+                    / (high - low if high != low else 1e-3), 0.0, 1.0)
+    kept = 1.0 - ramp
+    return freqs / factor * (1.0 - kept) + freqs * kept
+
+
+def yarn_softmax_scale(head_dim: int, scaling: Optional[dict]) -> float:
+    """The softmax scale of attention whose queries and keys are
+    ``head_dim`` wide under a "yarn" rule: ``head_dim^-0.5 · m²``, ``m =
+    0.1 · mscale_all_dim · ln(factor) + 1`` (1 where ``mscale_all_dim``
+    is 0 or the factor at most 1). Cos and sin stay unscaled where
+    ``mscale == mscale_all_dim``, the one case this tree has; another
+    ratio is refused."""
+    scale = head_dim ** -0.5
+    if not scaling:
+        return scale
+    if scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 0):
+        raise ValueError(
+            "yarn with mscale != mscale_all_dim scales cos and sin, which "
+            "`rope` does not do")
+    factor, all_dim = float(scaling["factor"]), float(scaling["mscale_all_dim"])
+    if factor <= 1 or not all_dim:
+        return scale
+    return scale * (0.1 * all_dim * math.log(factor) + 1.0) ** 2
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: Optional[float],

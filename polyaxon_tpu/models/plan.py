@@ -32,6 +32,25 @@ family whose rows carry a state, leaves *per row* under
 from, so the pool matches nothing for such a cache
 (``serving/paged.py``). ``moe_expert_tokens`` / ``moe_pairs_elsewhere``
 count, on the device, the decode steps' routed (row, choice) pairs.
+
+**A latent a token** (``models/kimi_k2.py``). A family whose attention
+caches one vector a token for all heads writes the cache surfaces
+itself and takes the walks from here. Its mixer has no ``step`` (its
+decode goes through ``attend``) and keeps ``{"latent": [B, S, W]}`` of a
+sequence, which `sequence_layers` hands back by that name; a prefix
+comes to it as ``carried["latent"]`` [L, B, Mpad, W], empty
+(`Family.init_rows`) behind nothing. The paged cache is one leaf
+``latent`` [L, P, 1, page, W]: pages of a single "KV head", so that
+llama's page-wise writes (``paged_write_step``, ``paged_write_span``),
+``paged_gather`` and ``serving/paged.py page_bytes`` take it as they
+take K or V, and with no leaf a page or a row the radix tree matches
+for it as for llama. The engine's names on such a module:
+``paged_init_cache`` (the leaf and the counters), ``decode_step_paged``,
+``paged_prefill_kv`` and ``paged_insert_prefill`` (one leaf where llama
+has two), ``paged_gather_prefix``, ``paged_prefill_suffix_kv`` and
+``paged_insert_suffix`` (the matched pages' latents, the tail behind
+them), and the slot cache's ``init_cache`` / ``prefill`` /
+``decode_step_ragged`` over ``latent`` [L, B, C, W].
 """
 
 from __future__ import annotations
@@ -62,8 +81,11 @@ class Mixer(NamedTuple):
     """One kind of mixer. ``stack``: the key of ``params`` that holds
     its layers' weights. ``sequence(cfg, layer, x [B, S, D], i, behind)
     -> (x, kept)``: the layer over a sequence behind `Behind`; ``kept``
-    is ``{"k", "v"}`` of an attention layer, the leaves the sequence
-    carries on of any other. ``step(cfg, layer, x [B, 1, D], i, state,
+    is ``{"k", "v"}`` of an attention layer over per-head K and V, by
+    any other name what else a layer leaves behind: the leaves the
+    sequence carries on, or what an attention without per-head K and V
+    caches a token (a latent; such a layer reads its prefix from
+    ``behind.carried``). ``step(cfg, layer, x [B, 1, D], i, state,
     started) -> (x, state)``: one position a row over the cache's
     ``state`` (rows not ``started`` begin from zeros), or None for
     attention, which goes through the cache's ``attend``. ``scope``:
@@ -174,8 +196,13 @@ def sequence_pass(family: Family, cfg, params: dict, tokens: jax.Array,
     KV, Hd] in plan order, their v, the mixers' ``kept`` leaves [L,
     B, ...] each)."""
     x, ks, vs, kept = sequence_layers(family, cfg, params, tokens, *prefix)
-    return x, jnp.stack(ks), jnp.stack(vs), {
+    return x, _stacked(ks), _stacked(vs), {
         name: jnp.stack(leaves) for name, leaves in kept.items()}
+
+
+def _stacked(leaves: list):
+    """None for a family no layer of which keeps per-head K and V."""
+    return jnp.stack(leaves) if leaves else None
 
 
 def sequence_layers(family: Family, cfg, params: dict, tokens: jax.Array,
@@ -190,8 +217,10 @@ def sequence_layers(family: Family, cfg, params: dict, tokens: jax.Array,
     dt = cfg.dtype
     B, S = tokens.shape
     if k_prefix is None:
-        shape = (_attention_layers(family, cfg), B, 0, cfg.n_kv_heads,
-                 cfg.head_dim)
+        # Heads of per-head K and V; a family that keeps none (a latent
+        # a token, `Mixer`) has neither number.
+        shape = (_attention_layers(family, cfg), B, 0,
+                 getattr(cfg, "n_kv_heads", 1), getattr(cfg, "head_dim", 1))
         k_prefix = v_prefix = jnp.zeros(shape, dt)
     if carried is None:
         carried = family.init_rows(cfg, B)
@@ -209,12 +238,9 @@ def sequence_layers(family: Family, cfg, params: dict, tokens: jax.Array,
             pre = _before(family, cfg, ffn, layer, x)
             with _scope(mixer.scope):
                 x, out = mixer.sequence(cfg, layer, x, i, behind)
-            if mixer.step is None:
-                ks.append(out["k"])
-                vs.append(out["v"])
-            else:
-                for name, leaf in out.items():
-                    kept.setdefault(name, []).append(leaf)
+            for name, leaf in out.items():
+                (ks if name == "k" else vs if name == "v"
+                 else kept.setdefault(name, [])).append(leaf)
         if ffn is not None:
             x, _ = family.ffns[ffn].block(cfg, params, fi, x, pre)
     return x, ks, vs, kept

@@ -35,7 +35,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from functools import lru_cache
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -414,6 +414,15 @@ class _Request:
     # re-admission path knows to account its suffix prefill.
     seq: int = 0
     preemptions: int = 0
+    # `tokens` as the radix tree compares them while the request waits
+    # (``serving/paged.py _common``): an int32 array made once at
+    # submit, on the caller's thread, where the pool matches prefixes;
+    # the list itself elsewhere.
+    match_key: Any = None
+
+    def __post_init__(self):
+        if self.match_key is None:
+            self.match_key = self.tokens
 
     def wait(self, timeout: Optional[float] = None) -> list[int]:
         if not self.done.wait(timeout):
@@ -651,7 +660,8 @@ class ContinuousBatchingEngine:
         # decode steps routed to each expert. The engine thread reads it
         # between ticks, when `stats()` has asked (`_serve_expert_tokens`).
         self._expert_counters = tuple(
-            name for name in ("moe_expert_tokens", "moe_pairs_elsewhere")
+            name for name in ("moe_expert_tokens", "moe_pairs_elsewhere",
+                              "mla_decode_positions")
             if name in self._cache)
         self._expert_tokens: Optional[dict] = None
         self._expert_tokens_asking = threading.Lock()  # one asker at a time
@@ -937,9 +947,14 @@ class ContinuousBatchingEngine:
         self._copy_page = None
         self._suffix_prefill = None
         if kv == "paged":
+            n_pages = self._pool.n_pages
+
             def copy_page(cache, src, dst):
+                # The leaves with a page axis (`page_bytes`' rule); a
+                # counter beside them is no page's content.
                 return {name: arr.at[:, dst].set(arr[:, src])
-                        for name, arr in cache.items()}
+                        if arr.ndim >= 3 and arr.shape[1] == n_pages
+                        else arr for name, arr in cache.items()}
 
             self._copy_page = jax.jit(copy_page, donate_argnums=(0,))
 
@@ -1180,6 +1195,8 @@ class ContinuousBatchingEngine:
                        klass=str(klass) or "batch")
         if request_id:
             req.id = str(request_id)
+        if self._pool is not None and self._pool.prefix_cache:
+            req.match_key = np.asarray(req.tokens, np.int32)
         if self.request_tracing:
             # Built BEFORE the lock (span allocation off the critical
             # section); ringed only AFTER a successful enqueue so
@@ -1454,10 +1471,10 @@ class ContinuousBatchingEngine:
             for i in range(min(len(q), self._admit_window)):
                 req = q[i]
                 barrier = req.admit_skips >= self._admit_skip_cap
-                if self._pool.can_admit(len(req.tokens), req.tokens):
-                    score = (float("inf") if barrier else
-                             float(self._pool.peek_matched_tokens(
-                                 len(req.tokens), req.tokens)))
+                matched = self._pool.admissible_match(
+                    len(req.tokens), req.match_key)
+                if matched is not None:
+                    score = float("inf") if barrier else float(matched)
                     if score > best_score:
                         best_i, best_score = i, score
                 if barrier:
@@ -1478,10 +1495,10 @@ class ContinuousBatchingEngine:
             for i in range(min(len(q), self._admit_window)):
                 req = q[i]
                 barrier = req.admit_skips >= rc.skip_cap
-                if self._pool.can_admit(len(req.tokens), req.tokens):
-                    hot = (float("inf") if barrier else
-                           float(self._pool.peek_matched_tokens(
-                               len(req.tokens), req.tokens)))
+                matched = self._pool.admissible_match(
+                    len(req.tokens), req.match_key)
+                if matched is not None:
+                    hot = float("inf") if barrier else float(matched)
                     overdue = int(now - req.submitted_at > rc.ttft_target)
                     key = (rc.priority, overdue, hot, -req.seq)
                     if best is None or key > best[0]:
@@ -2093,6 +2110,9 @@ class ContinuousBatchingEngine:
             # `moe_pairs_elsewhere` [expert layer]: those routed to
             # experts another chip holds (families with routed experts
             # that count them; absent otherwise).
+            # `mla_decode_positions`: the positions the decode steps'
+            # latent attention read, over live rows and steps (a
+            # family that caches a latent a token; absent otherwise).
             **(self._read_expert_tokens() if self._expert_counters else {}),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
@@ -2125,6 +2145,11 @@ class ContinuousBatchingEngine:
                 # Bytes one page holds over all layers (K, V and any
                 # per-page state), and the state's part of it.
                 "kv_page_bytes": sum(self._page_bytes[:2]),
+                # Bytes a token costs over all layers in the pool's
+                # per-token leaves (K and V, or a latent with its
+                # padding).
+                "kv_token_bytes": (self._page_bytes[0]
+                                   // self._pool.page_size),
                 "kv_state_bytes_per_page": self._page_bytes[1],
                 # What a slot holds beside its pages, whatever its
                 # length: a family's per-row recurrent state.
@@ -2693,9 +2718,13 @@ class ContinuousBatchingEngine:
         routed-pairs counter to the host when `stats()` has asked."""
         if self._expert_tokens_wanted.is_set():
             self._expert_tokens_wanted.clear()
-            self._expert_tokens = {
-                name: np.asarray(self._cache[name]).tolist()
-                for name in self._expert_counters}
+            got = {name: np.asarray(self._cache[name]).tolist()
+                   for name in self._expert_counters}
+            if "mla_decode_positions" in got:
+                # Kept on the device as (multiples of 2^30, remainder).
+                high, low = got["mla_decode_positions"]
+                got["mla_decode_positions"] = (high << 30) + low
+            self._expert_tokens = got
             self._expert_tokens_ready.set()
 
     def _read_expert_tokens(self) -> dict:

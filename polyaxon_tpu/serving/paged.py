@@ -35,11 +35,36 @@ from typing import Optional
 import numpy as np
 
 
-def _common(key: tuple, tokens, start: int, limit: int) -> int:
-    """Length of the common prefix of ``key`` and ``tokens[start:]``,
-    capped at ``limit - start`` total tokens."""
+def _common(node: "_RadixNode", tokens, start: int, limit: int) -> int:
+    """Length of the common prefix of ``node.key`` and
+    ``tokens[start:]``, capped at ``limit - start`` total tokens.
+
+    A chain is thousands of tokens where documents are shared, and an
+    admission's pick asks this of every request it scans, so whole runs
+    are compared at once: a prompt handed over as an ``int32`` array
+    (the engine's, made once at submit) against the node's key as one
+    (kept on the node), any other sequence slice against slice, a page
+    at first and twice as many tokens after every run that agrees, then
+    token by token inside the run that differs. The same answers as the
+    walk token by token."""
+    key = node.key
     n = min(len(key), max(limit - start, 0))
-    j = 0
+    if n == 0 or key[0] != tokens[start]:
+        return 0
+    if isinstance(tokens, np.ndarray):
+        held = node.array
+        if held is None or len(held) < n:
+            held = node.array = np.asarray(key, np.int32)
+        differ = np.flatnonzero(held[:n] != tokens[start:start + n])
+        return int(differ[0]) if differ.size else n
+    j, run = 0, 16
+    while run >= 16 and j < n:
+        if key[j:j + run] == tuple(tokens[start + j:start + j + run]):
+            j += run
+            run *= 2
+        else:
+            run //= 2
+    j = min(j, n)
     while j < n and key[j] == tokens[start + j]:
         j += 1
     return j
@@ -53,10 +78,15 @@ class _RadixNode:
     leading tokens — match picks the child with the longest agreement
     (a fully-matched first page always beats any partial sibling)."""
 
-    __slots__ = ("key", "pages", "children", "parent", "last_used")
+    __slots__ = ("key", "pages", "children", "parent", "last_used", "array")
 
     def __init__(self, key: tuple, pages: list, parent: "_RadixNode"):
         self.key = key
+        # `key` as an int32 array, made when a prompt that is one is
+        # first compared with it (`_common`). A key only ever shrinks
+        # to a prefix of itself (a split, an eviction), so the array's
+        # own prefix stays true.
+        self.array: Optional[np.ndarray] = None
         self.pages = pages
         self.children: list[_RadixNode] = []
         self.parent = parent
@@ -120,8 +150,13 @@ class RadixPrefixIndex:
         cow = None
         while True:
             best, bj = None, 0
+            # Most siblings differ at their first token: one read of
+            # the prompt's, compared as plain ints.
+            head = int(tokens[i]) if i < limit else None
             for child in node.children:
-                j = _common(child.key, tokens, i, limit)
+                if child.key[0] != head:
+                    continue
+                j = _common(child, tokens, i, limit)
                 if j > bj:
                     best, bj = child, j
             if best is None or bj == 0:
@@ -155,8 +190,11 @@ class RadixPrefixIndex:
         limit = len(tokens)
         while i < limit:
             best, bj = None, 0
+            head = int(tokens[i])
             for child in node.children:
-                j = _common(child.key, tokens, i, limit)
+                if child.key[0] != head:
+                    continue
+                j = _common(child, tokens, i, limit)
                 if j > bj:
                     best, bj = child, j
             if best is None or bj == 0:
@@ -237,6 +275,11 @@ class RadixPrefixIndex:
         right now: pages in maximal all-unreferenced suffixes of the
         tree (a ref==0 page buried under a live descendant is resident
         but NOT reclaimable — admission planning must not count it)."""
+
+        # As a list: the walk reads a count a resident page, tens of
+        # thousands where documents are shared, and a numpy scalar read
+        # costs several times a list's.
+        ref = ref.tolist()
 
         def visit(node: _RadixNode):
             count, kids_clean = 0, True
@@ -354,6 +397,18 @@ class PagePool:
         with self._lock:
             return len(self._free) + self._reclaimable_locked()
 
+    def _live_locked(self, pages: list) -> int:
+        """How many of ``pages`` a live slot references (one read of
+        the counts, not one a page: a matched document is a thousand)."""
+        return int(np.count_nonzero(self._ref[pages])) if pages else 0
+
+    def _fits_locked(self, want: int) -> bool:
+        """Whether ``want`` pages can be allocated now. The free list
+        answers alone where it suffices: what eviction could reclaim
+        is a walk of the whole tree."""
+        return (want <= len(self._free)
+                or want <= len(self._free) + self._reclaimable_locked())
+
     def _reclaimable_locked(self) -> int:
         if self._index is None:
             return 0
@@ -383,11 +438,12 @@ class PagePool:
             if self._index is None:
                 return {"nodes": 0, "pages": 0, "referenced": 0,
                         "resident": 0}
-            pages = list(self._index._page_owner)
-            referenced = sum(1 for p in pages if self._ref[p] > 0)
-            return {"nodes": self._index.n_nodes(), "pages": len(pages),
+            owned = len(self._index._page_owner)
+            pages = np.fromiter(self._index._page_owner, np.intp, owned)
+            referenced = int(np.count_nonzero(self._ref[pages]))
+            return {"nodes": self._index.n_nodes(), "pages": owned,
                     "referenced": referenced,
-                    "resident": len(pages) - referenced}
+                    "resident": owned - referenced}
 
     # ---------------------------------------------------------- planning
     def _match_locked(self, length: int, tokens, touch: bool):
@@ -405,13 +461,24 @@ class PagePool:
         at most their own reclaim slot (charged 1 — conservative) and
         every miss/CoW/private page costs one fresh allocation."""
         matched, _ = self._match_locked(length, tokens, touch=False)
-        live = sum(1 for p in matched if self._ref[p] > 0)
+        live = self._live_locked(matched)
         return self.pages_for(length) - live
 
     def can_admit(self, length: int, tokens=None) -> bool:
         with self._lock:
-            return (self._plan_locked(length, tokens)
-                    <= len(self._free) + self._reclaimable_locked())
+            return self._fits_locked(self._plan_locked(length, tokens))
+
+    def admissible_match(self, length: int, tokens=None) -> Optional[int]:
+        """`can_admit` and `peek_matched_tokens` from one walk of the
+        tree, for the engine's pick, which asks both of every request
+        it scans: the prefill tokens the tree would serve, or None
+        where the request does not fit now. Read-only."""
+        with self._lock:
+            matched, cow = self._match_locked(length, tokens, touch=False)
+            live = self._live_locked(matched)
+            if not self._fits_locked(self.pages_for(length) - live):
+                return None
+            return len(matched) * self.page_size + (cow[1] if cow else 0)
 
     def peek_matched_tokens(self, length: int, tokens=None) -> int:
         """How many prefill tokens the radix tree would serve for this
@@ -464,8 +531,8 @@ class PagePool:
             assert (row < 0).all(), \
                 f"slot {slot} admitted while still holding pages"
             matched, cow_src = self._match_locked(length, tokens, touch=True)
-            live = sum(1 for p in matched if self._ref[p] > 0)
-            if need - live > len(self._free) + self._reclaimable_locked():
+            live = self._live_locked(matched)
+            if not self._fits_locked(need - live):
                 return None
             self._reclaim_cache = None
             for i, page in enumerate(matched):
@@ -728,6 +795,11 @@ class WindowedPagePool(PagePool):
 
     def can_admit(self, length: int, tokens=None) -> bool:
         return self._window_room(length) and super().can_admit(length, None)
+
+    def admissible_match(self, length: int, tokens=None) -> Optional[int]:
+        if not self._window_room(length):
+            return None
+        return super().admissible_match(length, None)
 
     # -------------------------------------------------------- allocation
     def admit(self, slot: int, length: int,

@@ -140,6 +140,25 @@ CASES = {
     "smallthinker-prefill-page-writes": (
         "smallthinker", dict(program="prefill"),
         {"flash_fwd": 3, "grouped_matmul": 9}),
+    # Latent attention's decode kernel (ops/mla_decode.py) at the Kimi
+    # cell's own shapes: 64 absorbed queries of 640 (a latent of 576
+    # padded to whole lane tiles) a row, 64 slots of 18,432 tokens, five
+    # layers' pools of 32,769 pages stacked.
+    "mla-kimi_k2-cell-64x18432-h64-w640": (
+        "mla", dict(h=64, w=640, c=512, slots=64, max_len=18432,
+                    n_pages=32769, layers=5), {"mla_decode": 1}),
+    # The latent-attention / sigmoid-routed family's serving programs,
+    # a dense layer and an expert layer at the published head and latent
+    # widths and a quarter of its experts: the decode step holds one
+    # `mla_decode` a layer and no per-head K or V of the table's length
+    # (PER_HEAD_KV), the suffix prefill behind 64 cached pages the
+    # grouped matmuls of its one expert block that feeds a latent (the
+    # last layer's feeds nothing the program returns); neither moves
+    # the pool (POOL_LIMITS).
+    "kimi_k2-decode-step-latent-pages": (
+        "kimi_k2", dict(program="decode"), {"mla_decode": 2}),
+    "kimi_k2-suffix-behind-cached-latents": (
+        "kimi_k2", dict(program="suffix"), {}),
     # PROJECTION_SLICES' controls: the same programs over the tree with
     # every projection ``[D, N]`` as ``family.init`` draws it.
     "llama-decode-step-mistral7b-cells-plain-tree": (
@@ -184,6 +203,19 @@ POOL_LIMITS = {
     # pages (`llama.paged_write_pages`), each where it lies.
     "smallthinker-decode-step-two-page-spaces": ((2057, 4, 16, 128), 0, None),
     "smallthinker-prefill-page-writes": ((2057, 4, 16, 128), 0, None),
+    # One leaf of latents, a page a row a decode step and the tail's
+    # pages a suffix prefill, each where it lies.
+    "kimi_k2-decode-step-latent-pages": ((2049, 1, 16, 640), 0, None),
+    "kimi_k2-suffix-behind-cached-latents": ((2049, 1, 16, 640), 0, None),
+}
+
+# A latent-attention decode program reads its cache as latents: no
+# tensor of the block table's length (8 slots x 2,048 positions) with a
+# head dimension beside it, which is what an up-projected K or V of the
+# prefix would be (name -> a pattern no instruction's type may match).
+PER_HEAD_KV = {
+    "kimi_k2-decode-step-latent-pages":
+        r"\[8,(2048,8,(192|128|64)|8,2048,(192|128|64))\]",
 }
 
 
@@ -538,6 +570,64 @@ def _compile_qwen3_next(topo, program, slots=128, max_len=512, page=16,
             i32()).compile()
 
 
+def _compile_mla(topo, h, w, c, slots, max_len, n_pages, layers, page=16):
+    """`mla_decode_attention` alone, the pool stacked over the layers."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from polyaxon_tpu.ops.mla_decode import mla_decode_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return jax.jit(lambda *a: mla_decode_attention(
+        *a, scale=0.1, value_width=c, interpret=False)).lower(
+        aval((slots, h, w), jnp.bfloat16),
+        aval((layers, n_pages, 1, page, w), jnp.bfloat16),
+        aval((), jnp.int32), aval((slots, max_len // page), jnp.int32),
+        aval((slots,), jnp.int32)).compile()
+
+
+def _compile_kimi_k2(topo, program, slots=8, max_len=2048, page=16,
+                     n_pages=2049, bucket=128, n_pref=64):
+    """`decode_step_paged`, or the suffix prefill of a ``bucket``-token
+    tail behind ``n_pref`` matched pages with its insert, as the engine
+    builds them: a dense and an expert layer at the published head and
+    latent widths."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from polyaxon_tpu.models import kimi_k2 as k2
+
+    cfg = dataclasses.replace(
+        k2.CONFIGS["kimi_k2_6"], vocab_size=1024, dim=512, n_layers=2,
+        n_heads=8, q_lora_rank=256, ffn_dim=1024, n_experts=32,
+        held_experts=(8, 8), experts_per_token=4, moe_ffn_dim=256,
+        max_seq_len=max_len, attention_impl="flash",
+        paged_attention_impl="pallas")
+    if program == "decode":
+        return _compile_decode_step(topo, k2, cfg, slots, max_len, n_pages,
+                                    page)
+    params, cache, i32 = _engine_avals(topo, k2, cfg, slots, n_pages, page)
+
+    def suffix(params, tokens, cache, page_ids, m, real_len):
+        pref = jnp.maximum(page_ids[:n_pref], 0)
+        novel = k2.paged_prefill_suffix_kv(
+            cfg, params, tokens, *k2.paged_gather_prefix(cache, pref), m)
+        return k2.paged_insert_suffix(cache, *novel, page_ids, m, page,
+                                      real_len)
+
+    with _kernel_path():
+        return jax.jit(suffix, donate_argnums=(2,)).lower(
+            params, i32(1, bucket), cache, i32(max_len // page), i32(),
+            i32()).compile()
+
+
 def _compile_smallthinker(topo, program, slots=8, max_len=8192, page=16,
                           prompt=4607, held=True):
     """`decode_step_paged` over both page spaces, or the whole-prompt
@@ -673,6 +763,7 @@ def _child_main() -> int:
                         "nemotron_h": _compile_nemotron_h,
                         "qwen3_next": _compile_qwen3_next,
                         "smallthinker": _compile_smallthinker,
+                        "mla": _compile_mla, "kimi_k2": _compile_kimi_k2,
                         "llama_decode": _compile_llama_decode,
                         "llama_prefill": _compile_llama_prefill,
                         "lfm2_decode": _compile_lfm2_decode}[kind]
@@ -701,6 +792,9 @@ def _child_main() -> int:
                     text, PROJECTION_SLICES[held_case])
                 report[name]["temp_bytes"] = (
                     compiled.memory_analysis().temp_size_in_bytes)
+            if name in PER_HEAD_KV:
+                report[name]["per_head_kv"] = re.findall(
+                    rf"\w+{PER_HEAD_KV[name]}", text)[:5]
             pool_case = name.removesuffix(TOKEN_WISE)
             if pool_case in POOL_LIMITS:
                 report[name]["pool_sized"] = _pool_sized_instructions(
@@ -748,6 +842,14 @@ def test_compiles_for_described_tpu(aot_report, name):
     assert entry["ok"], f"the TPU compiler refused {name}: {entry['error']}"
     # The kernel itself, not a reference path that happens to compile.
     assert entry["kernels"] == CASES[name][2], entry
+
+
+@pytest.mark.parametrize("name", sorted(PER_HEAD_KV))
+def test_latent_decode_program_holds_no_per_head_kv(aot_report, name):
+    """The absorbed form reads the latents and nothing made of them: no
+    instruction of the decode program gives keys or values a head over
+    the rows' whole tables."""
+    assert aot_report["cases"][name].get("per_head_kv") == []
 
 
 @pytest.mark.parametrize("name", sorted(POOL_LIMITS))

@@ -1156,3 +1156,55 @@ class TestSuffixBucketing:
         assert info.misses == 2
         assert info.hits == 2
         assert stats["kv_invariant_violations"] == 0
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_radix_walk_compares_runs_and_gives_the_token_walks_answers(seed):
+    """`_common` compares whole runs (an int32 prompt against the
+    node's key as an array, any other sequence slice against slice):
+    for keys and prompts that agree up to every kind of place (nowhere,
+    inside the first page, on a page boundary, inside a late page, past
+    the key's end, past the limit) both give what the walk token by
+    token gives, and so do `match`, `peek_matched_tokens` and
+    `admissible_match` over a tree of documents with tails."""
+    from polyaxon_tpu.serving import paged
+
+    rng = np.random.default_rng(seed)
+    key = tuple(int(t) for t in rng.integers(0, 50, 200))
+    node = paged._RadixNode(key, [], None)
+
+    def walk(tokens, start, limit):
+        n = min(len(key), max(limit - start, 0))
+        j = 0
+        while j < n and key[j] == tokens[start + j]:
+            j += 1
+        return j
+
+    for agree in (0, 1, 15, 16, 17, 64, 150, 199, 200):
+        for start in (0, 3):
+            tokens = [99] * start + list(key[:agree]) + [77] * (260 - agree)
+            for limit in (0, start + 10, start + 160, start + 200, 400):
+                want = walk(tokens, start, limit)
+                assert paged._common(node, tokens, start, limit) == want
+                assert paged._common(node, np.asarray(tokens, np.int32),
+                                     start, limit) == want
+    assert node.array is not None and len(node.array) == len(key)
+
+    ps = 4
+    pool = PagePool(4, 256, ps, 200)
+    docs = [rng.integers(0, 50, 40).tolist() for _ in range(3)]
+    prompts = [docs[i % 3] + rng.integers(0, 50, 5 + i).tolist()
+               for i in range(6)]
+    for slot, prompt in enumerate(prompts[:3]):
+        assert pool.admit(slot, len(prompt), prompt)
+        pool.commit_prefix(slot)
+    for prompt in prompts:
+        as_array = np.asarray(prompt, np.int32)
+        n = len(prompt)
+        seen = pool.peek_matched_tokens(n, prompt)
+        assert seen == pool.peek_matched_tokens(n, as_array)
+        assert pool.can_admit(n, prompt)
+        assert pool.admissible_match(n, prompt) == seen
+        assert pool.admissible_match(n, as_array) == seen
+    assert pool.peek_matched_tokens(len(prompts[3]), prompts[3]) >= 40
+    assert pool.check_invariants() == []

@@ -55,6 +55,10 @@ def test_random_schedule_keeps_both_spaces_sound(seed, window):
         if what == "admit":
             fits = plain.can_admit(arg)
             assert pool.can_admit(arg) == fits
+            # The engine's pick asks both questions in one call: the
+            # same answer from either pool (nothing matches in either).
+            assert (pool.admissible_match(arg) == plain.admissible_match(arg)
+                    == (0 if fits else None))
             assert bool(pool.admit(b, arg)) == bool(plain.admit(b, arg)) == fits
             if fits:
                 live[b] = arg - 1
@@ -151,17 +155,24 @@ def test_window_pool_counts_a_broken_window_space():
     assert any("leaked" in line for line in pool.check_invariants())
 
 
-def test_an_engine_without_window_layers_builds_the_plain_pool():
+@pytest.mark.parametrize("model", ["llama_tiny", "kimi_k2_tiny"])
+def test_an_engine_without_window_layers_builds_the_plain_pool(model):
     """Decided once, where the pool is built: a llama engine has a
-    `PagePool`, no window tables, and no `step.window` leaf."""
+    `PagePool`, no window tables, and no `step.window` leaf; so has a
+    family whose pages hold a latent a token, and the radix tree matches
+    for both (a page of either carries no state)."""
+    from polyaxon_tpu.models import family_of
     from polyaxon_tpu.serving.batching import ContinuousBatchingEngine
 
-    cfg = llama.CONFIGS["llama_tiny"]
-    params = llama.init(cfg, jax.random.key(0))["params"]
-    engine = ContinuousBatchingEngine("llama_tiny", cfg, params, slots=2,
+    family = family_of(model)
+    cfg = family.CONFIGS[model]
+    params = family.init(cfg, jax.random.key(0))["params"]
+    engine = ContinuousBatchingEngine(model, cfg, params, slots=2,
                                       kv="paged", page_size=4, kv_pages=32)
     try:
         assert type(engine._pool) is PagePool
+        assert engine._pool.prefix_cache
+        assert not engine._pool.whole_page_matches
         assert engine._window_tables is None
         out = engine.generate([[1, 2, 3, 4, 5]], 6)
         stats = engine.stats()
