@@ -20,14 +20,47 @@ takes ``o`` through ``W_UV``.
 
 The call is ``paged_decode``'s streamed form with one "KV head" and
 ``H`` queries on it: every layer's pool stacked and the layer a
-prefetched scalar, grid ``(B,)``, a loop over the row's live pages, 16 a
-turn (256 tokens at the serving page), each page copied by its own DMA
-into one of two buffers while the other is computed on; nothing is
-fetched past a row's length, an idle row (``pos = -1``) costs no copy
-and gives zeros, a hole (``tables[b, p] < 0``) is masked by its page's
-columns. The kernel is named ``mla_decode``. Off the chip the model
-runs `mla_decode_reference`, the same sums over gathered pages in plain
-``jnp``; under ``interpret`` the kernel runs on the CPU for the tests.
+prefetched scalar, grid ``(B,)``, a loop over the row's live pages, each
+page copied by its own DMA; nothing is fetched past a row's length, an
+idle row (``pos = -1``) costs no copy and gives zeros, a hole
+(``tables[b, p] < 0``) is masked by its page's columns. What the loop
+adds is its schedule, so that the copy engine, the matrix unit and the
+vector units work at the same time (counted on the chip: PERF.md §5-6,
+PR 49):
+
+- a *turn* is ``BLOCKS`` *sub-blocks* of ``SUB_PAGES`` pages (2 x 512
+  keys at the serving page) in one of ``STAGES`` buffers. The copies
+  run ``STAGES - 1`` turns ahead of the turn computed on, and past the
+  row's end into the next row's first turns (the stage and the count
+  of turns already begun are carried from row to row in SMEM), so that
+  a row's first turn does not wait for its pages from a standing start;
+- a whole turn with a whole turn to begin (all of a row but its edges)
+  is one straight line under one condition: one wait (a stage's copies
+  signal one semaphore, the wait is for the bytes of them all), then
+  the sums, with the next copies' starts laid between the sub-blocks,
+  so that a descriptor's scalar work goes under the matrix and vector
+  work. The compiler's bounds checks of every descriptor are off
+  (they were a third of a turn's instructions); the page id is clamped
+  to the pool instead. Only a row's edges go page by page, in a scalar
+  loop. Every run of starts or waits is one ``fori_loop`` traced once
+  (the whole turn's unrolled by the compiler): a turn unrolled in
+  Python cost the server a quarter of a minute of tracing at every
+  start, whatever the compile cache held;
+- each sub-block has its scores, maximum, ``exp``, sum and value
+  product to itself, so in straight-line code one sub-block's matmuls
+  lie under another's softmax; the parts are merged into the running
+  float32 maximum, sum and accumulator once a turn (the online-softmax
+  merge, between sub-blocks as between turns). The straight line has
+  nothing to mask (no hole, which the starts of a whole turn note a
+  stage as they read the table, and no column past the row's position)
+  and takes the sums without the mask; a turn that has is an edge.
+
+A table narrower than a turn makes as many sub-blocks as cover it, one
+narrower than a sub-block the one sub-block of its width: decided from
+the shape (`turn_shape`). The kernel is named ``mla_decode``, one call a
+layer a step. Off the chip the model runs `mla_decode_reference`, the
+same sums over gathered pages in plain ``jnp``; under ``interpret`` the
+kernel runs on the CPU for the tests.
 """
 
 from __future__ import annotations
@@ -41,49 +74,71 @@ from jax.experimental.pallas import tpu as pltpu
 
 from polyaxon_tpu.ops.flash import resolve_interpret
 from polyaxon_tpu.ops.paged_attention import (LANES, NEG_INF, _live_columns,
-                                              _quot, _rem)
+                                              _quot)
 
-# Pages a turn of the streamed loop: 256 tokens at the serving page.
-GROUP = 16
+# The streamed loop's schedule (PERF.md §5-6, PR 49: counted on the
+# chip at the serving cell's shape). A sub-block is what one softmax
+# chain covers, 512 keys at the serving page; a turn is BLOCKS of them,
+# copied and waited for as one; STAGES turns' buffers, so that
+# STAGES - 1 turns' pages are in flight while one is computed on.
+SUB_PAGES = 32
+BLOCKS = 2
+STAGES = 3
+
+
+class _Table:
+    """The prefetched block table, flat in SMEM, read as ``[B, maxp]``:
+    an entry of a flat array costs the scalar core one add, where the
+    tiles of two dimensions cost it eight operations a page copied."""
+
+    def __init__(self, ref, maxp: int):
+        self.ref, self.shape = ref, (ref.shape[0] // maxp, maxp)
+
+    def __getitem__(self, at):
+        row, p = at
+        return self.ref[row * self.shape[1] + p]
 
 
 def _mla_kernel(
-    tables_ref,  # scalar prefetch: [B, maxp] int32 page ids (-1 = hole)
+    tables_ref,  # scalar prefetch: [B · maxp] int32 page ids (-1 = hole)
     pos_ref,  # scalar prefetch: [B] int32 row positions (-1 = idle)
     layer_ref,  # scalar prefetch: [1] int32, the layer whose pages to read
     q_ref,  # [1, H, W]
     c_hbm,  # [L, P, 1, page, W], left in HBM
     o_ref,  # [1, H, C]
-    c_buf,  # VMEM [2, G·page, W]: the turn computed on, the next
-    sem,  # DMA semaphores [2 (buffer), G]
+    c_buf,  # VMEM [STAGES, G·page, W]: a turn's pages a stage
+    sem,  # DMA semaphores [STAGES]: a stage's copies share one
+    carry,  # SMEM [2]: the stage of this row's first turn, its turns begun
+    holes,  # SMEM [STAGES]: negative unless the stage's turn is whole, no hole
     acc_ref,  # VMEM [H, C] f32
     m_ref,  # VMEM [H, LANES] f32
     l_ref,  # VMEM [H, LANES] f32
     *,
     scale: float,
     page: int,
-    group: int,
+    maxp: int,
+    sub_pages: int,
+    blocks: int,
     value_width: int,
 ):
     b = pl.program_id(0)
-    maxp = tables_ref.shape[1]
-    pos = pos_ref[b]
+    rows = pl.num_programs(0)
+    table = _Table(tables_ref, maxp)  # for `_live_columns`
+    group = sub_pages * blocks  # pages a turn
+    sub = sub_pages * page  # keys a sub-block
+    ahead = STAGES - 1  # turns in flight beside the one computed on
     layer = layer_ref[0]
-    n_pages = jnp.minimum(_quot(pos + page, page), maxp)  # 0 when idle
+    pos = pos_ref[b]
+
+    def pages_of(row):
+        """The pages row ``row`` holds: 0 when idle, and past the last."""
+        n = jnp.minimum(
+            _quot(pos_ref[jnp.minimum(row, rows - 1)] + page, page), maxp)
+        return jnp.where(row < rows, n, 0)
+
+    n_pages, next_pages = pages_of(b), pages_of(b + 1)
     n_turns = _quot(n_pages + group - 1, group)
-
-    def each_live_copy(turn, buf, act):
-        for i in range(group):
-            p = turn * group + i
-            # A hole's page is masked; the clamps keep the reads legal.
-            pid = jnp.maximum(tables_ref[b, jnp.minimum(p, maxp - 1)], 0)
-            copy = pltpu.make_async_copy(
-                c_hbm.at[layer, pid, 0],
-                c_buf.at[buf, pl.ds(i * page, page)], sem.at[buf, i])
-
-            @pl.when(p < n_pages)
-            def _act():
-                getattr(copy, act)()
+    next_turns = _quot(next_pages + group - 1, group)
 
     @pl.when(b == 0)
     def _clear():
@@ -91,46 +146,227 @@ def _mla_kernel(
         # and 0 x what an earlier row left there is 0; only what the
         # buffer held before the first copy is not known to be finite.
         c_buf[:] = jnp.zeros_like(c_buf)
+        carry[0] = 0
+        carry[1] = 0
+
+    first_stage, begun = carry[0], carry[1]
+
+    def turn_ahead(turn):
+        """(row, first page, pages the row holds) of the turn ``ahead``
+        turns on: this row's, or the next row's once this row's are all
+        begun, so that a row finds its first turns under way."""
+        own = turn + ahead < n_turns
+        return (jnp.where(own, b, jnp.minimum(b + 1, rows - 1)),
+                jnp.where(own, turn + ahead, turn + ahead - n_turns) * group,
+                jnp.where(own, n_pages, next_pages))
+
+    def begin(row, first, stage, lo, hi, holes_or, unroll: bool = False):
+        """Begin the copies of pages ``first + lo .. first + hi`` of
+        ``row`` into their places of ``stage``; returns ``holes_or``
+        or-ed with the table's entries they read (page ids are not
+        negative: the bitwise or is, with a hole). A hole's page is
+        masked; the clamp keeps every read inside the pool, whatever the
+        entry (the compiler's own checks of every descriptor are off:
+        they were a third of a turn's instructions). One loop, traced
+        once: unrolled where the bounds are static (a whole turn's
+        starts, laid between the sums), a scalar loop at a row's edges."""
+        base = row * maxp + first
+
+        def one(i, entries):
+            entry = tables_ref[base + i]
+            pltpu.make_async_copy(
+                c_hbm.at[layer, jax.lax.clamp(0, entry, c_hbm.shape[1] - 1),
+                         0],
+                c_buf.at[stage, pl.ds(pl.multiple_of(i * page, page), page)],
+                sem.at[stage]).start()
+            return entries | entry
+
+        return jax.lax.fori_loop(lo, hi, one, holes_or, unroll=unroll)
+
+    def begin_edge(ahead_turn, stage):
+        """Begin what its row holds of a turn, page by page, and note
+        whether that was a whole turn without a hole."""
+        row, first, live = ahead_turn
+        count = jnp.clip(live - first, 0, group)
+        entries = begin(row, first, stage, 0, count, jnp.int32(0))
+        holes[stage] = jnp.where(count == group, entries, -1)
+
+    def wait(turn, stage, whole: bool):
+        if whole:
+            # The stage's copies signal one semaphore: one wait for the
+            # bytes of them all.
+            pltpu.make_async_copy(c_buf.at[stage], c_buf.at[stage],
+                                  sem.at[stage]).wait()
+        else:
+            # One wait a page the turn holds, each for a page's bytes.
+            one_page = pltpu.make_async_copy(
+                c_buf.at[stage, pl.ds(0, page)],
+                c_buf.at[stage, pl.ds(0, page)], sem.at[stage])
+
+            def wait_one(_, carried):
+                one_page.wait()
+                return carried
+
+            jax.lax.fori_loop(
+                0, jnp.minimum(n_pages - turn * group, group), wait_one,
+                None)
+
+    def accumulate(turn, stage, masked: bool, between):
+        """One online-softmax update over a turn: each sub-block's
+        scores, maximum, sum and value product on their own (no
+        sub-block waits for another's softmax), merged into the
+        running float32 maximum, sum and accumulator at the end.
+        ``between(j)`` is laid behind sub-block ``j``."""
+        m_prev = m_ref[:, :1]
+        parts = []
+        for j in range(blocks):
+            c = c_buf[stage, pl.ds(j * sub, sub)]  # [T, W]
+            s = jax.lax.dot_general(
+                q_ref[0], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [H, T]
+            if masked:
+                mask = _live_columns(
+                    table, b, turn * group + j * sub_pages, pos,
+                    page=page, group=sub_pages)[0]  # [1, T]
+                s = jnp.where(mask, s, NEG_INF)
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            if masked:
+                p = jnp.where(mask, p, 0.0)
+            pv = jnp.dot(p.astype(c.dtype), c[:, :value_width],
+                         preferred_element_type=jnp.float32)
+            parts.append((m, jnp.sum(p, axis=-1, keepdims=True), pv))
+            between(j)
+        m_new = functools.reduce(jnp.maximum, [m for m, _, _ in parts],
+                                 m_prev)
+        alpha = jnp.exp(m_prev - m_new)
+        acc, l_new = acc_ref[:] * alpha, l_ref[:, :1] * alpha
+        for m, l, pv in parts:
+            weight = jnp.exp(m - m_new)
+            acc, l_new = acc + pv * weight, l_new + l * weight
+        acc_ref[:] = acc
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    def take_turn(turn, stage, ahead_stage, ahead_turn, steady: bool):
+        """A steady turn (whole and with nothing to mask, a whole turn
+        to begin: all of a row but its edges) is one straight line: the
+        one wait, then the sums with the starts of the turn ``ahead`` on
+        laid between the sub-blocks, so that the scalar work of a
+        descriptor goes under the matrix and vector work. At a row's
+        edges the starts and the waits go page by page, and the sums
+        take the mask."""
+        if not steady:
+            begin_edge(ahead_turn, ahead_stage)
+            wait(turn, stage, False)
+            accumulate(turn, stage, True, lambda j: None)
+            return
+        row, first, _ = ahead_turn
+        entries = [jnp.int32(0)]
+
+        def between(j):
+            entries[0] = begin(row, first, ahead_stage, j * sub_pages,
+                               (j + 1) * sub_pages, entries[0], unroll=True)
+
+        wait(turn, stage, True)
+        accumulate(turn, stage, False, between)
+        holes[ahead_stage] = entries[0]
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(n_turns > 0)
-    def _first():
-        each_live_copy(0, 0, "start")
+    def wrapped(stage):
+        return jnp.where(stage >= STAGES, stage - STAGES, stage)
 
-    def turn_body(turn, carry):
-        buf = _rem(turn, 2)
+    def begin_unbegun(v, carried):
+        # Turn v of this row (or of the next, past this row's end),
+        # unless the row before began it.
+        pl.when(v >= begun)(lambda: begin_edge(
+            turn_ahead(v - ahead), wrapped(first_stage + v)))
+        return carried
 
-        @pl.when(turn + 1 < n_turns)
-        def _next():
-            each_live_copy(turn + 1, 1 - buf, "start")
+    jax.lax.fori_loop(0, ahead, begin_unbegun, None)
 
-        each_live_copy(turn, buf, "wait")
-        mask = _live_columns(tables_ref, b, turn * group, pos,
-                             page=page, group=group)[0]  # [1, T]
-        c = c_buf[buf]  # [T, W]
-        s = jax.lax.dot_general(
-            q_ref[0], c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = jnp.where(mask, s * scale, NEG_INF)  # [H, T]
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.dot(p.astype(c.dtype), c[:, :value_width],
-                     preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-        return carry
+    def turn_body(turn, stage):
+        ahead_stage = wrapped(stage + ahead)
+        ahead_turn = _, first, live = turn_ahead(turn)
+        whole = (turn + 1) * group
+        # Whole, with a whole turn to begin, no hole and no column past
+        # the row's position.
+        steady = ((whole <= n_pages) & (first + group <= live)
+                  & (holes[stage] >= 0) & (whole * page <= pos + 1))
+        for case in (True, False):
+            pl.when(steady == case)(functools.partial(
+                take_turn, turn, stage, ahead_stage, ahead_turn, case))
+        return wrapped(stage + 1)
 
-    jax.lax.fori_loop(0, n_turns, turn_body, None)
+    carry[0] = jax.lax.fori_loop(0, n_turns, turn_body, first_stage)
+    carry[1] = jnp.minimum(ahead, next_turns)
     l = l_ref[:, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)  # idle row → zeros
     o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+
+
+def turn_shape(maxp: int) -> tuple[int, int]:
+    """(pages a sub-block, sub-blocks a turn) over a table ``maxp``
+    wide: one narrower than a sub-block makes the one sub-block of its
+    width, one narrower than a turn as many sub-blocks as cover it."""
+    sub_pages = min(SUB_PAGES, maxp)
+    return sub_pages, min(BLOCKS, pl.cdiv(maxp, sub_pages))
+
+
+def schedule_stats(maxp: int, page: int) -> dict:
+    """The shape the loop runs at over tables ``maxp`` wide of pages of
+    ``page`` tokens, as ``/v1/stats`` says it."""
+    sub_pages, blocks = turn_shape(maxp)
+    return {"mla_decode_keys_per_turn": sub_pages * blocks * page,
+            "mla_decode_turns_in_flight": STAGES - 1}
+
+
+def _attend(q, pool, layer, tables, pos, scale, value_width, interpret):
+    B, H, W = q.shape
+    page = pool.shape[-2]
+    sub_pages, blocks = turn_shape(tables.shape[1])
+    compiler_params = None
+    if not interpret:
+        # Rows run in order: row 0 clears the buffers, and each row
+        # begins the next one's first copies. No descriptor is checked
+        # against its bounds: `copy` clips every page id to the pool.
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True)
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, page=page,
+                          maxp=tables.shape[1], sub_pages=sub_pages,
+                          blocks=blocks, value_width=value_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, value_width),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((STAGES, blocks * sub_pages * page, W),
+                           pool.dtype),
+                pltpu.SemaphoreType.DMA((STAGES,)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SMEM((STAGES,), jnp.int32),
+                pltpu.VMEM((H, value_width), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, value_width), q.dtype),
+        compiler_params=compiler_params,
+        interpret=interpret,
+        name="mla_decode",
+    )(tables.astype(jnp.int32).reshape(-1), pos.astype(jnp.int32), layer, q,
+      pool)
+
+
+# Jitted, so that a program's call sites (one a layer) share a lowering
+# of the unrolled turn.
+_call = jax.jit(_attend, static_argnums=(5, 6, 7))
 
 
 def mla_decode_attention(
@@ -148,44 +384,15 @@ def mla_decode_attention(
     (positions 0..pos inclusive: the step's latent must already be in
     the pool). Returns the probability-weighted sum of the latents'
     first ``value_width`` columns, [B, H, value_width]."""
-    interpret = resolve_interpret(interpret)
-    B, H, W = q.shape
-    _, _, one, page, width = pool.shape
+    W = q.shape[-1]
+    _, _, one, _, width = pool.shape
     if one != 1 or width != W or W % LANES or value_width % LANES:
         raise ValueError(
             f"mla_decode takes a pool [L, P, 1, page, W] and queries "
             f"[B, H, W] with W and value_width whole lane tiles; got pool "
             f"{pool.shape}, q {q.shape}, value_width {value_width}")
-    maxp = tables.shape[1]
-    group = min(GROUP, maxp)
-    compiler_params = None
-    if not interpret:
-        # Rows run in order: row 0 clears the buffer.
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    return pl.pallas_call(
-        functools.partial(_mla_kernel, scale=scale, page=page, group=group,
-                          value_width=value_width),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, H, value_width),
-                                   lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, group * page, W), pool.dtype),
-                pltpu.SemaphoreType.DMA((2, group)),
-                pltpu.VMEM((H, value_width), jnp.float32),
-                pltpu.VMEM((H, LANES), jnp.float32),
-                pltpu.VMEM((H, LANES), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, value_width), q.dtype),
-        compiler_params=compiler_params,
-        interpret=interpret,
-        name="mla_decode",
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+    return _call(q, pool, jnp.asarray(layer, jnp.int32).reshape(1), tables,
+                 pos, float(scale), value_width, resolve_interpret(interpret))
 
 
 def mla_decode_reference(q: jax.Array, pool: jax.Array, layer,

@@ -43,6 +43,7 @@ import numpy as np
 
 from polyaxon_tpu.obs import metrics as obs_metrics
 from polyaxon_tpu.obs import reqtrace
+from polyaxon_tpu.ops import mla_decode
 from polyaxon_tpu.serving.quantize import held_transposed_bytes, weight_bytes
 from polyaxon_tpu.serving.speculative import LaneView, SpeculationPolicy
 
@@ -2112,8 +2113,13 @@ class ContinuousBatchingEngine:
             # that count them; absent otherwise).
             # `mla_decode_positions`: the positions the decode steps'
             # latent attention read, over live rows and steps (a
-            # family that caches a latent a token; absent otherwise).
+            # family that caches a latent a token; absent otherwise),
+            # and beside it the shape its kernel's loop runs at here:
+            # `mla_decode_keys_per_turn`, `mla_decode_turns_in_flight`.
             **(self._read_expert_tokens() if self._expert_counters else {}),
+            **(mla_decode.schedule_stats(self._pool.max_pages_per_row,
+                                         self._pool.page_size)
+               if "mla_decode_positions" in self._expert_counters else {}),
             **({"draft_model": self.draft[0],
                 "spec_k": self.spec_k,
                 "spec_rounds": self._spec_rounds,

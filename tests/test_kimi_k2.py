@@ -343,40 +343,79 @@ def test_thirty_two_shares_add_up_to_the_uncut_layer():
 
 
 # -------------------------------------------------------------- the kernel
+# name -> (page, table width, each row's position (-1 = idle), holes as
+# (row, table entry)). At a page of 16 a sub-block is 512 keys and a
+# turn 1,024 (64 pages): the rows below end inside a sub-block, on its
+# edge and on a turn's; the longer ones have whole turns with a whole
+# turn two on (the straight line, with the mask and without); the
+# copies begun two turns ahead run into the next row's first turns,
+# past an idle row or into none.
+MLA_KERNEL_CASES = {
+    # Rows of unequal length, an idle row, a hole inside a live range, a
+    # row that fills its table, which is narrower than a sub-block (the
+    # one sub-block of its width is a turn).
+    "table-narrower-than-a-sub-block-a-hole-a-full-row": (
+        PAGE, 20, [37, -1, 159, 3, 70], [(2, 3)]),
+    "ends-inside-a-sub-block-on-its-edge-on-a-turns-edge": (
+        16, 330, [300, 511, 512, 1023, 1024, 4095], []),
+    "shorter-than-a-turn-beside-several-turns": (
+        16, 330, [100, 5000, 5, 4200, 0, 5279], []),
+    "idle-row-between-live-ones-and-last": (
+        16, 330, [4500, -1, 2100, -1, -1, 40], []),
+    "idle-rows-first-and-all-but-one": (
+        16, 330, [-1, -1, -1, 3290, -1, -1], []),
+    "hole-inside-a-whole-turn": (
+        16, 330, [5000, 1500, 2100, 1023, 2047, 300],
+        [(0, 10), (0, 70), (0, 200), (1, 63), (2, 64), (2, 127), (4, 0)]),
+    # Two sub-blocks cover a table of 40 pages: the turn is wider.
+    "table-narrower-than-a-turn": (
+        16, 40, [639, 17, -1, 400, 511], [(0, 20)]),
+    "table-of-one-page": (16, 1, [15, -1, 0, 7, 3], []),
+}
+
+
 @pytest.mark.parametrize("layer", [0, 1])
-def test_mla_decode_kernel_under_interpret_matches_jnp(layer):
-    """Rows of unequal length, an idle row, a hole inside a live range,
-    a row that fills its table, at the published widths' tiling (a
-    latent of 256, values over its first 128)."""
+@pytest.mark.parametrize("case", MLA_KERNEL_CASES)
+def test_mla_decode_kernel_under_interpret_matches_jnp(case, layer):
+    """The streamed loop against the same sums in plain ``jnp``, at the
+    published widths' tiling (a latent of 256, values over its first
+    128), over what its schedule has to get right (MLA_KERNEL_CASES)."""
+    page, maxp, pos, holes = MLA_KERNEL_CASES[case]
     rng = np.random.default_rng(layer)
-    L, P, W, C, H, B, maxp = 2, 64, 256, 128, 8, 5, 20
-    pool = jnp.asarray(rng.normal(size=(L, P, 1, PAGE, W)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(B, H, W)), jnp.float32)
-    pos = np.array([37, -1, 159, 3, 70], np.int32)
+    L, W, C, H, B = 2, 256, 128, 8, len(pos)
+    pos = np.array(pos, np.int32)
     tables = np.full((B, maxp), -1, np.int32)
-    nxt = 1
+    P = 1 + B * maxp              # by the shape: the cases share a program
+    ids = iter(rng.permutation(P - 1) + 1)         # scattered; 0 is no row's
     for b in range(B):
-        for p in range(pos[b] // PAGE + 1 if pos[b] >= 0 else 0):
-            tables[b, p] = nxt
-            nxt += 1
-    tables[2, 3] = -1
+        for p in range(pos[b] // page + 1 if pos[b] >= 0 else 0):
+            tables[b, p] = next(ids)
+    for b, p in holes:
+        tables[b, p] = -1
+    pool = jnp.asarray(rng.normal(size=(L, P, 1, page, W)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, H, W)), jnp.float32)
     args = (q, pool, layer, jnp.asarray(tables), jnp.asarray(pos))
     got = mla_decode_attention(*args, scale=0.1, value_width=C,
                                interpret=True)
     want = mla_decode_reference(*args, scale=0.1, value_width=C)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
-    assert not np.asarray(got[1]).any()           # the idle row: zeros
-    # The hole's page is not read: other contents there, same answer.
-    moved = pool.at[layer, 0].set(9.0)            # holes clamp to page 0
-    again = mla_decode_attention(q, moved, layer, jnp.asarray(tables),
-                                 jnp.asarray(pos), scale=0.1, value_width=C,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(again), np.asarray(got), atol=1e-6)
+    assert not np.asarray(got)[pos < 0].any()     # an idle row: zeros
+    if holes:
+        # A hole's page is masked: other contents there, same answer.
+        moved = pool.at[layer, 0].set(9.0)        # holes clip to page 0
+        again = mla_decode_attention(q, moved, *args[2:], scale=0.1,
+                                     value_width=C, interpret=True)
+        np.testing.assert_allclose(np.asarray(again), np.asarray(got),
+                                   atol=1e-6)
+
+
+def test_mla_decode_takes_whole_lane_tiles_only():
+    q, pool = jnp.zeros((2, 8, 40)), jnp.zeros((1, 4, 1, PAGE, 40))
     with pytest.raises(ValueError, match="whole lane tiles"):
-        mla_decode_attention(q[..., :40], pool[..., :40], 0,
-                             jnp.asarray(tables), jnp.asarray(pos),
-                             scale=0.1, value_width=32, interpret=True)
+        mla_decode_attention(q, pool, 0, jnp.zeros((2, 3), jnp.int32),
+                             jnp.zeros((2,), jnp.int32), scale=0.1,
+                             value_width=32, interpret=True)
 
 
 # -------------------------------------------------------------- the engine
@@ -429,6 +468,10 @@ def test_engine_shares_a_page_aligned_prefix_between_two_requests(model):
     # the first at the prompt's last token.
     assert stats["mla_decode_positions"] == sum(
         len(p) + i for p in (a, b, c) for i in range(6))
+    # The shape the kernel's loop runs at over this engine's tables: 12
+    # pages of 8 a row (one sub-block of the table's width a turn).
+    assert stats["mla_decode_keys_per_turn"] == 96
+    assert stats["mla_decode_turns_in_flight"] == 2
     held = np.asarray(stats["moe_expert_tokens"])
     assert held.shape == (2, 8)
     assert int(held.sum()) + sum(stats["moe_pairs_elsewhere"]) == 2 * 4 * 18
@@ -445,4 +488,4 @@ def test_llama_engines_stats_gain_the_token_bytes_and_nothing_else():
     finally:
         engine.stop()
     assert stats["kv_token_bytes"] * 4 == stats["kv_page_bytes"]
-    assert "mla_decode_positions" not in stats
+    assert not [name for name in stats if name.startswith("mla_decode")]
