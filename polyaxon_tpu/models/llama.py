@@ -78,8 +78,8 @@ class LlamaConfig:
     paged_attention_impl: str = "auto"
     # Flash-kernel tuning (runtime keys flow here via model_overrides):
     # fwd tile sizes and backward implementation ("pallas" | "xla").
-    # None = the kernel's own defaults (512 fwd tiles; pallas bwd on
-    # real TPU); "auto" = trace-time VMEM-budget pick (flash.auto_blocks).
+    # None (or "auto") = the kernel's own rule from the shapes
+    # (flash.auto_blocks; pallas bwd on real TPU).
     # Sweepable per-run from bench.py; setting one with a non-flash
     # attention_impl is an error.
     flash_block_q: Optional[int | str] = None

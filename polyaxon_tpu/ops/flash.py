@@ -1,24 +1,44 @@
 """Blocked flash attention as a Pallas TPU kernel.
 
-TPU-first design (pallas_guide.md): the forward pass tiles Q into
-``block_q`` × head_dim VMEM blocks and streams K/V blocks through the
-innermost (sequential) grid dimension, keeping the online-softmax
-running max/denominator and the output accumulator in f32 VMEM scratch
-— O(S) memory instead of the O(S²) logits tensor, with every matmul on
-the MXU (``preferred_element_type=f32``). Causal blocks strictly above
-the diagonal are skipped with ``pl.when`` (no wasted MXU cycles), and
-GQA is handled in the K/V index maps (kv head = q head // n_rep) so
-grouped heads are never materialized ``n_rep`` times in HBM.
+TPU-first design (pallas_guide.md): a grid step of the forward keeps a
+block of ``block_q`` query rows, copies a block of ``block_k`` keys and
+values that is several sub-blocks long and walks it with an inner loop,
+the online-softmax running max/denominator and the output accumulator
+in f32 VMEM scratch — O(S) memory instead of the O(S²) logits tensor,
+with every matmul on the MXU (``preferred_element_type=f32``). The
+loop's trip counts come from the block's indices (``_turn_ranges``):
+sub-blocks above the causal diagonal or below the window's band are
+never visited, a sub-block that lies wholly inside takes a turn with no
+mask (no iota, compare or select), only one that meets the diagonal,
+the window's edge or packed segments builds one. A grid step with
+nothing to visit names the block the step before left resident
+(``_resident_block``), so it copies nothing. GQA is handled in the K/V
+index maps (kv head = q head // n_rep) so grouped heads are never
+materialized ``n_rep`` times in HBM. The tiles follow what a call can
+see (lengths, head size, window, which kernel) in one place:
+``auto_blocks``.
+
+What a start of a server pays is part of the design (PERF.md §6, PRs 37,
+49-51): a ``pallas_call`` is traced and lowered at every call site of
+every program whatever the compile cache holds, and a static layer
+plan's prefill has a site a layer. So each loop here is one
+``fori_loop`` whose body is traced once (two bodies a kernel, the plain
+turn and the masked one, whatever the sequence length), and the entry
+is jitted (``_call``): a program's sites of one shape and window share
+one lowering. ``tests/test_ops.py TestFlashText`` holds both.
 
 The backward pass under ``jax.custom_vjp`` has two implementations:
 
 - **Pallas** (default on real TPU): the FlashAttention-2 split — a
-  dk/dv kernel gridded over K/V blocks that streams Q blocks (GQA
-  groups accumulate onto their shared kv head inside VMEM scratch, so
-  dk/dv never materialize per-q-head), and a dq kernel gridded like
-  the forward. Both recompute P from the saved logsumexp residual,
-  keep every matmul on the MXU in f32 accumulation, and skip causal /
-  out-of-window blocks with ``pl.when``.
+  dk/dv kernel that keeps a block of keys and walks the query rows it
+  copies (GQA groups accumulate onto their shared kv head inside VMEM
+  scratch, so dk/dv never materialize per-q-head), its score tiles
+  transposed (``s^T = k q^T``, ``dp^T = v dO^T``) so that both
+  accumulating products are plain ``[bk, bq] @ [bq, D]`` and ``lse`` /
+  ``delta`` are lane-dense rows, and a dq kernel built like the
+  forward. Both recompute P from the saved logsumexp residual, keep
+  every matmul on the MXU in f32 accumulation, and walk and skip
+  sub-blocks as the forward does.
 - **Chunked XLA** (the CPU test mesh's interpret mode, and the parity
   reference): recomputes attention probabilities one K/V block at a
   time from the same residual, so it also never materializes S×S.
@@ -36,8 +56,6 @@ The reference delegates attention entirely to user frameworks
 from __future__ import annotations
 
 import functools
-import json
-import os
 import warnings
 from typing import Optional
 
@@ -82,115 +100,210 @@ def pick_block(seq: int, preferred: int) -> int:
     return block
 
 
-_pick_block = pick_block  # internal alias
+# What a grid cell may hold by `_tile_bytes`' estimate: Mosaic's scoped
+# limit is 16 MiB a kernel on v5e unless a call raises it, and none here
+# does; the rest is headroom for what the estimate leaves out
+# (tests/test_aot_tpu_compile.py compiles the cells' shapes: the fit is
+# the compiler's word, this is the screen before it).
+VMEM_BUDGET = 14 * 2**20
 
-# Per-core VMEM is ~128 MiB on v5e/v4; the budget leaves headroom for
-# Mosaic's double-buffered input pipelining and the bwd kernels' extra
-# accumulators (dk/dv scratch ≈ the fwd footprint again).
-VMEM_BUDGET = 48 * 2**20
-
-
-def _tile_bytes(bq: int, bk: int, d: int) -> int:
-    """Estimated fwd-kernel VMEM residency for one grid cell: bf16 Q
-    tile + double-buffered bf16 K/V streams + f32 scores + f32 output
-    accumulator + lane-broadcast m/l scratch."""
-    return (bq * d * 2          # q tile (bf16)
-            + 2 * 2 * bk * d * 2  # k + v, double-buffered (bf16)
-            + bq * bk * 4       # scores (f32)
-            + bq * d * 4        # o accumulator (f32)
-            + 2 * bq * LANES * 4)  # m / l scratch (f32)
-
-
-# Committed per-device-kind tile picks from the AOT topology probe
-# (perf/aot.py flash_pick): each entry is a tile set Mosaic actually
-# compiled for that chip, i.e. VMEM-fit EVIDENCE rather than the
-# heuristic's estimate. Keyed by `jax.Device.device_kind`.
-FLASH_TILES_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "perf", "flash_tiles.json")
+# A grid step of each kernel: the rows it keeps (RESIDENT: query rows in
+# the forward and dq, keys in dk/dv), the positions of the other operand
+# it copies (COPIED) and the sub-block of those a turn of its inner loop
+# takes (SUB). Counted on a TPU v5e at Mistral-7B's training shape
+# (3 x 4,096, 32 / 8 heads of 128; scripts/bench_flash.py, PERF.md §5-6,
+# PRs 50-51): a step's fixed cost is paid once a copied block, a row's
+# keys are copied once a KV head where the block is the whole row, and a
+# 512 x 512 score tile keeps the MXU fed where 256 or 1,024 do not.
+RESIDENT, COPIED, SUB = 512, 4096, 512
+KERNELS = ("fwd", "dkdv", "dq")
 
 
-@functools.lru_cache(maxsize=1)
-def _committed_tile_picks() -> dict:
-    # Committed data: a missing or corrupt table is a broken checkout
-    # and raises, rather than quietly tuning from the heuristic alone.
-    with open(FLASH_TILES_PATH) as fh:
-        table = json.load(fh)
-    return {k: v for k, v in table.items() if not k.startswith("_")}
+def _tile_bytes(resident: int, copied: int, d: int) -> int:
+    """Estimated VMEM residency of one grid cell of the forward (the
+    backward kernels hold about as much: two operands kept and copied,
+    two accumulators): the resident rows in and out, the
+    double-buffered copied blocks of two streamed operands, a turn's
+    float32 score tiles (scores, probabilities and a mask) and the
+    float32 accumulator with its lane-broadcast m / l."""
+    return (2 * 2 * resident * d * 2      # q in, o out (bf16, 2 buffers)
+            + 2 * 2 * copied * d * 2      # k + v, double-buffered (bf16)
+            + 3 * resident * min(SUB, copied) * 4  # score tiles (f32)
+            + resident * d * 4            # o accumulator (f32)
+            + 2 * resident * LANES * 4)   # m / l scratch (f32)
 
 
-def auto_blocks(seq_q: int, seq_k: int, head_dim: int,
-                *, vmem_budget: int = VMEM_BUDGET,
-                device_kind: Optional[str] = None) -> tuple[int, int]:
-    """Trace-time (block_q, block_k) choice keyed on (seq, head_dim,
-    VMEM budget) — VERDICT r4 item 3's staged MFU lever. Larger tiles
-    amortize the online-softmax rescale and grid overhead (fewer
-    passes over the K/V stream per Q tile) but must leave VMEM room
-    for pipelining; the historical fixed 512x512 default is kept as
-    the FLOOR of preference order so auto never picks worse than the
-    measured r3/r4 configuration, and 1024-tiles are tried first where
-    the budget allows (small head_dim). Shapes that don't tile fall
-    back through ``pick_block`` exactly as explicit sizes do.
+def auto_blocks(seq_q: int, seq_k: int, head_dim: int, *,
+                window: Optional[int] = None,
+                block_q: Optional[int] = None,
+                block_k: Optional[int] = None,
+                vmem_budget: int = VMEM_BUDGET) -> dict:
+    """The one place the tiles are decided: per kernel (``fwd``,
+    ``dkdv``, ``dq``) the ``(block_q, block_k, sub)`` of a grid step and
+    of a turn of its inner loop, from what the call can see. A copied
+    block is no longer than the window (a longer one copies keys its
+    rows cannot see), and copied then resident are halved while the
+    cell does not fit ``vmem_budget`` (wide heads); ``pick_block`` then
+    makes them divide the sequence, as it does explicit sizes. An
+    explicit ``block_q`` / ``block_k`` (the model's ``flash_block_q/k``)
+    is the forward's tile as given and an upper limit on the
+    backward's."""
+    resident, copied = RESIDENT, COPIED
+    while window and copied > max(window, LANES):
+        copied //= 2
+    while (_tile_bytes(resident, copied, head_dim) > vmem_budget
+           and max(resident, copied) > LANES):
+        if copied >= resident:
+            copied //= 2
+        else:
+            resident //= 2
+    out = {}
+    for kernel in KERNELS:
+        # dk/dv keeps the keys and walks the query rows.
+        bq, bk = ((copied, resident) if kernel == "dkdv"
+                  else (resident, copied))
+        if block_q is not None:
+            bq = block_q if kernel == "fwd" else min(bq, block_q)
+        if block_k is not None:
+            bk = block_k if kernel == "fwd" else min(bk, block_k)
+        bq, bk = pick_block(seq_q, bq), pick_block(seq_k, bk)
+        out[kernel] = (bq, bk, pick_block(bq if kernel == "dkdv" else bk,
+                                          SUB))
+    return out
 
-    ``device_kind`` (ISSUE 12): a chip with a committed pick in
-    ``perf/flash_tiles.json`` uses that compile-validated tile set
-    first — still subject to the same seq-tiling and VMEM-budget
-    screens, so a probed pick can never select tiles the budget math
-    or the shape would reject."""
-    pick = _committed_tile_picks().get(device_kind or "")
-    if pick:
-        bq, bk = int(pick["block_q"]), int(pick["block_k"])
-        if _tile_bytes(bq, bk, head_dim) <= vmem_budget:
-            got_q = _pick_block(seq_q, bq)
-            got_k = _pick_block(seq_k, bk)
-            if got_q == min(bq, seq_q) and got_k == min(bk, seq_k):
-                return got_q, got_k
-    for bq in (1024, 512, 256, 128):
-        for bk in (1024, 512, 256, 128):
-            if bk > bq * 2:
-                continue  # tall score tiles win nothing; skip extremes
-            if _tile_bytes(bq, bk, head_dim) <= vmem_budget:
-                got_q = _pick_block(seq_q, bq)
-                got_k = _pick_block(seq_k, bk)
-                if got_q == min(bq, seq_q) and got_k == min(bk, seq_k):
-                    return got_q, got_k
-    return _pick_block(seq_q, 512), _pick_block(seq_k, 512)
+
+def _clip(x, lo, hi):
+    if all(isinstance(n, int) for n in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
 
 
-def _block_visible(qi, ki, block_q: int, block_k: int, causal: bool,
-                   window: int):
-    """Whether block (qi, ki) contributes at all — the grid-skip
-    predicate shared by the fwd and both bwd kernels. Causal blocks
-    strictly above the diagonal contribute nothing; with a sliding
-    window, blocks entirely below the band neither."""
+def _count(y, sub: int, n: int):
+    """How many of the sub-blocks ``0 .. n-1`` start at or under ``y``
+    (``j * sub <= y``): plain arithmetic for static indices, two
+    scalar operations a kernel's traced ones."""
+    if isinstance(y, int):
+        return max(0, min((y + sub) // sub, n))
+    return jnp.minimum(jax.lax.div(jnp.maximum(y + sub, 0), sub), n)
+
+
+def _turn_ranges(fixed0, fixed_n: int, base, sub: int, n_sub: int, *,
+                 over_cols: bool, causal: bool, window: int):
+    """``(lo, a, b, hi)`` over the ``n_sub`` sub-blocks of ``sub``
+    positions that start at ``base`` on the looped axis, against the
+    ``fixed_n`` positions from ``fixed0`` on the other: the sub-blocks
+    ``[lo, hi)`` hold a position that contributes, and of those
+    ``[a, b)`` hold none that is masked. ``over_cols``: the loop runs
+    over keys and the fixed span is query rows (forward, dq); else over
+    query rows against fixed keys (dk/dv). The loops' trip counts, the
+    index maps' clamps and the choice of the masked turn are all this
+    one rule: causal positions above the diagonal contribute nothing,
+    with a sliding window those below the band neither (packed segments
+    can mask anywhere: ``_walk`` then takes ``[lo, hi)`` masked)."""
     if not causal:
-        return True
-    visible = qi * block_q + block_q > ki * block_k
-    if window:
-        in_band = ki * block_k + block_k > qi * block_q - (window - 1)
-        visible = jnp.logical_and(visible, in_band)
-    return visible
+        return 0, 0, n_sub, n_sub
+    count = functools.partial(_count, sub=sub, n=n_sub)
+    last = fixed0 + fixed_n - 1 - base  # relative to the looped axis
+    first = fixed0 - base
+    if over_cols:  # keys at or under a row; the band's edge below
+        hi, b = count(last), count(first + 1 - sub)
+        lo = count(first + 1 - window - sub) if window else 0
+        a = count(last - window) if window else 0
+    else:  # rows at or over a key; the band's edge above
+        lo, a = count(first - sub), count(last - 1)
+        hi = count(last + window - 1) if window else n_sub
+        b = count(first + window - sub) if window else n_sub
+    hi = _clip(hi, lo, n_sub)
+    a = _clip(a, lo, hi)
+    return lo, a, _clip(b, a, hi), hi
 
 
-def _block_mask(qi, ki, block_q: int, block_k: int, causal: bool,
-                window: int, qseg_ref, kseg_ref):
-    """The in-block [block_q, block_k] validity mask (or None when the
-    whole block is valid) — single source of truth for the causal
-    triangle, window band, and packed-segment masking used identically
-    by all three kernels."""
+def _resident_block(i, fixed0, fixed_n: int, block: int, n_blocks: int,
+                    **rule):
+    """The copied block a grid step names on the streamed axis: its own
+    where it holds a visible position, else the nearest one that does,
+    which the step before left resident, so that a skipped step copies
+    nothing."""
+    if not rule["causal"]:
+        return i  # every block is visible
+    lo, _, _, hi = _turn_ranges(fixed0, fixed_n, 0, block, n_blocks, **rule)
+    return jnp.clip(i, jnp.minimum(lo, n_blocks - 1),
+                    jnp.maximum(hi - 1, lo))
+
+
+def _walk(ranges, turn, segments: bool) -> None:
+    """``turn(j, masked)`` for the sub-blocks ``[lo, hi)`` of a copied
+    block: one loop over those that lie wholly inside (the plain turn)
+    and one over those that meet the diagonal or the window's edge, on
+    either side of them (the masked turn), each a ``fori_loop`` traced
+    once whose trip count the block's indices decide. Runs that a
+    configuration cannot have are not traced."""
+    lo, a, b, hi = ranges
+
+    def never(n):  # a run this configuration cannot have
+        return isinstance(n, int) and n == 0
+
+    def loop(n, at, masked):
+        def body(t, carry):
+            turn(at(t), masked)
+            return carry
+        if not never(n):
+            jax.lax.fori_loop(0, n, body, None)
+
+    if segments:  # a mask anywhere: every visible sub-block takes one
+        return loop(hi - lo, lambda t: lo + t, True)
+    loop(b - a, lambda t: a + t, False)
+    lead, tail = a - lo, hi - b  # masked sub-blocks under / over them
+    if never(lead):
+        loop(tail, lambda t: b + t, True)
+    elif never(tail):
+        loop(lead, lambda t: lo + t, True)
+    else:
+        loop(lead + tail,
+             lambda t: jnp.where(t < lead, lo + t, b + t - lead), True)
+
+
+def _sub_mask(diff0, shape, rows_axis: int, causal: bool, window: int,
+              qseg, kseg):
+    """The validity mask of a score tile on the masked turn: ``diff0``
+    is row minus column at the tile's origin, ``rows_axis`` the tile
+    axis the query rows lie on (1 in dk/dv's transposed tiles),
+    ``qseg`` / ``kseg`` the segment ids shaped to broadcast over the
+    tile. One source for the causal triangle, the window band and
+    packed segments in all three kernels."""
     mask = None
     if causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = rows >= cols
+        diff = (diff0 + jax.lax.broadcasted_iota(jnp.int32, shape, rows_axis)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis))
+        mask = diff >= 0
         if window:
-            mask &= rows - cols < window
-    if qseg_ref is not None:
-        seg = qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
+            mask &= diff < window
+    if qseg is not None:
+        seg = qseg == kseg
         mask = seg if mask is None else mask & seg
     return mask
+
+
+def _lanes(x, width: int):
+    """A lane-broadcast [rows, LANES] value over ``width`` lanes: whole
+    lane tiles repeated where the width is a multiple of them (a block
+    of a sequence shorter than the tiles may not be)."""
+    if width <= LANES:
+        return x[:, :width]
+    if width % LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+    return pltpu.repeat(x, width // LANES, axis=1)
+
+
+def _rows_to_lanes(row, n: int):
+    """A lane-dense [1, n] row as the lane-broadcast [n, LANES] column
+    the score tiles of the forward's orientation take."""
+    return jnp.broadcast_to(row, (LANES, n)).T
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _segment_rows(segments: jax.Array) -> jax.Array:
@@ -201,17 +314,33 @@ def _segment_rows(segments: jax.Array) -> jax.Array:
     return segments.astype(jnp.int32)[:, None, :]
 
 
+def _sub_rows(x: jax.Array, sub: int) -> jax.Array:
+    """[..., S] values of the looped axis as [..., S / sub, 1, sub]: a
+    sub-block is a leading index, since a lane offset cannot be
+    traced."""
+    return x.reshape(*x.shape[:-1], x.shape[-1] // sub, 1, sub)
+
+
+def _compiler_params(interpret: bool, n_parallel: int, n_arbitrary: int):
+    if interpret:
+        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel
+        + ("arbitrary",) * n_arbitrary)
+
+
 def _fwd_kernel(
     q_ref,  # [1, 1, block_q, D]
     k_ref,  # [1, 1, block_k, D]
     v_ref,  # [1, 1, block_k, D]
-    *rest,  # [qseg [1,1,block_q], kseg [1,1,block_k] when use_segments,]
-            # o [1,1,block_q,D], lse [1,1,block_q,1],
+    *rest,  # [qseg [1,1,block_q], kseg [1,block_k/sub,1,sub] when
+            # use_segments,] o [1,1,block_q,D], lse [1,1,1,block_q],
             # acc/m/l VMEM scratch
     causal: bool,
     scale: float,
     block_q: int,
     block_k: int,
+    sub: int,     # keys a turn of the inner loop takes
     window: int,  # 0 = unbounded
     use_segments: bool,
 ):
@@ -220,52 +349,55 @@ def _fwd_kernel(
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
         qseg_ref = kseg_ref = None
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    n_k = pl.num_programs(3)
+    qi, kc = pl.program_id(2), pl.program_id(3)
+    n_kc = pl.num_programs(3)
 
-    @pl.when(ki == 0)
+    @pl.when(kc == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(_block_visible(qi, ki, block_q, block_k, causal, window))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s *= scale  # [block_q, block_k]
-
-        mask = _block_mask(qi, ki, block_q, block_k, causal, window,
-                           qseg_ref, kseg_ref)
-        if mask is not None:
+    def turn(j, masked: bool):
+        col = pl.multiple_of(j * sub, sub)
+        k = k_ref[0, 0, pl.ds(col, sub), :]
+        v = v_ref[0, 0, pl.ds(col, sub), :]
+        s = _dot(q_ref[0, 0], k, (1, 1)) * scale  # [block_q, sub]
+        if masked:
+            mask = _sub_mask(
+                qi * block_q - kc * block_k - col, s.shape, 0, causal,
+                window,
+                qseg_ref[0, 0][:, None] if use_segments else None,
+                kseg_ref[0, j] if use_segments else None)
             s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]  # [block_q, 1]
+        # m and l stay lane-broadcast [block_q, LANES] from one turn to
+        # the next: a row's value is spread over the lanes once, by the
+        # reduction that made it, and whole lane tiles of it are
+        # repeated over a wider tile for nothing.
+        m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if mask is not None:
+        p = jnp.exp(s - _lanes(m_new, sub))
+        if masked:
             p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)  # [block_q, 1]
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = (acc_ref[:] * _lanes(alpha, acc_ref.shape[-1])
+                      + _dot(p.astype(v.dtype), v, (1, 0)))
+        m_ref[:] = m_new
 
-        v = v_ref[0, 0]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    _walk(_turn_ranges(qi * block_q, block_q, kc * block_k, sub,
+                       block_k // sub, over_cols=True, causal=causal,
+                       window=window), turn, use_segments)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(kc == n_kc - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, :1] + jnp.log(l_safe)).astype(lse_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:] / _lanes(l_safe, acc_ref.shape[-1])
+                       ).astype(o_ref.dtype)
+        # Rows along the lanes: a [block_q, 1] block of float32 is one
+        # value a 128-lane tile in HBM, 128 times the bytes.
+        lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe)).T[:1]
 
 
 def _flash_fwd_pallas(
@@ -275,8 +407,7 @@ def _flash_fwd_pallas(
     segments,  # [B, Sq] int32 or None (packed-sequence ids)
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
+    blocks: tuple[int, int, int],  # (block_q, block_k, sub): auto_blocks
     interpret: bool,
     window: int = 0,
 ) -> tuple[jax.Array, jax.Array]:
@@ -284,55 +415,57 @@ def _flash_fwd_pallas(
     kv = k.shape[1]
     sk = k.shape[2]
     n_rep = h // kv
-    grid = (b, h, sq // block_q, sk // block_k)
-
+    block_q, block_k, sub = blocks
+    n_kc = sk // block_k
     use_segments = segments is not None
+    rule = dict(over_cols=True, causal=causal, window=window)
 
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, window=window, use_segments=use_segments,
-    )
-    compiler_params = None
-    if not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        )
-    scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-        pltpu.VMEM((block_q, LANES), jnp.float32),
-    ]
+    def qmap(b_, h_, qi, kc):
+        return (b_, h_, qi, 0)
+
+    def k_block(qi, kc):
+        return _resident_block(kc, qi * block_q, block_q, block_k, n_kc,
+                               **rule)
+
+    def kmap(b_, h_, qi, kc):
+        return (b_, h_ // n_rep, k_block(qi, kc), 0)
+
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(
+            _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, sub=sub, window=window,
+            use_segments=use_segments),
+        grid=(b, h, sq // block_q, n_kc),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda b_, h_, qi, ki, n_rep=n_rep: (b_, h_ // n_rep, ki, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, d),
-                lambda b_, h_, qi, ki, n_rep=n_rep: (b_, h_ // n_rep, ki, 0),
-            ),
+            pl.BlockSpec((1, 1, block_q, d), qmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
         ] + ([
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, ki: (b_, 0, qi)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, h_, qi, ki: (b_, 0, ki)),
+            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, kc: (b_, 0, qi)),
+            pl.BlockSpec((1, block_k // sub, 1, sub),
+                         lambda b_, h_, qi, kc: (b_, k_block(qi, kc), 0, 0)),
         ] if use_segments else []),
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d), qmap),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b_, h_, qi, kc: (b_, h_, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
-        scratch_shapes=scratch,
-        compiler_params=compiler_params,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+        ],
+        compiler_params=_compiler_params(interpret, 3, 1),
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v, *([_segment_rows(segments)] * 2 if use_segments else []))
-    return o, lse[..., 0]
+    )(q, k, v, *([_segment_rows(segments),
+                  _sub_rows(segments.astype(jnp.int32), sub)]
+                 if use_segments else []))
+    return o, lse[:, :, 0]
 
 
 def _flash_bwd_xla(
@@ -442,126 +575,128 @@ def _flash_bwd_xla(
 
 
 def _bwd_dkdv_kernel(
-    q_ref,      # [1, 1, block_q, D]   (q head = kv*n_rep + r)
-    k_ref,      # [1, 1, block_k, D]
-    v_ref,      # [1, 1, block_k, D]
-    do_ref,     # [1, 1, block_q, D]
-    delta_ref,  # [1, 1, block_q, 1]
-    lse_ref,    # [1, 1, block_q, 1]
-    dlse_ref,   # [1, 1, block_q, 1]  cotangent of the lse output
-    *rest,      # [qseg [1,1,block_q], kseg [1,1,block_k] when use_segments,]
-                # dk [1,1,block_k,D], dv [1,1,block_k,D], scratch x2
+    q_ref,    # [1, 1, block_q, D]   (q head = kv*n_rep + r)
+    k_ref,    # [1, 1, block_k, D]
+    v_ref,    # [1, 1, block_k, D]
+    do_ref,   # [1, 1, block_q, D]
+    lse_ref,  # [1, 1, block_q/sub, 1, sub]
+    dd_ref,   # [1, 1, block_q/sub, 1, sub]  delta - dlse
+    *rest,    # [qseg [1,block_q/sub,1,sub], kseg [1,1,block_k] when
+              # use_segments,] dk [1,1,block_k,D], dv [1,1,block_k,D],
+              # scratch x2
     causal: bool,
     scale: float,
     block_q: int,
     block_k: int,
+    sub: int,  # query rows a turn of the inner loop takes
     window: int,
     use_segments: bool,
 ):
+    """Score tiles in the transposed orientation (``s^T = k q^T``,
+    ``dp^T = v dO^T``: keys down the sublanes, query rows along the
+    lanes), so that ``dv += p^T dO`` and ``dk += ds^T q`` contract over
+    a tile's last dimension and no tile is transposed; ``lse`` and
+    ``delta`` are then lane-dense rows."""
     if use_segments:
         qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         qseg_ref = kseg_ref = None
     ki = pl.program_id(2)
-    r, qi = pl.program_id(3), pl.program_id(4)
-    n_rep, n_q = pl.num_programs(3), pl.num_programs(4)
+    r, qc = pl.program_id(3), pl.program_id(4)
+    n_rep, n_qc = pl.num_programs(3), pl.num_programs(4)
 
-    @pl.when(jnp.logical_and(r == 0, qi == 0))
+    @pl.when(jnp.logical_and(r == 0, qc == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_visible(qi, ki, block_q, block_k, causal, window))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k]
-
-        mask = _block_mask(qi, ki, block_q, block_k, causal, window,
-                           qseg_ref, kseg_ref)
-        p = jnp.exp(s - lse_ref[0, 0])  # lse block: [block_q, 1]
-        if mask is not None:
+    def turn(j, masked: bool):
+        row = pl.multiple_of(j * sub, sub)
+        q = q_ref[0, 0, pl.ds(row, sub), :]
+        do = do_ref[0, 0, pl.ds(row, sub), :]
+        s = _dot(k_ref[0, 0], q, (1, 1)) * scale  # [block_k, sub]
+        p = jnp.exp(s - lse_ref[0, 0, j])  # lse row: [1, sub]
+        if masked:
+            mask = _sub_mask(
+                qc * block_q + row - ki * block_k, s.shape, 1, causal,
+                window,
+                qseg_ref[0, j] if use_segments else None,
+                kseg_ref[0, 0][:, None] if use_segments else None)
             p = jnp.where(mask, p, 0.0)
+        dp = _dot(v_ref[0, 0], do, (1, 1))  # v @ do^T → [block_k, sub]
+        # d lse/d s_j = p_j, so an lse cotangent enters ds additively:
+        # dd is delta less that cotangent.
+        ds = p * (dp - dd_ref[0, 0, j]) * scale
+        dv_acc[:] += _dot(p.astype(do.dtype), do, (1, 0))
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, (1, 0))
 
-        do = do_ref[0, 0]
-        dv_acc[:] += jax.lax.dot_general(  # p^T @ do → [block_k, D]
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(  # do @ v^T → [block_q, block_k]
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # d lse/d s_j = p_j, so an lse cotangent enters ds additively.
-        ds = p * (dp - delta_ref[0, 0] + dlse_ref[0, 0]) * scale
-        dk_acc[:] += jax.lax.dot_general(  # ds^T @ q → [block_k, D]
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _walk(_turn_ranges(ki * block_k, block_k, qc * block_q, sub,
+                       block_q // sub, over_cols=False, causal=causal,
+                       window=window), turn, use_segments)
 
-    @pl.when(jnp.logical_and(r == n_rep - 1, qi == n_q - 1))
+    @pl.when(jnp.logical_and(r == n_rep - 1, qc == n_qc - 1))
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(
-    q_ref,      # [1, 1, block_q, D]
-    k_ref,      # [1, 1, block_k, D]
-    v_ref,      # [1, 1, block_k, D]
-    do_ref,     # [1, 1, block_q, D]
-    delta_ref,  # [1, 1, block_q, 1]
-    lse_ref,    # [1, 1, block_q, 1]
-    dlse_ref,   # [1, 1, block_q, 1]  cotangent of the lse output
-    *rest,      # [qseg, kseg when use_segments,] dq, dq_acc scratch
+    q_ref,    # [1, 1, block_q, D]
+    k_ref,    # [1, 1, block_k, D]
+    v_ref,    # [1, 1, block_k, D]
+    do_ref,   # [1, 1, block_q, D]
+    lse_ref,  # [1, 1, 1, block_q]
+    dd_ref,   # [1, 1, 1, block_q]  delta - dlse
+    *rest,    # [qseg, kseg as the forward's when use_segments,] dq,
+              # dq_acc / lse / dd scratch
     causal: bool,
     scale: float,
     block_q: int,
     block_k: int,
+    sub: int,
     window: int,
     use_segments: bool,
 ):
     if use_segments:
-        qseg_ref, kseg_ref, dq_ref, dq_acc = rest
+        qseg_ref, kseg_ref, dq_ref, dq_acc, lse_rows, dd_rows = rest
     else:
-        dq_ref, dq_acc = rest
+        dq_ref, dq_acc, lse_rows, dd_rows = rest
         qseg_ref = kseg_ref = None
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    n_k = pl.num_programs(3)
+    qi, kc = pl.program_id(2), pl.program_id(3)
+    n_kc = pl.num_programs(3)
 
-    @pl.when(ki == 0)
+    @pl.when(kc == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+        # A row's lse and dd come in along the lanes (dense in HBM) and
+        # are spread over a row's lanes once a row block, not once a
+        # turn.
+        lse_rows[:] = _rows_to_lanes(lse_ref[0, 0], block_q)
+        dd_rows[:] = _rows_to_lanes(dd_ref[0, 0], block_q)
 
-    @pl.when(_block_visible(qi, ki, block_q, block_k, causal, window))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-
-        mask = _block_mask(qi, ki, block_q, block_k, causal, window,
-                           qseg_ref, kseg_ref)
-        p = jnp.exp(s - lse_ref[0, 0])
-        if mask is not None:
+    def turn(j, masked: bool):
+        col = pl.multiple_of(j * sub, sub)
+        k = k_ref[0, 0, pl.ds(col, sub), :]
+        v = v_ref[0, 0, pl.ds(col, sub), :]
+        s = _dot(q_ref[0, 0], k, (1, 1)) * scale  # [block_q, sub]
+        p = jnp.exp(s - _lanes(lse_rows[:], sub))
+        if masked:
+            mask = _sub_mask(
+                qi * block_q - kc * block_k - col, s.shape, 0, causal,
+                window,
+                qseg_ref[0, 0][:, None] if use_segments else None,
+                kseg_ref[0, j] if use_segments else None)
             p = jnp.where(mask, p, 0.0)
+        dp = _dot(do_ref[0, 0], v, (1, 1))  # do @ v^T → [block_q, sub]
+        ds = p * (dp - _lanes(dd_rows[:], sub)) * scale
+        dq_acc[:] += _dot(ds.astype(k.dtype), k, (1, 0))
 
-        do = do_ref[0, 0]
-        dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0] + dlse_ref[0, 0]) * scale
-        dq_acc[:] += jax.lax.dot_general(  # ds @ k → [block_q, D]
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _walk(_turn_ranges(qi * block_q, block_q, kc * block_k, sub,
+                       block_k // sub, over_cols=True, causal=causal,
+                       window=window), turn, use_segments)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(kc == n_kc - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -569,8 +704,8 @@ def _bwd_dq_kernel(
 def _flash_bwd_pallas(
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
+    dkdv_blocks: tuple[int, int, int],  # (block_q, block_k, sub) of each
+    dq_blocks: tuple[int, int, int],
     window: int,
     interpret: bool,
     res,
@@ -586,54 +721,59 @@ def _flash_bwd_pallas(
     kv = k.shape[1]
     sk = k.shape[2]
     n_rep = h // kv
-
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [B,H,Sq,1]
-    lse4 = lse[..., None]  # [B,H,Sq,1]
-    dlse4 = dlse.astype(jnp.float32)[..., None]  # [B,H,Sq,1]
     use_segments = segments is not None
-    seg_args = ([_segment_rows(segments)] * 2) if use_segments else []
+    if use_segments:
+        segments = segments.astype(jnp.int32)
 
-    n_q, n_k = sq // block_q, sk // block_k
-    common = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k, window=window, use_segments=use_segments)
-
-    def cparams(n_parallel: int, n_arbitrary: int):
-        if interpret:
-            return None
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * n_parallel
-            + ("arbitrary",) * n_arbitrary)
+    # d lse/d s_j = p_j: the lse cotangent enters ds beside delta.
+    dd = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+          - dlse.astype(jnp.float32))  # [B,H,Sq]
+    common = dict(causal=causal, scale=scale, window=window,
+                  use_segments=use_segments)
 
     # dk/dv: grid (b, kv, k_block, group_rep, q_block); the two inner
     # dims revisit the same (b, kv, k_block) output block, so the
-    # accumulators live in scratch and are written once at the end.
-    dkdv_grid = (b, kv, n_k, n_rep, n_q)
-    qmap = lambda b_, kvh, ki, r, qi, n=n_rep: (b_, kvh * n + r, qi, 0)
+    # accumulators live in scratch and are written once at the end. The
+    # rows of Q and dO are the copied operand, lse and dd sub-block
+    # rows of it.
+    block_q, block_k, sub = dkdv_blocks
+    n_qc = sq // block_q
+    rule = dict(over_cols=False, causal=causal, window=window)
+
+    def q_block(ki, qc):
+        return _resident_block(qc, ki * block_k, block_k, block_q, n_qc,
+                               **rule)
+
+    def qmap(b_, kvh, ki, r, qc):
+        return (b_, kvh * n_rep + r, q_block(ki, qc), 0)
+
+    def qsub(b_, kvh, ki, r, qc):
+        return (b_, kvh * n_rep + r, q_block(ki, qc), 0, 0)
+
+    def kmap(b_, kvh, ki, r, qc):
+        return (b_, kvh, ki, 0)
+
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, **common),
-        grid=dkdv_grid,
+        functools.partial(_bwd_dkdv_kernel, block_q=block_q, block_k=block_k,
+                          sub=sub, **common),
+        grid=(b, kv, sk // block_k, n_rep, n_qc),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), qmap),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, kvh, ki, r, qi: (b_, kvh, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, kvh, ki, r, qi: (b_, kvh, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
             pl.BlockSpec((1, 1, block_q, d), qmap),
-            pl.BlockSpec((1, 1, block_q, 1), qmap),
-            pl.BlockSpec((1, 1, block_q, 1), qmap),
-            pl.BlockSpec((1, 1, block_q, 1), qmap),
+            pl.BlockSpec((1, 1, block_q // sub, 1, sub), qsub),
+            pl.BlockSpec((1, 1, block_q // sub, 1, sub), qsub),
         ] + ([
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, kvh, ki, r, qi: (b_, 0, qi)),
+            pl.BlockSpec((1, block_q // sub, 1, sub),
+                         lambda b_, kvh, ki, r, qc: (b_, q_block(ki, qc),
+                                                     0, 0)),
             pl.BlockSpec((1, 1, block_k),
-                         lambda b_, kvh, ki, r, qi: (b_, 0, ki)),
+                         lambda b_, kvh, ki, r, qc: (b_, 0, ki)),
         ] if use_segments else []),
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, kvh, ki, r, qi: (b_, kvh, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, kvh, ki, r, qi: (b_, kvh, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, kv, sk, d), k.dtype),
@@ -643,93 +783,113 @@ def _flash_bwd_pallas(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=cparams(3, 2),
+        compiler_params=_compiler_params(interpret, 3, 2),
         interpret=interpret,
         name="flash_bwd_dkdv",
-    )(q, k, v, do, delta, lse4, dlse4, *seg_args)
+    )(q, k, v, do, _sub_rows(lse, sub), _sub_rows(dd, sub),
+      *([_sub_rows(segments, sub), _segment_rows(segments)]
+        if use_segments else []))
 
-    # dq: gridded like the forward, accumulating over k blocks.
+    # dq: gridded like the forward, K and V the copied operand.
+    block_q, block_k, sub = dq_blocks
+    n_kc = sk // block_k
+    rule = dict(over_cols=True, causal=causal, window=window)
+
+    def qmap(b_, h_, qi, kc):
+        return (b_, h_, qi, 0)
+
+    def rowmap(b_, h_, qi, kc):
+        return (b_, h_, 0, qi)
+
+    def k_block(qi, kc):
+        return _resident_block(kc, qi * block_q, block_q, block_k, n_kc,
+                               **rule)
+
+    def kmap(b_, h_, qi, kc):
+        return (b_, h_ // n_rep, k_block(qi, kc), 0)
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
-        grid=(b, h, n_q, n_k),
+        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
+                          sub=sub, **common),
+        grid=(b, h, sq // block_q, n_kc),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, n=n_rep: (b_, h_ // n, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, qi, ki, n=n_rep: (b_, h_ // n, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d), qmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_q, d), qmap),
+            pl.BlockSpec((1, 1, 1, block_q), rowmap),
+            pl.BlockSpec((1, 1, 1, block_q), rowmap),
         ] + ([
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, ki: (b_, 0, qi)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, h_, qi, ki: (b_, 0, ki)),
+            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qi, kc: (b_, 0, qi)),
+            pl.BlockSpec((1, block_k // sub, 1, sub),
+                         lambda b_, h_, qi, kc: (b_, k_block(qi, kc), 0, 0)),
         ] if use_segments else []),
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, block_q, d), qmap)],
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=cparams(3, 1),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32),
+                        pltpu.VMEM((block_q, LANES), jnp.float32)],
+        compiler_params=_compiler_params(interpret, 3, 1),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, delta, lse4, dlse4, *seg_args)[0]
+    )(q, k, v, do, lse[:, :, None], dd[:, :, None],
+      *([_segment_rows(segments), _sub_rows(segments, sub)]
+        if use_segments else []))[0]
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, segments, causal, scale, block_q, block_k, interpret,
-           window, bwd_impl):
+def _flash(q, k, v, segments, causal, scale, tiles, interpret, window,
+           bwd_impl):
     """Returns (o, lse). Differentiable in both outputs — an lse
     cotangent (ring attention's online merge uses lse) enters the bwd
     as an additive term in ds. Callers that only need o discard lse;
-    its cotangent is then structurally zero."""
-    return _flash_fwd_pallas(q, k, v, segments, causal, scale, block_q,
-                             block_k, interpret, window)
+    its cotangent is then structurally zero. ``tiles``: the (block_q,
+    block_k, sub) of the forward, dk/dv and dq kernels, in that order
+    (``auto_blocks``)."""
+    return _flash_fwd_pallas(q, k, v, segments, causal, scale, tiles[0],
+                             interpret, window)
 
 
-def _flash_fwd_rule(q, k, v, segments, causal, scale, block_q, block_k,
-                    interpret, window, bwd_impl):
-    o, lse = _flash_fwd_pallas(q, k, v, segments, causal, scale, block_q,
-                               block_k, interpret, window)
+def _flash_fwd_rule(q, k, v, segments, causal, scale, tiles, interpret,
+                    window, bwd_impl):
+    o, lse = _flash_fwd_pallas(q, k, v, segments, causal, scale, tiles[0],
+                               interpret, window)
     return (o, lse), (q, k, v, segments, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, window,
-                    bwd_impl, res, cts):
+def _flash_bwd_rule(causal, scale, tiles, interpret, window, bwd_impl, res,
+                    cts):
     do, dlse = cts
     if bwd_impl == "pallas":
-        # Smaller default tiles than the fwd: the bwd keeps three
-        # [block_q, block_k] f32 intermediates (s, p, ds) plus two
-        # accumulators live in VMEM at once.
-        bq = pick_block(res[0].shape[2], min(block_q, 256))
-        bk = pick_block(res[1].shape[2], min(block_k, 256))
-        return _flash_bwd_pallas(causal, scale, bq, bk, window, interpret,
-                                 res, do, dlse) + (None,)
-    return _flash_bwd_xla(causal, scale, block_k, window, res, do,
+        return _flash_bwd_pallas(causal, scale, tiles[1], tiles[2], window,
+                                 interpret, res, do, dlse) + (None,)
+    return _flash_bwd_xla(causal, scale, tiles[0][2], window, res, do,
                           dlse) + (None,)
 
 
+_STATIC = (4, 5, 6, 7, 8, 9)
+_flash = jax.custom_vjp(_flash, nondiff_argnums=_STATIC)
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+# Jitted, so that a program's call sites of one shape and window (a
+# static layer plan's prefill has one a layer) share one tracing and
+# lowering of the kernels: PERF.md §6, PRs 37 and 51.
+_call = jax.jit(_flash, static_argnums=_STATIC)
+
+
+def _kernel_takes(block_q: int, block_k: int, head_dim: int) -> bool:
+    return (block_q >= 128 and block_k >= 128
+            and (head_dim % 128 == 0 or head_dim == 64))
 
 
 def implementation_for(seq_q: int, seq_k: int, head_dim: int,
-                       block_q: int = 512, block_k: int = 512) -> str:
+                       block_q: Optional[int] = None,
+                       block_k: Optional[int] = None) -> str:
     """Which implementation ``flash_attention`` runs for a shape:
     ``"pallas"`` when the sequence tiles into >=128 blocks and head_dim
     is lane-compatible, else ``"einsum"`` (the reference)."""
-    bq = _pick_block(seq_q, block_q)
-    bk = _pick_block(seq_k, block_k)
-    if bq < 128 or bk < 128 or (head_dim % 128 and head_dim != 64):
-        return "einsum"
-    return "pallas"
+    bq, bk, _ = auto_blocks(seq_q, seq_k, head_dim, block_q=block_q,
+                            block_k=block_k)["fwd"]
+    return "pallas" if _kernel_takes(bq, bk, head_dim) else "einsum"
 
 
 def flash_attention(
@@ -739,8 +899,8 @@ def flash_attention(
     *,
     causal: bool = True,
     softmax_scale: Optional[float] = None,
-    block_q: int | str = 512,  # tile size, or "auto" (auto_blocks)
-    block_k: int | str = 512,
+    block_q: Optional[int | str] = None,  # None / "auto": auto_blocks
+    block_k: Optional[int | str] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     segment_ids: Optional[jax.Array] = None,  # [B, S] packed-sequence ids
@@ -754,6 +914,10 @@ def flash_attention(
 
     ``segment_ids``: packed sequences — attention is additionally
     restricted to equal segment ids (requires Sq == Sk).
+
+    ``block_q`` / ``block_k``: the query rows a grid step of the
+    forward keeps and the keys it copies; left out (or ``"auto"``) they
+    follow the shapes (``auto_blocks``).
 
     Gives way to the einsum reference (``ops.attention.xla_attention``),
     with a warning per shape, when shapes don't tile (seq not divisible
@@ -773,8 +937,8 @@ def flash_attention_with_lse(
     *,
     causal: bool = True,
     softmax_scale: Optional[float] = None,
-    block_q: int | str = 512,  # tile size, or "auto" (auto_blocks)
-    block_k: int | str = 512,
+    block_q: Optional[int | str] = None,  # None / "auto": auto_blocks
+    block_k: Optional[int | str] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     segment_ids: Optional[jax.Array] = None,
@@ -800,18 +964,12 @@ def flash_attention_with_lse(
         # Validate before the shape-based give-way so a typo can't ride
         # silently on non-tiling shapes.
         raise ValueError(f"unknown bwd_impl `{bwd_impl}`")
-    if block_q == "auto" or block_k == "auto":
-        # Trace-time auto-pick keyed on (seq, head_dim, VMEM budget).
-        # On a TPU backend the committed per-chip pick table is
-        # consulted first (compile-validated tiles beat the estimate).
-        kind = (jax.devices()[0].device_kind
-                if jax.default_backend() == "tpu" else None)
-        abq, abk = auto_blocks(sq, sk, d, device_kind=kind)
-        block_q = abq if block_q == "auto" else block_q
-        block_k = abk if block_k == "auto" else block_k
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
-    if implementation_for(sq, sk, d, block_q, block_k) == "einsum":
+    blocks = auto_blocks(
+        sq, sk, d, window=window,
+        block_q=None if block_q == "auto" else block_q,
+        block_k=None if block_k == "auto" else block_k)
+    bq, bk, _ = blocks["fwd"]
+    if not _kernel_takes(bq, bk, d):
         from polyaxon_tpu.ops.attention import xla_attention_with_lse
 
         # One warning per shape (the default warnings filter dedups on
@@ -830,12 +988,12 @@ def flash_attention_with_lse(
         # Pallas bwd wherever the kernel compiles; the chunked-XLA bwd
         # is faster than an interpreted Pallas kernel on the CPU mesh.
         bwd_impl = "xla" if interpret else "pallas"
-    scale = softmax_scale if softmax_scale is not None else d**-0.5
+    scale = float(softmax_scale) if softmax_scale is not None else d**-0.5
+    tiles = tuple(blocks[kernel] for kernel in KERNELS)
 
     def kernel(qT, kT, vT, *segments):
-        return _flash(qT, kT, vT, segments[0] if segments else None,
-                      causal, scale, bq, bk, interpret, window or 0,
-                      bwd_impl)
+        return _call(qT, kT, vT, segments[0] if segments else None,
+                     causal, scale, tiles, interpret, window or 0, bwd_impl)
 
     # Kernel layout: heads-major [B, H, S, D] so (seq, head_dim) is the
     # trailing (sublane, lane) tile. Under a multi-device mesh the call

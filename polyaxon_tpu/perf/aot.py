@@ -58,8 +58,9 @@ def flash_pick(tiles: dict) -> Optional[dict]:
     """The per-topology tile pick from a probe's candidate records: the
     largest (block_q, block_k) Mosaic actually compiled — compilation
     IS the VMEM-fit evidence (a tile set that doesn't fit fails with
-    RESOURCE_EXHAUSTED at compile, not at run time). Committed to
-    ``perf/flash_tiles.json`` and consulted by ``ops/flash.py``."""
+    RESOURCE_EXHAUSTED at compile, not at run time). Kept in the
+    probe's report; the tiles a default call takes are one rule in
+    ``ops/flash.py auto_blocks``."""
     best = None
     for tag, rec in tiles.items():
         if not rec.get("compiled"):

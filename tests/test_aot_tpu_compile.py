@@ -42,8 +42,8 @@ CASES = {
     "flash-llama3_1b-s8192-window-segments": (
         "flash", dict(h=32, kv=8, d=64, s=8192, window=4096, segments=True),
         FLASH_KERNELS),
-    "flash-llama3_1b-committed-1024-tiles": (
-        "flash", dict(h=32, kv=8, d=64, s=2048, block="committed"),
+    "flash-llama3_1b-explicit-1024-tiles": (
+        "flash", dict(h=32, kv=8, d=64, s=2048, block=1024),
         FLASH_KERNELS),
     "flash-llama3_8b-d128-segments": (
         "flash", dict(h=32, kv=8, d=128, s=2048, segments=True),
@@ -52,6 +52,34 @@ CASES = {
         "flash", dict(h=32, kv=8, d=128, s=8192, window=4096), FLASH_KERNELS),
     "flash-gemma_2b-d256-mqa": (
         "flash", dict(h=8, kv=1, d=256, s=2048), FLASH_KERNELS),
+    # The shapes the benchmark's cells send through the kernels with the
+    # tiles a default call takes (ops/flash.py auto_blocks): the train
+    # cell's three kernels (3 rows of 4,096, 32 / 8 heads of 128), and
+    # the forward alone, which is all a prefill traces, at SmallThinker's
+    # longest prompt (28 / 4 heads, full and under the window), Qwen3-
+    # Next's and Kimi's head size 256 (a 16,384-token document there)
+    # and LFM2's 64, and at a prompt that is one block of 320. The fit
+    # in VMEM is the compiler's word, not `_tile_bytes`' estimate.
+    "flash-mistral7b-train-cell-3x4096": (
+        "flash", dict(h=32, kv=8, d=128, s=4096, b=3), FLASH_KERNELS),
+    "flash-fwd-smallthinker-cell-12288-full": (
+        "flash", dict(h=28, kv=4, d=128, s=12288, b=1, grad=False),
+        {"flash_fwd": 1}),
+    "flash-fwd-smallthinker-cell-12288-window": (
+        "flash", dict(h=28, kv=4, d=128, s=12288, b=1, window=4096,
+                      grad=False), {"flash_fwd": 1}),
+    "flash-fwd-qwen3_next-cell-d256": (
+        "flash", dict(h=16, kv=2, d=256, s=1024, b=1, grad=False),
+        {"flash_fwd": 1}),
+    "flash-fwd-kimi_k2-cell-16384-d256": (
+        "flash", dict(h=64, kv=64, d=256, s=16384, b=1, grad=False),
+        {"flash_fwd": 1}),
+    "flash-fwd-lfm2-cell-d64": (
+        "flash", dict(h=32, kv=8, d=64, s=1024, b=1, grad=False),
+        {"flash_fwd": 1}),
+    "flash-fwd-one-block-of-320": (
+        "flash", dict(h=32, kv=8, d=128, s=320, b=1, grad=False),
+        {"flash_fwd": 1}),
     "flash-in-fsdp4-mesh": (
         "flash", dict(h=32, kv=8, d=64, s=2048, b=8, mesh={"fsdp": 4}),
         FLASH_KERNELS),
@@ -282,10 +310,11 @@ def _described_mesh(topo, axes):
 
 
 def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
-                   block=None, mesh=None):
+                   block=None, mesh=None, grad=True):
     """Forward AND backward (the custom-vjp Pallas kernels) of one flash
-    call, under `mesh` when given, batch and heads sharded as the train
-    step shards them."""
+    call (``grad`` False: the forward alone, what a prefill traces),
+    under `mesh` when given, batch and heads sharded as the train step
+    shards them; ``block``: explicit tiles in place of the rule's."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -306,10 +335,7 @@ def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
             aval((b, s, kv, d), jnp.bfloat16, bshd)]
     if segments:
         args.append(aval((b, s), jnp.int32, P(batch or None, None)))
-    tiles = {}
-    if block == "committed":
-        pick = flash._committed_tile_picks()[topo.devices[0].device_kind]
-        tiles = dict(block_q=pick["block_q"], block_k=pick["block_k"])
+    tiles = dict(block_q=block, block_k=block) if block else {}
 
     def loss(q, k, v, *seg):
         out = flash.flash_attention(
@@ -318,8 +344,8 @@ def _compile_flash(topo, h, kv, d, s, b=2, window=None, segments=False,
         return jnp.sum(out.astype(jnp.float32))
 
     with mesh:
-        return jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
-            *args).compile()
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2)) if grad
+                       else loss).lower(*args).compile()
 
 
 def _compile_paged(topo, h, kv, d, page=16, slots=8, max_len=8192,

@@ -268,50 +268,66 @@ class TestFlash:
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
     def test_auto_blocks_pick(self):
-        """The VMEM-budget auto-pick (VERDICT r4 item 3 staged lever):
-        tiles divide the seq, stay >= 128 where the seq allows, and a
-        tight budget forces smaller tiles than a loose one."""
-        from polyaxon_tpu.ops.flash import _tile_bytes, auto_blocks
+        """The one tile rule: per kernel the rows a grid step keeps, the
+        positions it copies and the sub-block of a turn; tiles divide
+        the seq, stay >= 128 where the seq allows, and a tight budget
+        forces smaller tiles than a loose one."""
+        from polyaxon_tpu.ops.flash import (COPIED, KERNELS, RESIDENT, SUB,
+                                            _tile_bytes, auto_blocks)
 
-        bq, bk = auto_blocks(2048, 2048, 64)
-        assert 2048 % bq == 0 and 2048 % bk == 0
-        assert bq >= 128 and bk >= 128
-        assert _tile_bytes(bq, bk, 64) <= 48 * 2**20
-        # Tight budget → strictly smaller score tile than the default.
-        tq, tk = auto_blocks(2048, 2048, 64, vmem_budget=2**20)
+        tiles = auto_blocks(2048, 2048, 64)
+        assert tuple(tiles) == KERNELS
+        for kernel, (bq, bk, sub) in tiles.items():
+            assert 2048 % bq == 0 and 2048 % bk == 0
+            assert bq >= 128 and bk >= 128
+            # The looped axis: keys in fwd / dq, query rows in dk/dv.
+            assert (bq if kernel == "dkdv" else bk) % sub == 0
+        bq, bk, _ = tiles["fwd"]
+        assert _tile_bytes(bq, bk, 64) <= 14 * 2**20
+        # Tight budget → strictly smaller tiles than the default.
+        tq, tk, _ = auto_blocks(2048, 2048, 64, vmem_budget=2**20)["fwd"]
         assert tq * tk < bq * bk
         # Non-power-of-two seq still yields a dividing tile.
-        oq, ok_ = auto_blocks(1536, 1536, 128)
-        assert 1536 % oq == 0 and 1536 % ok_ == 0
+        oq, ok_, osub = auto_blocks(1536, 1536, 128)["fwd"]
+        assert 1536 % oq == 0 and 1536 % ok_ == 0 and ok_ % osub == 0
+        # A copied block no longer than the window: a longer one copies
+        # keys its rows cannot see.
+        assert auto_blocks(8192, 8192, 128, window=1024)["fwd"][1] <= 1024
+        # Long rows at the train cell's head size: the constants as they
+        # were counted on the chip, dk/dv keeping keys.
+        assert auto_blocks(16384, 16384, 128) == {
+            "fwd": (RESIDENT, COPIED, SUB), "dkdv": (COPIED, RESIDENT, SUB),
+            "dq": (RESIDENT, COPIED, SUB)}
+        # Explicit sizes are the forward's tile and the backward's limit.
+        assert auto_blocks(2048, 2048, 64, block_q=128, block_k=256) == {
+            "fwd": (128, 256, 256), "dkdv": (128, 256, 128),
+            "dq": (128, 256, 256)}
 
     def test_auto_blocks_committed_pick_table(self):
-        """ISSUE 12: device kinds probed by the AOT topology sweep use
-        the committed compile-validated pick, still screened by the
-        budget and seq-tiling rules; unknown kinds fall back to the
-        heuristic unchanged."""
-        import json
+        """The tile table is folded into the rule (``perf/flash_tiles.json``
+        is gone: it was probed at head size 64 and read only under
+        ``"auto"``): the constants pass the rule's own VMEM screen at
+        every head size a benchmark cell sends, a wide head halves what
+        is copied before what is kept, and ``"auto"`` is the default."""
+        import os
 
-        from polyaxon_tpu.ops.flash import (FLASH_TILES_PATH, _tile_bytes,
-                                            auto_blocks)
+        from polyaxon_tpu.ops import flash
 
-        table = {k: v for k, v in
-                 json.load(open(FLASH_TILES_PATH)).items()
-                 if not k.startswith("_")}
-        assert table, "flash_tiles.json must commit at least one pick"
-        for kind, pick in table.items():
-            bq, bk = pick["block_q"], pick["block_k"]
-            # Picks were validated by a real Mosaic compile at the
-            # probe shapes (head_dim 64); the budget screen must agree.
-            assert _tile_bytes(bq, bk, 64) <= 48 * 2**20
-            got = auto_blocks(4096, 4096, 64, device_kind=kind)
-            assert got == (min(bq, 4096), min(bk, 4096))
-            # A seq the pick doesn't tile falls through to the
-            # heuristic rather than forcing a non-dividing block.
-            oq, ok_ = auto_blocks(1536, 1536, 64, device_kind=kind)
-            assert 1536 % oq == 0 and 1536 % ok_ == 0
-        # Unknown kind == no kind: identical heuristic answer.
-        assert auto_blocks(2048, 2048, 64, device_kind="TPU v9000") \
-            == auto_blocks(2048, 2048, 64)
+        assert not os.path.exists(os.path.join(
+            os.path.dirname(flash.__file__), "..", "perf",
+            "flash_tiles.json"))
+        for d in (64, 128, 256):
+            for kernel, (bq, bk, sub) in flash.auto_blocks(
+                    16384, 16384, d).items():
+                resident, copied = (bk, bq) if kernel == "dkdv" else (bq, bk)
+                assert flash._tile_bytes(resident, copied, d) \
+                    <= flash.VMEM_BUDGET
+                assert resident == flash.RESIDENT and sub == flash.SUB
+        wide = flash.auto_blocks(16384, 16384, 512)["fwd"]
+        assert wide[0] == flash.RESIDENT and wide[1] < flash.COPIED
+        # A seq the constants don't tile gets dividing blocks.
+        for bq, bk, sub in flash.auto_blocks(1536, 1536, 64).values():
+            assert 1536 % bq == 0 and 1536 % bk == 0
 
     def test_auto_blocks_matches_reference(self):
         q, k, v = _qkv()
@@ -388,6 +404,199 @@ class TestFlashPallasBackward:
             np.testing.assert_allclose(np.asarray(a, np.float32),
                                        np.asarray(b, np.float32),
                                        atol=0.1, rtol=0.1)
+
+
+class TestFlashSchedule:
+    """What the inner loops add (a copied block several sub-blocks long,
+    the plain turn beside the masked one, dk/dv's transposed tiles):
+    outputs, all three gradients and the lse cotangent against the
+    einsum reference, the Pallas backward in interpret mode. A grid
+    step keeps 128 rows, copies 512 positions and a turn takes 128, so
+    S = 512 walks every run a configuration can have."""
+
+    @pytest.fixture(autouse=True)
+    def small_tiles(self, monkeypatch):
+        from polyaxon_tpu.ops import flash
+
+        monkeypatch.setattr(flash, "RESIDENT", 128)
+        monkeypatch.setattr(flash, "COPIED", 512)
+        monkeypatch.setattr(flash, "SUB", 128)
+
+    @staticmethod
+    def _loss(fn):
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            # Both outputs carry a cotangent: the lse one enters ds.
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+        return loss
+
+    def _check(self, qkv=None, seg=None, **kw):
+        from polyaxon_tpu.ops.attention import xla_attention_with_lse
+        from polyaxon_tpu.ops.flash import flash_attention_with_lse
+
+        q, k, v = _qkv(**{"s": 512, **(qkv or {})})
+        kw["segment_ids"] = seg
+        flash_fn = lambda *a: flash_attention_with_lse(  # noqa: E731
+            *a, bwd_impl="pallas", **kw)
+        ref_fn = lambda *a: xla_attention_with_lse(*a, **kw)  # noqa: E731
+        # The outputs as a differentiated call computes them.
+        for got, want in zip(jax.vjp(flash_fn, q, k, v)[0], ref_fn(q, k, v)):
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        gf = jax.grad(self._loss(flash_fn), (0, 1, 2))(q, k, v)
+        gr = jax.grad(self._loss(ref_fn), (0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+    def test_the_tiles_are_the_small_ones(self):
+        from polyaxon_tpu.ops import flash
+
+        assert flash.auto_blocks(512, 512, 64) == {
+            "fwd": (128, 512, 128), "dkdv": (512, 128, 128),
+            "dq": (128, 512, 128)}
+
+    @pytest.mark.parametrize("case", [
+        # Only the last visible sub-block of a row block meets the
+        # diagonal; the ones under it take the plain turn.
+        dict(causal=True),
+        dict(causal=False),
+        # A window narrower than a sub-block: no plain turn at all.
+        dict(causal=True, window=64),
+        # A window whose trailing edge cuts a sub-block: masked turns on
+        # both sides of the plain ones.
+        dict(causal=True, window=200),
+        dict(causal=True, window=256),
+    ], ids=["causal", "full", "window64", "window200", "window256"])
+    def test_matches_reference(self, case):
+        self._check(**case)
+
+    @pytest.mark.parametrize("d", [64, 128, 256])
+    def test_head_sizes(self, d):
+        self._check(qkv={"d": d, "b": 1}, causal=True)
+
+    @pytest.mark.parametrize("h,kv", [(8, 2), (7, 1)], ids=["4to1", "7to1"])
+    def test_gqa_groups(self, h, kv):
+        self._check(qkv={"h": h, "kv": kv, "b": 1}, causal=True, window=200)
+
+    @pytest.mark.parametrize("window", [None, 200])
+    def test_packed_segments(self, window):
+        seg = jnp.asarray(
+            [[0] * 100 + [1] * 300 + [2] * 112, [0] * 384 + [1] * 128],
+            jnp.int32)
+        self._check(seg=seg, causal=True, window=window)
+
+    @pytest.mark.parametrize("sq,sk", [(256, 512), (512, 256)])
+    def test_forward_with_unequal_lengths(self, sq, sk):
+        """Sq != Sk (a ring step's block against another's keys): the
+        einsum reference for the full square, and for the causal one
+        the kernel's own alignment, row i over keys 0..i."""
+        from polyaxon_tpu.ops.attention import (repeat_kv,
+                                                xla_attention_with_lse)
+        from polyaxon_tpu.ops.flash import flash_attention_with_lse
+
+        q, _, _ = _qkv(s=sq)
+        _, k, v = _qkv(s=sk, seed=1)
+        def flash(causal):
+            return jax.vjp(functools.partial(
+                flash_attention_with_lse, causal=causal), q, k, v)[0]
+
+        for a, b in zip(flash(False),
+                        xla_attention_with_lse(q, k, v, causal=False)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, repeat_kv(k, 2)) * 64 ** -0.5
+        seen = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        logits = jnp.where(seen[None, None], logits, -1e30)
+        want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1),
+                          repeat_kv(v, 2))
+        o, lse = flash(True)
+        np.testing.assert_allclose(o, want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(lse, jax.nn.logsumexp(logits, -1),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("over_cols", [True, False])
+    @pytest.mark.parametrize("window", [0, 1, 100, 128, 300, 1000])
+    def test_turn_ranges_against_the_dense_mask(self, over_cols, window):
+        """``(lo, a, b, hi)`` by brute force: a sub-block is visited iff
+        it holds a visible position and takes the plain turn iff it
+        holds no masked one, for every block pair of a 1,024 square."""
+        from polyaxon_tpu.ops.flash import _turn_ranges
+
+        n, fixed_n, sub, n_sub = 1024, 256, 128, 3
+        rows, cols = np.arange(n)[:, None], np.arange(n)[None, :]
+        dense = rows >= cols
+        if window:
+            dense &= rows - cols < window
+        for fixed0 in range(0, n, fixed_n):
+            for base in range(0, n - sub * n_sub + 1, sub):
+                lo, a, b, hi = _turn_ranges(
+                    fixed0, fixed_n, base, sub, n_sub, over_cols=over_cols,
+                    causal=True, window=window)
+                assert 0 <= lo <= a <= b <= hi <= n_sub
+                for j in range(n_sub):
+                    looped = slice(base + j * sub, base + (j + 1) * sub)
+                    fixed = slice(fixed0, fixed0 + fixed_n)
+                    tile = (dense[fixed, looped] if over_cols
+                            else dense[looped, fixed])
+                    assert (lo <= j < hi) == tile.any(), (fixed0, base, j)
+                    if tile.all():
+                        assert a <= j < b, (fixed0, base, j)
+                    elif tile.any():
+                        assert not a <= j < b, (fixed0, base, j)
+
+
+class TestFlashText:
+    """What a start of a server pays for the kernels (PERF.md §6, PRs
+    37, 49-51): a ``pallas_call`` is traced and lowered at every call
+    site of every program, so the kernels' text is held: as long at
+    12,288 tokens as at 2,048 (every loop a ``fori_loop`` traced once),
+    at most twice what the seed's kernels were (210 / 219 / 533 lines
+    of jaxpr at 28 / 4 heads of 128), and shared by a program's sites
+    of one shape and window (the entry is jitted)."""
+
+    SEED_LINES = {"plain": 210, "window": 219, "grad": 533}
+
+    @staticmethod
+    def _lines(kind, s):
+        q = jax.ShapeDtypeStruct((1, s, 28, 128), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, s, 4, 128), jnp.bfloat16)
+
+        def fwd(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, interpret=False, bwd_impl="pallas",
+                window=4096 if kind == "window" else None)
+
+        fn = fwd
+        if kind == "grad":
+            fn = jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                          (0, 1, 2))
+        return len(str(jax.make_jaxpr(fn)(q, k, k)).splitlines())
+
+    @pytest.mark.parametrize("kind", ["plain", "window", "grad"])
+    def test_text_does_not_grow_with_the_sequence(self, kind):
+        assert self._lines(kind, 2048) == self._lines(kind, 12288)
+
+    @pytest.mark.parametrize("kind", ["plain", "window", "grad"])
+    def test_text_is_at_most_twice_the_seeds(self, kind):
+        assert self._lines(kind, 12288) <= 2 * self.SEED_LINES[kind]
+
+    @pytest.mark.parametrize("windows", [(None,) * 8,
+                                         (None, 256, 256, 256) * 2],
+                             ids=["one-kind", "full-and-window"])
+    def test_sites_of_one_shape_share_a_lowering(self, windows):
+        """Eight calls inside one jit (a static plan's prefill: a site a
+        layer) lower one body a distinct window, not one a site."""
+        import re
+
+        def program(q, k, v):
+            for window in windows:
+                q = q + flash_attention(q, k, v, causal=True, window=window)
+            return q
+
+        q = jax.ShapeDtypeStruct((1, 512, 4, 128), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+        text = jax.jit(program).lower(q, k, k).as_text()
+        bodies = re.findall(r"func\.func private @_flash\w*\(", text)
+        assert len(bodies) == len(set(windows))
+        assert len(re.findall(r"call @_flash\w*\(", text)) == len(windows)
 
 
 @pytest.fixture()
