@@ -6,8 +6,8 @@ below is the one list of them: the factory table, the server's and the
 engine's lookup (``family_of``) and the train loop's (``config_of``) all
 read it, so a new family is its file and its name in that tuple. A
 decoder whose layers follow a static plan (lfm2, nemotron_h, qwen3_next,
-smallthinker, kimi_k2) is its config, draw, mixers and expert block and one
-table for ``models/plan.py``, which holds the walks and the engine's
+smallthinker, kimi_k2, exaone_moe) is its config, draw, mixers and expert
+block and one table for ``models/plan.py``, which holds the walks and the engine's
 surfaces they share.
 Factories accept config overrides (e.g. ``seq_len``/``remat``) from the
 JAXJob runtime section.
@@ -18,15 +18,15 @@ from __future__ import annotations
 import functools
 from typing import Callable
 
-from polyaxon_tpu.models import (bert, kimi_k2, lfm2, llama, mnist, moe,
-                                 nemotron_h, qwen3_next, resnet,
+from polyaxon_tpu.models import (bert, exaone_moe, kimi_k2, lfm2, llama,
+                                 mnist, moe, nemotron_h, qwen3_next, resnet,
                                  smallthinker, t5, vit)
 from polyaxon_tpu.models.common import ModelDef
 
 # Decoders first: `serving/server.py` lists the servable names in this
 # order.
 FAMILIES = (llama, moe, lfm2, nemotron_h, qwen3_next, smallthinker, kimi_k2,
-            t5, vit, bert, resnet, mnist)
+            exaone_moe, t5, vit, bert, resnet, mnist)
 
 _FACTORIES: dict[str, Callable[..., ModelDef]] = {}
 

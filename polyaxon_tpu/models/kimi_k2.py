@@ -466,42 +466,12 @@ def _mla_step(cfg: KimiK2Config, layer: dict, x: jax.Array,
     return _out(cfg, layer, x, read(q).astype(cfg.dtype)[:, None])
 
 
-def routed_experts(cfg: KimiK2Config, stack: dict, i: int,
-                   tokens: jax.Array, sequence: bool):
-    """The held experts' part of the routed sum in expert layer ``i``
-    for ``tokens`` [T, D] (already normalised): (r [T, D], the held
-    choices' one-hot [T, K, count] or None for a sequence)."""
-    dt = cfg.dtype
-    # The scores decide a top-k, where a rounding flips an expert: the
-    # router's own matmul runs in float32 at full precision.
-    logits = jnp.dot(tokens.astype(jnp.float32),
-                     stack["router"][i].astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    top_idx, top_w, _ = moe.route(cfg, logits, stack["expert_bias"][i])
-    first = cfg.held[0]
-    if sequence:
-        return moe.sorted_dispatch(
-            tokens, top_idx, top_w, stack["w_gate"], stack["w_up"],
-            stack["w_down"], first, dt, layer=i), None
-    return moe.dense_dispatch(
-        tokens, top_idx, top_w, stack["w_gate"][i], stack["w_up"][i],
-        stack["w_down"][i], tokens.shape[0], dt, first=first)
-
-
-def expert_block(cfg: KimiK2Config, stack: dict, i: int, x: jax.Array):
-    """Expert layer ``i``'s residual over ``x`` [B, S, D], its B·S
-    tokens one dispatch group; nothing is dropped. A single position a
-    row (a decode step) goes through the one-hot buffers, a sequence
-    through sorted pairs. Returns (x after the residual, the held
-    choices' one-hot [B·S, K, count] or None)."""
-    dt = cfg.dtype
-    B, S, D = x.shape
-    tokens = llama._norm(cfg, x, stack["moe_norm"][i]).reshape(B * S, D)
-    routed, onehot = routed_experts(cfg, stack, i, tokens, sequence=S > 1)
-    shared = (jax.nn.silu(tokens @ _w(stack["ws_gate"][i], dt))
-              * (tokens @ _w(stack["ws_up"][i], dt))
-              ) @ _w(stack["ws_down"][i], dt)
-    return x + (routed + shared).reshape(B, S, D), onehot
+# The expert block is ``models/moe.py``'s, shared with
+# ``models/exaone_moe.py``: sigmoid scores and a selection bias over
+# every expert, the held ones' part of the routed sum, one shared
+# expert.
+routed_experts = moe.deepseek_routed_experts
+expert_block = moe.deepseek_expert_block
 
 
 def init_rows(cfg: KimiK2Config, rows: int) -> dict:

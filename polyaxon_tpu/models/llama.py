@@ -930,7 +930,7 @@ def paged_write_pages(pool: jax.Array, kv: jax.Array,
 
 
 def paged_write_span(pool: jax.Array, kv: jax.Array, page_ids: jax.Array,
-                     start, real_len=None) -> jax.Array:
+                     start, real_len=None, first=None) -> jax.Array:
     """A prefill's ``kv`` [L, S, KV, Hd] into the whole pool
     [L, P, KV, page, Hd] at absolute positions start..start+S-1 of the
     row whose block-table row is ``page_ids`` [maxp] (-1 = not
@@ -939,6 +939,10 @@ def paged_write_span(pool: jax.Array, kv: jax.Array, page_ids: jax.Array,
     its token, every other slot keeps what the page held, and the pages
     are put back. ``start`` is a plain int or a traced scalar,
     ``real_len`` (traced, or None: all S) how much of ``kv`` is real.
+    ``first`` (None: ``start``) is the first position written where the
+    span was computed from further back than it may write: below it lie
+    pages other rows share (a suffix behind a match under a window,
+    ``models/smallthinker.py``).
 
     The count of pages is static: a span from inside a page touches at
     most ceil(S / page) + 1. What a page holds outside the span stays
@@ -956,9 +960,11 @@ def paged_write_span(pool: jax.Array, kv: jax.Array, page_ids: jax.Array,
          else -(-S // page) + 1)
     end = start + (S if real_len is None else real_len)
     slots = start // page + jnp.arange(n)  # the row's logical pages
+    mine = (slots < maxp) & (slots * page < end)
+    if first is not None:
+        mine &= (slots + 1) * page > first
     ids = jnp.where(
-        (slots < maxp) & (slots * page < end),
-        jnp.maximum(page_ids[jnp.minimum(slots, maxp - 1)], 0), 0)
+        mine, jnp.maximum(page_ids[jnp.minimum(slots, maxp - 1)], 0), 0)
     # Slot j of touched page i is position (start // page + i)·page + j,
     # which is token i·page + j - start % page of `kv`: the span shifted
     # into its first page, cut into pages.
@@ -967,7 +973,8 @@ def paged_write_span(pool: jax.Array, kv: jax.Array, page_ids: jax.Array,
         page - start % page, n * page, axis=1)
     new = shifted.reshape(L, n, page, KV, Hd).swapaxes(2, 3)
     at = slots[:, None] * page + jnp.arange(page)[None, :]  # [n, page]
-    real = ((at >= start) & (at < end))[None, :, None, :, None]
+    real = ((at >= (start if first is None else first))
+            & (at < end))[None, :, None, :, None]
     pages = jnp.where(real, new.astype(pool.dtype), pool[:, ids])
     return pool.at[:, ids].set(pages)
 

@@ -555,6 +555,52 @@ def sorted_dispatch(tokens: jax.Array, top_idx: jax.Array, top_w: jax.Array,
                              0.0), axis=1).astype(dt)
 
 
+def deepseek_routed_experts(cfg, stack: dict, i: int, tokens: jax.Array,
+                            sequence: bool):
+    """The DeepSeek-V3 expert block's routed sum (``models/kimi_k2.py``,
+    ``models/exaone_moe.py``), the part of it the experts held here give
+    (``cfg.held``: first, count), in expert layer ``i`` for ``tokens``
+    [T, D] (already normalised): (r [T, D], the held choices' one-hot
+    [T, K, count] or None for a sequence). ``stack`` holds the expert
+    layers' ``router`` [L, D, E], ``expert_bias`` [L, E] and the held
+    experts' ``w_gate`` / ``w_up`` / ``w_down`` [L, count, ...]."""
+    dt = cfg.dtype
+    # The scores decide a top-k, where a rounding flips an expert: the
+    # router's own matmul runs in float32 at full precision.
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     stack["router"][i].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top_idx, top_w, _ = route(cfg, logits, stack["expert_bias"][i])
+    first = cfg.held[0]
+    if sequence:
+        return sorted_dispatch(
+            tokens, top_idx, top_w, stack["w_gate"], stack["w_up"],
+            stack["w_down"], first, dt, layer=i), None
+    return dense_dispatch(
+        tokens, top_idx, top_w, stack["w_gate"][i], stack["w_up"][i],
+        stack["w_down"][i], tokens.shape[0], dt, first=first)
+
+
+def deepseek_expert_block(cfg, stack: dict, i: int, x: jax.Array):
+    """Expert layer ``i``'s residual over ``x`` [B, S, D] in the
+    DeepSeek-V3 block: the routed sum (`deepseek_routed_experts`) and
+    one shared SwiGLU expert (``ws_gate`` / ``ws_up`` / ``ws_down``)
+    over the block's normed input, its B·S tokens one dispatch group;
+    nothing is dropped. A single position a row (a decode step) goes
+    through the one-hot buffers, a sequence through sorted pairs.
+    Returns (x after the residual, the held choices' one-hot [B·S, K,
+    count] or None)."""
+    dt = cfg.dtype
+    B, S, D = x.shape
+    tokens = llama._norm(cfg, x, stack["moe_norm"][i]).reshape(B * S, D)
+    routed, onehot = deepseek_routed_experts(cfg, stack, i, tokens,
+                                             sequence=S > 1)
+    shared = (jax.nn.silu(tokens @ _w(stack["ws_gate"][i], dt))
+              * (tokens @ _w(stack["ws_up"][i], dt))
+              ) @ _w(stack["ws_down"][i], dt)
+    return x + (routed + shared).reshape(B, S, D), onehot
+
+
 def moe_block(
     cfg: MoEConfig,
     x: jax.Array,  # [B, S, D]

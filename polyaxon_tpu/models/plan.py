@@ -1,6 +1,6 @@
 """A decoder whose layers follow a static plan, stated once: what
-``models/lfm2.py``, ``nemotron_h.py``, ``qwen3_next.py`` and
-``smallthinker.py`` share.
+``models/lfm2.py``, ``nemotron_h.py``, ``qwen3_next.py``,
+``smallthinker.py``, ``kimi_k2.py`` and ``exaone_moe.py`` share.
 
 Such a family stacks its parameters by kind and walks its layers in
 published order, in Python, while a program is traced: nothing of a
@@ -51,6 +51,29 @@ has two), ``paged_gather_prefix``, ``paged_prefill_suffix_kv`` and
 ``paged_insert_suffix`` (the matched pages' latents, the tail behind
 them), and the slot cache's ``init_cache`` / ``prefill`` /
 ``decode_step_ragged`` over ``latent`` [L, B, C, W].
+
+**Window layers beside full ones** (``models/smallthinker.py``,
+``models/exaone_moe.py``). A family some of whose attention layers see
+the last ``sliding_window`` positions only declares ``paged_window`` and
+is given two page spaces (``serving/paged.py WindowedPagePool``): ``k``
+/ ``v`` [L_full, P, KV, page, Hd] and ``window`` {``k``, ``v``}
+[L_window, P_w, KV, page, Hd], a row's two block-table rows ``[2,
+maxp]`` wherever llama's surface takes one. Its two mixers take the
+walks from here; the paged surface is written once, in
+``models/smallthinker.py``, over a `Family` table it is handed:
+``paged_init_cache(cfg, n_pages, page_size, window_pages)``,
+``decode_step_paged`` (``tables`` a pair), ``paged_prefill_kv`` /
+``paged_insert_prefill`` (by kind; the window layers' the pages the row
+holds), and behind a shared prefix ``paged_gather_prefix`` (the full
+layers' matched pages: the radix tree shares that space alone),
+``paged_prefill_suffix_kv(cfg, params, suffix, k_prefix, v_prefix,
+start)`` and ``paged_insert_suffix(cache, *kv, page_ids, start, m,
+real_len)``: the run starts at ``start``, below the match of ``m``
+tokens, so that the window layers, which start empty there, come out
+exact by ``m``; the full layers read the cached pages below ``m``
+(``carried["near"]`` hands a full layer those of the stretch the run
+computes again) and write from ``m`` on. ``start`` and ``m`` are plain
+numbers: such a pool matches whole pages.
 """
 
 from __future__ import annotations

@@ -47,10 +47,31 @@ tiles and writes its K and V by whole pages (``llama.paged_write_pages``),
 the window layers' only the pages the row holds. ``moe_expert_tokens``
 ``[L, E]`` counts the decode steps' (row, choice) pairs by expert.
 
-The pool matches no prefix for this family, and the engine's chunked
-prefill and speculation refuse a ``sliding_window`` as they do llama's.
-The walks over the plan and the surfaces no page space touches are
-``models/plan.py``'s, bound below to this family's table (`FAMILY`).
+**A shared prefix.** The radix tree shares the full space's pages
+between rows (``serving/paged.py WindowedPagePool``); the window space
+is nobody's but the row's. A prompt that matches ``m`` tokens (whole
+pages) takes the suffix surface below: its program starts at ``start
+= m - sliding_window x (window layers)``, a page boundary at or below
+what is exact (``serving/paged.py window_suffix_start``), and computes
+``[start, P)``. A window layer attends within that run alone and so
+starts empty at ``start``; a full layer attends the cached pages for
+every key below ``m`` and its own from ``m`` on, and writes nothing
+below ``m``. A window layer's output is exact wherever its input is
+over the ``sliding_window - 1`` positions before it and at it, a full
+layer's wherever its input is, so the i-th window layer's input is
+exact from ``start + (sliding_window - 1) · i`` and every window
+layer's K and V over ``[m - sliding_window, m)``, and everything from
+``m`` on, is what a whole-prompt pass gives. Nothing is kept for it
+anywhere. (At the published sizes of this family ``6 x 4,096`` lies
+past the context: no match could skip a position, so the pool matches
+nothing there, as it did before it shared. ``models/exaone_moe.py``,
+window 128, is the family it pays for.)
+
+The engine's chunked prefill and speculation refuse a
+``sliding_window`` as they do llama's. The walks over the plan and the
+surfaces no page space touches are ``models/plan.py``'s, bound below to
+this family's table (`FAMILY`); the paged surface takes the table as its
+first argument, and ``models/exaone_moe.py`` binds it to its own.
 """
 
 from __future__ import annotations
@@ -69,13 +90,16 @@ from polyaxon_tpu.models.common import (
     scaled_init,
     truncated_normal_init,
 )
-from polyaxon_tpu.ops.attention import dot_product_attention
+from polyaxon_tpu.ops.attention import (dot_product_attention,
+                                        xla_attention_with_lse)
 
 SEQ2SEQ = False
 # A prefill's sequence is padded to a multiple of this: the flash
 # kernel tiles a sequence into blocks of at least 128 and gives way to
 # the einsum reference (a [S, S] score matrix a head) where it cannot.
 PREFILL_TILE = 256
+# The least block the flash kernel tiles a sequence into.
+FLASH_TILE = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,25 +154,27 @@ CONFIGS: dict[str, SmallThinkerConfig] = {
 }
 
 
-def _kinds(cfg: SmallThinkerConfig) -> tuple:
+def _kinds(cfg) -> tuple:
     return tuple("window" if windowed else "full"
                  for windowed in cfg.window_layout)
 
 
-def layer_plan(cfg: SmallThinkerConfig) -> tuple:
+def layer_plan(cfg) -> tuple:
     """Per layer, in published order: (its attention's kind, ``full`` or
     ``window``; its index among that kind's layers, which is its layer
     of that kind's page pool; whether the rotary embedding turns its q
-    and k). The parameters are stacked over every layer."""
+    and k). The parameters are stacked over every layer. Read off
+    ``cfg.window_layout`` and ``cfg.rope_layout``, whichever family's
+    config carries them."""
     return tuple((kind, i, bool(rotary)) for (kind, i), rotary
                  in zip(plan.indexed(_kinds(cfg)), cfg.rope_layout))
 
 
-def kind_counts(cfg: SmallThinkerConfig) -> dict:
+def kind_counts(cfg) -> dict:
     return plan.kind_counts(_kinds(cfg), ("full", "window"))
 
 
-def _layer_window(cfg: SmallThinkerConfig, kind: str) -> Optional[int]:
+def _layer_window(cfg, kind: str) -> Optional[int]:
     return cfg.sliding_window if kind == "window" else None
 
 
@@ -255,18 +281,86 @@ def expert_block(cfg: SmallThinkerConfig, stack: dict, i: int, x: jax.Array,
     return x + out.reshape(B, S, D), onehot
 
 
-def _attention(kind: str, cfg: SmallThinkerConfig, layer: dict,
-               x: jax.Array, l: int, behind: plan.Behind):
+def _with_lse(cfg, q: jax.Array, k: jax.Array, v: jax.Array, causal: bool):
+    """(attention [B, S, H, Hd], its rows' logsumexp [B, H, S]): flash
+    attention where `dot_product_attention` would run it."""
+    impl = cfg.attention_impl
+    if impl == "auto":
+        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+    if impl == "flash":
+        from polyaxon_tpu.ops.flash import flash_attention_with_lse
+
+        return flash_attention_with_lse(q, k, v, causal=causal)
+    return xla_attention_with_lse(q, k, v, causal=causal)
+
+
+def _attend_behind(cfg, q: jax.Array, k: jax.Array, v: jax.Array,
+                   prefix: tuple, near: tuple) -> jax.Array:
+    """A full layer's attention of a run at positions start..start+S−1
+    behind a match of ``m`` tokens: ``prefix`` is the cached K and V
+    [B, start, KV, Hd] of the positions below the run, ``near`` those
+    [B, m − start, KV, Hd] of the positions the run computes again,
+    which stand where the run's own K and V would: keys below ``m`` are
+    the cache's, from ``m`` on the run's. Two attentions merged by their
+    logsumexp, both of shapes the flash kernel takes: the run over
+    itself under the causal mask, and over the prefix under none (every
+    key there lies below every query). The prefix is cut at a multiple
+    of `FLASH_TILE`; what is left of it leads the run's keys, behind as
+    many query rows of zeros, which are cut off again."""
+    S, R = q.shape[1], near[0].shape[1]
+    lead = prefix[0].shape[1] % FLASH_TILE
+    cut = prefix[0].shape[1] - lead
+    pad = -(lead + S) % PREFILL_TILE
+
+    def whole(ahead, own):  # [lead of the prefix; near; the run's own]
+        return jnp.pad(jnp.concatenate([ahead[:, cut:], own], axis=1),
+                       ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+    run, lse = _with_lse(
+        cfg, jnp.pad(q, ((0, 0), (lead, pad), (0, 0), (0, 0))),
+        whole(prefix[0], jnp.concatenate([near[0], k[:, R:]], axis=1)),
+        whole(prefix[1], jnp.concatenate([near[1], v[:, R:]], axis=1)), True)
+    run, lse = run[:, lead:lead + S], lse[:, :, lead:lead + S]
+    if cut == 0:
+        return run
+    far, lse_far = _with_lse(cfg, q, prefix[0][:, :cut], prefix[1][:, :cut],
+                             False)
+    total = jnp.logaddexp(lse, lse_far)
+
+    def share(part, part_lse):  # [B, S, H, Hd] by [B, H, S]
+        weight = jnp.exp(part_lse - total).swapaxes(1, 2)[..., None]
+        return part.astype(jnp.float32) * weight
+
+    return (share(run, lse) + share(far, lse_far)).astype(q.dtype)
+
+
+def _attention(kind: str, cfg, layer: dict, x: jax.Array, l: int,
+               behind: plan.Behind):
     """Layer ``l``'s attention over a whole sequence at
-    ``behind.positions`` (flash attention, under the window in a window
-    layer; no prefix: the pool matches none for this family)."""
+    ``behind.positions``: flash attention, under the window in a window
+    layer, which attends within the sequence alone. A full layer behind
+    a match (``behind.carried["near"]``, `paged_prefill_suffix_kv`)
+    attends the cached pages too (`_attend_behind`)."""
     h = llama._norm(cfg, x, layer["attn_norm"])
     q, k, v, gate = llama._qkv(cfg, layer, h, behind.positions,
                                bool(cfg.rope_layout[l]))
-    attn = dot_product_attention(
-        q, k, v, causal=True, impl=cfg.attention_impl,
-        window=_layer_window(cfg, kind))
+    near = behind.carried.get("near")
+    if kind == "window" or near is None:
+        attn = dot_product_attention(
+            q, k, v, causal=True, impl=cfg.attention_impl,
+            window=_layer_window(cfg, kind))
+    else:
+        i = layer_plan(cfg)[l][1]
+        attn = _attend_behind(cfg, q, k, v, (behind.k[i], behind.v[i]),
+                              (near["k"][i], near["v"][i]))
     return llama._attn_out(cfg, layer, x, attn, gate), {"k": k, "v": v}
+
+
+def attention_mixers() -> dict:
+    """The two kinds of attention layer as a `plan.Family`'s mixers."""
+    return {kind: plan.Mixer("attn", functools.partial(_attention, kind),
+                             None, kind + "_attention")
+            for kind in ("full", "window")}
 
 
 def _layers(cfg: SmallThinkerConfig) -> tuple:
@@ -279,9 +373,7 @@ def _layers(cfg: SmallThinkerConfig) -> tuple:
 FAMILY = plan.Family(
     name=__name__, configs=CONFIGS, init=init,
     logical_axes=logical_axes, layers=_layers,
-    mixers={kind: plan.Mixer("attn", functools.partial(_attention, kind),
-                             None, kind + "_attention")
-            for kind in ("full", "window")},
+    mixers=attention_mixers(),
     # The router reads the layer's normed input, before attention.
     ffns={"moe": plan.Ffn(
         lambda cfg, layer, x: routing(
@@ -305,8 +397,8 @@ apply = functools.partial(plan.apply, FAMILY)
 model_def = functools.partial(plan.model_def, FAMILY)
 
 
-def decode_step_ragged(cfg: SmallThinkerConfig, params: dict, cache: dict,
-                       tokens: jax.Array, pos: jax.Array):
+def window_decode_step_ragged(family: plan.Family, cfg, params: dict,
+                              cache: dict, tokens: jax.Array, pos: jax.Array):
     """One step with per-row positions ([B], −1 = idle) over the slot
     cache: llama's ``cached_attn_step``, a window layer's mask cut to
     its window."""
@@ -324,17 +416,18 @@ def decode_step_ragged(cfg: SmallThinkerConfig, params: dict, cache: dict,
         kv["k"], kv["v"] = put_layer(kv["k"], k, l), put_layer(kv["v"], v, l)
         return x
 
-    logits, _, _ = plan.decode(FAMILY, cfg, params, tokens, pos, attend,
+    logits, _, _ = plan.decode(family, cfg, params, tokens, pos, attend,
                                {}, {})
     return logits, kv
 
 
+decode_step_ragged = functools.partial(window_decode_step_ragged, FAMILY)
 decode_step = functools.partial(plan.decode_step, decode_step_ragged)
 generate = functools.partial(llama.generate_loop, prefill, decode_step)
 
 
 # ------------------------------------------------------------ paged cache
-def paged_window(cfg: SmallThinkerConfig) -> int:
+def paged_window(cfg) -> int:
     """The window of this family's window layers: what tells the engine
     to build the pool with a window space (``serving/paged.py
     WindowedPagePool``) and to hand `paged_init_cache` its size and
@@ -342,10 +435,10 @@ def paged_window(cfg: SmallThinkerConfig) -> int:
     return cfg.sliding_window
 
 
-def paged_init_cache(cfg: SmallThinkerConfig, n_pages: int, page_size: int,
-                     window_pages: int) -> dict:
-    """The two page spaces (module docstring) and the decode steps'
-    routed pairs by expert."""
+def window_page_spaces(cfg, n_pages: int, page_size: int,
+                       window_pages: int) -> dict:
+    """The two page spaces (module docstring), to which a family adds
+    its counters."""
     n = kind_counts(cfg)
 
     def pool(layers, pages):
@@ -354,17 +447,25 @@ def paged_init_cache(cfg: SmallThinkerConfig, n_pages: int, page_size: int,
 
     return {"k": pool(n["full"], n_pages), "v": pool(n["full"], n_pages),
             "window": {"k": pool(n["window"], window_pages),
-                       "v": pool(n["window"], window_pages)},
+                       "v": pool(n["window"], window_pages)}}
+
+
+def paged_init_cache(cfg: SmallThinkerConfig, n_pages: int, page_size: int,
+                     window_pages: int) -> dict:
+    """The two page spaces and the decode steps' routed pairs by
+    expert."""
+    return {**window_page_spaces(cfg, n_pages, page_size, window_pages),
             "moe_expert_tokens": jnp.zeros((cfg.n_layers, cfg.n_experts),
                                            jnp.int32)}
 
 
-def decode_step_paged(cfg: SmallThinkerConfig, params: dict, cache: dict,
-                      tokens: jax.Array, pos: jax.Array, tables: tuple):
-    """`decode_step_ragged` over the two page spaces: ``tables`` is (the
-    full space's block tables, the window space's), [B, maxp] each and
-    indexed by the same logical page; a window layer writes and reads
-    through the second, from its window's first page on."""
+def window_decode_step_paged(family: plan.Family, cfg, params: dict,
+                             cache: dict, tokens: jax.Array, pos: jax.Array,
+                             tables: tuple):
+    """`window_decode_step_ragged` over the two page spaces: ``tables``
+    is (the full space's block tables, the window space's), [B, maxp]
+    each and indexed by the same logical page; a window layer writes and
+    reads through the second, from its window's first page on."""
     page = cache["k"].shape[-2]
     window = cfg.sliding_window
     coords = {
@@ -384,7 +485,7 @@ def decode_step_paged(cfg: SmallThinkerConfig, params: dict, cache: dict,
             rotary=rotary)
         return x
 
-    logits, _, counters = plan.decode(FAMILY, cfg, params, tokens, pos,
+    logits, _, counters = plan.decode(family, cfg, params, tokens, pos,
                                       attend, {}, plan.counters_of(cache))
     return logits, {
         "k": pools["full"][0], "v": pools["full"][1],
@@ -392,8 +493,24 @@ def decode_step_paged(cfg: SmallThinkerConfig, params: dict, cache: dict,
         **counters}
 
 
-def paged_prefill_kv(cfg: SmallThinkerConfig, params: dict,
-                     prompt: jax.Array):
+decode_step_paged = functools.partial(window_decode_step_paged, FAMILY)
+
+
+def _by_kind(cfg, ks: list, vs: list, n: Optional[int] = None) -> tuple:
+    """A sequence pass's K and V of one row, every layer's [1, S, KV,
+    Hd] in plan order, as the two spaces take them: (the full layers' k
+    [L_full, n, KV, Hd], their v, the window layers' k, their v), the
+    first ``n`` positions of each (None: all S)."""
+    def of(kind, leaves):
+        return jnp.stack([leaf[0, :n] for leaf, (k, _, _)
+                          in zip(leaves, layer_plan(cfg)) if k == kind])
+
+    return (of("full", ks), of("full", vs), of("window", ks),
+            of("window", vs))
+
+
+def window_paged_prefill_kv(family: plan.Family, cfg, params: dict,
+                            prompt: jax.Array):
     """The prompt pass for one row [1, P], padded to whole flash tiles
     (causal: what lies behind the prompt changes nothing in it): (the
     full layers' k [L_full, P, KV, Hd], their v, the window layers' k
@@ -401,14 +518,11 @@ def paged_prefill_kv(cfg: SmallThinkerConfig, params: dict,
     number for `paged_insert_prefill`, which is handed no config."""
     P = prompt.shape[1]
     padded = jnp.pad(prompt, ((0, 0), (0, -P % PREFILL_TILE)))
-    _, ks, vs, _ = plan.sequence_layers(FAMILY, cfg, params, padded)
+    _, ks, vs, _ = plan.sequence_layers(family, cfg, params, padded)
+    return (*_by_kind(cfg, ks, vs, P), cfg.sliding_window)
 
-    def of(kind, leaves):
-        return jnp.stack([leaf[0, :P] for leaf, (k, _, _)
-                          in zip(leaves, layer_plan(cfg)) if k == kind])
 
-    return (of("full", ks), of("full", vs), of("window", ks),
-            of("window", vs), cfg.sliding_window)
+paged_prefill_kv = functools.partial(window_paged_prefill_kv, FAMILY)
 
 
 def paged_insert_prefill(cache: dict, k_full: jax.Array, v_full: jax.Array,
@@ -438,3 +552,55 @@ def paged_insert_prefill(cache: dict, k_full: jax.Array, v_full: jax.Array,
             "window": {
                 "k": put(cache["window"]["k"], k_window, page_ids[1], first),
                 "v": put(cache["window"]["v"], v_window, page_ids[1], first)}}
+
+
+# ------------------------------------------------- behind a shared prefix
+# The matched pages' K and V, the full layers': the window space holds
+# nothing of a prefix.
+paged_gather_prefix = llama.paged_gather_prefix
+
+
+def window_paged_prefill_suffix_kv(family: plan.Family, cfg, params: dict,
+                                   suffix: jax.Array, k_prefix: jax.Array,
+                                   v_prefix: jax.Array, start: int):
+    """The run ``suffix`` [1, S] at positions start..start+S−1 of a
+    prompt whose first ``m`` tokens are matched, ``m`` the length of
+    ``k_prefix`` / ``v_prefix`` [L_full, m, KV, Hd] (whole pages:
+    `paged_gather_prefix`), ``start`` a plain number (the pool's
+    ``suffix_start``): K and V as `_by_kind` gives them, [.., S, KV,
+    Hd], for `paged_insert_suffix`. What lies past the real tokens is
+    padding, behind every real position."""
+    def behind(kv, lo, hi):
+        return kv[:, None, lo:hi]
+
+    m = k_prefix.shape[1]
+    near = {"k": behind(k_prefix, start, m), "v": behind(v_prefix, start, m)}
+    _, ks, vs, _ = plan.sequence_layers(
+        family, cfg, params, suffix, behind(k_prefix, 0, start),
+        behind(v_prefix, 0, start), {"near": near}, start)
+    return _by_kind(cfg, ks, vs)
+
+
+paged_prefill_suffix_kv = functools.partial(window_paged_prefill_suffix_kv,
+                                            FAMILY)
+
+
+def paged_insert_suffix(cache: dict, k_full: jax.Array, v_full: jax.Array,
+                        k_window: jax.Array, v_window: jax.Array,
+                        page_ids: jax.Array, start: int, m: int,
+                        real_len) -> dict:
+    """A suffix run's K and V (`paged_prefill_suffix_kv`) into the row's
+    pages ``page_ids`` [2, maxp], ``real_len`` of it real: the full
+    layers' from position ``m`` on (below it the pages are shared, and
+    what the run computed there again is dropped), the window layers'
+    into whatever window pages the row holds (the others name the
+    scratch page)."""
+    def put(pool, kv, ids, first):
+        return llama.paged_write_span(pool, kv, ids, start, real_len, first)
+
+    return {**cache,
+            "k": put(cache["k"], k_full, page_ids[0], m),
+            "v": put(cache["v"], v_full, page_ids[0], m),
+            "window": {
+                "k": put(cache["window"]["k"], k_window, page_ids[1], None),
+                "v": put(cache["window"]["v"], v_window, page_ids[1], None)}}

@@ -609,7 +609,7 @@ class ContinuousBatchingEngine:
             self._pool = WindowedPagePool(
                 n_rows, self.max_len, page_size,
                 (n_rows * maxp if kv_pages is None else kv_pages) + 1,
-                window=window(cfg))
+                window=window(cfg), prefix_cache=prefix_cache)
             self._window_tables = self._pool.window_tables
         elif kv == "paged":
             from polyaxon_tpu.serving.paged import PagePool
@@ -650,12 +650,19 @@ class ContinuousBatchingEngine:
 
             self._page_bytes = page_bytes(
                 self._cache, self._pool.n_pages, self._pool.page_size)
-            self._pool.whole_page_matches = self._page_bytes[1] > 0
+            if self._page_bytes[1]:
+                self._pool.whole_page_matches = True
             if self._page_bytes[2]:
                 self._pool.match_nothing()
             if self._window_tables is not None:
                 from polyaxon_tpu.serving.paged import window_page_bytes
 
+                # The window layers a suffix program walks behind a
+                # match (`WindowedPagePool.suffix_start`), read off the
+                # cache like the rest; where they make a match save
+                # nothing, the pool matches nothing.
+                self._pool.set_window_layers(
+                    self._cache["window"]["k"].shape[0])
                 self._window_page_bytes = window_page_bytes(self._cache)
         # A family may keep, with its cache, the (row, choice) pairs its
         # decode steps routed to each expert. The engine thread reads it
@@ -999,6 +1006,9 @@ class ContinuousBatchingEngine:
 
                     return prefill_program(run)
 
+                if self._window_tables is not None:
+                    compiled_suffix_prefill = self._windowed_suffix_prefill(
+                        prefill_program)
                 self._suffix_prefill = compiled_suffix_prefill
         # Cache-aware admission: scan a bounded window of the pending
         # queue and admit the admissible request with the hottest
@@ -1011,6 +1021,10 @@ class ContinuousBatchingEngine:
         self._admit_skip_cap = 16
         self._prefill_tokens_total = 0
         self._prefill_tokens_skipped = 0
+        # Under a window a suffix program starts below its match: of the
+        # matched tokens, those it computed again (0 for any other pool).
+        self._prefill_tokens_matched = 0
+        self._prefill_tokens_recomputed = 0
         self._hit_window: collections.deque = collections.deque(maxlen=64)
         self._hit_window_min = 8
 
@@ -1519,8 +1533,13 @@ class ContinuousBatchingEngine:
                              prefill_len: int) -> int:
         """Per-admission radix-reuse accounting: counters, the rolling
         hit-rate gauge, and the request's cached-token stamp. Returns
-        the prefill tokens to skip."""
-        skip = min(res.matched_tokens, prefill_len)
+        the prefill tokens to skip: those matched, or under a window
+        those below the suffix program's start."""
+        skip = matched = min(res.matched_tokens, prefill_len)
+        if matched and self._window_tables is not None:
+            skip = self._pool.suffix_start(matched)
+            self._prefill_tokens_recomputed += matched - skip
+        self._prefill_tokens_matched += matched
         req.prefix_cached_tokens = skip
         outcome = ("full" if skip >= prefill_len
                    else "partial" if skip > 0 else "miss")
@@ -1658,6 +1677,10 @@ class ContinuousBatchingEngine:
                 row_t, row_d, pos0, tok0]
             return
         if prefill_tokens:
+            # What the tree matched; `skip` is where the computation
+            # starts, which only a pool with a window space puts lower.
+            matched = (min(admit_res.matched_tokens, len(prefill_tokens))
+                       if admit_res else 0)
             if skip >= len(prefill_tokens):
                 # Whole prefill served from the radix cache:
                 # every page is already written — no program
@@ -1667,21 +1690,23 @@ class ContinuousBatchingEngine:
                         "prefill", mode="cached",
                         prompt_tokens=len(prefill_tokens),
                         cached_tokens=skip)
-            elif skip > 0 and self._suffix_prefill is not None:
+            elif matched > 0 and self._suffix_prefill is not None:
                 # Partial hit: compute KV only for the novel
                 # suffix, attending the matched prefix pages
                 # gathered from the pool — O(S·P) instead of
-                # the full O(P²) recompute.
+                # the full O(P²) recompute. Under a window the
+                # suffix starts below the match (`skip` < `matched`).
                 if req.trace is not None:
                     req.trace.start_phase(
                         "prefill", mode="suffix",
                         prompt_tokens=len(prefill_tokens),
                         cached_tokens=skip,
+                        recomputed_tokens=matched - skip,
                         state_pages_written=self._state_pages(
                             skip, len(prefill_tokens)))
                 suffix = prefill_tokens[skip:]
-                n_pref = -(-skip // self._pool.page_size)
-                bucket = bucket_suffix_len(len(suffix))
+                n_pref = -(-matched // self._pool.page_size)
+                bucket = self._suffix_bucket(len(suffix), matched - skip)
                 padded = np.zeros(bucket, np.int32)
                 padded[:len(suffix)] = suffix
                 fn = self._suffix_prefill(bucket, n_pref)
@@ -1690,7 +1715,7 @@ class ContinuousBatchingEngine:
                     jnp.asarray([padded], jnp.int32),
                     self._cache,
                     jnp.asarray(self._pool.padded_row(b)),
-                    jnp.int32(skip),
+                    jnp.int32(matched),
                     jnp.int32(len(suffix)), *self._row_arg(b))
             else:
                 if req.trace is not None:
@@ -1734,6 +1759,50 @@ class ContinuousBatchingEngine:
         if temp_bytes is not None:
             self._prefill_temp_bytes = max(
                 self._prefill_temp_bytes or 0, temp_bytes)
+
+    def _suffix_bucket(self, n: int, recomputed: int) -> int:
+        """The padded length of a suffix program over ``n`` tokens, the
+        first ``recomputed`` of them matched ones computed again (under
+        a window; 0 elsewhere): that stretch as it is, `bucket_suffix_len`
+        of the novel ones behind it, and under a window the sum rounded
+        up to the family's whole flash tiles, which its sequence passes
+        pad to anyway: fewer programs for the same work."""
+        bucket = recomputed + bucket_suffix_len(max(n - recomputed, 1))
+        if self._window_tables is not None:
+            tile = self._family_mod.PREFILL_TILE
+            bucket = -(-bucket // tile) * tile
+        return bucket
+
+    def _windowed_suffix_prefill(self, prefill_program):
+        """`_suffix_prefill` for a pool with a window space: the program
+        over a run that starts below the match (`WindowedPagePool.
+        suffix_start`; both are plain numbers, such a pool matches whole
+        pages). Its full layers read the matched pages and write from
+        the match on, its window layers start empty and write the row's
+        own window pages (the family's suffix surface, ``models/
+        smallthinker.py``)."""
+        family, cfg, ps = self._family_mod, self.cfg, self._pool.page_size
+
+        @lru_cache(maxsize=32)
+        def compiled_suffix_prefill(slen: int, n_pref: int):
+            matched = n_pref * ps
+            start = self._pool.suffix_start(matched)
+
+            def run(params, suffix, cache, page_ids, m, real_len):
+                del m  # `matched`, told by the count of pages
+                pref = jnp.maximum(page_ids[0, :n_pref], 0)
+                novel = family.paged_prefill_suffix_kv(
+                    cfg, params, suffix,
+                    *family.paged_gather_prefix(cache, pref), start)
+                return family.paged_insert_suffix(
+                    cache, *novel, page_ids, start, matched, real_len)
+
+            # A profile tells this program from a whole-prompt one by
+            # its module's name, `jit_run.suffix`; `jit_run` finds both.
+            run.__name__ = "run.suffix"
+            return prefill_program(run)
+
+        return compiled_suffix_prefill
 
     def _row_arg(self, row: int) -> tuple:
         """What a prefill program is told beside the block table: the
@@ -2168,6 +2237,11 @@ class ContinuousBatchingEngine:
                 # refcount/CoW accounting bug — bench and CI fail it).
                 "prefill_tokens_total": self._prefill_tokens_total,
                 "prefill_tokens_skipped": self._prefill_tokens_skipped,
+                # Tokens the tree matched, and those of them a suffix
+                # program computed again (under a window; 0 elsewhere):
+                # skipped = matched - recomputed.
+                "prefill_tokens_matched": self._prefill_tokens_matched,
+                "prefill_tokens_recomputed": self._prefill_tokens_recomputed,
                 "kv_prefix_hit_rate": (
                     round(self._prefill_tokens_skipped
                           / self._prefill_tokens_total, 4)

@@ -736,6 +736,22 @@ class PagePool:
         return self.tables[slot].copy()
 
 
+def window_suffix_start(matched: int, window: int, window_layers: int,
+                        page_size: int) -> int:
+    """Where the suffix program behind ``matched`` tokens starts in a
+    model with ``window_layers`` layers that attend the last ``window``
+    positions and start empty there: the page boundary at or below
+    ``matched - window x window_layers``, 0 for a shorter match (which
+    saves the full layers' writes and no computation: a pool none of
+    whose rows can hold a longer one matches nothing). Never above
+    ``matched - window - (window - 1) x (window_layers - 1)``, the
+    highest start from which every window layer's K and V over
+    ``[matched - window, matched)`` come out exact (``models/
+    smallthinker.py``)."""
+    below = matched - window * window_layers
+    return max(0, below // page_size * page_size)
+
+
 class WindowedPagePool(PagePool):
     """A pool for a model whose layers are of two kinds: *full* layers
     keep every position of a row, *window* layers attend the last
@@ -756,22 +772,39 @@ class WindowedPagePool(PagePool):
     every row's longest chain (``slots`` x that many pages, plus
     scratch page 0), so only the full space ever makes a request wait.
 
-    A matched prefix could be adopted only where its last ``window``
-    positions are still resident in the window layers, which no retired
-    row's are for long: this pool matches nothing (`match_nothing`).
+    **A shared prefix.** The radix tree indexes the full space's pages
+    and shares them between rows as `PagePool` does (refcounts, LRU
+    eviction), by whole pages: the suffix program takes the match's
+    length from the pages it is handed, and the window space has no page
+    to fork. The window space is shared with nobody. A row that adopts
+    ``m`` matched tokens still needs, in every window layer, K and V of
+    the ``window`` positions below ``m``, which no retired row keeps: the
+    engine's suffix program starts at `suffix_start` (``m``), far enough
+    below the match that every window layer's come out exact (``models/
+    smallthinker.py`` says why), and writes them into the row's own
+    window pages. What the pool adds for it is that one number;
+    nothing is pinned, so eviction and release are the full space's.
+    Where that stretch, ``window x window_layers``, is as long as a row
+    may grow, no match can skip a position, and the pool matches nothing
+    (`set_window_layers`): a tree that saves nothing still costs its
+    evictions, a walk of its nodes a page once the free list is dry.
 
     Which kind a pool is is decided once, where the engine builds it; a
     model without window layers gets a plain `PagePool` and runs none of
     this."""
 
     def __init__(self, slots: int, max_len: int, page_size: int,
-                 n_pages: int, window: int):
+                 n_pages: int, window: int, window_layers: int = 1,
+                 prefix_cache: bool = True):
         super().__init__(slots, max_len, page_size, n_pages,
-                         prefix_cache=False)
+                         prefix_cache=prefix_cache)
         if window < page_size or window % page_size:
             raise ValueError(f"window {window} is not whole pages of "
                              f"{page_size}")
+        self.whole_page_matches = True
         self.window = window
+        self.max_len = max_len
+        self.set_window_layers(window_layers)
         self.window_pages_per_row = min(window // page_size + 1,
                                         self.max_pages_per_row)
         self.window_n_pages = slots * self.window_pages_per_row + 1
@@ -794,24 +827,40 @@ class WindowedPagePool(PagePool):
             return stop - first <= len(self._window_free)
 
     def can_admit(self, length: int, tokens=None) -> bool:
-        return self._window_room(length) and super().can_admit(length, None)
+        return self._window_room(length) and super().can_admit(length, tokens)
 
     def admissible_match(self, length: int, tokens=None) -> Optional[int]:
         if not self._window_room(length):
             return None
-        return super().admissible_match(length, None)
+        return super().admissible_match(length, tokens)
+
+    def set_window_layers(self, n: int) -> None:
+        """How many window layers a suffix program walks (`suffix_start`):
+        the engine reads it off the cache it builds, before any
+        admission. Where ``window x n`` reaches the rows' length every
+        suffix program would start at 0: the pool then matches nothing
+        (`match_nothing`), as for a cache a match cannot resume from."""
+        self.window_layers = n
+        if self.prefix_cache and self.window * n >= self.max_len:
+            self.match_nothing()
+
+    def suffix_start(self, matched: int) -> int:
+        """`window_suffix_start` for this pool's window and layers."""
+        return window_suffix_start(matched, self.window, self.window_layers,
+                                   self.page_size)
 
     # -------------------------------------------------------- allocation
     def admit(self, slot: int, length: int,
               tokens: Optional[list] = None) -> Optional[AdmitResult]:
-        """Every page of positions 0..length-1 in the full space, the
+        """Every page of positions 0..length-1 in the full space
+        (matched ones adopted from the tree, as `PagePool.admit`), the
         last `window_pages_per_row` of them at most in the window
-        space; None = nothing allocated in either."""
+        space, the row's own; None = nothing allocated in either."""
         assert (self.window_tables[slot] < 0).all(), \
             f"slot {slot} admitted while still holding window pages"
         if not self._window_room(length):
             return None
-        result = super().admit(slot, length, None)
+        result = super().admit(slot, length, tokens)
         if result is None:
             return None
         first, stop = self._window_span(length)

@@ -323,8 +323,12 @@ SURFACES = {
              "paged_gather": 2, "paged_gather_prefix": 3,
              "paged_prefill_suffix_kv": 8, "paged_insert_suffix": 9,
              "paged_insert_prefill": 7},
+    # Two page spaces; behind a shared prefix the run's start and the
+    # match are plain numbers (a pool with a window space matches whole
+    # pages).
     "smallthinker": {**EVERY, "paged_init_cache": 4, "paged_window": 1,
-                     "paged_insert_prefill": 8},
+                     "paged_insert_prefill": 8, "paged_gather_prefix": 2,
+                     "paged_prefill_suffix_kv": 6, "paged_insert_suffix": 9},
 }
 SURFACES["nemotron_h"] = SURFACES["qwen3_next"] = SURFACES["rows"]
 CONFIG_CLASSES = {"lfm2": "Lfm2Config", "nemotron_h": "NemotronHConfig",

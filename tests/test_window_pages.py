@@ -18,16 +18,23 @@ PAGE = 16
 
 
 def _schedule(seed: int, slots: int, max_len: int, steps: int):
-    """A random run of an engine's calls: ("admit", slot, length),
-    ("step", slot) one position on, ("release", slot)."""
+    """A random run of an engine's calls: ("admit", slot, prompt),
+    ("step", slot) one position on, ("release", slot). A prompt is one
+    of three shared documents cut somewhere and a tail of its own, so
+    that the radix tree has matches to offer."""
     rng = np.random.default_rng(seed)
+    documents = rng.integers(0, 50, (3, max_len // 2))
     pos = [-1] * slots
     for _ in range(steps):
         b = int(rng.integers(slots))
         if pos[b] < 0:
             n = int(rng.integers(2, max_len // 2))
+            shared = int(rng.integers(0, n))
+            prompt = np.concatenate([
+                documents[int(rng.integers(3))][:shared],
+                rng.integers(50, 100, n - shared)]).astype(np.int32)
             pos[b] = n - 1
-            yield "admit", b, n
+            yield "admit", b, prompt
         elif pos[b] + 1 >= max_len or rng.random() < 0.02:
             pos[b] = -1
             yield "release", b, 0
@@ -42,26 +49,42 @@ def test_random_schedule_keeps_both_spaces_sound(seed, window):
     stays clean, no row ever holds more than window/page + 1 window
     pages and every one of them lies within the window, a released page
     is on the free list at once (and is the next one taken), and the
-    full space's tables and free count are, call for call, a plain
-    `PagePool`'s."""
+    full space's tables, matches, evictions and free count are, call for
+    call, those of a plain `PagePool` that matches whole pages: the
+    radix tree shares the full space's pages under a window as it does
+    without one, and the window space is shared with nobody."""
     slots, max_len, n_pages = 4, 512, 96
-    pool = WindowedPagePool(slots, max_len, PAGE, n_pages, window=window)
-    plain = PagePool(slots, max_len, PAGE, n_pages, prefix_cache=False)
+    pool = WindowedPagePool(slots, max_len, PAGE, n_pages, window=window,
+                            window_layers=3)
+    plain = PagePool(slots, max_len, PAGE, n_pages)
+    plain.whole_page_matches = True
+    assert pool.prefix_cache and pool.whole_page_matches
     most = window // PAGE + 1
     assert pool.window_pages_per_row == most
     assert pool.window_n_pages == slots * most + 1
     live = {}
     for what, b, arg in _schedule(seed, slots, max_len, 3000):
         if what == "admit":
-            fits = plain.can_admit(arg)
-            assert pool.can_admit(arg) == fits
+            n = len(arg)
+            fits = plain.can_admit(n, arg)
+            assert pool.can_admit(n, arg) == fits
             # The engine's pick asks both questions in one call: the
-            # same answer from either pool (nothing matches in either).
-            assert (pool.admissible_match(arg) == plain.admissible_match(arg)
-                    == (0 if fits else None))
-            assert bool(pool.admit(b, arg)) == bool(plain.admit(b, arg)) == fits
+            # same answer from either pool.
+            offered = plain.admissible_match(n, arg)
+            assert pool.admissible_match(n, arg) == offered
+            assert (offered is not None) == fits
+            mine, theirs = pool.admit(b, n, arg), plain.admit(b, n, arg)
+            assert bool(mine) == bool(theirs) == fits
             if fits:
-                live[b] = arg - 1
+                assert mine == theirs and mine.cow is None
+                assert mine.matched_tokens == offered
+                assert mine.matched_tokens % PAGE == 0
+                start = pool.suffix_start(mine.matched_tokens)
+                assert start % PAGE == 0
+                assert start == max(0, mine.matched_tokens - 3 * window)
+                pool.commit_prefix(b)
+                plain.commit_prefix(b)
+                live[b] = n - 1
         elif what == "release":
             if b in live:
                 del live[b]
@@ -101,6 +124,8 @@ def test_random_schedule_keeps_both_spaces_sound(seed, window):
     stats = pool.window_stats()
     assert stats["live"] == 0 and stats["free"] == stats["total"]
     assert pool.check_invariants() == []
+    assert pool.prefix_hits == plain.prefix_hits > 0
+    assert pool.prefix_evictions == plain.prefix_evictions > 0
 
 
 def test_a_released_window_page_is_reusable_at_once():
@@ -155,26 +180,37 @@ def test_window_pool_counts_a_broken_window_space():
     assert any("leaked" in line for line in pool.check_invariants())
 
 
-@pytest.mark.parametrize("model", ["llama_tiny", "kimi_k2_tiny"])
-def test_an_engine_without_window_layers_builds_the_plain_pool(model):
-    """Decided once, where the pool is built: a llama engine has a
-    `PagePool`, no window tables, and no `step.window` leaf; so has a
-    family whose pages hold a latent a token, and the radix tree matches
-    for both (a page of either carries no state)."""
+def _engine(model, **args):
     from polyaxon_tpu.models import family_of
     from polyaxon_tpu.serving.batching import ContinuousBatchingEngine
 
     family = family_of(model)
     cfg = family.CONFIGS[model]
     params = family.init(cfg, jax.random.key(0))["params"]
-    engine = ContinuousBatchingEngine(model, cfg, params, slots=2,
-                                      kv="paged", page_size=4, kv_pages=32)
+    return ContinuousBatchingEngine(model, cfg, params, slots=2, kv="paged",
+                                    page_size=4, kv_pages=32, **args)
+
+
+@pytest.mark.parametrize("model,whole_pages,matches", [
+    ("llama_tiny", False, True), ("kimi_k2_tiny", False, True),
+    ("moe_tiny", False, True), ("lfm2_tiny", True, True),
+    ("qwen3_next_tiny", False, False), ("nemotron_h_tiny", False, False)])
+def test_an_engine_without_window_layers_builds_the_plain_pool(
+        model, whole_pages, matches):
+    """Decided once, where the pool is built: a llama engine has a
+    `PagePool`, no window tables, no `step.window` leaf and nothing of
+    a suffix that starts below its match; so has a family whose pages
+    hold a latent a token, one whose pages hold a state (whole pages
+    only) and one whose rows do (no match at all): each is handed the
+    pool it was before a window family's learned to share."""
+    engine = _engine(model)
     try:
         assert type(engine._pool) is PagePool
-        assert engine._pool.prefix_cache
-        assert not engine._pool.whole_page_matches
+        assert engine._pool.prefix_cache == matches
+        assert engine._pool.whole_page_matches == whole_pages
         assert engine._window_tables is None
-        out = engine.generate([[1, 2, 3, 4, 5]], 6)
+        assert not hasattr(engine._pool, "suffix_start")
+        out = engine.generate([[1, 2, 3, 4, 5, 6, 7, 8, 9, 10]] * 2, 6)
         stats = engine.stats()
     finally:
         engine.stop()
@@ -182,6 +218,37 @@ def test_an_engine_without_window_layers_builds_the_plain_pool(model):
     assert "step.window" not in stats["tick_phase_ns"]
     assert not any(key.startswith("kv_window") for key in stats)
     assert stats["kv_pages_free"] == 32
+    assert stats["prefill_tokens_recomputed"] == 0
+    assert stats["prefill_tokens_matched"] == stats["prefill_tokens_skipped"]
+
+
+@pytest.mark.parametrize("model,layers", [("smallthinker_tiny", 3),
+                                          ("exaone_moe_tiny", 3)])
+def test_an_engine_with_window_layers_builds_the_pool_that_shares(model,
+                                                                  layers):
+    """A family with window layers is given both spaces, the radix tree
+    over the full one (whole pages) and the count of window layers a
+    suffix program walks, read off the cache; `prefix_cache=False`
+    reaches this pool as it reaches the plain one."""
+    engine = _engine(model)
+    try:
+        assert type(engine._pool) is WindowedPagePool
+        assert engine._pool.prefix_cache and engine._pool.whole_page_matches
+        assert engine._pool.window_layers == layers
+        assert engine._suffix_prefill is not None
+    finally:
+        engine.stop()
+    # `prefix_cache=False` reaches this pool; and rows no longer than
+    # `window x layers` could skip nothing behind a match, so that pool
+    # matches nothing, as it did before it learned to share.
+    for args in (dict(prefix_cache=False),
+                 dict(max_len=engine._pool.window * layers)):
+        engine = _engine(model, **args)
+        try:
+            assert not engine._pool.prefix_cache
+            assert engine._pool.radix_stats()["pages"] == 0
+        finally:
+            engine.stop()
 
 
 def _gathered_reference(q, k_pool, v_pool, layer, tables, pos, window):
